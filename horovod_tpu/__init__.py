@@ -19,12 +19,6 @@ Layer map (TPU analog of reference SURVEY §1):
 
 from horovod_tpu.version import __version__  # noqa: F401
 
-# Bridge old/new jax spellings (jax.shard_map vs experimental.shard_map)
-# before any submodule builds a step function.
-from horovod_tpu.common import jax_compat as _jax_compat
-
-_jax_compat.install()
-
 from horovod_tpu.common.basics import (  # noqa: F401
     ccl_built,
     cross_rank,

@@ -3,8 +3,8 @@ the BERT flagship.
 
 The reference benchmarks encoder pretraining only (docs/benchmarks.rst
 protocol); a causal LM is where the Pallas flash kernel's traced loop
-bound pays off (future k-blocks cost zero MXU work — ops/flash_attention
-measured 1.5-3.8x over XLA dot attention at 2k-8k tokens). Same TPU-first
+bound pays off (future k-blocks cost zero MXU work; its speed against
+XLA dot attention is not measured on today's code). Same TPU-first
 recipe as the encoder: bf16 activations on the MXU, fp32 params, pre-LN
 residual blocks, static shapes.
 """

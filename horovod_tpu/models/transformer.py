@@ -20,11 +20,11 @@ from horovod_tpu.ops.flash_attention import attention
 class FlashSelfAttention(nn.Module):
     """Self-attention whose core is the length-routed attention op
     (ops/flash_attention.py): same q/k/v/out projection geometry as
-    ``nn.MultiHeadDotProductAttention``. At/above the measured crossover
+    ``nn.MultiHeadDotProductAttention``. At/above the crossover
     (HOROVOD_FLASH_MIN_SEQ, default 1024) the Pallas flash kernel runs and
-    the [T, T] score matrix never touches HBM; below it plain XLA dot
-    attention wins (BENCH_r05: flash was 16% slower at seq 128) and the
-    router uses that instead. Bidirectional (BERT) by default; set
+    the [T, T] score matrix never touches HBM; below it the router uses
+    plain XLA dot attention (an earlier chip run, no longer on file, had
+    flash 16% slower at seq 128; not measured on today's code). Bidirectional (BERT) by default; set
     ``causal`` for decoder use."""
 
     heads: int

@@ -43,11 +43,12 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30  # large-negative instead of -inf: keeps exp() NaN-free
 
-# Short-sequence crossover for the auto-router (:func:`attention`). Measured
-# on v5e (BENCH_r05): plain XLA dot attention beats the Pallas kernel at seq
-# 128 (980 vs 820 seqs/s on BERT-Base — the score tiles are too small to
-# fill the grid), flash wins from ~2k (1.5x) through 8k (3+x). Sequences
-# shorter than this route to XLA; override with HOROVOD_FLASH_MIN_SEQ.
+# Short-sequence crossover for the auto-router (:func:`attention`). An
+# earlier chip run, no longer on file, had plain XLA dot attention ahead of
+# the Pallas kernel at seq 128 on BERT-Base (the score tiles are too small
+# to fill the grid) and flash ahead from ~2k through 8k; not measured on
+# today's code. Sequences shorter than this route to XLA; override with
+# HOROVOD_FLASH_MIN_SEQ.
 DEFAULT_FLASH_MIN_SEQ = 1024
 
 
@@ -417,13 +418,14 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array,
               sm_scale: Optional[float] = None,
               min_flash_seq: Optional[int] = None,
               **flash_kwargs) -> jax.Array:
-    """Length-routed attention: XLA dot attention below the measured
-    crossover, the Pallas flash kernel at/above it.
+    """Length-routed attention: XLA dot attention below the crossover,
+    the Pallas flash kernel at/above it.
 
-    BENCH_r05 showed ``use_flash=True`` costing 16% at seq 128 — a kernel
-    built for long context has nothing to amortize on tiny score tiles.
-    This router keeps the long-context win (3x+ at 8k causal) without
-    making short-sequence models pay for it. Routing keys on the KV length
+    A kernel built for long context has nothing to amortize on tiny score
+    tiles (an earlier chip run, no longer on file, had ``use_flash=True``
+    costing 16% at seq 128; not measured on today's code). This router
+    keeps the long-context path without making short-sequence models pay
+    for it. Routing keys on the KV length
     (the side that grows the score matrix). Semantics-bearing flash-only
     features (``return_lse``, ``q_offset``/``k_offset``) force the flash
     path regardless of length — the XLA path cannot honor them, and

@@ -245,7 +245,8 @@ def test_xla_attention_matches_dense_reference():
 
 def test_bert_short_seq_uses_router(monkeypatch):
     """BertBase(use_flash=True) at seq 128 must not invoke the Pallas
-    kernel — the regression BENCH_r05 caught (flash 16% slower there)."""
+    kernel (an earlier chip run, no longer on file, had flash 16% slower
+    there)."""
     from horovod_tpu.models.transformer import BertEncoder
     from horovod_tpu.ops import flash_attention as fa
 

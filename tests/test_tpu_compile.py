@@ -1,0 +1,126 @@
+"""Compiles for a described TPU v5e 2x2, with no chip attached.
+
+The TPU compiler is installed beside JAX and compiles for a topology that is
+described, not attached. It refuses what interpret mode cannot see: a slice
+not aligned to the tiling, a kernel that wants more VMEM than it may use, a
+program that does not fit the device. Nothing runs, so these cases say
+nothing about results or times. A compile that passes is not a chip run.
+
+Code that asks ``jax.default_backend()`` sees the CPU here, so the kernels
+get ``interpret=False`` from the test.
+"""
+
+import functools
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or it logs under /tmp
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import optax  # noqa: E402
+import pytest  # noqa: E402
+from jax.sharding import (NamedSharding, PartitionSpec as P,  # noqa: E402
+                          SingleDeviceSharding)
+
+from horovod_tpu.ops import flash_attention as fa  # noqa: E402
+
+HEADS = 12
+BLOCK = 512  # flash_attention's default block for these lengths
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except (RuntimeError, NotImplementedError, ImportError) as e:
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e!r}")
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _no_persistent_cache():
+    """A compile for a described device is written to the persistent cache
+    but cannot be read back without a chip; the next one would warn."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    old = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", old)
+    cc.reset_cache()
+
+
+def _forward(causal, scale, q, k, v, o, lse, do, off):
+    return fa._flash_fwd(q, k, v, off, off, causal, scale, BLOCK, BLOCK,
+                         False)[:2]
+
+
+def _backward(pick, causal, scale, q, k, v, o, lse, do, off):
+    # the two backward kernels share one function; the one whose outputs
+    # are dropped is dead code to the compiler
+    grads = fa._flash_bwd(causal, scale, BLOCK, BLOCK, False,
+                          (q, k, v, o, lse, off, off), (do, None))
+    return pick(grads)
+
+
+KERNELS = {
+    "forward": _forward,
+    "dq": functools.partial(_backward, lambda g: g[0]),
+    "dkv": functools.partial(_backward, lambda g: g[1:3]),
+}
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("seq,head_dim", [(1024, 64), (8192, 64),
+                                          (8192, 128)])
+@pytest.mark.parametrize("kernel", list(KERNELS))
+def test_flash_kernel_compiles_for_v5e(topo, kernel, seq, head_dim, causal):
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    x = arg((1, seq, HEADS, head_dim), jnp.bfloat16)
+    lse = arg((HEADS, 1, seq), jnp.float32)
+    off = arg((1,), jnp.float32)
+    fn = functools.partial(KERNELS[kernel], causal, head_dim ** -0.5)
+    text = jax.jit(fn).lower(x, x, x, x, lse, x, off).compile().as_text()
+    assert text.count("tpu_custom_call") == 1, text.count("tpu_custom_call")
+
+
+def test_gpt2_width_dp_step_compiles_for_four_v5e(topo, monkeypatch):
+    """One whole ``dp.make_train_step`` of a two-layer decoder at GPT-2 small
+    widths, T = 1024 and 8 sequences per chip, on the four described
+    devices: the kernels inside a real step, and the gradient all-reduce."""
+    from horovod_tpu.models import GptSmall
+    from horovod_tpu.parallel import dp, mesh as mesh_lib
+
+    monkeypatch.setattr(fa, "flash_attention", functools.partial(
+        fa.flash_attention, interpret=False))
+    mesh = mesh_lib.data_parallel_mesh(topo.devices)
+    model = GptSmall().clone(layers=2)
+    opt = optax.adamw(1e-4)
+
+    def loss_fn(params, batch, rng):
+        logits = model.apply({"params": params}, batch["tokens"])
+        return optax.softmax_cross_entropy_with_integer_labels(
+            logits, batch["labels"]).mean(), {}
+
+    def on_mesh(tree, spec):
+        sharding = NamedSharding(mesh, spec)
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=sharding), tree)
+
+    tokens = jax.ShapeDtypeStruct((8 * mesh.devices.size, model.max_len),
+                                  jnp.int32)
+    params = jax.eval_shape(model.init, jax.random.key(0), tokens)["params"]
+    step = dp.make_train_step(loss_fn, opt, mesh)
+    text = step.lower(
+        on_mesh(params, P()), on_mesh(jax.eval_shape(opt.init, params), P()),
+        on_mesh({"tokens": tokens, "labels": tokens}, P(dp.DP_AXES)),
+        on_mesh(jax.eval_shape(lambda: jax.random.key(1)), P()),
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") == 3 * model.layers
+    assert "all-reduce" in text
