@@ -39,6 +39,11 @@ from horovod_tpu.metrics.registry import (  # noqa: F401
     get_registry,
 )
 from horovod_tpu.metrics.straggler import StragglerDetector  # noqa: F401
+from horovod_tpu.profiler.annotate import (
+    STEP_DISPATCH_SPAN,
+    host_annotation,
+    step_annotation,
+)
 
 # Family names shared by every frontend step timer (keras callback, torch
 # optimizer, the jax make_train_step wrapper) — the driver's straggler
@@ -64,7 +69,12 @@ class _TimedStep:
     bracketed with engine STEP_BEGIN/STEP_END flight marks and fed to the
     rolling anomaly detector (horovod_tpu.obs.attribution) — one lock-free
     engine record each side plus a deque append, cheap enough for every
-    step."""
+    step.
+
+    While a ``jax.profiler`` trace is being collected each invocation also
+    writes an ``hvd.step`` span (``step_num`` = this wrapper's call count)
+    holding an ``hvd.step.dispatch`` span around the wrapped call; with no
+    trace running both are an object and a flag test."""
 
     def __init__(self, fn, framework: str):
         self._fn = fn
@@ -74,6 +84,7 @@ class _TimedStep:
                                              framework=framework)
         self._attr = None
         self._attr_resolved = False
+        self._calls = 0  # step_num of the next hvd.step span
 
     def __call__(self, *args, **kwargs):
         if not self._attr_resolved:
@@ -82,16 +93,22 @@ class _TimedStep:
             self._attr = _get_attributor()
             self._attr_resolved = True
         attr = self._attr
-        sid = attr.next_step() if attr is not None else 0
-        if attr is not None:
-            attr.step_begin(sid)
-        t0 = time.perf_counter()
-        out = self._fn(*args, **kwargs)
-        dt = time.perf_counter() - t0
-        self._hist.observe(dt)
-        self._steps.inc()
-        if attr is not None:
-            attr.step_end(sid, dt)
+        # the wrapper's own spans, on the profiler's clock beside the device
+        # planes: hvd.step around everything, hvd.step.dispatch around the
+        # wrapped call; the difference is what this wrapper costs
+        with step_annotation(self._calls):
+            self._calls += 1
+            sid = attr.next_step() if attr is not None else 0
+            if attr is not None:
+                attr.step_begin(sid)
+            t0 = time.perf_counter()
+            with host_annotation(STEP_DISPATCH_SPAN):
+                out = self._fn(*args, **kwargs)
+            dt = time.perf_counter() - t0
+            self._hist.observe(dt)
+            self._steps.inc()
+            if attr is not None:
+                attr.step_end(sid, dt)
         return out
 
     def __getattr__(self, item):

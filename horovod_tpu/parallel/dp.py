@@ -27,6 +27,7 @@ from horovod_tpu.ops.fusion import fused_apply_tree
 from horovod_tpu.parallel import collectives, zero
 from horovod_tpu.parallel.collectives import Average, Op
 from horovod_tpu.parallel.zero import sharded_opt_init  # noqa: F401 (re-export)
+from horovod_tpu.profiler.annotate import step_phase
 
 # The replica axes a pure-DP step reduces over.
 DP_AXES = ("data", "fsdp")
@@ -74,9 +75,12 @@ def _make_param_update(optimizer, op, axes, compression, prescale_factor,
         hierarchical, bucket_bytes)
 
     def apply(grads, opt_state, params):
-        grads = allreduce_grads(grads)
-        updates, new_opt_state = optimizer.update(grads, opt_state, params)
-        return optax.apply_updates(params, updates), new_opt_state
+        with step_phase("grad_exchange"):
+            grads = allreduce_grads(grads)
+        with step_phase("optimizer_update"):
+            updates, new_opt_state = optimizer.update(grads, opt_state,
+                                                      params)
+            return optax.apply_updates(params, updates), new_opt_state
 
     return apply, P()
 
@@ -256,14 +260,17 @@ def make_train_step(loss_fn: Callable,
         # Decorrelate per-replica randomness (dropout etc.) while keeping
         # params identical: fold the replica id into the key.
         rng = jax.random.fold_in(rng, collectives.axis_rank(axes))
-        if bucket_bytes > 0:
-            (loss, aux), grads = _vjp_grads(loss_fn, params, batch, rng)
-        else:
-            (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-                params, batch, rng)
+        with step_phase("forward_backward"):
+            if bucket_bytes > 0:
+                (loss, aux), grads = _vjp_grads(loss_fn, params, batch, rng)
+            else:
+                (loss, aux), grads = jax.value_and_grad(
+                    loss_fn, has_aux=True)(params, batch, rng)
         new_params, new_opt_state = _apply_update(grads, opt_state, params)
-        loss = collectives.allreduce(loss, op=Average, axis=axes)
-        return TrainStepOutput(new_params, new_opt_state, loss, _sync_aux(aux))
+        with step_phase("output_sync"):
+            loss = collectives.allreduce(loss, op=Average, axis=axes)
+            aux = _sync_aux(aux)
+        return TrainStepOutput(new_params, new_opt_state, loss, aux)
 
     batch_spec = P(axes)
     mapped = jax.shard_map(
@@ -338,17 +345,20 @@ def make_stateful_train_step(loss_fn: Callable,
 
     def _local_step(params, opt_state, model_state, batch, rng):
         rng = jax.random.fold_in(rng, collectives.axis_rank(axes))
-        if bucket_bytes > 0:
-            (loss, (new_model_state, aux)), grads = _vjp_grads(
-                loss_fn, params, model_state, batch, rng)
-        else:
-            (loss, (new_model_state, aux)), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(params, model_state, batch, rng)
+        with step_phase("forward_backward"):
+            if bucket_bytes > 0:
+                (loss, (new_model_state, aux)), grads = _vjp_grads(
+                    loss_fn, params, model_state, batch, rng)
+            else:
+                (loss, (new_model_state, aux)), grads = jax.value_and_grad(
+                    loss_fn, has_aux=True)(params, model_state, batch, rng)
         new_params, new_opt_state = _apply_update(grads, opt_state, params)
-        loss = collectives.allreduce(loss, op=Average, axis=axes)
+        with step_phase("output_sync"):
+            loss = collectives.allreduce(loss, op=Average, axis=axes)
+            new_model_state = _sync_state(new_model_state)
+            aux = _sync_state(aux)
         return StatefulTrainStepOutput(new_params, new_opt_state,
-                                       _sync_state(new_model_state), loss,
-                                       _sync_state(aux))
+                                       new_model_state, loss, aux)
 
     mapped = jax.shard_map(
         _local_step, mesh=mesh,

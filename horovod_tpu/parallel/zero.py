@@ -41,6 +41,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from horovod_tpu.parallel import collectives
 from horovod_tpu.parallel.collectives import Average, Op, Sum
+from horovod_tpu.profiler.annotate import step_phase
 
 # Flat groups are padded to a multiple of axis_size * LANE so the layout is
 # identical whether or not the int8 path (which quantizes LANE-sized blocks)
@@ -191,46 +192,52 @@ def apply_sharded_update(optimizer,
 
     g_shards, p_shards = {}, {}
     for group in groups:
-        gflat = _flatten_group(leaves, group, leaf_align)
-        gflat = collectives._scale(gflat, prescale_factor)
-        if quantized:
-            shard = collectives.quantized_reducescatter(
-                gflat, op=op, axis=axes, block_size=block_size)
-            shard = shard.astype(group.dtype)
-        elif compression is not None:
-            wire, ctx = compression.compress(gflat)
-            shard = collectives.reducescatter(wire, op=op, axis=axes)
-            shard = compression.decompress(shard, ctx)
-        else:
-            shard = collectives.reducescatter(gflat, op=op, axis=axes)
-        g_shards[group.key] = collectives._scale(shard, postscale_factor)
-        pflat = _flatten_group(p_leaves, group, leaf_align)
-        p_shards[group.key] = _local_shard(pflat, rank, group.shard)
+        with step_phase("grad_exchange"):
+            gflat = _flatten_group(leaves, group, leaf_align)
+            gflat = collectives._scale(gflat, prescale_factor)
+            if quantized:
+                shard = collectives.quantized_reducescatter(
+                    gflat, op=op, axis=axes, block_size=block_size)
+                shard = shard.astype(group.dtype)
+            elif compression is not None:
+                wire, ctx = compression.compress(gflat)
+                shard = collectives.reducescatter(wire, op=op, axis=axes)
+                shard = compression.decompress(shard, ctx)
+            else:
+                shard = collectives.reducescatter(gflat, op=op, axis=axes)
+            g_shards[group.key] = collectives._scale(shard, postscale_factor)
+        with step_phase("optimizer_update"):
+            pflat = _flatten_group(p_leaves, group, leaf_align)
+            p_shards[group.key] = _local_shard(pflat, rank, group.shard)
 
-    local_state = jax.tree_util.tree_map(lambda s: jnp.squeeze(s, 0),
-                                         opt_state)
-    updates, new_state = optimizer.update(g_shards, local_state, p_shards)
+    with step_phase("optimizer_update"):
+        local_state = jax.tree_util.tree_map(lambda s: jnp.squeeze(s, 0),
+                                             opt_state)
+        updates, new_state = optimizer.update(g_shards, local_state,
+                                              p_shards)
+        new_state = jax.tree_util.tree_map(lambda s: s[None], new_state)
 
     update_leaves = [None] * len(leaves)
-    for group in groups:
-        u = updates[group.key]
-        if quantized:
-            full = collectives.quantized_allgather(
-                u, axis=axes, block_size=block_size).astype(group.dtype)
-        elif compression is not None:
-            # dtype-cast compression rides BOTH phases (the wire-byte
-            # accounting in bench.py assumes it)
-            wire, ctx = compression.compress(u)
-            full = lax.all_gather(wire, axes, axis=0, tiled=True)
-            full = compression.decompress(full, ctx)
-        else:
-            full = lax.all_gather(u, axes, axis=0, tiled=True)
-        for i, leaf in zip(group.indices,
-                           _unflatten_group(full, group, leaf_align)):
-            update_leaves[i] = leaf
-    updates_tree = jax.tree_util.tree_unflatten(treedef, update_leaves)
-    new_params = optax.apply_updates(params, updates_tree)
-    new_state = jax.tree_util.tree_map(lambda s: s[None], new_state)
+    with step_phase("param_gather"):
+        for group in groups:
+            u = updates[group.key]
+            if quantized:
+                full = collectives.quantized_allgather(
+                    u, axis=axes, block_size=block_size).astype(group.dtype)
+            elif compression is not None:
+                # dtype-cast compression rides BOTH phases (the wire-byte
+                # accounting in bench.py assumes it)
+                wire, ctx = compression.compress(u)
+                full = lax.all_gather(wire, axes, axis=0, tiled=True)
+                full = compression.decompress(full, ctx)
+            else:
+                full = lax.all_gather(u, axes, axis=0, tiled=True)
+            for i, leaf in zip(group.indices,
+                               _unflatten_group(full, group, leaf_align)):
+                update_leaves[i] = leaf
+        updates_tree = jax.tree_util.tree_unflatten(treedef, update_leaves)
+    with step_phase("optimizer_update"):
+        new_params = optax.apply_updates(params, updates_tree)
     return new_params, new_state
 
 

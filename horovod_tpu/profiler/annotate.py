@@ -2,6 +2,11 @@
 
 Two distinct mechanisms, matching where the work actually happens:
 
+- :func:`step_phase` — the compiled training step's own phases
+  (:data:`PHASES`), as ``phase_<name>`` named scopes: the step builders of
+  ``parallel/dp.py`` and ``parallel/zero.py`` write them, so every device
+  operation of a step says whether it is forward/backward, gradient
+  exchange, optimizer update, parameter gather or output sync.
 - :func:`collective_scope` — ``jax.named_scope`` for code that runs INSIDE a
   jitted program (the in-jit collectives of ``parallel/collectives.py``).
   The scope becomes HLO op-name metadata, so the device trace of a bench
@@ -10,7 +15,8 @@ Two distinct mechanisms, matching where the work actually happens:
   work (eager engine enqueue, negotiation wait, the data-plane execute
   callback). These appear on the Python/host threads of the same JAX
   profiler trace, which is what lets :mod:`~horovod_tpu.profiler.trace_merge`
-  line engine activity up beside device activity.
+  line engine activity up beside device activity. :func:`step_annotation`
+  is the same for one whole training step (``hvd.step``, with its number).
 
 Both degrade to cheap no-ops when jax is not importable — the torch/TF
 frontends and the engine executor (``common/eager.py``) must stay usable in
@@ -26,6 +32,25 @@ import contextlib
 @contextlib.contextmanager
 def _null_scope():
     yield
+
+
+# The phases of one compiled training step. The scope's prefix is ``phase_``
+# and never ``hvd_``: readers of the compiled text name a collective by the
+# first ``hvd_*`` scope of its op_name, which has to stay the collective's.
+PHASES = ("forward_backward", "grad_exchange", "optimizer_update",
+          "param_gather", "output_sync")
+PHASE_PREFIX = "phase_"
+# Host spans the step wrapper (``metrics.timed_step``) writes.
+STEP_SPAN = "hvd.step"
+STEP_DISPATCH_SPAN = "hvd.step.dispatch"
+
+
+def step_phase(name: str):
+    """Name the enclosed traced ops as one phase of the training step
+    (``phase_<name>`` in HLO op-name metadata; the program is unchanged)."""
+    if name not in PHASES:
+        raise ValueError(f"unknown step phase {name!r}; one of {PHASES}")
+    return collective_scope(PHASE_PREFIX + name)
 
 
 def collective_scope(name: str):
@@ -52,3 +77,15 @@ def host_annotation(name: str, **kwargs):
         return annotation(name, **kwargs)
     except Exception:
         return _null_scope()
+
+
+def step_annotation(step_num: int):
+    """Annotate one whole training step on the host (XProf/Perfetto group
+    device work by it): ``jax.profiler.StepTraceAnnotation``, a no-op
+    without jax and a flag test when no trace is being collected."""
+    try:
+        import jax
+        annotation = jax.profiler.StepTraceAnnotation
+    except (ImportError, AttributeError):
+        return _null_scope()
+    return annotation(STEP_SPAN, step_num=step_num)
