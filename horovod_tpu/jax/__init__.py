@@ -3,7 +3,7 @@
 horovod/tensorflow/__init__.py:568-742).
 
 The reference wraps an imperative optimizer and hooks per-parameter gradient
-callbacks; the optax analog wraps a GradientTransformation so the fused
+callbacks; the optax analog wraps a GradientTransformation so the
 gradient allreduce happens inside the one compiled train step.
 """
 
@@ -82,8 +82,9 @@ def DistributedOptimizer(optimizer: optax.GradientTransformation,
     ``backward_passes_per_step`` (local aggregation, fewer collectives),
     ``gradient_predivide_factor`` (splits the averaging divisor across
     pre/post scaling, reference torch/__init__.py). Use inside shard_map /
-    a mesh context — the reduction is ``lax.psum`` over the DP axes, fused
-    per dtype into single collectives.
+    a mesh context — the reduction is ``lax.psum`` over the DP axes, leaf by
+    leaf (:func:`collectives.allreduce_tree`, as ``make_train_step``); XLA
+    combines the collectives.
     """
     if gradient_predivide_factor != 1.0 and op is not Average:
         raise ValueError("gradient_predivide_factor supported only with Average")
@@ -94,46 +95,30 @@ def DistributedOptimizer(optimizer: optax.GradientTransformation,
         raise ValueError("backward_passes_per_step must be >= 1")
 
     def _reduce(tree):
+        ax = _axes_in_scope(axis)
         if op is Adasum:
             leaves, treedef = jax.tree_util.tree_flatten(tree)
-            outs = collectives.grouped_allreduce(
-                leaves, op=op, axis=_axes_in_scope(axis))
+            outs = collectives.grouped_allreduce(leaves, op=op, axis=ax)
             return jax.tree_util.tree_unflatten(treedef, outs)
+        # Average = sum * (1/size); gradient_predivide_factor splits the
+        # divisor around the wire.
+        scaled = dict(op=op) if gradient_predivide_factor == 1.0 else dict(
+            op=Sum, prescale_factor=1.0 / gradient_predivide_factor,
+            postscale_factor=gradient_predivide_factor
+            / collectives.axis_size(ax))
         if getattr(compression, "quantized", False):
             # int8 block payloads are not psum-reducible — ride the
-            # dequantize-reduce-requantize collective.
-            def red(v):
-                ax = _axes_in_scope(axis)
-                if gradient_predivide_factor != 1.0:
-                    return collectives.quantized_allreduce(
-                        v, op=Sum, axis=ax,
-                        prescale_factor=1.0 / gradient_predivide_factor,
-                        postscale_factor=gradient_predivide_factor
-                        / collectives.axis_size(ax),
-                        block_size=compression.block_size)
-                return collectives.quantized_allreduce(
-                    v, op=op, axis=ax, block_size=compression.block_size)
-        elif gradient_predivide_factor != 1.0:
-            pre = 1.0 / gradient_predivide_factor
-            # Average = sum * (1/size); split the divisor around the wire.
-            def red(v):
-                v, ctx = compression.compress(v)
-                ax = _axes_in_scope(axis)
-                out = collectives.allreduce(
-                    v, op=Sum, axis=ax,
-                    prescale_factor=pre,
-                    postscale_factor=gradient_predivide_factor
-                    / collectives.axis_size(ax),
-                    accumulate_in_fp32=compression is Compression.none)
-                return compression.decompress(out, ctx)
-        else:
-            def red(v):
-                v, ctx = compression.compress(v)
-                out = collectives.allreduce(
-                    v, op=op, axis=_axes_in_scope(axis),
-                    accumulate_in_fp32=compression is Compression.none)
-                return compression.decompress(out, ctx)
-        return fused_apply_tree(red, tree)
+            # dequantize-reduce-requantize collective, on the flat
+            # buffer its block cohorts need.
+            return fused_apply_tree(
+                lambda v: collectives.quantized_allreduce(
+                    v, axis=ax, block_size=compression.block_size,
+                    **scaled), tree)
+        # The plain path is dp.make_train_step's: leaf by leaf.
+        return collectives.allreduce_tree(
+            tree, axis=ax, **scaled,
+            compression=None if compression is Compression.none
+            else compression)
 
     def _axes_in_scope(ax):
         # Filter requested axes down to those bound in the current trace so

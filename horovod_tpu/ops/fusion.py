@@ -5,10 +5,30 @@ device buffer, runs one collective, and unpacks
 (reference: horovod/common/fusion_buffer_manager.cc,
 ops/collective_operations.h:65-86, threshold set at operations.cc:444).
 
-Under XLA the packing is free to express — we concatenate flattened tensors
-per dtype inside the traced program and let the compiler schedule the copies —
-and the payoff is identical: one ICI collective instead of N, amortizing
-per-collective latency for the long tail of small gradients.
+Under XLA the packing is easy to express (concatenate flattened tensors per
+dtype inside the traced program) but it is not free, and the compiler does
+not need it. On a TPU v5e the ``ravel``/``concatenate``/slice/``reshape`` of
+GPT-2 small's 196 gradient leaves (0.498 GB of fp32) ran as 65 relayouts of
+tiled, weight-shaped arrays plus the in-place ``dynamic-update-slice``s of
+the flat buffer: 10.6-10.9 ms of a step on ONE chip, where the all-reduce
+itself compiles to nothing, and 12.0 ms beside an 8.75 ms collective on
+four (PERF.md §5-6, PR 24). Given the leaves, XLA's all-reduce combiner
+groups them into a few variadic all-reduces in their own layouts. So the
+gradient exchange's plain path reduces leaf by leaf
+(:func:`horovod_tpu.parallel.collectives.allreduce_tree`, PR 25) and does
+not come here.
+
+Who still wants the flat buffer, and why:
+
+- the int8 wire format (``dp._make_grad_allreduce``, ``DistributedOptimizer``):
+  quantization blocks and the reduce-scatter's rows are cut from one flat,
+  aligned payload, so the result depends on the layout;
+- ``collectives.grouped_allreduce``: the reference's contract that a group
+  is reduced as one unit;
+- ``broadcast_parameters``: a masked psum per leaf at start-up, off the
+  step's path, where one collective per dtype is the simpler program;
+- :mod:`horovod_tpu.parallel.bucketing`, which packs per (bucket, dtype) in
+  the same way to bound each collective's size.
 """
 
 from __future__ import annotations

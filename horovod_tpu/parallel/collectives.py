@@ -124,6 +124,50 @@ def _allreduce(x, op, axis, prescale_factor, postscale_factor,
     return _scale(out, postscale_factor)
 
 
+def wire_allreduce(x: jax.Array,
+                   op: Op = Average,
+                   axis=DEFAULT_AXIS,
+                   prescale_factor: float = 1.0,
+                   postscale_factor: float = 1.0,
+                   compression=None,
+                   hierarchical: bool = False) -> jax.Array:
+    """Allreduce ``x`` in ``compression``'s wire dtype: cast, reduce, cast
+    back. ``compression`` is a cast compressor (fp16/bf16) or None;
+    block-quantized payloads are not psum-reducible and go through
+    :func:`quantized_allreduce`. A cast payload stays in its dtype on the
+    wire; without one, 16-bit inputs accumulate in fp32. ``hierarchical``
+    takes the first of ``axis`` as the slow outer level."""
+    ctx = None
+    if compression is not None:
+        x, ctx = compression.compress(x)
+    kwargs = dict(op=op, prescale_factor=prescale_factor,
+                  postscale_factor=postscale_factor,
+                  accumulate_in_fp32=compression is None)
+    if hierarchical:
+        axes = _axes(axis)
+        out = hierarchical_allreduce(x, outer_axis=axes[0],
+                                     inner_axis=axes[1:], **kwargs)
+    else:
+        out = allreduce(x, axis=axis, **kwargs)
+    if compression is not None:
+        out = compression.decompress(out, ctx)
+    return out
+
+
+def allreduce_tree(tree, **kwargs):
+    """The gradient exchange of both frontends' plain path:
+    :func:`wire_allreduce` on every leaf of ``tree``, leaf by leaf.
+
+    No flat buffer: each leaf keeps the shape and tiled layout the backward
+    pass gave it, and XLA's all-reduce combiner groups the collectives;
+    over a group of one nothing is left of them. What packing by hand cost
+    on the chip is in :mod:`horovod_tpu.ops.fusion`. Every leaf's
+    collective carries the ``hvd_allreduce_*`` scope, and
+    ``hvd_injit_collective_traces_total`` counts one per leaf."""
+    return jax.tree_util.tree_map(
+        functools.partial(wire_allreduce, **kwargs), tree)
+
+
 def grouped_allreduce(xs: Sequence[jax.Array],
                       op: Op = Average,
                       axis=DEFAULT_AXIS,

@@ -3,10 +3,14 @@
 Reference analog: the DistributedOptimizer flow (reference:
 horovod/torch/optimizer.py:110-260 — per-parameter hooks fire async
 allreduces, step() synchronizes). On TPU the entire step (forward, backward,
-fused gradient allreduce over the ``data`` mesh axis, optimizer update) is ONE
-compiled XLA program: the "async overlap" the reference engineers by hand is
-done by XLA's latency-hiding scheduler, which overlaps ICI collectives with
-the backward pass automatically.
+gradient allreduce over the ``data`` mesh axis, optimizer update) is ONE
+compiled XLA program. The gradients are reduced leaf by leaf and XLA's
+all-reduce combiner groups them; nothing is packed by hand. What that buys
+on a v5e (PERF.md §6, PR 25): over a group of one the exchange compiles to
+nothing, and on a 2x2 the combiner's all-reduces are synchronous
+instructions, the first of them issued between backward's kernels, so the
+wire time is NOT hidden: ``exposed_collective_ms`` reads all of
+``collective_ms``. ``bucket_bytes`` and a 16-bit wire are the levers for it.
 
 The step is built with ``jax.shard_map`` so the gradient allreduce is an
 *explicit* collective — the hook point for compression (fp16 wire format),
@@ -89,12 +93,18 @@ def _make_grad_allreduce(op, axes, compression, prescale_factor,
                          postscale_factor, hierarchical, bucket_bytes=0):
     """The gradient-combining tree map shared by both step builders.
 
-    ``bucket_bytes > 0`` fuses per (bucket, dtype) instead of per dtype
-    over the whole tree: the collectives are elementwise, so the partition
-    cannot change values (bit-exact vs the unbucketed path for plain/cast
-    wire formats), and each bucket's collective depends only on its own
-    leaves — the overlap hook. Adasum is untouched: its exchange is
-    already per-tensor (maximally bucketed)."""
+    The plain path (no compression or a cast wire format, no buckets, every
+    op but Adasum) reduces the tree leaf by leaf
+    (:func:`collectives.allreduce_tree`) and leaves the grouping to XLA's
+    all-reduce combiner. int8 keeps the flat, block-aligned buffer of
+    :func:`fused_apply_tree`, and Adasum its per-tensor exchange.
+
+    ``bucket_bytes > 0`` fuses per (bucket, dtype) into flat payloads:
+    the collectives are elementwise, so the partition cannot change values
+    (every bucket partition bit-equal to every other for plain/cast wire
+    formats; the leaf-by-leaf path is another program, equal to 2 ulp),
+    and each bucket's collective depends only on its own leaves — the
+    overlap hook."""
     from horovod_tpu.parallel.bucketing import bucketed_apply_tree
     quantized = bool(getattr(compression, "quantized", False))
     if quantized:
@@ -128,24 +138,15 @@ def _make_grad_allreduce(op, axes, compression, prescale_factor,
             return jax.tree_util.tree_unflatten(treedef, outs)
         return adasum_tree
 
-    def red(v):
-        if compression is not None:
-            v, ctx = compression.compress(v)
-        kwargs = dict(op=op, prescale_factor=prescale_factor,
-                      postscale_factor=postscale_factor,
-                      accumulate_in_fp32=compression is None)
-        if hierarchical:
-            out = collectives.hierarchical_allreduce(
-                v, outer_axis=axes[0], inner_axis=axes[1:], **kwargs)
-        else:
-            out = collectives.allreduce(v, axis=axes, **kwargs)
-        if compression is not None:
-            out = compression.decompress(out, ctx)
-        return out
-
+    wire = dict(op=op, axis=axes, prescale_factor=prescale_factor,
+                postscale_factor=postscale_factor, compression=compression,
+                hierarchical=hierarchical)
     if bucket_bytes > 0:
+        red = functools.partial(collectives.wire_allreduce, **wire)
         return lambda tree: bucketed_apply_tree(red, tree, bucket_bytes)
-    return lambda tree: fused_apply_tree(red, tree)
+    # The plain path packs nothing: each leaf is reduced in the shape and
+    # layout backward gave it, and combining the collectives is XLA's job.
+    return functools.partial(collectives.allreduce_tree, **wire)
 
 
 def _vjp_grads(loss_fn, params, *args):

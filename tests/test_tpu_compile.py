@@ -12,6 +12,7 @@ get ``interpret=False`` from the test.
 
 import functools
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or it logs under /tmp
 
@@ -89,16 +90,14 @@ def test_flash_kernel_compiles_for_v5e(topo, kernel, seq, head_dim, causal):
     assert text.count("tpu_custom_call") == 1, text.count("tpu_custom_call")
 
 
-def test_gpt2_width_dp_step_compiles_for_four_v5e(topo, monkeypatch):
-    """One whole ``dp.make_train_step`` of a two-layer decoder at GPT-2 small
-    widths, T = 1024 and 8 sequences per chip, on the four described
-    devices: the kernels inside a real step, and the gradient all-reduce."""
+@pytest.fixture(scope="module")
+def gpt2_width_step_text(topo):
+    """``text(chips)``: the compiled text of one whole ``dp.make_train_step``
+    of a two-layer decoder at GPT-2 small widths, T = 1024 and 8 sequences
+    per chip, on the first ``chips`` described devices. Compiled once each."""
     from horovod_tpu.models import GptSmall
     from horovod_tpu.parallel import dp, mesh as mesh_lib
 
-    monkeypatch.setattr(fa, "flash_attention", functools.partial(
-        fa.flash_attention, interpret=False))
-    mesh = mesh_lib.data_parallel_mesh(topo.devices)
     model = GptSmall().clone(layers=2)
     opt = optax.adamw(1e-4)
 
@@ -107,20 +106,66 @@ def test_gpt2_width_dp_step_compiles_for_four_v5e(topo, monkeypatch):
         return optax.softmax_cross_entropy_with_integer_labels(
             logits, batch["labels"]).mean(), {}
 
-    def on_mesh(tree, spec):
-        sharding = NamedSharding(mesh, spec)
-        return jax.tree_util.tree_map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
-                                           sharding=sharding), tree)
+    @functools.lru_cache(maxsize=None)
+    def text(chips):
+        mesh = mesh_lib.data_parallel_mesh(topo.devices[:chips])
 
-    tokens = jax.ShapeDtypeStruct((8 * mesh.devices.size, model.max_len),
-                                  jnp.int32)
-    params = jax.eval_shape(model.init, jax.random.key(0), tokens)["params"]
-    step = dp.make_train_step(loss_fn, opt, mesh)
-    text = step.lower(
-        on_mesh(params, P()), on_mesh(jax.eval_shape(opt.init, params), P()),
-        on_mesh({"tokens": tokens, "labels": tokens}, P(dp.DP_AXES)),
-        on_mesh(jax.eval_shape(lambda: jax.random.key(1)), P()),
-    ).compile().as_text()
-    assert text.count("tpu_custom_call") == 3 * model.layers
-    assert "all-reduce" in text
+        def on_mesh(tree, spec):
+            sharding = NamedSharding(mesh, spec)
+            return jax.tree_util.tree_map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                               sharding=sharding), tree)
+
+        tokens = jax.ShapeDtypeStruct((8 * chips, model.max_len), jnp.int32)
+        params = jax.eval_shape(model.init, jax.random.key(0),
+                                tokens)["params"]
+        step = dp.make_train_step(loss_fn, opt, mesh)
+        return step.lower(
+            on_mesh(params, P()),
+            on_mesh(jax.eval_shape(opt.init, params), P()),
+            on_mesh({"tokens": tokens, "labels": tokens}, P(dp.DP_AXES)),
+            on_mesh(jax.eval_shape(lambda: jax.random.key(1)), P()),
+        ).compile().as_text()
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(fa, "flash_attention", functools.partial(
+            fa.flash_attention, interpret=False))
+        yield text, model
+
+
+EXCHANGE = "phase_grad_exchange"   # dp.py's scope around the gradient exchange
+
+
+def _under_exchange(text, *opcodes):
+    """Lines of instructions with one of ``opcodes`` traced under the
+    gradient exchange's scope."""
+    kinds = "|".join(re.escape(o) for o in opcodes)
+    return [line for line in text.splitlines() if EXCHANGE in line
+            and re.search(rf"[\s)](?:{kinds})\(", line)]
+
+
+def test_gpt2_width_dp_step_compiles_for_four_v5e(gpt2_width_step_text):
+    """The kernels inside a real step, and the gradient all-reduce."""
+    text, model = gpt2_width_step_text
+    assert text(4).count("tpu_custom_call") == 3 * model.layers
+    assert "all-reduce" in text(4)
+
+
+def test_one_chip_step_has_nothing_to_exchange(gpt2_width_step_text):
+    """Over a group of one the compiler removes the leaf-by-leaf all-reduce
+    and nothing is left of the exchange: no packing, no instruction at all."""
+    text, model = gpt2_width_step_text
+    assert text(1).count("tpu_custom_call") == 3 * model.layers
+    assert "all-reduce" not in text(1)
+    assert EXCHANGE not in text(1)
+    assert "phase_optimizer_update" in text(1)   # the scopes are there
+
+
+def test_four_chip_exchange_is_all_reduces_and_no_packing(
+        gpt2_width_step_text):
+    """The leaves go to the wire in their own layouts: the combiner's
+    all-reduces, and no relayout into a flat buffer or back."""
+    text, _ = gpt2_width_step_text
+    assert _under_exchange(text(4), "all-reduce")
+    assert not _under_exchange(text(4), "reshape", "copy", "concatenate",
+                               "dynamic-update-slice")
