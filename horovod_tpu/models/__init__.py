@@ -4,6 +4,11 @@ from horovod_tpu.models.gpt import (  # noqa: F401
     GptMedium,
     GptSmall,
 )
+from horovod_tpu.models.olmoe import (  # noqa: F401
+    Olmoe1B7B,
+    OlmoeDecoder,
+    olmoe_loss,
+)
 from horovod_tpu.models.transformer import (  # noqa: F401
     BertBase,
     BertEncoder,

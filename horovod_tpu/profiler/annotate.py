@@ -7,6 +7,8 @@ Two distinct mechanisms, matching where the work actually happens:
   ``parallel/dp.py`` and ``parallel/zero.py`` write them, so every device
   operation of a step says whether it is forward/backward, gradient
   exchange, optimizer update, parameter gather or output sync.
+- :func:`moe_scope` — the parts of an expert layer (:data:`MOE_SCOPES`:
+  router, dispatch, experts, combine), written by ``parallel/ep.moe_topk``.
 - :func:`collective_scope` — ``jax.named_scope`` for code that runs INSIDE a
   jitted program (the in-jit collectives of ``parallel/collectives.py``).
   The scope becomes HLO op-name metadata, so the device trace of a bench
@@ -40,6 +42,10 @@ def _null_scope():
 PHASES = ("forward_backward", "grad_exchange", "optimizer_update",
           "param_gather", "output_sync")
 PHASE_PREFIX = "phase_"
+# The parts of one expert layer (``parallel/ep.moe_topk``), under the step's
+# ``phase_forward_backward``. Neither ``phase_`` nor ``hvd_``: the phase and
+# collective readers key on those prefixes.
+MOE_SCOPES = ("moe_router", "moe_dispatch", "moe_experts", "moe_combine")
 # Host spans the step wrapper (``metrics.timed_step``) writes.
 STEP_SPAN = "hvd.step"
 STEP_DISPATCH_SPAN = "hvd.step.dispatch"
@@ -51,6 +57,14 @@ def step_phase(name: str):
     if name not in PHASES:
         raise ValueError(f"unknown step phase {name!r}; one of {PHASES}")
     return collective_scope(PHASE_PREFIX + name)
+
+
+def moe_scope(name: str):
+    """Name the enclosed traced ops as one part of an expert layer."""
+    if name not in MOE_SCOPES:
+        raise ValueError(f"unknown expert-layer scope {name!r}; one of "
+                         f"{MOE_SCOPES}")
+    return collective_scope(name)
 
 
 def collective_scope(name: str):

@@ -1,5 +1,8 @@
-"""Expert-parallel MoE vs a dense single-device reference (SURVEY §2.8:
-EP over the alltoall primitive — the layer the reference lacks)."""
+"""The two layers of ``parallel/ep.py`` against dense single-device
+references: the top-1 capacity-routed exchange over the ``expert`` axis
+(SURVEY §2.8: EP over the alltoall primitive — the layer the reference
+lacks), and below it the dropless top-k layer that trains (``moe_topk``;
+top-1 is ``k = 1``)."""
 
 import numpy as np
 import jax
@@ -8,7 +11,8 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from horovod_tpu.parallel import mesh as mesh_lib
-from horovod_tpu.parallel.ep import moe_layer, top1_dispatch
+from horovod_tpu.parallel.ep import (load_balancing_loss, moe_layer,
+                                     moe_topk, route_topk, top1_dispatch)
 
 N = 8  # expert-axis extent
 D, H = 16, 32
@@ -144,3 +148,114 @@ def test_moe_layer_rejects_wrong_gate_width(ep_mesh):
             in_specs=(P("expert"), P(), P("expert"), P("expert")),
             out_specs=P("expert"), check_vma=False)(
                 x, bad_gate, jnp.asarray(w_in), jnp.asarray(w_out))
+
+
+# -- the dropless top-k layer (moe_topk): what trains -------------------------
+
+E, F, T = 16, 24, 96  # experts, expert width, tokens
+
+
+def _gated_weights(seed=0):
+    rng = np.random.RandomState(seed)
+    return tuple(jnp.asarray(w, jnp.float32) for w in (
+        rng.randn(D, E),                     # router
+        rng.randn(E, D, F) * 0.2, rng.randn(E, D, F) * 0.2,  # gate, up
+        rng.randn(E, F, D) * 0.2))           # down
+
+
+def _dense_gated(x, w_router, w_gate, w_up, w_down, k):
+    """Every expert for every token, masked by the top-k choice: no sort,
+    no grouped matmul, no capacity."""
+    probs = jax.nn.softmax(x @ w_router, axis=-1)
+    weights, chosen = jax.lax.top_k(probs, k)
+    gate = jnp.zeros_like(probs).at[
+        jnp.arange(x.shape[0])[:, None], chosen].set(weights)
+    hidden = jax.nn.silu(jnp.einsum("td,edf->tef", x, w_gate)) * \
+        jnp.einsum("td,edf->tef", x, w_up)
+    return jnp.einsum("te,tef,efd->td", gate, hidden, w_down)
+
+
+@pytest.mark.parametrize("k", [1, 2, 8], ids=["top1", "top2", "top8"])
+def test_moe_topk_matches_dense_reference(k):
+    """Top-1 is ``k = 1``; nothing is dropped at any k."""
+    weights = _gated_weights(k)
+    x = jnp.asarray(np.random.RandomState(10 + k).randn(T, D), jnp.float32)
+    got, stats = jax.jit(lambda x, *w: moe_topk(x, *w, k))(x, *weights)
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(_dense_gated(x, *weights, k)),
+                               rtol=2e-4, atol=2e-5)
+    assert int(stats.expert_tokens.sum()) == k * T
+    assert stats.expert_tokens.dtype == jnp.int32
+    np.testing.assert_allclose(float(stats.router_prob_mean.sum()), 1.0,
+                               rtol=1e-5)
+
+
+@pytest.mark.parametrize("k", [1, 8], ids=["top1", "top8"])
+def test_moe_topk_keeps_every_pair_under_the_worst_imbalance(k):
+    """An adversarial router sends every token to the same k experts: a
+    capacity would drop all but a few; here the counts sum to k T, the other
+    experts get nothing, and the output is the dense reference's."""
+    w_router, w_gate, w_up, w_down = _gated_weights(3)
+    x = np.random.RandomState(4).randn(T, D).astype(np.float32)
+    x[:, 0] = 1.0  # a constant feature the router keys on
+    favoured = np.array([3, 5, 6, 7, 9, 12, 13, 15][:k])
+    bias = np.zeros((D, E), np.float32)
+    bias[0, favoured] = 50.0
+    w_router = w_router * 0.01 + bias
+    x = jnp.asarray(x)
+    got, stats = jax.jit(lambda x, *w: moe_topk(x, *w, k))(
+        x, w_router, w_gate, w_up, w_down)
+    counts = np.asarray(stats.expert_tokens)
+    assert counts.sum() == k * T
+    assert (counts[favoured] == T).all()
+    assert np.delete(counts, favoured).sum() == 0
+    np.testing.assert_allclose(
+        np.asarray(got),
+        np.asarray(_dense_gated(x, w_router, w_gate, w_up, w_down, k)),
+        rtol=2e-4, atol=2e-5)
+
+
+def test_moe_topk_gradients_match_dense_reference():
+    """Through the sort, both permutations (whose backward passes are
+    gathers, not scatter-adds) and the grouped matmuls."""
+    weights = _gated_weights(6)
+    x = jnp.asarray(np.random.RandomState(7).randn(T, D), jnp.float32)
+
+    def loss(layer, x, *w):
+        return jnp.sum(jnp.tanh(layer(x, *w)) ** 2)
+    got = jax.jit(jax.grad(
+        lambda x, *w: loss(lambda *a: moe_topk(*a, 4)[0], x, *w),
+        argnums=(0, 1, 2, 3, 4)))(x, *weights)
+    want = jax.grad(
+        lambda x, *w: loss(lambda *a: _dense_gated(*a, 4), x, *w),
+        argnums=(0, 1, 2, 3, 4))(x, *weights)
+    for g, w in zip(got, want):
+        assert float(jnp.abs(w).sum()) > 0
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-3,
+                                   atol=2e-5)
+
+
+def test_moe_topk_rejects_mismatched_expert_counts():
+    w_router, w_gate, w_up, w_down = _gated_weights()
+    with pytest.raises(ValueError, match="routes to"):
+        moe_topk(jnp.zeros((4, D)), w_router[:, :E - 1], w_gate, w_up,
+                 w_down, 2)
+
+
+def test_router_weights_are_not_renormalised_and_losses_by_hand():
+    w_router = _gated_weights(8)[0]
+    x = jnp.asarray(np.random.RandomState(9).randn(T, D), jnp.float32)
+    weights, chosen, probs, logits = route_topk(x, w_router, 4)
+    assert chosen.shape == (T, 4) and chosen.dtype == jnp.int32
+    np.testing.assert_allclose(
+        np.asarray(weights),
+        np.take_along_axis(np.asarray(probs), np.asarray(chosen), axis=-1))
+    assert float(weights.sum(axis=-1).max()) < 1.0  # the softmax's own
+    # a uniform router: every expert gets k T / E pairs at probability 1 / E
+    uniform = load_balancing_loss(jnp.full((2, E), 4 * T // E),
+                                  jnp.full((2, E), 1.0 / E), 4)
+    assert float(uniform) == pytest.approx(4.0)
+    # everything to 4 experts at probability 1/4 each: E / k times as much
+    counts = jnp.zeros((E,), jnp.int32).at[:4].set(T)
+    collapsed = load_balancing_loss(counts, counts / (4.0 * T), 4)
+    assert float(collapsed) == pytest.approx(E / 4 * 4.0)

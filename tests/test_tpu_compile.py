@@ -169,3 +169,47 @@ def test_four_chip_exchange_is_all_reduces_and_no_packing(
     assert _under_exchange(text(4), "all-reduce")
     assert not _under_exchange(text(4), "reshape", "copy", "concatenate",
                                "dynamic-update-slice")
+
+
+# -- the expert layer at OLMoE's widths ----------------------------------------
+
+@pytest.fixture(scope="module")
+def olmoe_layer_text(topo):
+    """Forward and backward of ``ep.moe_topk`` at the published widths
+    (8192 tokens of 2048, top-8 of 64 experts of 1024, bf16), compiled for
+    one described chip."""
+    from horovod_tpu.parallel import ep
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(x, router, gate, up, down):
+        out, stats = ep.moe_topk(x, router, gate, up, down, 8)
+        return out.astype(jnp.float32).sum() + stats.router_z_loss
+
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        arg((8192, 2048)), arg((2048, 64), jnp.float32),
+        arg((64, 2048, 1024)), arg((64, 2048, 1024)),
+        arg((64, 1024, 2048))).compile().as_text()
+
+
+def test_expert_layer_compiles_to_grouped_matmul_kernels(olmoe_layer_text):
+    """The TPU compiler lowers each ``ragged_dot`` (three forward, six
+    backward) to a Mosaic call of its own, with two metadata calls: what
+    ``benchmark/configs/olmoe-1b-7b.py`` counts as ``RAGGED_DOT_CALLS`` and
+    ``benchmark/harness/moe.py`` names ``moe_experts``."""
+    names = re.findall(r'custom_call_target="tpu_custom_call".*?'
+                       r'op_name="([^"]*)"', olmoe_layer_text)
+    assert sorted(set(names)) == ["ragged-dot-metadata", "ragged-dot-none"]
+    assert names.count("ragged-dot-none") == 9 and len(names) == 11
+
+
+def test_expert_layer_moves_rows_by_gathers_alone(olmoe_layer_text):
+    """Dispatch and combine, forward and backward: no scatter."""
+    opcodes = re.findall(r"[\s)]([a-z\-]+)\(", olmoe_layer_text)
+    assert "scatter" not in opcodes
+    assert "gather" in opcodes and "sort" in opcodes
+    for scope in ("moe_router", "moe_dispatch", "moe_experts",
+                  "moe_combine"):
+        assert scope in olmoe_layer_text
