@@ -5,7 +5,7 @@ softmax(QK^T)V blockwise in VMEM with online log-sum-exp accumulation, so
 the [T, T] score matrix never exists in HBM — the kernel streams K/V blocks
 through the MXU and keeps the fp32 accumulators on chip.
 
-Three design points make this the building block the rest of the framework
+Four design points make this the building block the rest of the framework
 composes with:
 
 - **log-sum-exp residual**: ``return_lse=True`` also returns the per-row
@@ -17,12 +17,28 @@ composes with:
   staged into SMEM) shift the causal mask to global coordinates, so a
   sequence-sharded rank can attend its local q block against a rotating
   remote K/V shard. Blocks entirely in the future cost zero work — the k
-  loop's *traced* upper bound excludes them.
+  loop's *traced* upper bound excludes them. (Blocks entirely in the past
+  still compute their all-true mask: a second, unmasked loop body was
+  measured on the v5e and gained nothing, ``PERF.md`` §6, PR 27;
+  :func:`block_plan` counts the three kinds of block.)
 - **custom VJP**: backward is two Pallas kernels (dq gridded over q tiles,
   dk/dv gridded over k tiles) recomputing probabilities from the saved lse,
   the standard flash backward. The lse output is differentiable too
   (d lse/d s = softmax prob), so gradients flow through ring-attention
   merges.
+- **the probabilities are the MXU's stationary operand**: each kernel's
+  time on the v5e follows the instruction bundles of its loop body, and
+  what fills them is register spills around the [block, block] fp32 score
+  tile, not arithmetic. So each kernel picks the orientation of that tile
+  that keeps its row statistics along lanes and lets the narrow [block, d]
+  operand stream past the probabilities: the forward and dq kernels work on
+  ``k q^T`` and accumulate ``v^T p^T`` / ``k^T ds^T`` as [d, block_q]; the
+  dk/dv kernel works on ``q k^T`` and accumulates [d, block_k] for heads up
+  to ``_NARROW_HEAD``, and on ``k q^T`` with plain [block_k, d] sums for
+  wider ones. No score tile is ever transposed; ``sm_scale`` multiplies
+  the [block, d] operand of the scores where that is exact (the fp32 scores
+  otherwise) and the fp32 ``dq``/``dk`` sums instead of ``ds``; a
+  fully-masked row is one select on its ``lse``.
 
 Layout: [batch, seq, heads, head_dim] in, same out; internally each
 (batch, head) pair is one grid row. Pure-JAX reference semantics are tested
@@ -33,6 +49,7 @@ chip.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -52,11 +69,24 @@ NEG_INF = -1e30  # large-negative instead of -inf: keeps exp() NaN-free
 DEFAULT_FLASH_MIN_SEQ = 1024
 
 
+# Heads at most this wide fill half of the MXU's 128 columns or less: an
+# accumulation with the head width as its output's columns wastes the rest
+# of every pass (see ``_bwd_dkv_kernel``).
+_NARROW_HEAD = 64
+
+
 def _pos(off_f32, base, shape, dim):
     """Global positions (fp32 — exact for T < 2^24) of a tile. The iota is
     integer (TPU's tpu.iota only produces ints) then cast."""
     iota = lax.broadcasted_iota(jnp.int32, shape, dim).astype(jnp.float32)
     return off_f32 + base + iota
+
+
+def _visible(q_off, k_off, q_base, k_base, shape, q_dim):
+    """``q_pos >= k_pos`` over a score tile whose q positions run along
+    ``q_dim`` (0: scores as ``q k^T``, 1: transposed, ``k q^T``)."""
+    return (_pos(q_off, q_base, shape, q_dim)
+            >= _pos(k_off, k_base, shape, 1 - q_dim))
 
 
 def _causal_num_k(q_off, k_off, qi, block_q, block_k, num_k):
@@ -68,124 +98,172 @@ def _causal_num_k(q_off, k_off, qi, block_q, block_k, num_k):
     return jnp.clip(eff, 0, num_k).astype(jnp.int32)
 
 
-def _fwd_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *,
-                block_q: int, block_k: int, causal: bool, sm_scale: float,
-                kv_len: int):
-    qi = pl.program_id(1)
-    q_off, k_off = qo_ref[0], ko_ref[0]
+def _scale_operand(x, sm_scale: float):
+    """``(x * sm_scale, True)`` where that is exact: a power of two (heads
+    of 64: 0.125) multiplies an operand of any float dtype without rounding,
+    so the scale goes onto the [block, d] operand once a tile instead of onto
+    the [block, block] scores every block. ``(x, False)`` otherwise: the
+    scores take it in fp32, never a re-rounded operand."""
+    if math.frexp(sm_scale)[0] != 0.5:
+        return x, False
+    return (x.astype(jnp.float32) * sm_scale).astype(x.dtype), True
+
+
+def _safe_lse(lse):
+    """A fully-masked row has lse = NEG_INF, and exp(s - lse) would
+    overflow. -NEG_INF instead makes every exp(s - lse) exactly 0: such rows
+    get zero gradients from one select a row, none over the tile."""
+    return jnp.where(lse > NEG_INF / 2, lse, -NEG_INF)
+
+
+_NT = (((1,), (1,)), ((), ()))   # a @ b^T
+_NN = (((1,), (0,)), ((), ()))   # a @ b
+_TN = (((0,), (0,)), ((), ()))   # a^T @ b
+
+
+def _dot(a, b, dims):
     # Matmuls run in the input dtype (bf16 rides the fast MXU path; fp32
     # inputs keep full precision) and accumulate in fp32 via
     # preferred_element_type — casting inputs up to fp32 would force 3-pass
     # fp32 MXU matmuls and ~30% more step time.
-    q = q_ref[0]  # [block_q, d]
-    d_v = v_ref.shape[-1]
+    return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
 
-    m = jnp.full((block_q, 1), NEG_INF, jnp.float32)
-    l = jnp.zeros((block_q, 1), jnp.float32)
-    o = jnp.zeros((block_q, d_v), jnp.float32)
-    q_pos = _pos(q_off, qi * block_q, (block_q, block_k), 0)
+
+def _fwd_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *,
+                block_q: int, block_k: int, causal: bool, sm_scale: float,
+                kv_len: int):
+    """One q tile against the k blocks it sees, on transposed scores
+    ``k q^T`` [block_k, block_q]: a q row's statistics then lie along lanes
+    ([1, block_q], four registers where a [block_q, 1] column takes 64), the
+    reductions run down sublanes, ``lse`` leaves as the row it is stored as,
+    and ``v^T p^T`` [d, block_q] streams the narrow operand past the
+    probabilities instead of filling half the MXU's columns with ``d``."""
+    qi = pl.program_id(1)
+    q_off, k_off = qo_ref[0], ko_ref[0]
+    q, scaled = _scale_operand(q_ref[0], sm_scale)  # [block_q, d]
 
     def body(kj, carry):
-        m, l, o = carry
+        m, l, acc = carry  # [1, block_q], [1, block_q], [d_v, block_q]
         k = k_ref[0, pl.ds(kj * block_k, block_k), :]
         v = v_ref[0, pl.ds(kj * block_k, block_k), :]
-        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * sm_scale
+        s = _dot(k, q, _NT)
+        if not scaled:
+            s = s * sm_scale
         if causal:
-            k_pos = _pos(k_off, kj * block_k, (block_q, block_k), 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        m_i = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m, m_i)
-        p = jnp.exp(s - m_new)  # rows fully at NEG_INF decay to ~0
+            s = jnp.where(_visible(q_off, k_off, qi * block_q, kj * block_k,
+                                   s.shape, 1), s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
+        p = jnp.exp(s - m_new)  # q rows fully at NEG_INF decay to ~0
         alpha = jnp.exp(m - m_new)
-        l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        o = o * alpha + lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return m_new, l, o
+        l = l * alpha + jnp.sum(p, axis=0, keepdims=True)
+        acc = acc * alpha + _dot(v, p.astype(v.dtype), _TN)
+        return m_new, l, acc
 
     num_k = kv_len // block_k
     if causal:
         num_k = _causal_num_k(q_off, k_off, qi, block_q, block_k, num_k)
-    m, l, o = lax.fori_loop(0, num_k, body, (m, l, o))
-    o_ref[0] = (o / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-    lse = jnp.where(l > 0, m + jnp.log(jnp.maximum(l, 1e-30)), NEG_INF)
+    m, l, acc = lax.fori_loop(
+        0, num_k, body,
+        (jnp.full((1, block_q), NEG_INF, jnp.float32),
+         jnp.zeros((1, block_q), jnp.float32),
+         jnp.zeros((v_ref.shape[-1], block_q), jnp.float32)))
+    # a row that saw only masked scores carries m = NEG_INF and the mean of
+    # the v rows it visited: it is dead, output 0 and lse = NEG_INF, like a
+    # row whose every block was skipped
+    live = m > NEG_INF / 2
+    o = jnp.where(live, acc / jnp.maximum(l, 1e-30), 0.0)
+    o_ref[0] = o.T.astype(o_ref.dtype)
     # lse rides a full-row (1, 1, Tq) block revisited across q tiles — TPU
     # lowering wants the last two block dims tiling-aligned or equal to the
     # array dims, which a (1, block_q) block is not.
-    lse_ref[0, 0, pl.ds(qi * block_q, block_q)] = lse[:, 0]
+    lse_ref[0, :, pl.ds(qi * block_q, block_q)] = jnp.where(
+        live, m + jnp.log(jnp.maximum(l, 1e-30)), NEG_INF)
 
 
 def _bwd_dq_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                    corr_ref, dq_ref, *, block_q: int, block_k: int,
                    causal: bool, sm_scale: float, kv_len: int):
     """dq for one q tile: loop k tiles, recompute p from lse, accumulate
-    ds @ k. ``corr`` is (dlse - delta) precomputed on host-side JAX."""
+    ``k^T ds^T`` [d, block_q], all on transposed scores like the forward.
+    ``corr`` is (dlse - delta) precomputed on host-side JAX; it and ``lse``
+    broadcast as the rows they are stored as. ds goes to the MXU unscaled
+    and the fp32 sum takes ``sm_scale`` once."""
     qi = pl.program_id(1)
     q_off, k_off = qo_ref[0], ko_ref[0]
-    q = q_ref[0]
+    q, scaled = _scale_operand(q_ref[0], sm_scale)
     do = do_ref[0]
-    lse = lse_ref[0, 0, pl.ds(qi * block_q, block_q)][:, None]
-    corr = corr_ref[0, 0, pl.ds(qi * block_q, block_q)][:, None]
-    live = lse > NEG_INF / 2  # fully-masked rows produce zero grads
-    q_pos = _pos(q_off, qi * block_q, (block_q, block_k), 0)
+    lse = _safe_lse(lse_ref[0, :, pl.ds(qi * block_q, block_q)])
+    corr = corr_ref[0, :, pl.ds(qi * block_q, block_q)]  # [1, block_q]
 
     def body(kj, dq):
         k = k_ref[0, pl.ds(kj * block_k, block_k), :]
         v = v_ref[0, pl.ds(kj * block_k, block_k), :]
-        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * sm_scale
-        p = jnp.where(live, jnp.exp(s - lse), 0.0)
+        s = _dot(k, q, _NT)
+        if not scaled:
+            s = s * sm_scale
+        p = jnp.exp(s - lse)
         if causal:
-            k_pos = _pos(k_off, kj * block_k, (block_q, block_k), 1)
-            p = jnp.where(q_pos >= k_pos, p, 0.0)
-        dp = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        ds = (p * (dp + corr) * sm_scale).astype(k.dtype)
-        return dq + lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
+            p = jnp.where(_visible(q_off, k_off, qi * block_q, kj * block_k,
+                                   s.shape, 1), p, 0.0)
+        ds = p * (_dot(v, do, _NT) + corr)
+        return dq + _dot(k, ds.astype(k.dtype), _TN)
 
     num_k = kv_len // block_k
     if causal:
         num_k = _causal_num_k(q_off, k_off, qi, block_q, block_k, num_k)
     dq = lax.fori_loop(0, num_k, body,
-                       jnp.zeros((block_q, q.shape[-1]), jnp.float32))
-    dq_ref[0] = dq.astype(dq_ref.dtype)
+                       jnp.zeros((q.shape[-1], block_q), jnp.float32))
+    dq_ref[0] = (dq * sm_scale).T.astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                     corr_ref, dk_ref, dv_ref, *, block_q: int, block_k: int,
                     causal: bool, sm_scale: float, q_len: int):
     """dk/dv for one k tile: loop q tiles (starting past fully-causal-masked
-    ones), recompute p, accumulate p^T @ do and ds^T @ q."""
+    ones), recompute p, accumulate p^T @ do and ds^T @ q.
+
+    Neither accumulation transposes a score tile. Narrow heads take scores
+    as ``q k^T`` and sum ``do^T p`` and ``q^T ds`` [d, block_k]: the head
+    width streams past the probabilities (``lse``/``corr`` turn into columns
+    once a block, a row of block_q values). Wider heads fill the MXU's
+    columns themselves: scores transposed, ``k q^T``, the sums plain
+    ``p^T do`` and ``ds^T q`` [block_k, d], ``lse``/``corr`` rows as
+    stored. Which is faster where: ``PERF.md`` §6, PR 27."""
     kj = pl.program_id(1)
     q_off, k_off = qo_ref[0], ko_ref[0]
-    k = k_ref[0]  # [block_k, d]
+    k, scaled = _scale_operand(k_ref[0], sm_scale)  # [block_k, d]
     v = v_ref[0]
-    k_pos = _pos(k_off, kj * block_k, (block_q, block_k), 1)
+    narrow = k.shape[-1] <= _NARROW_HEAD
+
+    def summed(probs, rows):
+        """``probs^T @ rows`` over the q positions, as the sums lie:
+        [d, block_k] for narrow heads, [block_k, d] otherwise."""
+        return _dot(rows, probs, _TN) if narrow else _dot(probs, rows, _NN)
+
+    def zeros(d):
+        return jnp.zeros((d, block_k) if narrow else (block_k, d),
+                         jnp.float32)
 
     def body(i, carry):
         dk, dv = carry
         q = q_ref[0, pl.ds(i * block_q, block_q), :]
         do = do_ref[0, pl.ds(i * block_q, block_q), :]
-        lse = lse_ref[0, 0, pl.ds(i * block_q, block_q)][:, None]
-        corr = corr_ref[0, 0, pl.ds(i * block_q, block_q)][:, None]
-        live = lse > NEG_INF / 2
-        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * sm_scale
-        p = jnp.where(live, jnp.exp(s - lse), 0.0)
+        lse = _safe_lse(lse_ref[0, :, pl.ds(i * block_q, block_q)])
+        corr = corr_ref[0, :, pl.ds(i * block_q, block_q)]  # [1, block_q]
+        if narrow:  # scores [block_q, block_k]
+            lse, corr = lse[0][:, None], corr[0][:, None]
+            s, dp = _dot(q, k, _NT), _dot(do, v, _NT)
+        else:       # scores [block_k, block_q]
+            s, dp = _dot(k, q, _NT), _dot(v, do, _NT)
+        if not scaled:
+            s = s * sm_scale
+        p = jnp.exp(s - lse)
         if causal:
-            q_pos = _pos(q_off, i * block_q, (block_q, block_k), 0)
-            p = jnp.where(q_pos >= k_pos, p, 0.0)
-        dv = dv + lax.dot_general(p.astype(do.dtype), do,
-                                  (((0,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-        dp = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        ds = (p * (dp + corr) * sm_scale).astype(q.dtype)
-        dk = dk + lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-        return dk, dv
+            p = jnp.where(_visible(q_off, k_off, i * block_q, kj * block_k,
+                                   s.shape, 0 if narrow else 1), p, 0.0)
+        ds = p * (dp + corr)
+        return (dk + summed(ds.astype(q.dtype), q),
+                dv + summed(p.astype(do.dtype), do))
 
     num_q = q_len // block_q
     start = 0
@@ -194,10 +272,11 @@ def _bwd_dkv_kernel(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         min_k_pos = k_off + kj * block_k
         s0 = jnp.floor((min_k_pos - q_off) / block_q)
         start = jnp.clip(s0, 0, num_q).astype(jnp.int32)
-    dk, dv = lax.fori_loop(
-        start, num_q, body,
-        (jnp.zeros((block_k, k.shape[-1]), jnp.float32),
-         jnp.zeros((block_k, v.shape[-1]), jnp.float32)))
+    dk, dv = lax.fori_loop(start, num_q, body,
+                           (zeros(k.shape[-1]), zeros(v.shape[-1])))
+    dk = dk * sm_scale
+    if narrow:
+        dk, dv = dk.T, dv.T
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
@@ -340,6 +419,47 @@ def _pick_block(t: int, preferred: int) -> int:
     return b
 
 
+def block_plan(tq: int, tk: int, block_q: int, block_k: int, causal: bool,
+               q_offset: int = 0, k_offset: int = 0) -> dict:
+    """Block visits of one (batch, head), the same for all three kernels:
+    every score of an ``interior`` block is visible, the diagonal crosses a
+    ``diagonal`` block (half its work is masked away), ``skipped`` blocks
+    lie wholly in the future and are never loaded. Pure arithmetic on
+    static values, the kernels' own bounds. The kernels run one masked body
+    over interior and diagonal blocks alike (``PERF.md`` §6, PR 27)."""
+    num_q, num_k = tq // block_q, tk // block_k
+    if not causal:
+        return {"interior": num_q * num_k, "diagonal": 0, "skipped": 0}
+    interior = seen = 0
+    for qi in range(num_q):
+        first = q_offset + qi * block_q - k_offset   # relative to k[0]
+        n_seen = min(max((first + block_q - 1) // block_k + 1, 0), num_k)
+        interior += min(max((first + 1) // block_k, 0), n_seen)
+        seen += n_seen
+    return {"interior": interior, "diagonal": seen - interior,
+            "skipped": num_q * num_k - seen}
+
+
+def _count_block_visits(plan: dict, batch_heads: int):
+    """Monitoring, at trace time like ``collectives._count_trace``: the
+    blocks of each kind in what was just traced."""
+    from horovod_tpu.metrics.registry import get_registry
+    for kind, visits in plan.items():
+        get_registry().counter(
+            "hvd_flash_block_visits",
+            "flash-attention block visits traced, by kind of block",
+            kind=kind).inc(visits * batch_heads)
+
+
+def _static_offset(offset) -> Optional[int]:
+    """The offset as a Python int, or None where it is traced."""
+    if offset is None:
+        return 0
+    if isinstance(offset, jax.core.Tracer):
+        return None
+    return int(offset)
+
+
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = False,
                     sm_scale: Optional[float] = None,
@@ -368,6 +488,10 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
              else jnp.asarray(q_offset, jnp.float32).reshape(1))
     k_off = (jnp.zeros((1,), jnp.float32) if k_offset is None
              else jnp.asarray(k_offset, jnp.float32).reshape(1))
+    offsets = _static_offset(q_offset), _static_offset(k_offset)
+    if None not in offsets:
+        _count_block_visits(block_plan(tq, k.shape[1], block_q, block_k,
+                                       causal, *offsets), b * h)
     o, lse = _flash(q, k, v, q_off, k_off, causal, scale, block_q, block_k,
                     interpret)
     return (o, lse) if return_lse else o

@@ -1,5 +1,7 @@
 """Pallas flash attention vs dense reference (interpret mode on CPU)."""
 
+import functools
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -119,6 +121,187 @@ def test_flash_global_offsets_shift_causal_mask():
                              q_offset=-1000.0, return_lse=True)
     assert np.all(np.asarray(lse) < -1e29)
     np.testing.assert_array_equal(np.asarray(o), 0)
+
+
+# ---------------------------------------------------------------------------
+# Interior, diagonal and skipped tiles, both orientations of the score tile
+
+
+def _dense_causal(q, k, v, q_off=0, k_off=0):
+    """(o, lse) of causal attention at global positions, in float32; a row
+    that sees no key gives o = 0 and lse = NEG_INF, as the kernel does."""
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * q.shape[-1] ** -0.5
+    seen = (q_off + jnp.arange(q.shape[1])[:, None]
+            >= k_off + jnp.arange(k.shape[1])[None, :])
+    live = seen.any(-1)[None, None, :]
+    s = jnp.where(seen[None, None], s, -1e30)
+    p = jnp.where(live[..., None], jax.nn.softmax(s, -1), 0.0)
+    lse = jnp.where(live, jax.scipy.special.logsumexp(s, axis=-1), -1e30)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v), lse
+
+
+def _qkv(seed, shape, dtype):
+    rng = np.random.RandomState(seed)
+    return tuple(jnp.asarray(rng.randn(*shape), dtype) for _ in range(3))
+
+
+def _assert_close(got, want, dtype):
+    tol = dict(rtol=2e-3, atol=2e-4) if dtype == jnp.float32 else \
+        dict(rtol=3e-2, atol=3e-2)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), **tol)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("head_dim", [64, 128])
+@pytest.mark.parametrize("blocks", [(128, 128), (64, 128), (128, 64)])
+def test_flash_causal_grid_forward_and_grad(blocks, head_dim, dtype):
+    """T = 512 at blocks of 128 is a 4 x 4 grid: interior, diagonal and
+    skipped tiles all occur, also where block_q != block_k. Heads of 64
+    take the scale on the operand (a power of two) and sum dk/dv
+    transposed, heads of 128 take it on the scores and sum them plain."""
+    q, k, v = _qkv(7, (1, 512, 2, head_dim), dtype)
+    dout = jnp.asarray(np.random.RandomState(8).randn(*q.shape), dtype)
+
+    def loss(attend, q, k, v):
+        return jnp.sum(attend(q, k, v).astype(jnp.float32) * dout)
+
+    flash = functools.partial(flash_attention, causal=True, interpret=True,
+                              block_q=blocks[0], block_k=blocks[1])
+    dense = lambda q, k, v: _dense_causal(q, k, v)[0]  # noqa: E731
+    _assert_close(flash(q, k, v), dense(q, k, v), dtype)
+    got = jax.grad(functools.partial(loss, flash), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(functools.partial(loss, dense), argnums=(0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        assert g.dtype == dtype
+        _assert_close(g, w, dtype)
+
+
+# ring attention's three cases, and a shard boundary inside a block
+RING_SHARDS = {"before": (512, 0), "across": (256, 256), "after": (0, 512),
+               "across_inside_a_block": (96, 0)}
+
+
+@pytest.mark.parametrize("blocks", [(128, 128), (64, 128)])
+@pytest.mark.parametrize("shard", list(RING_SHARDS))
+def test_flash_traced_offsets_value_and_grad(shard, blocks):
+    """Under jit with traced offsets, a k shard wholly before the q shard
+    (every tile interior), across the diagonal and wholly after it (every
+    tile skipped): (o, lse) and the gradients, with a cotangent on lse."""
+    q, k, v = _qkv(11, (1, 256, 2, 64), jnp.float32)
+    rng = np.random.RandomState(12)
+    dout = jnp.asarray(rng.randn(*q.shape), jnp.float32)
+    dlse = jnp.asarray(rng.randn(1, 2, 256), jnp.float32)
+
+    def loss(attend, q, k, v, q_off, k_off):
+        o, lse = attend(q, k, v, q_off, k_off)
+        return jnp.sum(o * dout) + jnp.sum(lse * dlse), (o, lse)
+
+    def flash(q, k, v, q_off, k_off):
+        return flash_attention(q, k, v, causal=True, interpret=True,
+                               block_q=blocks[0], block_k=blocks[1],
+                               q_offset=q_off, k_offset=k_off,
+                               return_lse=True)
+
+    offsets = [jnp.float32(x) for x in RING_SHARDS[shard]]
+    grad = lambda attend: jax.jit(jax.grad(  # noqa: E731
+        functools.partial(loss, attend), argnums=(0, 1, 2), has_aux=True))
+    got, (o, lse) = grad(flash)(q, k, v, *offsets)
+    want, (o_ref, lse_ref) = grad(_dense_causal)(q, k, v, *offsets)
+    _assert_close(o, o_ref, jnp.float32)
+    _assert_close(lse, lse_ref, jnp.float32)
+    for g, w in zip(got, want):
+        _assert_close(g, w, jnp.float32)
+    if shard == "after":
+        assert not np.asarray(o).any() and np.all(np.asarray(lse) < -1e29)
+        assert not any(np.asarray(g).any() for g in got)
+
+
+@pytest.mark.parametrize("blocks", [(128, 128), (128, 64)])
+def test_flash_fully_masked_rows_stay_zero(blocks):
+    """Rows that see no key (here the first 64, by a negative q offset) sit
+    in tiles whose other rows are live: their output and dq are exactly
+    zero and they add nothing to dk/dv, with no select over the tile."""
+    q, k, v = _qkv(13, (1, 256, 2, 64), jnp.float32)
+    dout = jnp.asarray(np.random.RandomState(14).randn(*q.shape),
+                       jnp.float32)
+
+    def loss(attend, q, k, v):
+        return jnp.sum(attend(q, k, v) * dout)
+
+    flash = lambda q, k, v: flash_attention(  # noqa: E731
+        q, k, v, causal=True, interpret=True, block_q=blocks[0],
+        block_k=blocks[1], q_offset=-64.0)
+    dense = lambda q, k, v: _dense_causal(q, k, v, q_off=-64)[0]  # noqa: E731
+    o = np.asarray(flash(q, k, v))
+    assert not o[:, :64].any() and o[:, 64:].any()
+    got = jax.grad(functools.partial(loss, flash), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(functools.partial(loss, dense), argnums=(0, 1, 2))(q, k, v)
+    assert not np.asarray(got[0])[:, :64].any()
+    for g, w in zip(got, want):
+        assert np.isfinite(np.asarray(g)).all()
+        _assert_close(g, w, jnp.float32)
+
+
+PLANS = [  # tq, tk, block_q, block_k, q_offset, k_offset
+    (512, 512, 128, 128, 0, 0), (512, 512, 64, 128, 0, 0),
+    (512, 512, 128, 64, 0, 0), (256, 256, 128, 128, 512, 0),
+    (256, 256, 128, 128, 0, 512), (256, 256, 64, 128, 256, 256),
+    (256, 256, 64, 128, 96, 0), (256, 512, 128, 64, -64, 0),
+    (256, 128, 128, 128, 100, 37),
+]
+
+
+@pytest.mark.parametrize("tq,tk,block_q,block_k,q_offset,k_offset", PLANS)
+def test_block_plan_matches_brute_force(tq, tk, block_q, block_k, q_offset,
+                                        k_offset):
+    """A block is interior where the mask is all true, skipped where it is
+    all false, diagonal otherwise; together they are the grid."""
+    from horovod_tpu.ops.flash_attention import block_plan
+    seen = (q_offset + np.arange(tq)[:, None]
+            >= k_offset + np.arange(tk)[None, :])
+    tiles = seen.reshape(tq // block_q, block_q, tk // block_k, block_k)
+    want = {"interior": int(tiles.all((1, 3)).sum()),
+            "skipped": int((~tiles.any((1, 3))).sum())}
+    want["diagonal"] = tiles.shape[0] * tiles.shape[2] - sum(want.values())
+    assert block_plan(tq, tk, block_q, block_k, True, q_offset,
+                      k_offset) == want
+    assert block_plan(tq, tk, block_q, block_k, False) == {
+        "interior": tiles.shape[0] * tiles.shape[2], "diagonal": 0,
+        "skipped": 0}
+
+
+def _block_visits():
+    from horovod_tpu.metrics.registry import get_registry
+    return {kind: get_registry().counter("hvd_flash_block_visits",
+                                         kind=kind).value
+            for kind in ("interior", "diagonal", "skipped")}
+
+
+def test_block_visits_counted_at_trace_time():
+    """gpt2s-t8192's shape: 120 / 16 / 120 visits a (batch, head) at blocks
+    of 512, recorded when the call is traced, times batch * heads."""
+    x = jax.ShapeDtypeStruct((2, 8192, 3, 64), jnp.bfloat16)
+    before = _block_visits()
+    jax.eval_shape(functools.partial(flash_attention, causal=True,
+                                     interpret=True), x, x, x)
+    after = _block_visits()
+    assert {k: after[k] - before[k] for k in after} == {
+        "interior": 6 * 120, "diagonal": 6 * 16, "skipped": 6 * 120}
+
+
+def test_block_visits_not_counted_for_traced_offsets():
+    """Ring attention's offsets are traced: which body a tile takes is
+    decided on the chip, and the counter says nothing."""
+    x = jax.ShapeDtypeStruct((1, 256, 2, 64), jnp.float32)
+    off = jax.ShapeDtypeStruct((), jnp.float32)
+    before = _block_visits()
+    jax.eval_shape(lambda q, o: flash_attention(
+        q, q, q, causal=True, interpret=True, q_offset=o, k_offset=o),
+        x, off)
+    assert _block_visits() == before
 
 
 def test_merge_attention_combines_disjoint_key_sets():

@@ -1,0 +1,59 @@
+"""The bundle counter of ``profiler/kernel_bundles.py`` on a dump's text."""
+
+import pytest
+
+from horovod_tpu.profiler import kernel_bundles
+
+DUMP = """\
+= control target key start
+LH: loop header
+= control target key end
+
+     0   :  { %s1 = inlined_call_operand.vmem [shape: bf16[1,8,8]] }
+   0x1   :  { %2 = sst [smem:[#allocation2_spill]] %s1 }
+   0x2 LB: > { %s3 = sadd.s32 1, %s9  ;;  %s9 = sphi %s0, %s3 }
+   0x3   : > { %v4 = vld [vmem:[%s1] sm:$0xff]  ;;  %v5 = vld [vmem:[#allocation7_spill] sm:$0xff] }
+   0x4 LB: >> { %6 = vmatmul.bf16.gmra.mxu0 %v4  ;;  %v7 = vpop.f32.mrf.mxu0 }
+   0x5   : >> { %8 = vst [vmem:[#allocation8_spill] sm:$0xff] %v7  ;;  %v10 = vadd.f32 %v7, %v5 }
+   0x6   : >> { %v11 = vmul.f32 1.442695, %v10 }
+   0x7   : > { %12 = vst [vmem:[%s1] sm:$0xff] %v11 }
+   0x8 LB: >> { %v13 = vpow2.f32 %v11 }
+   0x9   : > { %p14 = scmp.ge.s32.totalorder %s3, 4 }
+   0xa   :  { %15 = vsyncpa [#allocation3], 1 }
+"""
+
+
+@pytest.mark.parametrize("depth,index,bundles", [
+    (0, 0, 3), (1, 1, 4), (2, 1, 3), (2, 2, 1)])
+def test_loops_count_bundles_by_depth_and_order(depth, index, bundles):
+    found = {(x.depth, x.index): x for x in kernel_bundles.loops(DUMP)}
+    assert sorted(found) == [(0, 0), (1, 1), (2, 1), (2, 2)]
+    assert found[depth, index].bundles == bundles
+
+
+def test_loops_tell_spills_from_loads_and_stores():
+    by_region = {(x.depth, x.index): x.ops for x in kernel_bundles.loops(DUMP)}
+    assert by_region[1, 1]["vld"] == 1 and by_region[1, 1]["vld_spill"] == 1
+    assert by_region[1, 1]["vst"] == 1
+    assert by_region[2, 1] == {"vmatmul": 1, "vpop": 1, "vst_spill": 1,
+                               "vadd": 1, "vmul": 1}
+    assert by_region[2, 2] == {"vpow2": 1}
+
+
+def test_dump_flags_name_the_directory(tmp_path, capsys):
+    assert str(tmp_path) in kernel_bundles.dump_flags(tmp_path)
+    assert kernel_bundles.main(["--flags", str(tmp_path)]) == 0
+    assert capsys.readouterr().out.strip() == kernel_bundles.dump_flags(
+        tmp_path)
+
+
+def test_main_lists_the_largest_program_first(tmp_path, capsys):
+    (tmp_path / "1-copy-64-final_bundles.txt").write_text(
+        "   0x1   :  { %1 = vsyncpa [#allocation3], 1 }\n")
+    (tmp_path / "2-fwd.1-71-final_bundles.txt").write_text(DUMP)
+    (tmp_path / "2-fwd.1-70-schedule-analysis_final_bundles.txt").write_text(
+        "Schedule analysis:\n" * 100)
+    assert kernel_bundles.main([str(tmp_path), "--top", "1"]) == 0
+    out = capsys.readouterr().out.splitlines()
+    assert out[0] == "2-fwd.1-71-final_bundles.txt"
+    assert out[2].startswith("  depth 1 #1: 4 bundles")

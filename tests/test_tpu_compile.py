@@ -74,7 +74,8 @@ KERNELS = {
 
 @pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
 @pytest.mark.parametrize("seq,head_dim", [(1024, 64), (8192, 64),
-                                          (8192, 128)])
+                                          (8192, 128), (2048, 64),
+                                          (4096, 128)])
 @pytest.mark.parametrize("kernel", list(KERNELS))
 def test_flash_kernel_compiles_for_v5e(topo, kernel, seq, head_dim, causal):
     one_chip = SingleDeviceSharding(topo.devices[0])
