@@ -157,8 +157,8 @@ class Job(NamedTuple):
 
 
 def resnet50_job(sizes, n_chips, seed) -> Job:
-    """ResNet-50 under bench.py's policy: bf16 compute, fp32 params and BN
-    statistics, NHWC, stem padded to 8; SGD with momentum."""
+    """ResNet-50 under ``models/resnet.py``'s policy: bf16 compute, fp32
+    params and BN statistics, NHWC, stem padded to 8; SGD with momentum."""
     from horovod_tpu.models import ResNet50
 
     model = ResNet50(num_classes=1000, dtype=jnp.bfloat16,
@@ -221,7 +221,7 @@ class Smoke:
 
     def check_on_chip(self, cond, message):
         """A check only the TPU backend can meet: a kernel's custom call,
-        memory statistics, a peak for the device kind."""
+        memory statistics, a collective in the compiled step."""
         _check(cond or self.rehearse, message)
 
     @contextlib.contextmanager
@@ -262,8 +262,6 @@ class Smoke:
     # -- one chip -----------------------------------------------------------
 
     def device(self, n_chips, cache_dir):
-        from horovod_tpu.profiler import mfu as pmfu
-
         devices = jax.devices()
         d0 = devices[0]
         if d0.platform != "tpu" and not self.rehearse:
@@ -276,15 +274,11 @@ class Smoke:
                 libtpu = importlib.metadata.version("libtpu")
             except importlib.metadata.PackageNotFoundError:
                 libtpu = None
-            peak = pmfu.peak_tflops(d0.device_kind)
-            self.check_on_chip(
-                peak > 0, f"device kind {d0.device_kind!r} is not in "
-                          "profiler/mfu.py PEAK_TFLOPS_BF16")
             checked.update(
                 platform=d0.platform, device_kind=d0.device_kind,
                 count=len(devices), jax=jax.__version__,
                 jaxlib=importlib.metadata.version("jaxlib"), libtpu=libtpu,
-                compile_cache_dir=cache_dir, peak_tflops_bf16=peak)
+                compile_cache_dir=cache_dir)
         return {"platform": d0.platform, "kind": d0.device_kind,
                 "count": len(devices)}
 

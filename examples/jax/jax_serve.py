@@ -15,7 +15,7 @@ the request lifecycle over real HTTP:
 
 Run:  python examples/jax/jax_serve.py
 (CPU-friendly: forces an 8-device virtual host mesh when no accelerator
-is attached, like bench.py.)
+is attached.)
 """
 
 import json

@@ -193,9 +193,6 @@ def load_library():
         lib.hvdtpu_data_ring_ops.argtypes = [ctypes.c_int64]
         lib.hvdtpu_data_algo_ops.restype = ctypes.c_int64
         lib.hvdtpu_data_algo_ops.argtypes = [ctypes.c_int64, ctypes.c_int32]
-        lib.hvdtpu_bench_combine.restype = ctypes.c_double
-        lib.hvdtpu_bench_combine.argtypes = [
-            ctypes.c_int32, ctypes.c_int64, ctypes.c_int32, ctypes.c_int32]
         lib.hvdtpu_metrics_snapshot.restype = ctypes.c_int64
         lib.hvdtpu_metrics_snapshot.argtypes = [
             ctypes.c_int64, ctypes.c_char_p, ctypes.c_int64]
@@ -243,23 +240,10 @@ def set_fault_spec(spec: str, seed: int = 0):
 
 def bench_flight_record(iters: int, enabled: bool = True) -> float:
     """ns per flight-recorder Record() call (``enabled=False`` times the
-    disabled early-out — the pair is bench.py's recorder-overhead delta).
+    disabled early-out; ``tests/test_flight_recorder.py`` reads the pair).
     Session-free: runs on a standalone recorder instance."""
     lib = load_library()
     return float(lib.hvdtpu_bench_flight_record(iters, 1 if enabled else 0))
-
-
-def bench_combine(dtype_name: str, num_elements: int, iters: int,
-                  scalar_baseline: bool = False) -> float:
-    """Payload bytes/s of the host SUM combine kernel (data_plane.cc).
-
-    ``scalar_baseline=True`` times the pre-vectorization per-element
-    fp16/bf16 kernel — the denominator of the bench's reported speedup.
-    Session-free: the kernel runs on local buffers, no transport."""
-    lib = load_library()
-    return float(lib.hvdtpu_bench_combine(
-        DTYPE_IDS[dtype_name], num_elements, iters,
-        1 if scalar_baseline else 0))
 
 
 class EngineSession:

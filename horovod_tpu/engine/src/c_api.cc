@@ -122,8 +122,8 @@ int64_t hvdtpu_flight_dump(int64_t session, const char* dir, char* buf,
   return CopyJson(json, buf, len);
 }
 
-// ns per FlightRecorder::Record call (bench.py's flight-recorder
-// overhead entry); enabled=0 times the disabled early-out.
+// ns per FlightRecorder::Record call (tests/test_flight_recorder.py
+// reads it); enabled=0 times the disabled early-out.
 double hvdtpu_bench_flight_record(int64_t iters, int32_t enabled) {
   return BenchFlightRecord(iters, enabled != 0);
 }
@@ -220,15 +220,6 @@ int64_t hvdtpu_get_tuned_params(int64_t session, char* buf, int64_t len) {
                 static_cast<int>(p.hierarchical),
                 static_cast<int>(p.small_tensor_algo));
   return CopyJson(json, buf, len);
-}
-
-// Host data-plane microbenchmark: payload bytes/s of the SUM combine
-// kernel (bench.py --host-microbench). dtype per DataType ids;
-// scalar_baseline=1 times the pre-vectorization scalar kernel.
-double hvdtpu_bench_combine(int32_t dtype, int64_t num_elements,
-                            int32_t iters, int32_t scalar_baseline) {
-  return BenchCombineSum(static_cast<DataType>(dtype), num_elements, iters,
-                         scalar_baseline != 0);
 }
 
 // Collectives served by the ring data path (diagnostics/tests).

@@ -36,30 +36,6 @@ void CombineTyped(T* acc, const T* src, int64_t n, ReduceKind kind) {
   }
 }
 
-// Reference scalar combine: per-element fp32 round trips through the exact
-// (branchy) converters. Kept ONLY as the microbenchmark baseline
-// (BenchCombineSum) so the vectorized kernel's speedup is measured against
-// the code it replaced, not guessed.
-void CombineHalfScalar(uint16_t* acc, const uint16_t* src, int64_t n,
-                       ReduceKind kind, bool bf16) {
-  auto to_f = bf16 ? Bfloat16ToFloat : HalfToFloat;
-  auto from_f = bf16 ? FloatToBfloat16 : FloatToHalf;
-  for (int64_t i = 0; i < n; ++i) {
-    float a = to_f(acc[i]);
-    float b = to_f(src[i]);
-    float r = a;
-    switch (kind) {
-      case ReduceKind::SUM:
-      case ReduceKind::AVERAGE: r = a + b; break;
-      case ReduceKind::MIN: r = std::min(a, b); break;
-      case ReduceKind::MAX: r = std::max(a, b); break;
-      case ReduceKind::PRODUCT: r = a * b; break;
-      case ReduceKind::ADASUM: break;
-    }
-    acc[i] = from_f(r);
-  }
-}
-
 // Hot-path half/bf16 combine: blocked bulk convert to fp32 (F16C or
 // branch-free autovectorized loops, half.cc), a tight fused reduce the
 // compiler vectorizes, bulk convert back. The reduce switch is hoisted to
@@ -278,58 +254,6 @@ void AdasumPair(std::vector<double>& a, const std::vector<double>& b) {
 }
 
 }  // namespace
-
-double BenchCombineSum(DataType dtype, int64_t num_elements, int iters,
-                       bool scalar_baseline) {
-  if (num_elements <= 0 || iters <= 0) return -1.0;
-  const int64_t es = DataTypeSize(dtype);
-  std::vector<uint8_t> acc(num_elements * es), src(num_elements * es);
-  // Patterned small values: SUM stays finite in half precision across the
-  // timed repetitions.
-  if (dtype == DataType::FLOAT32) {
-    auto* a = reinterpret_cast<float*>(acc.data());
-    auto* s = reinterpret_cast<float*>(src.data());
-    for (int64_t i = 0; i < num_elements; ++i) {
-      a[i] = static_cast<float>(i % 17) * 0.25f;
-      s[i] = static_cast<float>(i % 13) * 1e-4f;
-    }
-  } else if (dtype == DataType::FLOAT16 || dtype == DataType::BFLOAT16) {
-    const bool bf16 = dtype == DataType::BFLOAT16;
-    auto* a = reinterpret_cast<uint16_t*>(acc.data());
-    auto* s = reinterpret_cast<uint16_t*>(src.data());
-    for (int64_t i = 0; i < num_elements; ++i) {
-      const float fa = static_cast<float>(i % 17) * 0.25f;
-      const float fs = static_cast<float>(i % 13) * 1e-4f;
-      a[i] = bf16 ? FloatToBfloat16(fa) : FloatToHalf(fa);
-      s[i] = bf16 ? FloatToBfloat16(fs) : FloatToHalf(fs);
-    }
-  } else {
-    return -1.0;  // microbench covers the float family only
-  }
-  const bool half = dtype != DataType::FLOAT32;
-  const auto t0 = std::chrono::steady_clock::now();
-  for (int it = 0; it < iters; ++it) {
-    if (half && scalar_baseline) {
-      CombineHalfScalar(reinterpret_cast<uint16_t*>(acc.data()),
-                        reinterpret_cast<const uint16_t*>(src.data()),
-                        num_elements, ReduceKind::SUM,
-                        dtype == DataType::BFLOAT16);
-    } else {
-      Combine(acc.data(), src.data(), num_elements, dtype, ReduceKind::SUM);
-    }
-  }
-  const double secs =
-      std::chrono::duration<double>(std::chrono::steady_clock::now() - t0)
-          .count();
-  // Keep the reduction observable — a too-clever optimizer must not be
-  // allowed to drop the timed loop.
-  volatile uint8_t sink = acc[0];
-  (void)sink;
-  if (secs <= 0) return -1.0;
-  // Payload bytes reduced per second (one operand's wire bytes — the
-  // figure that compares directly against NIC line rate).
-  return static_cast<double>(num_elements) * es * iters / secs;
-}
 
 DataPlane::DataPlane(std::shared_ptr<ControllerTransport> transport)
     : transport_(std::move(transport)) {

@@ -56,14 +56,6 @@ enum class ReduceKind : int32_t {
 constexpr int32_t kSmallTensorStar = 0;
 constexpr int32_t kSmallTensorRecursiveDoubling = 1;
 
-// Microbenchmark hook (hvdtpu_bench_combine): payload bytes/s of the
-// in-process SUM combine kernel over num_elements of dtype (float family
-// only). scalar_baseline=true times the replaced per-element scalar
-// fp16/bf16 kernel instead, so the vectorized path's speedup is measured
-// against real code, not estimated. Returns -1.0 on unusable inputs.
-double BenchCombineSum(DataType dtype, int64_t num_elements, int iters,
-                       bool scalar_baseline);
-
 class DataPlane {
  public:
   explicit DataPlane(std::shared_ptr<ControllerTransport> transport);

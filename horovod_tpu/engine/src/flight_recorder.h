@@ -132,8 +132,8 @@ class FlightRecorder {
   int64_t origin_unix_us_ = 0;  // wall clock at construction
 };
 
-// ns per Record() call on this machine (bench.py flight-recorder
-// overhead entry). enabled=false times the disabled early-out.
+// ns per Record() call on this machine (tests/test_flight_recorder.py
+// reads it). enabled=false times the disabled early-out.
 double BenchFlightRecord(int64_t iters, bool enabled);
 
 }  // namespace hvdtpu

@@ -43,8 +43,8 @@ from horovod_tpu.common.exceptions import (
 from horovod_tpu.common.hvd_logging import get_logger
 
 # Prometheus families of the elastic recovery path (exported through the
-# standard per-worker registry; the chaos soak and the BENCH `elastic`
-# block assert on these exact names).
+# standard per-worker registry; the chaos soak asserts on these exact
+# names).
 RECOVERY_SECONDS = "hvd_elastic_recovery_seconds"
 RECOVERIES_TOTAL = "hvd_elastic_recoveries_total"
 RESIZE_BYTES = "hvd_resize_bytes"

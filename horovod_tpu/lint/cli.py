@@ -33,7 +33,7 @@ from horovod_tpu.lint.py_kv import (check_python_kv_epochs,
                                     check_python_kv_keys)
 
 # Repo layout contract: the scan roots relative to the repo root.
-PY_ROOTS = ("horovod_tpu", "examples", "bench.py")
+PY_ROOTS = ("horovod_tpu", "examples")
 CPP_ROOTS = ("horovod_tpu/engine/src", "horovod_tpu/engine/tsan_harness.cc")
 DESIGN_MD = "docs/DESIGN.md"
 DEFAULT_DOT = "horovod_tpu/engine/build/lock_order.dot"

@@ -236,9 +236,9 @@ def step_stats(snapshot: dict) -> Optional[tuple]:
 
 
 def bench_snapshot() -> dict:
-    """Compact engine + frontend telemetry for the BENCH json
-    (``engine_metrics`` field): the perf trajectory records cache hit
-    rate and fusion efficiency alongside img/s, not instead of them."""
+    """Compact engine + frontend telemetry as one dict: cache hit rate and
+    fusion efficiency beside the step counts. (It was the old whole-repo
+    benchmark's ``engine_metrics`` field; nothing calls it today.)"""
     out: dict = {"engine": None}
     reg_snap = get_registry().snapshot()
     st = step_stats(reg_snap)

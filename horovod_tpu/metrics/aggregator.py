@@ -19,8 +19,8 @@ This module is the middle tier that makes all of them O(hosts):
   the merged view plus compact per-rank vectors (step stats, anomaly
   counters, serving SLO samples) as ``/agg.json``.
 - :class:`TieredScrape` — the driver side of the tier, factored out of
-  ``ElasticDriver._scrape_worker_metrics`` so tests and ``bench.py
-  --telemetry-only`` drive the exact production consume path without a
+  ``ElasticDriver._scrape_worker_metrics`` so tests drive the exact
+  production consume path without a
   live driver. Per heartbeat each host is consumed through **exactly
   one** path: the aggregator when its ``/agg.json`` is fresh, the
   per-rank direct scrape otherwise (aggregator dead/stale) — never
@@ -120,8 +120,8 @@ def merge_snapshots(snaps: List[Tuple[int, dict]]) -> dict:
 
 def counter_totals(snapshot: dict) -> Dict[str, float]:
     """{family name -> summed value} for every counter family in a
-    snapshot — the quantity the BENCH telemetry block asserts
-    byte-identical between the direct and tiered scrape paths."""
+    snapshot — the quantity ``tests/test_telemetry_tier.py``
+    asserts byte-identical between the direct and tiered scrape paths."""
     out: Dict[str, float] = {}
     for m in snapshot.get("metrics", []):
         if m.get("kind") != "counter":

@@ -6,8 +6,8 @@ pytorch_imagenet_resnet50.py, examples/pytorch/pytorch_synthetic_benchmark.py).
 This is a from-scratch flax.linen implementation with an EXPLICIT TPU
 mixed-precision policy instead of a single dtype knob:
 
-- ``dtype`` (default fp32; the bench passes bf16): conv/matmul compute dtype
-  — what rides the MXU.
+- ``dtype`` (default fp32; the benchmark's configuration passes bf16):
+  conv/matmul compute dtype — what rides the MXU.
 - ``param_dtype`` (fp32): master weights, BN scale/bias AND the BN running
   statistics. flax additionally force-float32s the batch-statistics
   *reduction* itself (``_compute_stats(force_float32_reductions=True)``), so

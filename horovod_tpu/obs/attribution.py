@@ -531,16 +531,18 @@ def get_attributor() -> Optional[StepAttributor]:
 
 
 # ---------------------------------------------------------------------------
-# BENCH json block
+# the per-model record
 
 
 def bench_block(step_seconds_by_model: Dict[str, float]) -> dict:
-    """The BENCH json ``step_attribution`` block: per-model decomposition
-    plus a measured attribution-overhead figure.
+    """The ``step_attribution`` record: per-model decomposition plus a
+    measured attribution-overhead figure (read by
+    ``tests/test_attribution.py``; the chip's step phases are the
+    benchmark's, ``PERF.md`` section 3).
 
     ``step_seconds_by_model`` maps model name → measured per-step wall
     seconds. With a live engine session the per-model buckets come from
-    the flight ring's summary fractions; a single-process bench (no
+    the flight ring's summary fractions; a single-process run (no
     engine — XLA owns the overlap inside the jitted step) decomposes as
     100% compute with the source field saying so. Overhead: the
     attributor's per-step observe cost (anomaly window + gauge update),

@@ -25,7 +25,7 @@ Sampling rules: the decision is made ONCE, at ingress — downstream
 stages *adopt* an inbound trace id and never re-sample (a request is
 either fully traced or not at all). Unsampled requests take a
 single-pointer fast path (``req.trace is None``) so tracing at 0% is
-free and at 1% costs <1% p50 (BENCH ``telemetry`` block). The trace id
+free (``tests/test_tracing.py`` holds the fast path). The trace id
 is echoed as ``trace_id`` in every HTTP response — including 429
 rejections — for client-side correlation.
 

@@ -137,7 +137,7 @@ def tp_activation_wire_bytes(n_elements: int, world: int,
                              compression=None,
                              wire_bytes_per_elem: float = 4.0) -> int:
     """Ring-allreduce wire bytes per rank for one activation reduction of
-    ``n_elements`` — the serving BENCH's int8-vs-fp32 savings accounting.
+    ``n_elements`` — the int8-vs-fp32 savings ``make_tp_lm_step`` reports.
     fp32 psum moves ``2*(world-1)/world * 4`` bytes/element (reduce-scatter
     + all-gather phases); the quantized path moves int8 payloads plus one
     fp32 scale per block on each phase."""

@@ -225,8 +225,8 @@ def apply_sharded_update(optimizer,
                 full = collectives.quantized_allgather(
                     u, axis=axes, block_size=block_size).astype(group.dtype)
             elif compression is not None:
-                # dtype-cast compression rides BOTH phases (the wire-byte
-                # accounting in bench.py assumes it)
+                # dtype-cast compression rides BOTH phases (as
+                # collective_bytes_per_step counts it)
                 wire, ctx = compression.compress(u)
                 full = lax.all_gather(wire, axes, axis=0, tiled=True)
                 full = compression.decompress(full, ctx)
@@ -560,7 +560,7 @@ def reshard_wire_bytes(plan: ReshardPlan, sources, rows_by_group,
                        quantized: bool = False) -> int:
     """Total cross-rank wire bytes the plan moves under ``sources`` (the
     sum every rank's ``stats['wire_bytes_sent']`` would report) — the
-    BENCH/metrics accounting shares this one formula with the executor."""
+    metrics' accounting shares this one formula with the executor."""
     total = 0
     for seg in plan.segments:
         serving = sources.get(seg.src)
@@ -573,7 +573,7 @@ def reshard_wire_bytes(plan: ReshardPlan, sources, rows_by_group,
 
 def optimizer_state_bytes(params, n_shards: int, state_factor: float = 2.0,
                           block_size: int = LANE) -> dict:
-    """Memory math for the docs/bench: replicated vs sharded optimizer-state
+    """Memory math for the docs: replicated vs sharded optimizer-state
     bytes per replica. ``state_factor`` = state floats per param (2.0 for
     Adam m+v, 1.0 for momentum)."""
     leaves = jax.tree_util.tree_leaves(params)
@@ -595,8 +595,7 @@ def collective_bytes_per_step(n_params: int,
                               block_size: int = LANE,
                               scale_bytes: float = 4.0) -> int:
     """Ring-cost wire bytes each replica moves per step for the gradient
-    exchange, used by bench.py and the tests so the reported figures share
-    one formula.
+    exchange: the one formula the docs' figures and the tests share.
 
     Ring allreduce moves ``2 * (N-1)/N * payload`` per replica
     (reduce-scatter + all-gather); the sharded pipeline moves the same two

@@ -3,14 +3,9 @@
 The reference's perf methodology is timeline-driven (HOROVOD_TIMELINE,
 reference: horovod/common/timeline.cc, docs/timeline.rst): you can't fix what
 you can't attribute. This package is the TPU-native version of that story,
-split into four layers:
+split into layers (FLOP counts, peaks and MFU are the benchmark's:
+``benchmark/harness/flops.py`` and ``peaks.json``):
 
-- :mod:`~horovod_tpu.profiler.flops` — per-step FLOPs accounting via XLA's
-  own ``jit(...).lower().compile().cost_analysis()`` with analytic fallbacks
-  for the flagship models (the numbers ``bench.py`` used to hardcode).
-- :mod:`~horovod_tpu.profiler.mfu` — the one shared MFU/throughput
-  calculator (chip bf16 peak table + utilization math) that the bench, tests
-  and docs all consume, so the accounting cannot drift between them.
 - :mod:`~horovod_tpu.profiler.annotate` — ``jax.named_scope`` wrapping for
   in-jit collectives (shows up as HLO op metadata in device traces) and
   ``jax.profiler.TraceAnnotation`` wrapping for host-side engine negotiation
@@ -28,18 +23,6 @@ from jax-free processes.
 from __future__ import annotations
 
 _SUBMODULE_EXPORTS = {
-    # flops
-    "FlopsEstimate": "flops",
-    "compiled_flops": "flops",
-    "executable_flops": "flops",
-    "train_step_flops": "flops",
-    "resnet50_train_flops_per_image": "flops",
-    "transformer_train_flops_per_seq": "flops",
-    # mfu
-    "PEAK_TFLOPS_BF16": "mfu",
-    "peak_tflops": "mfu",
-    "mfu": "mfu",
-    "mfu_report": "mfu",
     # annotate
     "collective_scope": "annotate",
     "host_annotation": "annotate",
@@ -53,13 +36,13 @@ _SUBMODULE_EXPORTS = {
 }
 
 __all__ = sorted(_SUBMODULE_EXPORTS) + [
-    "annotate", "flight", "flops", "mfu", "trace_merge",
+    "annotate", "flight", "trace_merge",
 ]
 
 
 def __getattr__(name):
     import importlib
-    if name in ("annotate", "flight", "flops", "mfu", "trace_merge"):
+    if name in ("annotate", "flight", "trace_merge"):
         return importlib.import_module(f"{__name__}.{name}")
     mod = _SUBMODULE_EXPORTS.get(name)
     if mod is None:

@@ -11,7 +11,7 @@ Two distinct mechanisms, matching where the work actually happens:
   router, dispatch, experts, combine), written by ``parallel/ep.moe_topk``.
 - :func:`collective_scope` — ``jax.named_scope`` for code that runs INSIDE a
   jitted program (the in-jit collectives of ``parallel/collectives.py``).
-  The scope becomes HLO op-name metadata, so the device trace of a bench
+  The scope becomes HLO op-name metadata, so the device trace of a
   step shows ``hvd_allreduce_average/...`` spans on the TPU lanes.
 - :func:`host_annotation` — ``jax.profiler.TraceAnnotation`` for host-side
   work (eager engine enqueue, negotiation wait, the data-plane execute

@@ -1,6 +1,6 @@
 """Closed-loop autoscale smoke: load trace in, fleet-size trace out.
 
-The BENCH ``autoscale`` block and ``make autoscale-smoke`` both run this:
+``make autoscale-smoke`` and ``tests/test_autoscale.py`` run this:
 an in-process serving fleet (one ContinuousBatcher + ServingLoop + token
 bucket/priority admission per worker, fronted by the real RequestRouter)
 driven by the REAL :class:`~horovod_tpu.runner.elastic.autoscaler.Autoscaler`

@@ -1,9 +1,8 @@
-"""Load generation + latency microbenches behind the BENCH ``serving``
-block.
+"""Load generation + latency microbenches for the serving plane.
 
 Two instruments:
 
-- :func:`run_load` / :func:`run_points` — open-loop offered load against
+- :func:`run_load` — open-loop offered load against
   any ``submit(payload) -> result`` callable (the local frontend handler,
   an HTTP client, the router). Open-loop matters: a closed loop slows its
   own arrival rate when the server saturates and can never show the
@@ -118,15 +117,15 @@ def shared_prefix_trace(seed: int = 0, requests: int = 256,
                         tenant_mix: Optional[Sequence[float]] = None
                         ) -> List[dict]:
     """Seeded, replayable shared-prefix request trace — the first brick
-    of the ROADMAP trace-driven loadgen item, shared by the BENCH
-    ``serving_fastpath`` block, the smoke, and the tests.
+    of the ROADMAP trace-driven loadgen item, shared by the smoke and the
+    tests.
 
     Each tenant has one fixed ``prefix_len``-token system prompt; every
     request is that prefix plus a fresh ``tail_len``-token user turn.
     ``tenant_mix`` weights the tenant draw (default is zipf-ish: tenant 0
     dominates — the million-users-one-system-prompt shape where prefix
     reuse pays). Identical ``(seed, knobs)`` always reproduce the exact
-    same token streams, so a bench regression is re-runnable bit-for-bit.
+    same token streams, so a regression is re-runnable bit-for-bit.
     """
     rng = np.random.RandomState(seed)
     prefixes = [rng.randint(0, vocab, prefix_len).tolist()
@@ -142,27 +141,6 @@ def shared_prefix_trace(seed: int = 0, requests: int = 256,
                     "tokens": prefixes[t] + tail,
                     "max_new_tokens": int(max_new_tokens)})
     return out
-
-
-def trace_payload_fn(trace: Sequence[dict]) -> Callable[[int], dict]:
-    """Adapter: a replayable trace as the ``make_payload`` argument of
-    :func:`run_load` (wraps around when offered load outruns the trace)."""
-
-    def make_payload(i: int) -> dict:
-        return dict(trace[i % len(trace)])
-
-    return make_payload
-
-
-def run_points(submit: Callable[[dict], dict],
-               make_payload: Callable[[int], dict],
-               points_qps: Sequence[float],
-               duration_sec: float = 3.0) -> List[Dict[str, object]]:
-    """One :func:`run_load` window per offered-load point (the BENCH
-    serving sweep: at least one point past saturation so the JSON shows
-    backpressure, not collapse)."""
-    return [run_load(submit, qps, duration_sec, make_payload)
-            for qps in points_qps]
 
 
 # ---------------------------------------------------------------------------
@@ -267,7 +245,7 @@ def small_allreduce_latency(serving_mode: bool, ranks: int = 2,
 
 
 def small_tensor_cliff_report(**kwargs) -> Dict[str, object]:
-    """The BENCH line: small-allreduce latency with serving mode off vs on,
+    """Small-allreduce latency with serving mode off vs on,
     plus the speedup — the regression number for the fusion-cycle cost
     cliff satellite."""
     off = small_allreduce_latency(False, **kwargs)

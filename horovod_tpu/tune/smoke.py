@@ -1,5 +1,5 @@
-"""Bounded CPU-backend tuning session (``make tune-smoke``,
-``bench.py --tuning-only``, and the slow-marked pytest wrapper).
+"""Bounded CPU-backend tuning session (``make tune-smoke`` and the
+slow-marked pytest wrapper).
 
 A real closed loop on the real engine — no TPU needed: ``world`` loopback
 engine ranks run a synthetic training step whose backward produces a
@@ -173,12 +173,11 @@ def run_smoke(world: int = 2, epoch_steps: int = 5, samples: int = 12,
               warmup_epochs: int = 1, scale: int = 16,
               compute_seconds: float = 0.04,
               log_path: Optional[str] = None) -> dict:
-    """One bounded tuning session; returns the BENCH ``tuning`` block's
-    ``cpu_backend`` record (before/after exposed comm, converged config,
-    search trace length)."""
+    """One bounded tuning session; returns its record (before/after
+    exposed comm, converged config, search trace length)."""
     # The engine reads HOROVOD_TUNE at session creation (cpp scope); the
     # smoke owns its sessions, so it pins the knob for them (and restores
-    # the caller's value on the way out — bench.py runs in-process).
+    # the caller's value on the way out — the pytest wrapper runs in-process).
     prev_tune = os.environ.get("HOROVOD_TUNE")  # hvd-lint: disable=HVL004
     os.environ["HOROVOD_TUNE"] = "1"  # hvd-lint: disable=HVL004
     from horovod_tpu.metrics.registry import MetricsRegistry

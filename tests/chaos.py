@@ -36,19 +36,36 @@ from typing import Dict, List, Optional
 from horovod_tpu.common import journal
 
 
-def find_worker_pids(pattern: str) -> List[int]:
+def _descends_from(pid: int, ancestor: int) -> bool:
+    """Whether ``ancestor`` is ``pid`` or one of its live ancestors."""
+    while pid > 1:
+        if pid == ancestor:
+            return True
+        try:
+            with open(f"/proc/{pid}/stat") as f:
+                pid = int(f.read().rsplit(")", 1)[1].split()[1])  # ppid
+        except (OSError, ValueError, IndexError):
+            return False
+    return False
+
+
+def find_worker_pids(pattern: str, under: Optional[int] = None) -> List[int]:
     """PIDs of live processes whose command line matches ``pattern``
-    (pgrep -f semantics)."""
+    (pgrep -f semantics). ``under`` keeps only descendants of that pid: a
+    pattern such as the driver's or a KV replica's also matches what a test
+    in another xdist worker has started, and a kill must not reach it."""
     out = subprocess.run(["pgrep", "-f", pattern], capture_output=True,
                          text=True)
-    return [int(p) for p in out.stdout.split()]
+    pids = [int(p) for p in out.stdout.split()]
+    return [p for p in pids if under is None or _descends_from(p, under)]
 
 
 def kill_workers(pattern: str, sig: int = signal.SIGKILL,
-                 count: Optional[int] = None) -> List[int]:
-    """Kill up to ``count`` (default: all) processes matching ``pattern``.
-    Returns the PIDs actually signalled."""
-    pids = find_worker_pids(pattern)
+                 count: Optional[int] = None,
+                 under: Optional[int] = None) -> List[int]:
+    """Kill up to ``count`` (default: all) processes matching ``pattern``
+    (below ``under``, if given). Returns the PIDs actually signalled."""
+    pids = find_worker_pids(pattern, under)
     if count is not None:
         pids = pids[-count:]
     killed = []
@@ -188,12 +205,12 @@ class ControlPlane:
             pass
 
 
-def kv_replica_procs() -> Dict[int, List[str]]:
-    """PID -> argv for every live ``replica_kv`` subprocess (the chaos
-    surface for supervised runs: argv carries ``--id`` and the full
-    ``--endpoints`` list, so tests can find the leader from outside)."""
+def kv_replica_procs(under: Optional[int] = None) -> Dict[int, List[str]]:
+    """PID -> argv for every live ``replica_kv`` subprocess below ``under``
+    (the chaos surface for supervised runs: argv carries ``--id`` and the
+    full ``--endpoints`` list, so tests can find the leader from outside)."""
     out: Dict[int, List[str]] = {}
-    for pid in find_worker_pids("horovod_tpu.runner.replica_kv"):
+    for pid in find_worker_pids("horovod_tpu.runner.replica_kv", under):
         try:
             with open(f"/proc/{pid}/cmdline", "rb") as f:
                 out[pid] = f.read().decode().split("\x00")
@@ -202,11 +219,13 @@ def kv_replica_procs() -> Dict[int, List[str]]:
     return out
 
 
-def kill_kv_leader(timeout: float = 30.0, sig: int = signal.SIGKILL):
-    """SIGKILL the KV replica subprocess currently holding the lease.
-    Returns ``(pid, replica_id)``; asserts a replica fleet exists."""
+def kill_kv_leader(timeout: float = 30.0, sig: int = signal.SIGKILL,
+                   under: Optional[int] = None):
+    """SIGKILL the KV replica subprocess currently holding the lease, among
+    the replicas below ``under`` (the test's own launcher). Returns
+    ``(pid, replica_id)``; asserts a replica fleet exists."""
     from horovod_tpu.runner.replica_kv import wait_for_leader
-    procs = kv_replica_procs()
+    procs = kv_replica_procs(under)
     endpoints = None
     for argv in procs.values():
         if "--endpoints" in argv:

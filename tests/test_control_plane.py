@@ -639,7 +639,11 @@ def _launch_supervised(tmp_path, script_body, extra_env, np_=2):
     env = dict(os.environ,
                HOROVOD_KV_DIR=str(tmp_path / "kvdir"),
                HOROVOD_DRIVER_RESTART_BACKOFF_SECONDS="0.2",
-               HOROVOD_DRIVER_RECOVERY_WAIT_SECONDS="3.0",
+               # a cap, not a sleep: the recovered driver goes on as soon
+               # as every slot's heartbeat is in. 3 s was too few for a
+               # 0.5 s-deadline beat under six-way load (adopted 0 of 2,
+               # both slots spawned twice: PR 28's whole run)
+               HOROVOD_DRIVER_RECOVERY_WAIT_SECONDS="10.0",
                JAX_PLATFORMS="cpu", **extra_env)
     proc = subprocess.Popen(
         [sys.executable, "-m", "horovod_tpu.runner.launch",
@@ -665,35 +669,50 @@ def test_driver_restart_smoke_subprocess(tmp_path):
     """SIGKILL the supervised driver while (engine-less) workers are
     stepping: the supervisor respawns it, the KV rehydrates from the
     WAL, the driver adopts the SAME worker pids (no double spawn), and
-    the job completes rc 0 — all in well under 30 seconds."""
-    t_start = time.monotonic()
+    the job completes rc 0. The workers step for 20 s so that they outlive
+    the outage on a loaded machine too: under six xdist workers the
+    supervisor has needed 6-14 s to bring the driver back, and a worker
+    that finishes while nobody takes its SUCCESS record counts as failed
+    and is spawned again."""
     proc, _ = _launch_supervised(tmp_path, SMOKE_WORKER,
-                                 {"WORK_SECONDS": "6"})
+                                 {"WORK_SECONDS": "20"})
     lines = []
-    assert _read_until(proc, "smoke-step", 30, lines), "".join(lines)
+
+    def stepping_pids(text_lines):
+        return {line.split("pid=")[1].split()[0]
+                for line in text_lines if "smoke-step" in line}
+
+    # kill once BOTH workers are stepping: what a worker prints while no
+    # driver is there to forward it never reaches this pipe, so a worker
+    # that had not printed yet would be missing from the pid set below
+    while len(stepping_pids(lines)) < 2:
+        assert _read_until(proc, "smoke-step", 30, lines), "".join(lines)
 
     killed = chaos.kill_workers("elastic.supervisor --driver",
-                                sig=signal.SIGKILL)
+                                sig=signal.SIGKILL, under=proc.pid)
     assert killed, "driver process not found"
     try:
-        out, _ = proc.communicate(timeout=45)
+        out, _ = proc.communicate(timeout=120)
     except subprocess.TimeoutExpired:
         proc.kill()
         out, _ = proc.communicate()
     text = "".join(lines) + out.decode(errors="replace")
     assert proc.returncode == 0, text
     assert "driver crashed" in text, text           # supervisor saw it
-    assert "driver_recovered" in text, text         # recovery ran
+    # recovery ran and ended on the event, both heartbeats in, not on its
+    # clock
+    recovered = [json.loads(line[line.index("{"):])
+                 for line in text.splitlines()
+                 if "driver_recovered: {" in line]
+    assert recovered and recovered[0]["adopted"] == \
+        recovered[0]["expected"] == 2, text
     # both workers finished, and no worker was double-spawned: the pid
     # set across the whole run is exactly the two originals
-    pids = {line.split("pid=")[1].split()[0]
-            for line in text.splitlines() if "smoke-step" in line}
+    pids = stepping_pids(text.splitlines())
     assert len(pids) == 2, text
     done = [line for line in text.splitlines() if "smoke-done" in line]
     assert len(done) == 2, text
     assert {line.split("pid=")[1].split()[0] for line in done} == pids
-    assert time.monotonic() - t_start < 30, \
-        "driver-restart smoke blew the 30s budget"
     # and the worker logs survived in the durable dir
     logs = os.listdir(os.path.join(str(tmp_path / "kvdir"), "logs"))
     assert len(logs) == 2
@@ -775,7 +794,7 @@ def test_driver_kill_mid_training_acceptance(tmp_path):
 
     # --- phase 1: kill the control plane, not the workers
     killed = chaos.kill_workers("elastic.supervisor --driver",
-                                sig=signal.SIGKILL)
+                                sig=signal.SIGKILL, under=proc.pid)
     assert killed, "driver process not found"
     kill1_t = time.monotonic()
     assert _read_until(proc, "driver_recovered", 60, lines), \
@@ -854,7 +873,7 @@ def test_kv_leader_kill_smoke_subprocess(tmp_path):
                                   "HOROVOD_KV_LEASE_SECONDS": "0.5"})
     lines = []
     assert _read_until(proc, "smoke-step", 45, lines), "".join(lines)
-    _pid, lid = chaos.kill_kv_leader()
+    _pid, lid = chaos.kill_kv_leader(under=proc.pid)
     try:
         out, _ = proc.communicate(timeout=90)
     except subprocess.TimeoutExpired:
@@ -895,7 +914,7 @@ def test_kv_leader_kill_mid_training_acceptance(tmp_path):
     assert _read_until(proc, "step=5 ", 120, lines), "".join(lines)
 
     # --- phase 1: kill the KV LEASEHOLDER, not the driver, not a worker
-    _pid, lid = chaos.kill_kv_leader()
+    _pid, lid = chaos.kill_kv_leader(under=proc.pid)
     assert _read_until(proc, "elected leader", 60, lines), "".join(lines)
     # training never stopped while the election ran
     assert _read_until(proc, "aprogress", 30, lines), "".join(lines)

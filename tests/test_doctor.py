@@ -43,14 +43,46 @@ def _causes(verdict):
 # the drill matrix (ISSUE 20: >= 6 seeded scenarios)
 # ---------------------------------------------------------------------------
 
-def test_drill_worker_sigkill_mid_step(journal_dir):
-    """Drill 1: a worker is SIGKILLed mid-step (no drain). The doctor
-    must name the dead rank, not the resize that cleaned up after it."""
+def _kill_in_simcluster(journal_dir):
     with chaos.SimCluster(world=4, n_params=600) as c:
         c.run_steps(2, commit_every=1)
         c.kill(2)
         c.resize()
         c.run_steps(1)
+
+
+def _kill_in_64_rank_journal_with_shed_storm(journal_dir):
+    """A written 64-rank driver journal (spawns, step times, one exit
+    with -9, the resize after it) beside a serve-plane journal of 200
+    cache-exhaustion sheds: the louder incident is not the cause."""
+    wd = journal.JournalWriter(journal_dir, host="driver0", pid=1)
+    wd.append("driver", "resize", generation=1, slots=64, hosts=8,
+              first=True)
+    for r in range(64):
+        wd.append("driver", "worker_spawn", rank=r, generation=1,
+                  host=f"h{r // 8}", local_rank=r % 8)
+    for step in range(50):
+        for r in range(0, 64, 16):
+            wd.append("driver", "step_time", rank=r, step=step,
+                      step_time_sec=0.1)
+    wd.append("driver", "worker_exit", generation=1, reason="failure",
+              exit_code=-9, host="h3", local_rank=2)
+    wd.append("driver", "resize", generation=2, slots=63, hosts=8)
+    ws = journal.JournalWriter(journal_dir, host="serve0", pid=2)
+    for i in range(200):
+        ws.append("serve", "shed", reason="kv cache blocks exhausted",
+                  trace_id=f"t{i}")
+    wd.close()
+    ws.close()
+
+
+@pytest.mark.parametrize("kill", [_kill_in_simcluster,
+                                  _kill_in_64_rank_journal_with_shed_storm])
+def test_drill_worker_sigkill_mid_step(journal_dir, kill):
+    """Drill 1: a worker is SIGKILLed mid-step (no drain). The doctor
+    must name the dead rank, not the resize that cleaned up after it,
+    nor a shed storm that wrote fifty times as many events."""
+    kill(journal_dir)
     v = _diagnose(journal_dir)
     assert v["top_cause"] == "dead_rank", _causes(v)
     inc = v["incidents"][0]

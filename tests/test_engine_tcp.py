@@ -3,12 +3,13 @@ the reference's real-multi-process parallel tests (SURVEY §4: multiple
 processes on one machine, env-var rank injection)."""
 
 import os
-import socket
 import subprocess
 import sys
 import textwrap
 
 import pytest
+
+from conftest import free_port
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -53,15 +54,9 @@ WORKER = textwrap.dedent("""
 """)
 
 
-def _free_port():
-    with socket.socket() as s:
-        s.bind(("127.0.0.1", 0))
-        return s.getsockname()[1]
-
-
 def test_tcp_three_process_coordination(tmp_path):
     size = 3
-    port = _free_port()
+    port = free_port()
     script = tmp_path / "worker.py"
     script.write_text(WORKER.format(repo=REPO))
     procs = []
@@ -191,7 +186,7 @@ def test_tcp_ring_allgatherv_alltoallv_8ranks(tmp_path):
     no longer relays O(world*bytes) (VERDICT r4 item 6; reference analog:
     gloo ring selection, ops/gloo_operations.cc)."""
     size = 8
-    port = _free_port()
+    port = free_port()
     script = tmp_path / "worker.py"
     script.write_text(GATHER_WORKER.format(repo=REPO))
     procs = []
@@ -257,7 +252,7 @@ def test_data_plane_corruption_detected(tmp_path, mode, fault, size):
     """Negative path for the round-5 advisor findings: a truncated star
     Allgatherv broadcast and a corrupt RingAlltoallv bundle must surface as
     errors on every rank instead of handing callers bad offsets."""
-    port = _free_port()
+    port = free_port()
     script = tmp_path / "worker.py"
     script.write_text(FAULT_WORKER.format(repo=REPO))
     procs = []
@@ -282,7 +277,7 @@ def test_tcp_ring_data_plane(tmp_path):
     sum/max/bcast plus the ring-ops counter proving the star was bypassed
     (VERDICT r3 item 6; reference analog: gloo ring ops)."""
     size = 4
-    port = _free_port()
+    port = free_port()
     script = tmp_path / "worker.py"
     script.write_text(RING_WORKER.format(repo=REPO))
     procs = []

@@ -11,7 +11,6 @@ sleeps-as-synchronization.
 """
 
 import os
-import socket
 import subprocess
 import sys
 import textwrap
@@ -28,13 +27,9 @@ from horovod_tpu.common.exceptions import (
 )
 from horovod_tpu.engine import OP_ALLREDUCE, EngineSession, bindings
 
+from conftest import free_port
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _free_port():
-    with socket.socket() as s:
-        s.bind(("127.0.0.1", 0))
-        return s.getsockname()[1]
 
 
 def make_group(n, **kwargs):
@@ -229,7 +224,7 @@ def test_connect_retries_exhausted_fails_fast(monkeypatch):
     with pytest.raises(HorovodInternalError,
                        match="exhausted 3 connect attempts"):
         EngineSession(rank=1, size=2, transport="tcp", addr="127.0.0.1",
-                      port=_free_port(), timeout_sec=30.0)
+                      port=free_port(), timeout_sec=30.0)
     assert time.monotonic() - t0 < 10.0
 
 
@@ -260,7 +255,7 @@ def test_connect_storm_backoff_recovers(tmp_path):
     """Acceptance (c): N injected connect failures, then backoff retries
     succeed — the job comes up and the retry count is observable."""
     size = 2
-    port = _free_port()
+    port = free_port()
     script = tmp_path / "worker.py"
     script.write_text(STORM_WORKER.format(repo=REPO))
     procs = []
@@ -336,7 +331,7 @@ def test_peer_death_mid_collective_fast_abort(tmp_path):
     every survivor raises HorovodInternalError in bounded wall clock —
     fast abort, not the 30s timeout."""
     size = 3
-    port = _free_port()
+    port = free_port()
     script = tmp_path / "worker.py"
     script.write_text(DEATH_WORKER.format(repo=REPO))
     procs = []
@@ -410,7 +405,7 @@ def test_corrupt_frame_detected_by_crc(tmp_path):
     framing check and surfaces Status::Corrupted carrying the tensor name;
     the other rank is released by the fast abort."""
     size = 2
-    port = _free_port()
+    port = free_port()
     script = tmp_path / "worker.py"
     script.write_text(CRC_WORKER.format(repo=REPO))
     procs = []

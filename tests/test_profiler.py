@@ -1,7 +1,7 @@
-"""Profiler subsystem (horovod_tpu/profiler): MFU arithmetic against
-hand-computed FLOPs, the cost-analysis-vs-analytic fallback contract, the
-engine-timeline + JAX-trace merge bridge, and the conv-path mixed-precision
-policy regression (bf16 compute must keep BN statistics in fp32)."""
+"""Profiler subsystem (horovod_tpu/profiler): the engine-timeline +
+JAX-trace merge bridge, the flight dumps' Perfetto export, and the conv-path
+mixed-precision policy regression (bf16 compute must keep BN statistics in
+fp32). FLOP counting is the benchmark's (tests/benchmark/test_bench_flops.py)."""
 
 import glob
 import json
@@ -15,117 +15,7 @@ import numpy as np
 import pytest
 
 from horovod_tpu.profiler import flight
-from horovod_tpu.profiler import flops as pflops
-from horovod_tpu.profiler import mfu as pmfu
 from horovod_tpu.profiler import trace_merge
-from horovod_tpu.profiler.flops import FlopsEstimate
-
-
-# ---------------------------------------------------------------------------
-# FLOPs accounting
-
-
-def test_compiled_flops_matches_hand_matmul():
-    m, k, n = 256, 512, 128
-    got = pflops.compiled_flops(jax.jit(lambda a, b: a @ b),
-                                jnp.ones((m, k)), jnp.ones((k, n)))
-    assert got is not None
-    hand = pflops.dense_flops(m, k, n)  # 2*m*k*n
-    # XLA's cost model counts the same MACs; allow fusion slack.
-    assert 0.8 <= got / hand <= 1.3
-
-
-def test_train_step_flops_tiny_model_matches_hand():
-    """End-to-end: value_and_grad of a one-matmul model costs ~3x the
-    forward (fwd + two backward matmuls) — the same fwd/bwd ratio the
-    analytic ResNet/transformer models assume."""
-    m, k, n = 128, 256, 64
-    w = jnp.ones((k, n))
-    x = jnp.ones((m, k))
-
-    def loss(w, x):
-        return jnp.sum(x @ w)
-
-    step = jax.jit(jax.grad(loss))
-    est = pflops.train_step_flops(step, (w, x))
-    assert est.source == "xla_cost_analysis"
-    fwd = pflops.dense_flops(m, k, n)
-    # grad-of-matmul = one backward matmul (dw = x^T @ dy) after XLA DCE's
-    # the unused primal; accept anything from 1x to 4x the forward cost.
-    assert fwd * 0.5 <= est.flops <= fwd * 4.0
-
-
-def test_cost_analysis_result_shapes():
-    f = pflops._flops_from_cost_analysis
-    assert f([{"flops": 10.0}]) == 10.0     # jax <= 0.4.x list form
-    assert f({"flops": 7.0}) == 7.0         # newer dict form
-    assert f([]) is None
-    assert f({"bytes accessed": 1.0}) is None
-    assert f(None) is None
-    assert f({"flops": float("nan")}) is None
-
-
-def test_fallback_path_when_cost_analysis_unavailable():
-    # object() has no .lower and jax.jit refuses it -> compiled_flops None
-    est = pflops.train_step_flops(object(), (), fallback_flops=123.0,
-                                  fallback_detail="hand model")
-    assert est.source == "analytic"
-    assert est.flops == 123.0
-    assert bool(est)
-
-
-def test_no_fallback_reports_unavailable():
-    est = pflops.train_step_flops(object(), ())
-    assert est.source == "unavailable"
-    assert not bool(est)
-
-
-def test_analytic_models():
-    assert pflops.resnet50_train_flops_per_image() == pytest.approx(
-        3 * 4.09e9)
-    assert pflops.resnet50_train_flops_per_image(train=False) == \
-        pytest.approx(4.09e9)
-    assert pflops.transformer_train_flops_per_seq(110e6, 128) == \
-        pytest.approx(6 * 110e6 * 128)
-
-
-# ---------------------------------------------------------------------------
-# MFU calculator
-
-
-def test_mfu_arithmetic_exact():
-    # 100 items/s * 1e9 FLOP/item = 1e11 FLOP/s on a 1-TFLOP chip = 10%
-    assert pmfu.mfu(100.0, 1e9, 1.0) == pytest.approx(0.1)
-
-
-def test_mfu_rejects_unusable_inputs():
-    assert pmfu.mfu(0.0, 1e9, 100.0) == -1.0
-    assert pmfu.mfu(10.0, -1.0, 100.0) == -1.0
-    assert pmfu.mfu(10.0, 1e9, -1.0) == -1.0
-
-
-def test_peak_table_prefix_match():
-    assert pmfu.peak_tflops("TPU v5 lite") == 197.0
-    assert pmfu.peak_tflops("TPU v4 (something)") == 275.0
-    assert pmfu.peak_tflops("GPU A100") == -1.0
-
-
-def test_mfu_report_provenance():
-    est = FlopsEstimate(1e9, "analytic", "hand")
-    rep = pmfu.mfu_report(100.0, est, 1.0)
-    assert rep["mfu"] == pytest.approx(0.1)
-    assert rep["flops_source"] == "analytic"
-    assert rep["peak_tflops_bf16"] == 1.0
-    # unusable throughput must surface as -1, never 0% or a crash
-    assert pmfu.mfu_report(-1.0, est, 1.0)["mfu"] == -1.0
-
-
-def test_bench_consumes_shared_calculator():
-    """bench.py must use the profiler's constants, not re-hardcode them."""
-    import bench
-    assert bench.RESNET50_PARAMS == pflops.RESNET50_PARAMS
-    assert bench.BERT_TRAIN_FLOPS_PER_SEQ == pytest.approx(
-        pflops.transformer_train_flops_per_seq(pflops.BERT_BASE_PARAMS, 128))
 
 
 # ---------------------------------------------------------------------------

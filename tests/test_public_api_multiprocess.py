@@ -4,10 +4,11 @@ broadcast, join, shutdown (reference analog: any test/parallel/* run under
 horovodrun)."""
 
 import os
-import socket
 import subprocess
 import sys
 import textwrap
+
+from conftest import free_port
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -61,16 +62,9 @@ WORKER = textwrap.dedent("""
 """)
 
 
-def _free_port():
-    with socket.socket() as s:
-        s.bind(("127.0.0.1", 0))
-        port = s.getsockname()[1]
-    return port
-
-
 def test_public_api_three_processes(tmp_path):
     size = 3
-    port = _free_port()
+    port = free_port()
     script = tmp_path / "worker.py"
     script.write_text(WORKER.format(repo=REPO))
     procs = []
@@ -128,7 +122,9 @@ def test_subset_communicator(tmp_path):
     (reference: operations.cc:712-714, controller.h:112-117)."""
     script = tmp_path / "subset.py"
     script.write_text(SUBSET_WORKER.format(repo=REPO))
-    port = _free_port()
+    # no rendezvous KV here: the subset's engine takes the controller port
+    # plus 2 and plus 3 (basics.py, the arithmetic fallback)
+    port = free_port(span=4)
     procs = []
     for r in range(3):
         env = dict(os.environ,

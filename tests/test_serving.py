@@ -99,17 +99,45 @@ def test_queued_deadline_expires_without_execution():
                           status="expired") == 1
 
 
-def test_backpressure_rejects_when_queue_full():
-    reg, batcher, _ = _stack(queue_depth=3)
-    for i in range(3):
-        batcher.submit([i], max_new_tokens=1)
-    with pytest.raises(AdmissionRejected):
-        batcher.submit([99], max_new_tokens=1)
+@pytest.mark.parametrize("loop_running", [False, True])
+def test_backpressure_rejects_when_queue_full(loop_running):
+    """A burst of 60 far outruns the server. With nobody draining the
+    queue everything past its depth is refused; with the loop running the
+    refusals are the whole of the damage: every admitted request still
+    completes with the right tokens."""
     from horovod_tpu.metrics import snapshot_value
+    toy = make_toy_step()
+
+    def slow_step(tokens, lengths):
+        time.sleep(0.005)  # the burst arrives inside one step
+        return toy(tokens, lengths)
+
+    reg, batcher, loop = _stack(slow_step, queue_depth=3,
+                                default_deadline_ms=30000.0)
+    if loop_running:
+        loop.start()
+    admitted, rejected = [], 0
+    try:
+        for i in range(60):
+            try:
+                admitted.append(batcher.submit([i, 1], max_new_tokens=2))
+            except AdmissionRejected:
+                rejected += 1
+        if loop_running:
+            for r in admitted:
+                assert r.wait(30.0) and r.status == "ok", r.status
+    finally:
+        loop.stop()
     snap = reg.snapshot()
+    assert rejected > 0 and admitted
     assert snapshot_value(snap, "hvd_serve_requests_total",
-                          status="rejected") == 1
-    assert snapshot_value(snap, "hvd_serve_queue_depth") == 3
+                          status="rejected") == rejected
+    if loop_running:
+        assert [r.generated for r in admitted] == \
+            [_toy_reference(r.tokens[:2], 2) for r in admitted]
+    else:
+        assert len(admitted) == 3
+        assert snapshot_value(snap, "hvd_serve_queue_depth") == 3
 
 
 def test_explicit_zero_budget_is_not_the_default_cap():
@@ -355,7 +383,7 @@ def test_serving_mode_small_completes_ahead_of_bulk(monkeypatch):
 
 
 def test_small_tensor_cliff_microbench_runs():
-    """The regression microbench the BENCH serving block embeds: counters
+    """The small-tensor regression microbench: counters
     prove the express lane engaged (on) and fusion engaged (off)."""
     from horovod_tpu.serve.loadgen import small_tensor_cliff_report
     rep = small_tensor_cliff_report(iters=6, big_elems=1 << 20)
@@ -658,6 +686,24 @@ def test_driver_aggregates_serve_targets():
 # fault injection: kill a rank mid-load (die action + elastic driver)
 
 
+def _wait_for_new_generation(driver, router, timeout=60.0):
+    """Block until the driver has moved on from generation 0 and two
+    workers have registered in the new generation. The router's table
+    moves to the new generation before they have: until then it still
+    lists the dead worker's old address as "up"."""
+    deadline = time.monotonic() + timeout
+    while time.monotonic() < deadline:
+        if driver.generation >= 1:
+            router.refresh_from_kv(driver._kv.get_json)
+            up = [w for w in router.workers()
+                  if w["state"] == "up" and w["generation"] >= 1]
+            if len(up) >= 2 and router.generation >= 1:
+                return
+        time.sleep(0.25)
+    pytest.fail(f"no recovery: generation={driver.generation}, "
+                f"workers={router.workers()}")
+
+
 def test_kill_rank_mid_load_drains_and_reroutes(tmp_path):
     """The serving-plane incident drill: two elastic serve workers under
     the real driver; rank 1's engine heartbeat dies mid-run via the
@@ -730,17 +776,7 @@ def test_kill_rank_mid_load_drains_and_reroutes(tmp_path):
 
         # the driver re-routed: a new generation exists and its workers
         # re-registered (respawned rank included)
-        deadline = time.monotonic() + 60
-        while time.monotonic() < deadline:
-            if driver.generation >= 1:
-                router.refresh_from_kv(driver._kv.get_json)
-                up = [w for w in router.workers() if w["state"] == "up"]
-                if len(up) >= 2 and router.generation >= 1:
-                    break
-            time.sleep(0.25)
-        else:
-            pytest.fail(f"no recovery: generation={driver.generation}, "
-                        f"workers={router.workers()}")
+        _wait_for_new_generation(driver, router)
 
         from horovod_tpu.metrics import snapshot_value
         snap = reg.snapshot()
@@ -1104,17 +1140,7 @@ def test_kill_worker_mid_decode_with_shared_prefixes(tmp_path):
                 outcomes["other"] += 1
             time.sleep(0.15)  # staggered: reuse hits after first publish
 
-        deadline = time.monotonic() + 60
-        while time.monotonic() < deadline:
-            if driver.generation >= 1:
-                router.refresh_from_kv(driver._kv.get_json)
-                up = [w for w in router.workers() if w["state"] == "up"]
-                if len(up) >= 2 and router.generation >= 1:
-                    break
-            time.sleep(0.25)
-        else:
-            pytest.fail(f"no recovery: generation={driver.generation}, "
-                        f"workers={router.workers()}")
+        _wait_for_new_generation(driver, router)
 
         from horovod_tpu.metrics import snapshot_value
         assert (snapshot_value(reg.snapshot(),

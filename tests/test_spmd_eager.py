@@ -7,10 +7,11 @@ gloo controller likewise runs alongside NCCL, gloo_context.cc:136-147).
 """
 
 import os
-import socket
 import subprocess
 import sys
 import textwrap
+
+from conftest import free_port
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -58,16 +59,10 @@ WORKER = textwrap.dedent("""
 """)
 
 
-def _free_port():
-    with socket.socket() as s:
-        s.bind(("127.0.0.1", 0))
-        return s.getsockname()[1]
-
-
 def test_spmd_job_eager_ops(tmp_path):
     script = tmp_path / "worker.py"
     script.write_text(WORKER.format(repo=REPO))
-    ctrl_port, jaxd_port = _free_port(), _free_port()
+    ctrl_port, jaxd_port = free_port(), free_port()
     procs = []
     for r in range(2):
         env = dict(os.environ,

@@ -59,14 +59,28 @@ def test_merge_sums_counters_adds_buckets_vectors_gauges():
     assert by_rank == {"0": 4.0, "1": 7.0}
 
 
-def test_merge_is_deterministic_under_input_order():
+@pytest.mark.parametrize("regroup,whole_merge_identical", [
+    (lambda snaps: list(reversed(snaps)), True),
+    # the tiered path: each host merges its own ranks and the driver
+    # merges the hosts (what `/agg.json` serves, keyed by host index);
+    # gauges then carry a host's label, so only the counters compare
+    (lambda snaps: [(0, merge_snapshots(snaps[:2])),
+                    (1, merge_snapshots(snaps[2:]))], False),
+], ids=["reversed", "two_hosts_of_two"])
+def test_merge_is_deterministic_under_input_order(regroup,
+                                                  whole_merge_identical):
     import json
     snaps = [_snap(r, anom=r + 1, steps=[0.1 * (r + 1)]) for r in range(4)]
-    a = json.dumps(merge_snapshots(snaps), sort_keys=True)
-    b = json.dumps(merge_snapshots(list(reversed(snaps))), sort_keys=True)
-    assert a == b  # sorted-rank accumulation: byte-identical merges
-    totals = counter_totals(merge_snapshots(snaps))
-    assert totals[ANOM] == 1 + 2 + 3 + 4
+    direct = merge_snapshots(snaps)
+    again = merge_snapshots(regroup(snaps))
+    # counter totals are byte-identical whichever way the ranks were
+    # grouped: a tiered scrape reports what a direct one would
+    assert json.dumps(counter_totals(direct), sort_keys=True) == \
+        json.dumps(counter_totals(again), sort_keys=True)
+    assert counter_totals(direct)[ANOM] == 1 + 2 + 3 + 4
+    if whole_merge_identical:  # sorted-rank accumulation
+        assert json.dumps(direct, sort_keys=True) == \
+            json.dumps(again, sort_keys=True)
 
 
 # ---------------------------------------------------------------------------

@@ -6,10 +6,11 @@ test/parallel/test_tensorflow2.py)."""
 import pytest
 
 import os
-import socket
 import subprocess
 import sys
 import textwrap
+
+from conftest import free_port
 
 # TF import + graph-mode session tests push the file past the ~3 min tier-1 per-file budget (ISSUE 2 satellite: tier-1 runs -m 'not slow')
 pytestmark = pytest.mark.slow
@@ -28,19 +29,13 @@ PRELUDE = textwrap.dedent("""
 """)
 
 
-def _free_port():
-    with socket.socket() as s:
-        s.bind(("127.0.0.1", 0))
-        return s.getsockname()[1]
-
-
 def _run_workers(tmp_path, body: str, size: int, timeout: int = 300):
     script = tmp_path / "worker.py"
     script.write_text(PRELUDE + textwrap.dedent(body) + textwrap.dedent("""
         hvd.shutdown()
         print(f"tf worker {rank} OK")
     """))
-    port = _free_port()
+    port = free_port()
     procs = []
     for r in range(size):
         env = dict(os.environ,

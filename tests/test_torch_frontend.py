@@ -4,7 +4,6 @@ the analog of the reference's test/parallel/test_torch.py patterns run
 across real processes over the TCP controller."""
 
 import os
-import socket
 import subprocess
 import sys
 import textwrap
@@ -12,16 +11,12 @@ import textwrap
 import pytest
 import torch
 
+from conftest import free_port
+
 # per-dtype torch op matrix pushes the file past the ~3 min tier-1 per-file budget (ISSUE 2 satellite: tier-1 runs -m 'not slow')
 pytestmark = pytest.mark.slow
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _free_port():
-    with socket.socket() as s:
-        s.bind(("127.0.0.1", 0))
-        return s.getsockname()[1]
 
 
 def _run_workers(tmp_path, body: str, size: int, timeout: int = 180):
@@ -39,7 +34,7 @@ def _run_workers(tmp_path, body: str, size: int, timeout: int = 180):
         hvd.shutdown()
         print(f"torch worker {rank} OK")
     """))
-    port = _free_port()
+    port = free_port()
     procs = []
     for r in range(size):
         env = dict(os.environ,

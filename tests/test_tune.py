@@ -283,7 +283,7 @@ def test_follower_adopts_leader_epoch_configs():
 def test_tune_smoke_session_cuts_exposed_comm(monkeypatch):
     """The real closed loop on the real engine: the converged config must
     cut exposed comm vs the untuned bucket_bytes=0 baseline (the CPU
-    -backend acceptance figure; the BENCH tail records the exact drop)."""
+    -backend acceptance figure)."""
     from horovod_tpu.tune import smoke
     out = smoke.run_smoke(world=2, epoch_steps=4, samples=8,
                           warmup_epochs=1, scale=32,
@@ -293,7 +293,7 @@ def test_tune_smoke_session_cuts_exposed_comm(monkeypatch):
     assert out["search_trace_len"] <= 8
     assert out["exposed_comm_drop_pct"] is not None
     # the smoke's compute/wire shape gives ~90% in practice; 20% is the
-    # loaded-CI floor — the >=30% acceptance number is recorded by BENCH
+    # loaded-CI floor
     assert out["exposed_comm_drop_pct"] >= 20.0
     assert out["converged_config"]["bucket_bytes"] > 0
 
