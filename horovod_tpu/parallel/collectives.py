@@ -163,7 +163,11 @@ def allreduce_tree(tree, **kwargs):
     over a group of one nothing is left of them. What packing by hand cost
     on the chip is in :mod:`horovod_tpu.ops.fusion`. Every leaf's
     collective carries the ``hvd_allreduce_*`` scope, and
-    ``hvd_injit_collective_traces_total`` counts one per leaf."""
+    ``hvd_injit_collective_traces_total`` counts one per leaf. On several
+    TPU chips the combiner's variadic all-reduces block the chip; a leaf it
+    leaves alone is cut up and rides inside the optimizer update, by the
+    compile options the step carries
+    (:data:`horovod_tpu.parallel.dp.ASYNC_EXCHANGE_COMPILER_OPTIONS`)."""
     return jax.tree_util.tree_map(
         functools.partial(wire_allreduce, **kwargs), tree)
 

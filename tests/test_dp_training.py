@@ -310,3 +310,72 @@ def test_all_step_options_compose(dp_mesh, mnist_setup):
         losses.append(float(out.loss))
     assert all(np.isfinite(l) for l in losses), losses
     assert losses[-1] < losses[0], losses
+
+
+# -- the compile options of the asynchronous exchange stay off a CPU mesh -------
+
+def _built(engaged):
+    from horovod_tpu.metrics.registry import get_registry
+    return get_registry().counter("hvd_async_exchange_steps_total",
+                                  engaged=engaged).value
+
+
+@pytest.mark.parametrize("stateful", [False, True],
+                         ids=["plain", "stateful"])
+@pytest.mark.parametrize("replicas", [1, 4, 8])
+def test_cpu_mesh_step_is_compiled_without_options(devices, monkeypatch,
+                                                   replicas, stateful):
+    """``dp.ASYNC_EXCHANGE_COMPILER_OPTIONS`` are a TPU compiler's: on a CPU
+    mesh of any size both step builders hand ``jax.jit`` none, and the
+    registry says so."""
+    seen = []
+    real_jit = jax.jit
+
+    def jit(fun, **kwargs):
+        seen.append(kwargs.get("compiler_options", "not passed"))
+        return real_jit(fun, **kwargs)
+
+    monkeypatch.setattr(dp.jax, "jit", jit)
+    mesh = mesh_lib.data_parallel_mesh(devices[:replicas])
+    no, yes = _built("no"), _built("yes")
+
+    def loss_fn(params, *rest):
+        loss = jnp.sum(params["w"] ** 2)
+        return (loss, ({}, {})) if stateful else (loss, {})
+
+    make = dp.make_stateful_train_step if stateful else dp.make_train_step
+    make(loss_fn, optax.sgd(0.1), mesh)
+    assert seen == [None]
+    assert (_built("no"), _built("yes")) == (no + 1, yes)
+
+
+@pytest.mark.parametrize("replicas", [4, 8])
+def test_exchange_on_a_cpu_mesh_is_the_leaf_by_leaf_one(devices, replicas):
+    """The step's exchange is ``collectives.allreduce_tree`` and nothing
+    around it: the gradients a step applies are, bit for bit, the ones that
+    function gives for the same shards in a program of its own."""
+    from horovod_tpu.parallel import collectives
+    model = MnistConvNet()
+    params = model.init(jax.random.key(0),
+                        jnp.zeros((1, 28, 28, 1)))["params"]
+    loss_fn = _loss_fn_factory(model)
+    mesh = mesh_lib.data_parallel_mesh(devices[:replicas])
+    axes = tuple(a for a in dp.DP_AXES if a in mesh.shape)
+    batch = dp.shard_batch(_make_batch(8 * replicas), mesh)
+    rng = jax.random.key(7)
+    step = dp.make_train_step(loss_fn, optax.sgd(1.0), mesh, donate=False)
+    out = step(dp.replicate(params, mesh),
+               dp.replicate(optax.sgd(1.0).init(params), mesh), batch, rng)
+
+    def local(params, batch, rng):
+        rng = jax.random.fold_in(rng, collectives.axis_rank(axes))
+        grads = jax.grad(lambda p: loss_fn(p, batch, rng)[0])(params)
+        return collectives.allreduce_tree(grads, axis=axes)
+
+    grads = jax.jit(jax.shard_map(
+        local, mesh=mesh, in_specs=(P(), P(axes), P()), out_specs=P(),
+        check_vma=False))(dp.replicate(params, mesh), batch, rng)
+    for p, g, new in zip(*map(jax.tree_util.tree_leaves,
+                              (params, grads, out.params))):
+        np.testing.assert_array_equal(np.asarray(p) - np.asarray(g),
+                                      np.asarray(new))

@@ -97,7 +97,7 @@ def gpt2_width_step_text(topo):
     of a two-layer decoder at GPT-2 small widths, T = 1024 and 8 sequences
     per chip, on the first ``chips`` described devices. Compiled once each."""
     from horovod_tpu.models import GptSmall
-    from horovod_tpu.parallel import dp, mesh as mesh_lib
+    from horovod_tpu.parallel import dp, mesh as mesh_lib, zero
 
     model = GptSmall().clone(layers=2)
     opt = optax.adamw(1e-4)
@@ -108,7 +108,7 @@ def gpt2_width_step_text(topo):
             logits, batch["labels"]).mean(), {}
 
     @functools.lru_cache(maxsize=None)
-    def text(chips):
+    def text(chips, sharded_update=False):
         mesh = mesh_lib.data_parallel_mesh(topo.devices[:chips])
 
         def on_mesh(tree, spec):
@@ -120,10 +120,16 @@ def gpt2_width_step_text(topo):
         tokens = jax.ShapeDtypeStruct((8 * chips, model.max_len), jnp.int32)
         params = jax.eval_shape(model.init, jax.random.key(0),
                                 tokens)["params"]
-        step = dp.make_train_step(loss_fn, opt, mesh)
+        step = dp.make_train_step(loss_fn, opt, mesh,
+                                  sharded_update=sharded_update)
+        if sharded_update:
+            opt_state = on_mesh(jax.eval_shape(
+                lambda p: zero.sharded_opt_init(opt, p, mesh), params),
+                P(dp.DP_AXES))
+        else:
+            opt_state = on_mesh(jax.eval_shape(opt.init, params), P())
         return step.lower(
-            on_mesh(params, P()),
-            on_mesh(jax.eval_shape(opt.init, params), P()),
+            on_mesh(params, P()), opt_state,
             on_mesh({"tokens": tokens, "labels": tokens}, P(dp.DP_AXES)),
             on_mesh(jax.eval_shape(lambda: jax.random.key(1)), P()),
         ).compile().as_text()
@@ -160,6 +166,9 @@ def test_one_chip_step_has_nothing_to_exchange(gpt2_width_step_text):
     assert "all-reduce" not in text(1)
     assert EXCHANGE not in text(1)
     assert "phase_optimizer_update" in text(1)   # the scopes are there
+    # nor anything of the asynchronous exchange (PR 29): no option got there
+    assert "async_collective_fusion" not in text(1)
+    assert "async_collective_name" not in text(1)
 
 
 def test_four_chip_exchange_is_all_reduces_and_no_packing(
@@ -170,6 +179,80 @@ def test_four_chip_exchange_is_all_reduces_and_no_packing(
     assert _under_exchange(text(4), "all-reduce")
     assert not _under_exchange(text(4), "reshape", "copy", "concatenate",
                                "dynamic-update-slice")
+
+
+def _entry(text):
+    """The lines of the entry computation, in the order they run."""
+    return re.search(r"^ENTRY .*?\{\n(.*?)^\}", text, re.S | re.M).group(
+        1).splitlines()
+
+
+def _operands(line):
+    """The float32 arrays an all-reduce's result holds (a tuple's: all),
+    scalars apart: the combiner may take the loss's all-reduce along."""
+    result = line.split(" all-reduce(")[0].split(" = ", 1)[1]
+    assert not re.search(r"\b(?:bf16|f16)\[", result), result
+    return len(re.findall(r"\bf32\[\d", result))
+
+
+def test_four_chip_exchange_rides_inside_the_update(gpt2_width_step_text):
+    """With ``dp.ASYNC_EXCHANGE_COMPILER_OPTIONS`` on the step's jit, the
+    all-reduce of one operand (the tied embedding's gradient, which the
+    combiner leaves alone) is gone from the entry computation: pieces of it
+    sit in ``%async_collective_fusion`` computations that loop fusions of
+    the optimizer update call. What is left blocking is variadic (the
+    combiner's groups of the blocks' leaves, PERF.md §6, PR 29). Every
+    gradient leaf is still reduced once, in float32."""
+    text, model = gpt2_width_step_text
+    entry = _entry(text(4))
+    hosts = [i for i, line in enumerate(entry)
+             if "calls=%async_collective_fusion" in line]
+    assert len(hosts) > 8, len(hosts)
+    assert all("kind=kLoop" in entry[i] for i in hosts)
+    # the pieces ride among the update's own fusions, not after them
+    assert "phase_optimizer_update" in " ".join(entry[hosts[0]:hosts[-1]])
+    # one exchange, cut up: every piece is the same f32[vocab, hidden]
+    pieces = re.findall(r"^\s*%[\w.\-]+ = (\S+) all-reduce\(.*"
+                        r"async_collective_fusion_config", text(4), re.M)
+    assert len(pieces) >= len(hosts)
+    assert {p.split("{")[0] for p in pieces} == {
+        f"f32[{model.vocab},{model.hidden}]"}
+    blocking = [line for line in entry if " all-reduce(" in line
+                and EXCHANGE in line]
+    assert blocking and all(_operands(line) > 1 for line in blocking)
+    leaves = len(jax.tree_util.tree_leaves(jax.eval_shape(
+        model.init, jax.random.key(0),
+        jnp.zeros((1, model.max_len), jnp.int32))["params"]))
+    assert sum(map(_operands, blocking)) + 1 == leaves
+    assert not _under_exchange(text(4), "reshape", "copy", "concatenate",
+                               "dynamic-update-slice")
+
+
+@pytest.mark.parametrize("chips,engaged", [(1, "no"), (4, "yes")])
+def test_mesh_decides_the_compile_options(topo, chips, engaged):
+    """Several TPU chips take ``dp.ASYNC_EXCHANGE_COMPILER_OPTIONS``; a mesh
+    of one is compiled as before PR 29, with no option: the same program.
+    The registry counts either."""
+    from horovod_tpu.metrics.registry import get_registry
+    from horovod_tpu.parallel import dp, mesh as mesh_lib
+    built = get_registry().counter("hvd_async_exchange_steps_total",
+                                   engaged=engaged)
+    before = built.value
+    options = dp.exchange_compiler_options(
+        mesh_lib.data_parallel_mesh(topo.devices[:chips]))
+    assert options == (dp.ASYNC_EXCHANGE_COMPILER_OPTIONS
+                       if chips > 1 else None)
+    assert built.value == before + 1
+
+
+def test_zero1_step_compiles_for_four_v5e_with_the_options(
+        gpt2_width_step_text):
+    """The options govern every program of a four-chip mesh: ZeRO-1's
+    reduce-scatter (an all-reduce and a slice on a 2x2) still builds."""
+    text, model = gpt2_width_step_text
+    zero1 = text(4, sharded_update=True)
+    assert zero1.count("tpu_custom_call") == 3 * model.layers
+    assert "all-reduce" in zero1 and "phase_param_gather" in zero1
 
 
 # -- the expert layer at OLMoE's widths ----------------------------------------
