@@ -4,6 +4,12 @@ from horovod_tpu.models.gpt import (  # noqa: F401
     GptMedium,
     GptSmall,
 )
+from horovod_tpu.models.nemotron_h import (  # noqa: F401
+    Nemotron3Nano30B,
+    NemotronHDecoder,
+    NemotronHTiny,
+    nemotron_h_loss,
+)
 from horovod_tpu.models.olmoe import (  # noqa: F401
     Olmoe1B7B,
     OlmoeDecoder,

@@ -555,7 +555,21 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array,
     path regardless of length — the XLA path cannot honor them, and
     silently dropping them would change the return contract or the causal
     mask (ring attention relies on exactly these).
+
+    Grouped-query attention: ``k`` and ``v`` may hold fewer heads than
+    ``q`` where ``q``'s are a multiple; query head ``j`` attends key head
+    ``j // (Hq / Hkv)``. The key heads are repeated to the query heads here,
+    before either path (their gradient is the sum over each group): the
+    kernels take equal heads only, and reading a key head once for its whole
+    group inside them is ``ROADMAP.md`` work.
     """
+    if k.shape[2] != q.shape[2]:
+        group, rest = divmod(q.shape[2], k.shape[2])
+        if rest or v.shape[2] != k.shape[2]:
+            raise ValueError(
+                f"{q.shape[2]} query heads are no multiple of "
+                f"{k.shape[2]} key and {v.shape[2]} value heads")
+        k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
     if flash_kwargs.get("return_lse") or \
             flash_kwargs.get("q_offset") is not None or \
             flash_kwargs.get("k_offset") is not None:

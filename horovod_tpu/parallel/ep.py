@@ -5,37 +5,65 @@ The reference has no MoE — SURVEY §2.8 records EP as ABSENT, with its
 alltoall primitive (operations.cc:1101-1162) named as the building block an
 expert-parallel layer needs.
 
-**:func:`moe_topk` — what trains** (``models/olmoe.py``; every expert on the
-chip, data-parallel over chips). Top-k routing that drops nothing, with
-static shapes and no ``[T, E, C]`` one-hot:
+**:func:`moe_dropless` — what trains** (``models/olmoe.py`` through
+:func:`moe_topk`, ``models/nemotron_h.py`` directly; data-parallel over
+chips). Top-k routing that drops nothing, with static shapes and no
+``[T, E, C]`` one-hot. The layer is given its two model-specific halves as
+functions, not as flags:
 
-- ``moe_router``: ``logits = x @ w_router`` and the softmax in float32 over
-  all experts, ``top_k`` weights and indices, not renormalised
-  (:func:`route_topk`);
+- **a router**, ``route(x) -> (weights [T, k], experts [T, k], scores
+  [T, E], logits [T, E])``, in float32 over *all* ``E`` experts:
+  :func:`route_topk` is OLMoE's (softmax, the k largest probabilities, not
+  renormalised); :func:`route_sigmoid_topk` is the DeepSeek-V3 / Nemotron-H
+  one (sigmoid scores, the choice made on ``scores + bias`` where ``bias`` is
+  a correction no gradient reaches, the weights the chosen *scores* without
+  the bias, renormalised and scaled);
+- **an expert**, ``expert(dot, rows, *weights) -> rows``, written over the
+  grouped matmul ``dot(rows, w)`` the layer hands it: :func:`swiglu_expert`
+  (``w_down(silu(w_gate x) * w_up x)``, three matrices),
+  :func:`relu2_expert` (``w_down relu(w_up x)^2``, two).
+
+The layer's own four parts are ``jax.named_scope``s
+(``profiler/annotate.MOE_SCOPES``), so a device trace says what each
+operation was:
+
+- ``moe_router``: the router, and the per-expert pair counts;
 - ``moe_dispatch``: the ``k T`` (token, slot) pairs are sorted by expert
-  (a stable argsort of the expert indices), the per-expert group sizes are
-  counted, and the tokens' rows are gathered into that order;
+  (a stable argsort), and the tokens' rows are gathered into that order;
 - ``moe_experts``: one grouped matmul per projection over the sorted rows
   (``jax.lax.ragged_dot``, which the TPU compiler lowers to its own Mosaic
-  kernel), expert = ``w_down(silu(w_gate x) * w_up x)``;
+  kernel);
 - ``moe_combine``: the rows are gathered back into token order and summed
   with their router weights.
 
-Those four names are ``jax.named_scope``s (``profiler/annotate.MOE_SCOPES``),
-so a device trace says what each operation of the layer was. Both
-permutations are bijections of the ``k T`` rows and their backward passes
-are the inverse gathers: nothing on this path is a scatter-add. The layer
-returns :class:`MoeStats`: the per-expert pair counts (they sum to ``k T``:
-no capacity, no drop, under any imbalance) and what the auxiliary losses
-need (:func:`load_balancing_loss`, the router z-loss).
+Both permutations are bijections of the ``k T`` rows and their backward
+passes are the inverse gathers: nothing on this path is a scatter-add. The
+layer returns :class:`MoeStats`: the per-expert pair counts over all ``E``
+(they sum to ``k T``: no capacity, no drop, under any imbalance) and what
+auxiliary losses need (:func:`load_balancing_loss`, the router z-loss).
+
+**A share** (``held = (first, count)``): the layer holds ``count`` of the
+router's ``E`` experts, ``first .. first + count - 1``, as one chip of an
+expert-parallel deployment does, and the expert weights it is given have
+``count`` leading rows. It still routes over all ``E`` and computes
+*exactly* the held experts' part of the sum: the held experts' pairs sort
+first, in expert order, the group sizes are the held experts' counts, and
+the rows past their sum belong to no group. ``ragged_dot`` promises nothing
+about such rows (the TPU's kernel spends no time on them and leaves them
+unwritten: whatever the buffer held, NaNs in a training step), so the
+layer's ``dot`` zeroes them by row index on the way in and on the way out:
+zero output, zero gradient. What the absent experts
+would have added is left out; the shares of a layer add up to the whole
+layer (``tests/test_expert_parallel.py``). On one chip a share runs without
+its exchange, and nothing here stands in for the absent chips.
 
 **:func:`moe_layer` — the exchange over the ``expert`` axis** (unit tests and
 the CPU dry run only; on no measured path). GShard-style top-1 routing into
 fixed-capacity ``[experts, capacity, d]`` buffers built from dense
 ``[T, E, C]`` one-hots (:func:`top1_dispatch`; tokens past capacity are
 dropped), one ``lax.all_to_all`` each way, two-matrix experts sharded over
-the axis. Composing :func:`moe_topk`'s routing with that exchange is
-ROADMAP Reach 1's four-chip cell.
+the axis. Composing the dropless layer's routing and shares with that
+exchange is ROADMAP Reach 1's four-chip cell.
 
 Shapes of :func:`moe_topk`: tokens ``[T, d]``; ``w_router`` ``[d, E]``;
 ``w_gate``, ``w_up`` ``[E, d, f]``; ``w_down`` ``[E, f, d]``. Of
@@ -47,7 +75,7 @@ Shapes of :func:`moe_topk`: tokens ``[T, d]``; ``w_router`` ``[d, E]``;
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple, Tuple
+from typing import Callable, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -81,6 +109,28 @@ def route_topk(x: jax.Array, w_router: jax.Array, k: int
     weights = jnp.sum(jnp.where(_chosen_mask(experts, probs.shape[-1]),
                                 probs[:, None, :], 0.0), axis=-1)
     return weights, experts, probs, logits
+
+
+def route_sigmoid_topk(x: jax.Array, w_router: jax.Array, bias: jax.Array,
+                       k: int, scale: float = 1.0
+                       ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """Sigmoid router with a correction bias (DeepSeek-V3, arXiv:2412.19437;
+    ``NemotronHTopkRouter``), in float32 at the highest matmul precision as
+    :func:`route_topk`. x: [T, d]; w_router: [d, E]; bias: [E]. The choice
+    is the top k of ``sigmoid(x w) + bias``; the bias moves the choice alone:
+    the weights are the chosen experts' scores *without* it, divided by
+    their sum + 1e-20 and multiplied by ``scale``, and no gradient reaches
+    the bias. Returns (weights [T, k], experts [T, k] int32, scores [T, E],
+    logits [T, E])."""
+    logits = jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    experts = lax.top_k(lax.stop_gradient(scores + bias), k)[1] \
+        .astype(jnp.int32)
+    chosen = jnp.sum(jnp.where(_chosen_mask(experts, scores.shape[-1]),
+                               scores[:, None, :], 0.0), axis=-1)
+    weights = chosen / (chosen.sum(axis=-1, keepdims=True) + 1e-20) * scale
+    return weights, experts, scores, logits
 
 
 def _chosen_mask(experts: jax.Array, n_experts: int) -> jax.Array:
@@ -128,48 +178,98 @@ def _permute_bwd(saved, g):
 _permute.defvjp(_permute_fwd, _permute_bwd)
 
 
-def moe_topk(x: jax.Array, w_router: jax.Array, w_gate: jax.Array,
-             w_up: jax.Array, w_down: jax.Array, k: int
-             ) -> Tuple[jax.Array, MoeStats]:
+def swiglu_expert(dot, rows, w_gate, w_up, w_down):
+    """``w_down(silu(w_gate x) * w_up x)``: OLMoE's gated expert."""
+    return dot(jax.nn.silu(dot(rows, w_gate)) * dot(rows, w_up), w_down)
+
+
+def relu2_expert(dot, rows, w_up, w_down):
+    """``w_down relu(w_up x)^2``: Nemotron-H's expert, two matrices and no
+    gate."""
+    return dot(jnp.square(jax.nn.relu(dot(rows, w_up))), w_down)
+
+
+def _held_first(experts: jax.Array, first: int, count: int) -> jax.Array:
+    """Sort keys of a share: a held expert's index among the held, and
+    ``count`` for every pair of an expert that lives elsewhere."""
+    local = experts - first
+    return jnp.where((local >= 0) & (local < count), local, count)
+
+
+def moe_dropless(x: jax.Array, route: Callable, expert: Callable,
+                 expert_weights: Sequence[jax.Array],
+                 held: Optional[Tuple[int, int]] = None
+                 ) -> Tuple[jax.Array, MoeStats]:
     """One dropless top-k expert layer over the tokens it is given.
 
-    x: [T, d] in the compute dtype; w_router: [d, E] (used in float32);
-    w_gate, w_up: [E, d, f]; w_down: [E, f, d], in the compute dtype.
-    Returns ([T, d] in x's dtype, :class:`MoeStats`). Every (token, slot)
-    pair is computed, whatever the imbalance: ``stats.expert_tokens`` sums
-    to ``k T``.
+    x: [T, d] in the compute dtype. ``route(x)`` is the router over all E
+    experts and ``expert(dot, rows, *expert_weights)`` one expert's function
+    over the grouped matmul ``dot`` (the module text has both contracts);
+    ``expert_weights`` lead with the experts held here: all E, or with
+    ``held = (first, count)`` the ``count`` from ``first`` on. Returns
+    ([T, d] in x's dtype: the held experts' part of the weighted sum,
+    :class:`MoeStats` over all E). Every (token, slot) pair of a held expert
+    is computed, whatever the imbalance; ``stats.expert_tokens`` sums to
+    ``k T``.
     """
     t, d = x.shape
-    n_experts = w_router.shape[-1]
-    if not (w_gate.shape[0] == w_up.shape[0] == w_down.shape[0]
-            == n_experts):
-        raise ValueError(
-            f"w_router routes to {n_experts} experts but the expert "
-            f"weights hold {w_gate.shape[0]}, {w_up.shape[0]} and "
-            f"{w_down.shape[0]}")
     with moe_scope("moe_router"):
-        weights, experts, probs, logits = route_topk(x, w_router, k)
+        weights, experts, scores, logits = route(x)
+        k, n_experts = experts.shape[-1], scores.shape[-1]
         stats = MoeStats(
             expert_tokens=jnp.sum(_chosen_mask(experts, n_experts),
                                   axis=(0, 1), dtype=jnp.int32),
-            router_prob_mean=probs.mean(axis=0),
+            router_prob_mean=scores.mean(axis=0),
             router_z_loss=jnp.mean(
                 jax.nn.logsumexp(logits, axis=-1) ** 2))
+    first, count = held if held is not None else (0, n_experts)
+    if not 0 <= first <= first + count <= n_experts or any(
+            w.shape[0] != count for w in expert_weights):
+        raise ValueError(
+            f"the router routes to {n_experts} experts and the layer holds "
+            f"{count} from {first} on, but the expert weights lead with "
+            f"{[w.shape[0] for w in expert_weights]}")
     with moe_scope("moe_dispatch"):
         # pair t * k + slot; stable, so an expert's rows keep token order
-        order = jnp.argsort(experts.reshape(-1), stable=True)
+        keys = experts.reshape(-1)
+        if held is not None:
+            keys = _held_first(keys, first, count)
+        order = jnp.argsort(keys, stable=True)
         inverse = jnp.argsort(order)
         rows = _gather_sorted(x, order, inverse, k)
     with moe_scope("moe_experts"):
-        sizes = stats.expert_tokens
-        hidden = jax.nn.silu(lax.ragged_dot(rows, w_gate, sizes)) * \
-            lax.ragged_dot(rows, w_up, sizes)
-        rows = lax.ragged_dot(hidden, w_down, sizes)
+        sizes = stats.expert_tokens[first:first + count]
+        if held is None:
+            def dot(a, w):
+                return lax.ragged_dot(a, w, sizes)
+        else:
+            # the rows past the held experts' pairs are in no group
+            in_a_group = (jnp.arange(k * t) < sizes.sum())[:, None]
+
+            def dot(a, w):
+                a = jnp.where(in_a_group, a, jnp.zeros((), a.dtype))
+                out = lax.ragged_dot(a, w, sizes)
+                return jnp.where(in_a_group, out, jnp.zeros((), out.dtype))
+        rows = expert(dot, rows, *expert_weights)
     with moe_scope("moe_combine"):
         rows = _permute(rows, inverse, order).reshape(t, k, d)
         out = jnp.einsum("tk,tkd->td", weights, rows,
                          preferred_element_type=jnp.float32)
     return out.astype(x.dtype), stats
+
+
+def moe_topk(x: jax.Array, w_router: jax.Array, w_gate: jax.Array,
+             w_up: jax.Array, w_down: jax.Array, k: int
+             ) -> Tuple[jax.Array, MoeStats]:
+    """OLMoE's layer: :func:`moe_dropless` under :func:`route_topk` with
+    :func:`swiglu_expert`, every expert held.
+
+    x: [T, d] in the compute dtype; w_router: [d, E] (used in float32);
+    w_gate, w_up: [E, d, f]; w_down: [E, f, d], in the compute dtype.
+    """
+    return moe_dropless(
+        x, functools.partial(route_topk, w_router=w_router, k=k),
+        swiglu_expert, (w_gate, w_up, w_down))
 
 
 def load_balancing_loss(expert_tokens: jax.Array,

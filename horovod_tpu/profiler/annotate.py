@@ -8,7 +8,12 @@ Two distinct mechanisms, matching where the work actually happens:
   operation of a step says whether it is forward/backward, gradient
   exchange, optimizer update, parameter gather or output sync.
 - :func:`moe_scope` — the parts of an expert layer (:data:`MOE_SCOPES`:
-  router, dispatch, experts, combine), written by ``parallel/ep.moe_topk``.
+  router, dispatch, experts, combine, written by ``parallel/ep.py``'s
+  dropless layer; ``moe_shared``, the shared expert every token visits, by
+  the model that has one).
+- :func:`ssm_scope` — the parts of a Mamba-2 mixer (:data:`SSM_SCOPES`:
+  in-projection, causal conv, the chunked scan of ``ops/ssd.py``, gated
+  group norm, out-projection), written by ``models/nemotron_h.py``.
 - :func:`collective_scope` — ``jax.named_scope`` for code that runs INSIDE a
   jitted program (the in-jit collectives of ``parallel/collectives.py``).
   The scope becomes HLO op-name metadata, so the device trace of a
@@ -45,7 +50,13 @@ PHASE_PREFIX = "phase_"
 # The parts of one expert layer (``parallel/ep.moe_topk``), under the step's
 # ``phase_forward_backward``. Neither ``phase_`` nor ``hvd_``: the phase and
 # collective readers key on those prefixes.
-MOE_SCOPES = ("moe_router", "moe_dispatch", "moe_experts", "moe_combine")
+MOE_SCOPES = ("moe_router", "moe_dispatch", "moe_experts", "moe_combine",
+              "moe_shared")
+# The parts of one Mamba-2 mixer (``models/nemotron_h.py``; ``ssm_scan`` is
+# ``ops/ssd.ssd_chunked``), under the same phase and with a prefix of their
+# own for the same reason.
+SSM_SCOPES = ("ssm_in_proj", "ssm_conv", "ssm_scan", "ssm_gate_norm",
+              "ssm_out_proj")
 # Host spans the step wrapper (``metrics.timed_step``) writes.
 STEP_SPAN = "hvd.step"
 STEP_DISPATCH_SPAN = "hvd.step.dispatch"
@@ -64,6 +75,14 @@ def moe_scope(name: str):
     if name not in MOE_SCOPES:
         raise ValueError(f"unknown expert-layer scope {name!r}; one of "
                          f"{MOE_SCOPES}")
+    return collective_scope(name)
+
+
+def ssm_scope(name: str):
+    """Name the enclosed traced ops as one part of a state-space mixer."""
+    if name not in SSM_SCOPES:
+        raise ValueError(f"unknown state-space mixer scope {name!r}; one of "
+                         f"{SSM_SCOPES}")
     return collective_scope(name)
 
 

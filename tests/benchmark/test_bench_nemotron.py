@@ -1,0 +1,340 @@
+"""The cell PR 30 added: ``run.py --rehearse`` for ``nemotron3n-t8192`` at
+the files' rehearse sizes on the CPU, the configuration's parameter and FLOP
+counts, how ``BENCHMARK.json`` holds the cell, and the state-space mixer's
+readers (``harness/ssm.py``) on a hand-built trace and a hand-written
+compiled text with known answers."""
+
+import json
+
+import jax
+import numpy as np
+import pytest
+
+import bench_paths  # noqa: F401  (puts benchmark/ on sys.path)
+from bench_run import check_rehearsal_result, result_line, run_cell
+from harness import hlo_text, ssm
+from harness import spec as spec_lib
+from harness.job import Run
+from harness.trace_reduce import DeviceTrace, Span, Trace
+
+CELL, CONFIG, TRAFFIC = "nemotron3n-t8192", "nemotron-3-nano-30b-a3b", \
+    "t8192-b1"
+MS = 1e6  # nanoseconds
+FB = "jit(_local_step)/phase_forward_backward"
+MIXER = "/jvp(NemotronHDecoder)/NemotronHBlock_0/NemotronHMamba2Mixer_0/"
+REMAT = "/checkpoint/rematted_computation/NemotronHBlock_0/" \
+    "NemotronHMamba2Mixer_0/"
+BACK = "/transpose(jvp(NemotronHDecoder))/NemotronHBlock_0/" \
+    "NemotronHMamba2Mixer_0/"
+
+
+def job_of(rehearse=False):
+    spec = spec_lib.load()
+    config, builder = spec_lib.config(spec, CONFIG, rehearse)
+    module = spec_lib.load_module(builder)
+    return module, module.build(config, spec_lib.traffic(TRAFFIC, rehearse)), \
+        config
+
+
+# -- the rehearsals --------------------------------------------------------------
+
+def test_rehearsal_ends_correct_and_reports_the_end_to_end_metrics():
+    result, earlier = result_line(run_cell(
+        "--workload", CELL, "--rehearse", "--seconds", "1", "--seed",
+        "2147483653", "--trace", "0"))
+    check_rehearsal_result(result, 1, {"tokens_per_s_per_chip",
+                                       "peak_hbm_gb", "setup_s"})
+    assert result["correct"] is True
+    facts = earlier[0]
+    assert facts["pattern"] == "ME*E" and facts["attention"] == "flash"
+    assert facts["experts"] == 16 and facts["experts_held"] == [4, 4]
+    checks = next(e for e in earlier if "checks" in e)
+    assert checks["programs_in_windows"] == 0
+    assert all(checks["checks"].values())
+    reference = next(e for e in earlier
+                     if e.get("check") == "float32 reference")
+    assert reference["loss_relative_error"] <= reference["loss_rtol"]
+    assert len(reference["gradient_relative_l2_error"]) == 14
+
+
+def test_traced_rehearsal_leaves_the_device_readers_out():
+    """No device plane on the CPU: the mixer's readers find nothing to read,
+    return None, and the line leaves their metrics out."""
+    result, earlier = result_line(run_cell(
+        "--workload", CELL, "--rehearse", "--seconds", "1", "--trace", "1"))
+    check_rehearsal_result(result, 1, {
+        "init_s", "compile_s", "programs_after_warmup", "host_dispatch_ms"})
+    assert not any("ssm_ms" in e or "moe_ms" in e for e in earlier)
+
+
+# -- the configuration -------------------------------------------------------------
+
+def test_parameter_count_is_the_issues_and_the_trees():
+    """666 963 456: by the configuration's own arithmetic and by a count
+    over the initialised tree's shapes (the 4 x 128 correction biases are
+    state, counted with the parameters as ISSUE 30 counts them)."""
+    _, job, config = job_of()
+    params, state = jax.eval_shape(job.init, jax.random.key(0))
+    count = sum(int(np.prod(x.shape)) for x in
+                jax.tree_util.tree_leaves(params))
+    biases = sum(int(np.prod(x.shape)) for path, x in
+                 jax.tree_util.tree_flatten_with_path(state)[0]
+                 if "bias" in jax.tree_util.keystr(path))
+    stated = config["deployment"]["parameters"]
+    assert biases == stated["of_which_router_state_not_parameters"] == 512
+    assert count + biases == stated["what_runs"] == 666963456
+    assert stated["what_runs"] == \
+        4 * stated["one_mamba2_layer"] + stated["one_attention_layer"] \
+        + 4 * stated["one_expert_layer_without_routed_experts"] \
+        + 4 * 8 * stated["one_routed_expert"] + 2 * 16384 * 2688 + 2688
+    assert 12 * stated["what_runs"] / 1e9 == pytest.approx(
+        config["deployment"]["resident_gb"], abs=0.005)
+
+
+def test_flop_count_by_hand():
+    """ISSUE 30's forward MFLOP a token at 8192: mixers 4 x (77.4 + 3.4),
+    expert layers 4 x 48.1, attention 113.9, the sliced head 88.1; 718
+    forward, 2.15 GFLOP trained."""
+    module, job, _ = job_of()
+    forward = module.nemotron_h_forward_flops_per_token(
+        "MEMEM*EME", hidden=2688, mamba_heads=64, mamba_head_dim=64,
+        state=128, groups=8, chunk=128, heads=32, kv_heads=2, head_dim=128,
+        experts=128, experts_per_token=6, held=8, expert_dim=1856,
+        shared_dim=3712, vocab=16384, seq=8192)
+    parts = forward["parts"]
+    assert parts["mamba_projections"] == 2 * 2688 * (10304 + 4096)
+    assert parts["mamba_scan"] == 2 * (128 * (8 * 128 + 4096)
+                                       + 2 * 4096 * 128)
+    assert parts["shared_expert"] == 4 * 2688 * 3712
+    assert parts["held_experts"] == 0.375 * 4 * 2688 * 1856
+    assert parts["attention_scores"] == 4 * 4096 * 8193 / 2
+    assert forward["mamba"] / 1e6 == pytest.approx(323.3, abs=0.1)
+    assert forward["experts"] / 1e6 == pytest.approx(192.3, abs=0.1)
+    assert forward["attention"] / 1e6 == pytest.approx(113.9, abs=0.1)
+    assert forward["head"] / 1e6 == pytest.approx(88.1, abs=0.1)
+    total = sum(forward[k] for k in ("mamba", "experts", "attention",
+                                     "head"))
+    assert job.model_flops_per_item == 3 * total
+    assert job.model_flops_per_item / 1e9 == pytest.approx(2.153, abs=0.001)
+
+
+def test_scan_cost_counts_what_no_program_can_avoid():
+    module, job, _ = job_of()
+    flops, nbytes = module.ssd_scan_cost(8192, 64, 64, 128, 8, 128)
+    masked = 2 * (64.5 * (8 * 128 + 4096) + 2 * 4096 * 128)
+    assert flops == 3 * 8192 * masked
+    assert flops < 3 * 8192 * module.ssd_forward_flops_per_token(
+        64, 64, 128, 8, 128)
+    # x and y 8192 B a position, B and C 4096, dt 256 (float32)
+    assert nbytes == 8192 * (3 * (8192 + 4096 + 256) + 2 * 8192)
+    assert job.facts["ssd_scan_flops_per_layer_step"] == flops
+    assert job.facts["ssd_scan_bytes_per_layer_step"] == nbytes
+    assert job.facts["ssm_layers"] == 4
+
+
+def test_configuration_is_at_the_published_widths():
+    """No width differs from ``published``; ``reduced`` lists exactly depth,
+    pattern, experts held and vocabulary."""
+    spec = spec_lib.load()
+    entry = next(c for c in spec["configs"] if c["name"] == CONFIG)
+    config, _ = spec_lib.config(spec, CONFIG)
+    published = config["published"]
+    differing = {k for k, v in published.items() if config[k] != v}
+    assert differing == {"hybrid_override_pattern", "n_routed_experts",
+                         "vocab_size"}
+    assert entry["reduced"] == config["reduced"] == [
+        "num_layers", "hybrid_override_pattern", "n_routed_experts",
+        "vocab_size"]
+    assert config["num_layers"] == 9 == len(config["hybrid_override_pattern"])
+    assert published["hybrid_override_pattern"].startswith(
+        config["hybrid_override_pattern"])
+    assert config["experts_held"] == {"first": 0, "of": 128}
+    assert config["n_routed_experts"] == 8
+    assert config["vocab_size"] * 8 == published["vocab_size"]
+    assert config["recompute"]["kinds"] == "ME"
+    job = job_of()[1]
+    assert job.stateful and job.flash_call == (1, 8192, 32, 128, True)
+    assert job.flash_layers == 1 and job.expected_custom_calls == 47
+    leaves = {"/".join(path[1:]) for path in job.check_leaves}
+    for leaf in ("NemotronHMamba2Mixer_0/A_log",
+                 "NemotronHMamba2Mixer_0/dt_bias",
+                 "NemotronHMamba2Mixer_0/conv1d/kernel",
+                 "NemotronHMamba2Mixer_0/in_proj/kernel",
+                 "NemotronHMamba2Mixer_0/out_proj/kernel",
+                 "NemotronHMoE_0/gate/weight",
+                 "NemotronHMoE_0/experts/up_proj",
+                 "NemotronHMoE_0/shared_experts/up_proj/kernel",
+                 "NemotronHAttention_0/k_proj/kernel", "embedding",
+                 "kernel"):
+        assert leaf in leaves, leaf
+    assert {path[0] for path in job.check_leaves} >= {
+        "NemotronHBlock_0", "NemotronHBlock_7", "NemotronHBlock_1",
+        "NemotronHBlock_8", "NemotronHBlock_5", "Embed_0", "LmHead"}
+
+
+def test_benchmark_json_holds_the_cell_together():
+    spec = spec_lib.load()
+    cell = spec_lib.workload(spec, CELL)
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        CONFIG, TRAFFIC, 1)
+    assert spec["workloads"][-1] is cell
+    assert spec["configs"][-1]["name"] == CONFIG
+    end_to_end = {m["name"] for m in spec_lib.metrics(spec, "end_to_end",
+                                                      CELL)}
+    assert end_to_end == {"tokens_per_s_per_chip", "peak_hbm_gb", "setup_s"}
+    got = {m["name"] for m in spec_lib.metrics(spec, "per_layer", CELL)}
+    like = {m["name"] for m in spec_lib.metrics(spec, "per_layer",
+                                                "olmoe-t4096")}
+    # the expert layer's readers under names of their own: the accepted
+    # entries' lists are pinned to ``olmoe-t4096`` by its tests
+    assert got == (like - {"moe_experts_mfu", "moe_time_share",
+                           "moe_dispatch_ms"}) | {
+        "ssm_time_share", "ssm_scan_ms", "ssm_scan_roofline",
+        "moe_time_share.share", "moe_dispatch_ms.share"}
+    added = spec["per_layer"][-5:]
+    assert [(m["name"], m["layer"]) for m in added] == [
+        ("ssm_time_share", "state-space mixer"),
+        ("ssm_scan_ms", "state-space mixer"),
+        ("ssm_scan_roofline", "state-space mixer"),
+        ("moe_time_share.share", "expert share"),
+        ("moe_dispatch_ms.share", "expert share")]
+    for m in added:
+        assert m["workloads"] == [CELL]
+        assert m["moves"] == "tokens_per_s_per_chip"
+        assert m["source"] == "program_span"
+    assert added[2]["unit"] == "%" and added[2]["better"] == "higher"
+    assert spec_lib.layer_reader("moe_time_share.share").__module__ == \
+        spec_lib.layer_reader("moe_time_share").__module__
+    traffic = spec_lib.traffic(TRAFFIC)
+    assert (traffic["per_chip_batch"], traffic["seq_len"]) == (1, 8192)
+    assert (traffic["block_steps"], traffic["warmup_blocks"],
+            traffic["trace_blocks"], traffic["step"]) == (5, 2, 3, {})
+    memory = traffic["memory_analysis"]
+    assert memory["workload"] == CELL and memory["tpu_custom_calls"] == 47
+    assert 10.67e9 < memory["argument_bytes"] + memory["temp_bytes"] < 15.0e9
+    four = [c for c in spec["workloads"] if c["chips"] == 4]
+    assert len(spec["workloads"]) == 7 and len(four) == 1
+
+
+# -- the mixer's readers ---------------------------------------------------------
+
+INSTRUCTIONS = [
+    # name, op_name, scope, says so itself
+    ("inp.1", FB + MIXER + "ssm_in_proj/in_proj/dot_general", "ssm_in_proj",
+     True),
+    ("conv.1", FB + MIXER + "ssm_conv/conv1d/mul", "ssm_conv", True),
+    ("scan.1", FB + MIXER + "ssm_scan/dot_general", "ssm_scan", True),
+    ("carry.1", FB + MIXER + "ssm_scan/while", "ssm_scan", True),
+    ("norm.1", FB + MIXER + "ssm_gate_norm/norm/mul", "ssm_gate_norm", True),
+    ("out.1", FB + MIXER + "ssm_out_proj/out_proj/dot_general",
+     "ssm_out_proj", True),
+    ("rdot.1", "ragged-dot-none", None, True),
+    ("head.1", FB + "/jvp(NemotronHDecoder)/LmHead/dot_general", None, True),
+    ("rescan.1", FB + REMAT + "ssm_scan/dot_general", "ssm_scan", True),
+    ("bscan.1", FB + BACK + "ssm_scan/dot_general", "ssm_scan", True),
+    ("bconv.1", FB + BACK + "ssm_conv/conv1d/mul", "ssm_conv", True),
+    ("adam.1", "jit(_local_step)/phase_optimizer_update/add", None, True),
+    ("copy.1", None, None, False),
+]
+
+
+def text(instructions):
+    def line(name, op_name):
+        meta = f', metadata={{op_name="{op_name}"}}' if op_name else ""
+        return f"  %{name} = f32[8]{{0}} add(%a, %a){meta}\n"
+    return ("HloModule jit__local_step, is_scheduled=true\n\n"
+            "ENTRY %main (a: f32[8]) -> f32[8] {\n"
+            "  %a = f32[8]{0} parameter(0)\n" +
+            "".join(line(name, op_name) for name, op_name, *_ in
+                    instructions) + "}\n")
+
+
+@pytest.fixture(scope="module")
+def hlo():
+    return hlo_text.HloIndex(text(INSTRUCTIONS))
+
+
+class FakeJob:
+    # least: 4 layers x max(2e9 / 1e12, 1e6 / 1e9) s = 8 ms
+    facts = {"ssm_layers": 4, "ssd_scan_flops_per_layer_step": 2.0e9,
+             "ssd_scan_bytes_per_layer_step": 1.0e6}
+
+
+def run_of(hlo, job=FakeJob):
+    return Run(job=job, chips=1, block_steps=2,
+               peaks={"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e9},
+               hlo=hlo, program=hlo.module, init_s=0.0, compile_s=0.0,
+               programs_after_warmup=0, dispatch_seconds=[],
+               items_per_step_per_chip=1000.0)
+
+
+@pytest.mark.parametrize("name,op_name,scope,own", INSTRUCTIONS,
+                         ids=[i[0] for i in INSTRUCTIONS])
+def test_scope_of_an_instruction(hlo, name, op_name, scope, own):
+    assert ssm.scope_of(hlo.get(name)) == (scope, own)
+
+
+def one_step():
+    """One step run. The compiler's unnamed copy inherits whatever came
+    before it: nothing at the start, ``ssm_scan`` after the scan, nothing
+    after a ragged dot."""
+    def ops(*named):
+        return [Span(name, lo * MS, hi * MS) for name, lo, hi in named]
+    return Trace(devices=[DeviceTrace(0, ops=ops(
+        ("copy.1", 0, 1),       # before any scope: outside the mixer
+        ("inp.1", 1, 11),       # in_proj 10
+        ("conv.1", 11, 13),     # conv 2
+        ("scan.1", 13, 23),     # scan 10
+        ("copy.1", 23, 25),     # inherits scan 2
+        ("carry.1", 25, 27),    # scan 2
+        ("norm.1", 27, 30),     # gate_norm 3
+        ("out.1", 30, 35),      # out_proj 5
+        ("rdot.1", 35, 40),     # the expert layer's: outside 5
+        ("copy.1", 40, 41),     # inherits outside
+        ("head.1", 41, 51),     # outside 10
+        ("rescan.1", 51, 61),   # scan (recomputed) 10
+        ("bscan.1", 61, 77),    # scan (backward) 16
+        ("bconv.1", 77, 80),    # conv (backward) 3
+        ("adam.1", 80, 100),    # outside 20
+    ), modules=[Span("jit__local_step(1)", 0, 100 * MS)])],
+        host=[Span("bench.block", 0, 100 * MS)])
+
+
+def test_parts_rules_and_the_three_readers(hlo, capsys):
+    trace, run = one_step(), run_of(hlo)
+    found = ssm.reduce(trace, hlo, hlo.module)
+    ms = {k: round(1e3 * v, 6) for k, v in found["seconds"].items()}
+    assert ms == {"ssm_in_proj": 10.0, "ssm_conv": 5.0, "ssm_scan": 40.0,
+                  "ssm_gate_norm": 3.0, "ssm_out_proj": 5.0}
+    assert found["inherited"] == pytest.approx({"ssm_scan": 0.002})
+    assert found["total"] == pytest.approx(0.100)
+    reader = spec_lib.layer_reader
+    assert reader("ssm_time_share")(trace, run) == pytest.approx(63.0)
+    assert reader("ssm_scan_ms")(trace, run) == pytest.approx(48.0)
+    # least 8 ms (compute-bound at these peaks) over 40 ms under ssm_scan
+    assert reader("ssm_scan_roofline")(trace, run) == pytest.approx(20.0)
+    said = [json.loads(x) for x in capsys.readouterr().out.splitlines()]
+    assert len(said) == 1  # made once for the three readers
+    assert said[0]["ssm_total_ms"] == pytest.approx(63.0)
+    assert said[0]["busy_in_steps_ms"] == pytest.approx(100.0)
+
+
+def test_a_program_without_the_mixer_reads_nothing():
+    """The parent's programs and every other configuration: no ``ssm_*``
+    scope, so the readers return None and raise nothing."""
+    other = hlo_text.HloIndex(text(
+        [i for i in INSTRUCTIONS if i[0] in ("rdot.1", "head.1", "adam.1")]))
+    trace, run = one_step(), run_of(other)
+    for name in ("ssm_time_share", "ssm_scan_ms", "ssm_scan_roofline"):
+        assert spec_lib.layer_reader(name)(trace, run) is None
+        assert spec_lib.layer_reader(name)(Trace(), run) is None
+        assert spec_lib.layer_reader(name)(None, run) is None
+
+
+def test_a_job_without_the_scans_cost_reads_no_roofline(hlo):
+    class NoFacts:
+        facts = {}
+    assert spec_lib.layer_reader("ssm_scan_roofline")(
+        one_step(), run_of(hlo, NoFacts)) is None
+    assert spec_lib.layer_reader("ssm_scan_ms")(
+        one_step(), run_of(hlo, NoFacts)) == pytest.approx(48.0)

@@ -83,6 +83,16 @@ def test_gpt_train_example():
     assert "done: final loss" in text, text
 
 
+def test_nemotron_h_train_example():
+    """The stateful step's language-model example: the routers' correction
+    biases are model state, synced over four virtual devices."""
+    text = _run_script(
+        "examples/jax/jax_nemotron_h_train.py", ("--steps", "8"),
+        env_extra={"XLA_FLAGS":
+                   "--xla_force_host_platform_device_count=4"})
+    assert "replicas 4" in text and "done: final loss" in text, text
+
+
 def test_jax_serve_example():
     """The serving-plane walkthrough (batcher -> router -> drain) runs
     end-to-end over real HTTP on the virtual mesh."""

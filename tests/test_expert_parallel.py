@@ -2,7 +2,10 @@
 references: the top-1 capacity-routed exchange over the ``expert`` axis
 (SURVEY §2.8: EP over the alltoall primitive — the layer the reference
 lacks), and below it the dropless top-k layer that trains (``moe_topk``;
-top-1 is ``k = 1``)."""
+top-1 is ``k = 1``), whole and cut into shares (``moe_dropless`` with
+``held``)."""
+
+import functools
 
 import numpy as np
 import jax
@@ -11,8 +14,10 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from horovod_tpu.parallel import mesh as mesh_lib
-from horovod_tpu.parallel.ep import (load_balancing_loss, moe_layer,
-                                     moe_topk, route_topk, top1_dispatch)
+from horovod_tpu.parallel.ep import (load_balancing_loss, moe_dropless,
+                                     moe_layer, moe_topk, relu2_expert,
+                                     route_sigmoid_topk, route_topk,
+                                     top1_dispatch)
 
 N = 8  # expert-axis extent
 D, H = 16, 32
@@ -259,3 +264,144 @@ def test_router_weights_are_not_renormalised_and_losses_by_hand():
     counts = jnp.zeros((E,), jnp.int32).at[:4].set(T)
     collapsed = load_balancing_loss(counts, counts / (4.0 * T), 4)
     assert float(collapsed) == pytest.approx(E / 4 * 4.0)
+
+
+# -- a share of the experts (moe_dropless with held): what one chip of an
+# expert-parallel deployment computes ------------------------------------------
+
+K_SHARE = 4
+SHARED = 40  # the shared expert's width
+
+
+def _share_weights(seed=0):
+    rng = np.random.RandomState(seed)
+    return {k: jnp.asarray(v, jnp.float32) for k, v in dict(
+        router=rng.randn(D, E) * 0.5, bias=rng.randn(E) * 0.05,
+        up=rng.randn(E, D, F) * 0.2, down=rng.randn(E, F, D) * 0.2,
+        shared_up=rng.randn(D, SHARED) * 0.2,
+        shared_down=rng.randn(SHARED, D) * 0.2).items()}
+
+
+def _relu2(x, w_up, w_down):
+    return jnp.square(jnp.maximum(x @ w_up, 0.0)) @ w_down
+
+
+def _uncut_layer(x, w):
+    """The whole layer, dense: every expert for every token, masked by the
+    choice (top k of sigmoid + bias; weights without the bias, renormalised,
+    x 2.5), plus the shared expert once."""
+    scores = jax.nn.sigmoid(x @ w["router"])
+    chosen = jax.lax.top_k(scores + w["bias"], K_SHARE)[1]
+    picked = (chosen[:, :, None] == jnp.arange(E)).any(axis=1)
+    gate = jnp.where(picked, scores, 0.0)
+    gate = 2.5 * gate / gate.sum(-1, keepdims=True)
+    hidden = jnp.square(jnp.maximum(
+        jnp.einsum("td,edf->tef", x, w["up"]), 0.0))
+    return jnp.einsum("te,tef,efd->td", gate, hidden, w["down"]) + \
+        _relu2(x, w["shared_up"], w["shared_down"])
+
+
+def _share(x, w, first, count):
+    """One chip's routed part: the experts first .. first + count - 1."""
+    route = functools.partial(route_sigmoid_topk, w_router=w["router"],
+                              bias=w["bias"], k=K_SHARE, scale=2.5)
+    return moe_dropless(
+        x, route, relu2_expert,
+        (w["up"][first:first + count], w["down"][first:first + count]),
+        held=(first, count))
+
+
+@pytest.mark.parametrize("shares", [1, 2, 4, 8])
+def test_the_shares_add_up_to_the_uncut_layer(shares):
+    """16 experts cut into 1, 2, 4 and 8 shares: the shares' routed parts
+    plus the shared expert counted once are the uncut reference's whole
+    layer, outputs and the gradients of tokens and router; each share's
+    counts are over all 16 experts and sum to k T."""
+    w = _share_weights(shares)
+    x = jnp.asarray(np.random.RandomState(20 + shares).randn(T, D),
+                    jnp.float32)
+    count = E // shares
+
+    def cut_layer(x, w):
+        parts = [_share(x, w, first, count)
+                 for first in range(0, E, count)]
+        for _, stats in parts:
+            assert stats.expert_tokens.shape == (E,)
+        return sum(out for out, _ in parts) + \
+            _relu2(x, w["shared_up"], w["shared_down"]), \
+            [stats.expert_tokens for _, stats in parts]
+    got, counts = jax.jit(cut_layer)(x, w)
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(_uncut_layer(x, w)),
+                               rtol=2e-4, atol=2e-5)
+    for c in counts:
+        assert int(c.sum()) == K_SHARE * T
+        np.testing.assert_array_equal(np.asarray(c), np.asarray(counts[0]))
+
+    def loss(layer, x, w):
+        return jnp.sum(jnp.tanh(layer(x, w)) ** 2)
+    got = jax.jit(jax.grad(lambda x, w: loss(
+        lambda *a: cut_layer(*a)[0], x, w), argnums=(0, 1)))(x, w)
+    want = jax.grad(lambda x, w: loss(_uncut_layer, x, w),
+                    argnums=(0, 1))(x, w)
+    assert float(jnp.abs(got[1]["bias"]).sum()) == 0.0  # no gradient
+    for g, v in zip(jax.tree_util.tree_leaves((got[0], {
+            k: a for k, a in got[1].items() if k != "bias"})),
+            jax.tree_util.tree_leaves((want[0], {
+                k: a for k, a in want[1].items() if k != "bias"}))):
+        assert float(jnp.abs(v).sum()) > 0
+        np.testing.assert_allclose(np.asarray(g), np.asarray(v), rtol=2e-3,
+                                   atol=2e-5)
+
+
+def test_a_share_that_is_sent_no_pair_gives_zeros_and_zero_gradients():
+    """A bias of -10 on the held experts: no token chooses them. The share's
+    output is zero, and so is every gradient it returns."""
+    w = _share_weights(5)
+    w["bias"] = w["bias"].at[4:8].set(-10.0)
+    x = jnp.asarray(np.random.RandomState(6).randn(T, D), jnp.float32)
+    out, stats = jax.jit(lambda x, w: _share(x, w, 4, 4))(x, w)
+    assert np.asarray(stats.expert_tokens)[4:8].sum() == 0
+    assert int(stats.expert_tokens.sum()) == K_SHARE * T
+    assert (np.asarray(out) == 0).all()
+    grads = jax.jit(jax.grad(
+        lambda x, w: jnp.sum(_share(x, w, 4, 4)[0] + 1.0) ** 2,
+        argnums=(0, 1)))(x, w)
+    for g in jax.tree_util.tree_leaves(grads):
+        assert np.isfinite(np.asarray(g)).all()
+        assert (np.asarray(g) == 0).all()
+
+
+def test_a_share_drops_nothing_when_every_pair_goes_to_one_held_expert():
+    """The router pushed to send every token's first choice to expert 6,
+    which this share holds: all T pairs are computed, as the dense
+    reference's share."""
+    w = _share_weights(7)
+    w["bias"] = w["bias"].at[6].set(10.0)
+    x = jnp.asarray(np.random.RandomState(8).randn(T, D), jnp.float32)
+    out, stats = jax.jit(lambda x, w: _share(x, w, 4, 4))(x, w)
+    counts = np.asarray(stats.expert_tokens)
+    assert counts[6] == T and counts.sum() == K_SHARE * T
+    scores = jax.nn.sigmoid(x @ w["router"])
+    chosen = jax.lax.top_k(scores + w["bias"], K_SHARE)[1]
+    picked = (chosen[:, :, None] == jnp.arange(E)).any(axis=1)
+    gate = jnp.where(picked, scores, 0.0)
+    gate = 2.5 * gate / gate.sum(-1, keepdims=True)
+    hidden = jnp.square(jnp.maximum(
+        jnp.einsum("td,edf->tef", x, w["up"][4:8]), 0.0))
+    want = jnp.einsum("te,tef,efd->td", gate[:, 4:8], hidden, w["down"][4:8])
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), rtol=2e-4,
+                               atol=2e-5)
+
+
+def test_a_share_rejects_weights_that_do_not_lead_with_its_count():
+    w = _share_weights()
+    route = functools.partial(route_sigmoid_topk, w_router=w["router"],
+                              bias=w["bias"], k=K_SHARE)
+    x = jnp.zeros((4, D))
+    with pytest.raises(ValueError, match="holds 4 from 4 on"):
+        moe_dropless(x, route, relu2_expert, (w["up"][:3], w["down"][:3]),
+                     held=(4, 4))
+    with pytest.raises(ValueError, match="routes to 16"):
+        moe_dropless(x, route, relu2_expert, (w["up"][:8], w["down"][:8]),
+                     held=(12, 8))

@@ -135,6 +135,28 @@ def test_an_unknown_phase_is_an_error():
     assert annotate.PHASE_PREFIX == "phase_"
 
 
+@pytest.mark.parametrize("scope,names,prefix,known,unknown", [
+    (annotate.moe_scope, annotate.MOE_SCOPES, "moe_", "moe_shared",
+     "moe_everything"),
+    (annotate.ssm_scope, annotate.SSM_SCOPES, "ssm_", "ssm_scan",
+     "ssm_everything")], ids=["moe", "ssm"])
+def test_layer_scopes_take_their_names_and_refuse_others(
+        scope, names, prefix, known, unknown):
+    """The expert layer's and the state-space mixer's parts: a name of the
+    list is written into the traced operations' ``op_name``; any other name
+    is an error. Neither prefix is a phase's or a collective's."""
+    assert known in names
+    assert all(n.startswith(prefix) for n in names)
+    with pytest.raises(ValueError, match="unknown .* scope"):
+        scope(unknown)
+
+    def f(x):
+        with scope(known):
+            return x * 2.0
+    text = jax.jit(f).lower(jnp.ones((4,))).as_text(debug_info=True)
+    assert known in text
+
+
 def test_wrapped_step_writes_one_span_a_call(mesh4, tmp_path):
     from jax.profiler import ProfileData
     step, state, batch = _job("plain", "allreduce", mesh4)

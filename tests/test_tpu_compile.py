@@ -297,3 +297,77 @@ def test_expert_layer_moves_rows_by_gathers_alone(olmoe_layer_text):
     for scope in ("moe_router", "moe_dispatch", "moe_experts",
                   "moe_combine"):
         assert scope in olmoe_layer_text
+
+
+# -- the Nemotron-H cell at its real size ----------------------------------------
+
+@pytest.fixture(scope="module")
+def nemotron_cell(topo):
+    """``nemotron3n-t8192`` as ``benchmark/compile_check.py`` compiles it:
+    the configuration's own job (nine layers at the published widths, 8192
+    tokens, blocks M and E recomputed) through
+    ``dp.make_stateful_train_step`` for one described chip."""
+    import sys
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path[:0] = [os.path.join(repo, "benchmark")]
+    from harness import spec as spec_lib
+    from horovod_tpu.parallel import dp, mesh as mesh_lib
+    spec = spec_lib.load()
+    cell = spec_lib.workload(spec, "nemotron3n-t8192")
+    traffic = spec_lib.traffic(cell["traffic"])
+    config, builder = spec_lib.config(spec, cell["config"])
+    job = spec_lib.load_module(builder).build(config, traffic)
+    mesh = mesh_lib.data_parallel_mesh(topo.devices[:1])
+
+    def on_mesh(tree, partition):
+        sharding = NamedSharding(mesh, partition)
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=sharding), tree)
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    params, state = jax.eval_shape(job.init, key)
+    batch = jax.eval_shape(functools.partial(job.make_batch, n=1), key)
+    step = dp.make_stateful_train_step(job.loss_fn, job.optimizer, mesh,
+                                       donate=True)
+    old = fa.flash_attention
+    fa.flash_attention = functools.partial(old, interpret=False)
+    try:
+        compiled = step.lower(
+            on_mesh(params, P()),
+            on_mesh(jax.eval_shape(job.optimizer.init, params), P()),
+            on_mesh(state, P()), on_mesh(batch, P(dp.DP_AXES)),
+            on_mesh(key, P())).compile()
+    finally:
+        fa.flash_attention = old
+    return job, traffic, compiled
+
+
+def test_nemotron_cell_fits_one_v5e_at_full_size(nemotron_cell):
+    job, traffic, compiled = nemotron_cell
+    memory = compiled.memory_analysis()
+    total = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    assert 10.67e9 < total < 15.0e9, total
+    # 667 M parameters and AdamW's moments at 12 bytes
+    assert memory.argument_size_in_bytes == pytest.approx(8.0e9, rel=2e-3)
+    recorded = traffic["memory_analysis"]
+    assert recorded["argument_bytes"] == memory.argument_size_in_bytes
+    assert recorded["temp_bytes"] == pytest.approx(
+        memory.temp_size_in_bytes, rel=0.02)
+
+
+def test_nemotron_cell_holds_its_kernels_and_scopes(nemotron_cell):
+    """Three flash kernels (the attention block keeps its activations) and
+    eleven ``ragged-dot`` calls in each of four expert layers whose forward
+    is recomputed; every ``ssm_*`` scope and ``moe_shared`` in the text."""
+    from horovod_tpu.profiler.annotate import MOE_SCOPES, SSM_SCOPES
+    job, _, compiled = nemotron_cell
+    text = compiled.as_text()
+    names = re.findall(r'custom_call_target="tpu_custom_call".*?'
+                       r'op_name="([^"]*)"', text)
+    ragged = [n for n in names if n.startswith("ragged-dot")]
+    assert len(names) == job.expected_custom_calls == 47
+    assert len(ragged) == 44
+    for scope in SSM_SCOPES + MOE_SCOPES:
+        assert scope in text, scope
+    opcodes = re.findall(r"[\s)]([a-z\-]+)\(", text)
+    assert "all-reduce" not in opcodes  # one chip exchanges nothing
