@@ -21,7 +21,7 @@ import optax
 
 import horovod_tpu as hvd
 from horovod_tpu.models import NemotronHTiny, nemotron_h_loss
-from horovod_tpu.parallel import dp
+from horovod_tpu.parallel import dp, ep
 
 
 def main():
@@ -35,7 +35,10 @@ def main():
     mesh = hvd.mesh()
     replicas = mesh.devices.size
 
-    model = NemotronHTiny()  # Mamba-2, experts, attention, experts
+    # Mamba-2, experts, attention, experts; each replica holds experts 0-1
+    # of 8 as one chip of an expert-parallel deployment does, and walks
+    # its share of the sorted (token, slot) pairs in static tiles
+    model = NemotronHTiny(experts_held=(0, 2))
     corpus = np.random.RandomState(0).randint(
         0, model.vocab, (args.batch_per_replica * replicas, args.seq_len))
     tokens = jnp.asarray(corpus, jnp.int32)
@@ -66,11 +69,17 @@ def main():
               if "bias" in jax.tree_util.keystr(path)]
     load = np.asarray(out.aux["expert_tokens"])
     largest = max(np.abs(b).max() for b in biases)
+    # the state carries the mean load over the replicas: of the tiles a
+    # share's walk was built with, the ones a replica worked in
+    pairs = model.experts_per_token * args.batch_per_replica * args.seq_len
+    tiles = [ep.share_tiles(layer, model.experts_held, pairs, record=True)
+             for layer in load]
     if hvd.rank() == 0:
         print(f"replicas {replicas}; loss {first:.4f} -> {last:.4f}; "
               f"largest correction bias {largest:.4f}; "
               f"expert load of the last step, first expert layer: "
-              f"{load[0].astype(int).tolist()}")
+              f"{load[0].astype(int).tolist()}; live tiles of those built, "
+              f"by expert layer: {tiles}")
     assert last < first, (first, last)
     if hvd.rank() == 0:
         print(f"done: final loss {last:.4f}")

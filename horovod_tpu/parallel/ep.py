@@ -34,28 +34,64 @@ operation was:
   (``jax.lax.ragged_dot``, which the TPU compiler lowers to its own Mosaic
   kernel);
 - ``moe_combine``: the rows are gathered back into token order and summed
-  with their router weights.
+  with their router weights, in float32.
 
-Both permutations are bijections of the ``k T`` rows and their backward
-passes are the inverse gathers: nothing on this path is a scatter-add. The
-layer returns :class:`MoeStats`: the per-expert pair counts over all ``E``
-(they sum to ``k T``: no capacity, no drop, under any imbalance) and what
-auxiliary losses need (:func:`load_balancing_loss`, the router z-loss).
+On a full load (``held=None``: every expert here) both permutations are
+bijections of the ``k T`` rows and their backward passes are the inverse
+gathers: nothing on that path is a scatter-add. The layer returns
+:class:`MoeStats`: the per-expert pair counts over all ``E`` (they sum to
+``k T``: no capacity, no drop, under any imbalance) and what auxiliary losses
+need (:func:`load_balancing_loss`, the router z-loss).
 
 **A share** (``held = (first, count)``): the layer holds ``count`` of the
 router's ``E`` experts, ``first .. first + count - 1``, as one chip of an
 expert-parallel deployment does, and the expert weights it is given have
 ``count`` leading rows. It still routes over all ``E`` and computes
-*exactly* the held experts' part of the sum: the held experts' pairs sort
-first, in expert order, the group sizes are the held experts' counts, and
-the rows past their sum belong to no group. ``ragged_dot`` promises nothing
-about such rows (the TPU's kernel spends no time on them and leaves them
-unwritten: whatever the buffer held, NaNs in a training step), so the
-layer's ``dot`` zeroes them by row index on the way in and on the way out:
-zero output, zero gradient. What the absent experts
-would have added is left out; the shares of a layer add up to the whole
-layer (``tests/test_expert_parallel.py``). On one chip a share runs without
-its exchange, and nothing here stands in for the absent chips.
+*exactly* the held experts' part of the sum; what the absent experts would
+have added is left out, and the shares of a layer add up to the whole layer
+(``tests/test_expert_parallel.py``). On one chip a share runs without its
+exchange, and nothing here stands in for the absent chips.
+
+The held experts' pairs sort *first*, in expert order, so they are a prefix
+of the sorted pairs whose length, the sum of the held experts' counts, the
+layer has. A share touches only that prefix: it **walks the sorted pairs in
+static tiles** of ``C`` rows (:func:`_walk`) and works only in the tiles
+that begin before the last held pair. ``C`` is :func:`share_tile_rows`:
+1.5 x the ``k T count / E`` pairs a balanced router sends the held experts,
+in whole row tiles of 512, computed from ``k``, ``T``, ``count`` and ``E``
+alone: on a balanced step one tile is live and the rest cost a loop's exit
+(4608 of 49 152 rows for 8 of 128 experts under top-6 of 8192 tokens). No
+capacity and no drop: if every pair chooses a held expert every tile is
+live. Everything done to rows happens inside the walk, a tile at a time:
+the gather of the tile's tokens' rows (``moe_dispatch``), the expert
+function over ``ragged_dot`` with the tile's own group sizes, the held
+experts' cumulative counts clipped to the tile's range (``moe_experts``),
+and the way back: the rows times their router weights are *scatter-added*
+into a float32 ``[T, d]`` (``moe_combine``: a tile's rows are a sparse
+subset of the ``[T, k]`` slots, so there is no bijection to invert). In the
+one tile that the last held pair cuts, the rows past it belong to no group.
+``ragged_dot`` promises nothing about such rows (the TPU's kernel spends no
+time on them and leaves them unwritten: whatever the buffer held, NaNs in a
+training step), so the tile's ``dot`` zeroes them by row index on the way
+in and on the way out: zero output, zero gradient.
+
+The walk is a ``jax.custom_vjp`` over two loops whose trip count is read
+from the data, one forward and one backward, so each ``ragged_dot`` is in
+the program once a direction whatever the number of tiles, and a tile that
+is not live costs nothing at all. It keeps no activation: the backward loop
+gathers a live tile's rows and takes them through the experts again (under
+a recomputed block that is the block's one recomputation: the recomputed
+forward walk's result is needed by nothing and the compiler removes it),
+gathers the tile's rows of the output's gradient, scatter-adds the rows'
+gradients into a float32 ``[T, d]``, and sums the expert weights' gradients
+over the live tiles in their own dtype (one live tile: exact; float32 sums
+were a gigabyte of the step's temporaries). At trace time
+``hvd_moe_share_tiles_total{kind="built"}`` counts the tiles a layer call
+was built with and ``hvd_moe_share_tile_rows`` holds ``C``; which tiles were
+live is data, read back outside the step: :func:`share_tiles` over the load
+a model's state carries. A share whose rule gives ``C = k T`` (1.5 x its
+part of the experts reaches all pairs) is the one-tile program with the
+zeroing by row index over all ``k T`` rows.
 
 **:func:`moe_layer` — the exchange over the ``expert`` axis** (unit tests and
 the CPU dry run only; on no measured path). GShard-style top-1 routing into
@@ -196,6 +232,174 @@ def _held_first(experts: jax.Array, first: int, count: int) -> jax.Array:
     return jnp.where((local >= 0) & (local < count), local, count)
 
 
+def _group_dot(sizes: jax.Array, in_a_group: jax.Array) -> Callable:
+    """The grouped matmul over rows of which only those ``in_a_group``
+    ([rows, 1] bool) belong to one of the ``sizes`` groups: the others are
+    zeroed on the way in and on the way out."""
+    def dot(a, w):
+        a = jnp.where(in_a_group, a, jnp.zeros((), a.dtype))
+        out = lax.ragged_dot(a, w, sizes)
+        return jnp.where(in_a_group, out, jnp.zeros((), out.dtype))
+    return dot
+
+
+# -- a share's walk over its sorted pairs -------------------------------------
+
+SHARE_TILE_HEADROOM = 1.5  # a tile, over the pairs a balanced router sends
+SHARE_TILE_MULTIPLE = 512  # rows: whole row tiles of the grouped matmul
+
+
+def share_tile_rows(k_t: int, count: int, n_experts: int) -> int:
+    """Rows of one tile of the walk: ``SHARE_TILE_HEADROOM`` x the
+    ``k T count / E`` pairs a balanced router sends ``count`` held experts
+    of ``n_experts``, rounded up to ``SHARE_TILE_MULTIPLE``; never more than
+    the ``k T`` pairs there are (a full load is one tile: no walk)."""
+    balanced = SHARE_TILE_HEADROOM * k_t * count / n_experts
+    multiples = max(1, -(-int(balanced) // SHARE_TILE_MULTIPLE))
+    return min(k_t, multiples * SHARE_TILE_MULTIPLE)
+
+
+def _share_tiles_counter(kind: str):
+    from horovod_tpu.metrics.registry import get_registry
+    return get_registry().counter(
+        "hvd_moe_share_tiles_total",
+        "tiles of a share's walk over its sorted pairs: built into a layer "
+        "call (at trace time), live in a step (read back from its load)",
+        kind=kind)
+
+
+def _count_built_tiles(tiles: int, rows: int):
+    """Monitoring, at trace time as ``hvd_ssd_chunks_total`` is."""
+    from horovod_tpu.metrics.registry import get_registry
+    _share_tiles_counter("built").inc(tiles)
+    get_registry().gauge(
+        "hvd_moe_share_tile_rows",
+        "rows of one tile of the share's walk traced last").set(rows)
+
+
+def share_tiles(load, held: Tuple[int, int], k_t: int, record: bool = False
+                ) -> Tuple[int, int]:
+    """(live, built): of the ``built`` tiles a share's walk is compiled
+    with, the ``live`` ones a step that routed this ``load`` worked in.
+    Host side, outside the step: ``load`` is one expert layer's pairs per
+    expert over all E (``MoeStats.expert_tokens``, or the ``load`` a
+    model's ``router_state`` carries to the next step), ``k_t`` its
+    (token, slot) pairs. ``record`` adds ``live`` to
+    ``hvd_moe_share_tiles_total{kind="live"}``."""
+    first, count = held
+    rows = share_tile_rows(k_t, count, len(load))
+    held_rows = int(sum(float(n) for n in load[first:first + count]))
+    live, built = -(-held_rows // rows), -(-k_t // rows)
+    if record:
+        _share_tiles_counter("live").inc(live)
+    return live, built
+
+
+def _rows_of(x: jax.Array, index: jax.Array) -> jax.Array:
+    """``x[index]`` for indices that a sort of the pairs made: in bounds."""
+    return x.at[index].get(mode="promise_in_bounds")
+
+
+def _share_tiles_of(tile, x, order, weights, sizes, expert):
+    """What both directions of a share's walk read: (the number of tiles
+    that begin before the last held pair, the function of a tile's index
+    that gives (its tokens, its pairs' router weights, its tokens' rows,
+    the experts as a function of (rows, *expert_weights))). A tile's
+    grouped matmul has the held experts' cumulative counts clipped to the
+    tile's range as group sizes, and a row past the last held pair is in no
+    group."""
+    k = weights.shape[-1]
+    order = jnp.pad(order, (0, -order.shape[0] % tile))  # whole tiles
+    by_pair = weights.reshape(-1)
+    ends = jnp.cumsum(sizes)
+
+    def at(i):
+        lo = i * tile
+
+        def inside(bounds):
+            return jnp.clip(bounds, lo, lo + tile)
+        pairs = lax.dynamic_slice(order, (lo,), (tile,))
+        tokens = lax.div(pairs, k)
+        dot = _group_dot(inside(ends) - inside(ends - sizes),
+                         (lo + jnp.arange(tile) < ends[-1])[:, None])
+        with moe_scope("moe_dispatch"):
+            rows = _rows_of(x, tokens)
+
+        def experts(rows, *expert_weights):
+            with moe_scope("moe_experts"):
+                return expert(dot, rows, *expert_weights)
+        return tokens, _rows_of(by_pair, pairs), rows, experts
+    return lax.div(ends[-1] + (tile - 1), tile), at
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _walk(x, order, inverse, weights, sizes, expert_weights, expert, tile):
+    """A share's dispatch, experts and combine as one walk over the sorted
+    pairs in tiles of ``tile`` rows, of which only those that begin before
+    the last held pair do anything: [T, d] float32, the held experts' part
+    of the weighted sum. Two loops with a trip count read from the data,
+    one forward and one backward, so each ``ragged_dot`` is in the program
+    once per direction, whatever the number of tiles."""
+    live, at = _share_tiles_of(tile, x, order, weights, sizes, expert)
+
+    def one_tile(i, out):
+        tokens, weight, rows, experts = at(i)
+        rows = experts(rows, *expert_weights)
+        with moe_scope("moe_combine"):
+            return out.at[tokens].add(
+                weight[:, None] * rows.astype(jnp.float32),
+                mode="promise_in_bounds")
+    return lax.fori_loop(0, live, one_tile, jnp.zeros(x.shape, jnp.float32))
+
+
+def _walk_fwd(x, order, inverse, weights, sizes, expert_weights, expert,
+              tile):
+    return _walk(x, order, inverse, weights, sizes, expert_weights, expert,
+                 tile), (x, order, inverse, weights, sizes, expert_weights)
+
+
+def _walk_bwd(expert, tile, saved, g):
+    """The tiles of the forward walk, live ones only. The walk keeps no
+    activation: a tile's rows are gathered and taken through the experts
+    again (under a recomputed block that is the one recomputation: the
+    forward walk's result is not needed there, and goes). What the tiles
+    add to the tokens' gradient is summed in float32, to the expert
+    weights' in the weights' dtype, as ``ragged_dot`` hands it over: exact
+    with one live tile (float32 sums were a gigabyte of the step's
+    temporaries at Nemotron-H's widths)."""
+    x, order, inverse, weights, sizes, expert_weights = saved
+    live, at = _share_tiles_of(tile, x, order, weights, sizes, expert)
+
+    def one_tile(i, carry):
+        d_x, d_by_row, d_experts = carry
+        tokens, weight, rows, experts = at(i)
+        rows, pull = jax.vjp(experts, rows, *expert_weights)
+        with moe_scope("moe_combine"):
+            g_rows = _rows_of(g, tokens)
+            d_by_row = lax.dynamic_update_slice(
+                d_by_row, jnp.sum(g_rows * rows.astype(jnp.float32), axis=-1),
+                (i * tile,))
+            d_rows = (weight[:, None] * g_rows).astype(rows.dtype)
+        d_rows, *d_tile = pull(d_rows)
+        with moe_scope("moe_experts"):
+            d_experts = tuple(a + d for a, d in zip(d_experts, d_tile))
+        with moe_scope("moe_dispatch"):
+            d_x = d_x.at[tokens].add(d_rows.astype(jnp.float32),
+                                     mode="promise_in_bounds")
+        return d_x, d_by_row, d_experts
+
+    d_x, d_by_row, d_experts = lax.fori_loop(0, live, one_tile, (
+        jnp.zeros(x.shape, jnp.float32),
+        jnp.zeros(-(-order.shape[0] // tile) * tile, jnp.float32),
+        tuple(jnp.zeros_like(w) for w in expert_weights)))
+    with moe_scope("moe_combine"):
+        d_weights = _rows_of(d_by_row, inverse).reshape(weights.shape)
+    return d_x.astype(x.dtype), None, None, d_weights, None, d_experts
+
+
+_walk.defvjp(_walk_fwd, _walk_bwd)
+
+
 def moe_dropless(x: jax.Array, route: Callable, expert: Callable,
                  expert_weights: Sequence[jax.Array],
                  held: Optional[Tuple[int, int]] = None
@@ -211,6 +415,15 @@ def moe_dropless(x: jax.Array, route: Callable, expert: Callable,
     :class:`MoeStats` over all E). Every (token, slot) pair of a held expert
     is computed, whatever the imbalance; ``stats.expert_tokens`` sums to
     ``k T``.
+
+    The sorted pairs are worked in tiles of :func:`share_tile_rows` rows, a
+    number computed from ``k``, ``T`` and ``count / E`` alone. A full load
+    is one tile of ``k T`` rows, and that is the program below the walk:
+    the rows gathered into expert order, one grouped matmul per projection,
+    the rows permuted back by the inverse gather. A share of fewer experts
+    is :func:`_walk`, which does nothing for a tile past the last held pair
+    and returns a live tile's weighted rows to their tokens by a
+    scatter-add into float32 (module text, "A share").
     """
     t, d = x.shape
     with moe_scope("moe_router"):
@@ -236,20 +449,22 @@ def moe_dropless(x: jax.Array, route: Callable, expert: Callable,
             keys = _held_first(keys, first, count)
         order = jnp.argsort(keys, stable=True)
         inverse = jnp.argsort(order)
+    sizes = stats.expert_tokens[first:first + count]
+    tile = share_tile_rows(k * t, count, n_experts)
+    if tile < k * t:
+        _count_built_tiles(-(-k * t // tile), tile)
+        out = _walk(x, order, inverse, weights, sizes, tuple(expert_weights),
+                    expert, tile)
+        return out.astype(x.dtype), stats
+    with moe_scope("moe_dispatch"):
         rows = _gather_sorted(x, order, inverse, k)
     with moe_scope("moe_experts"):
-        sizes = stats.expert_tokens[first:first + count]
         if held is None:
             def dot(a, w):
                 return lax.ragged_dot(a, w, sizes)
         else:
             # the rows past the held experts' pairs are in no group
-            in_a_group = (jnp.arange(k * t) < sizes.sum())[:, None]
-
-            def dot(a, w):
-                a = jnp.where(in_a_group, a, jnp.zeros((), a.dtype))
-                out = lax.ragged_dot(a, w, sizes)
-                return jnp.where(in_a_group, out, jnp.zeros((), out.dtype))
+            dot = _group_dot(sizes, (jnp.arange(k * t) < sizes.sum())[:, None])
         rows = expert(dot, rows, *expert_weights)
     with moe_scope("moe_combine"):
         rows = _permute(rows, inverse, order).reshape(t, k, d)

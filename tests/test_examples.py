@@ -91,6 +91,7 @@ def test_nemotron_h_train_example():
         env_extra={"XLA_FLAGS":
                    "--xla_force_host_platform_device_count=4"})
     assert "replicas 4" in text and "done: final loss" in text, text
+    assert "live tiles of those built" in text, text
 
 
 def test_jax_serve_example():

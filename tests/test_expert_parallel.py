@@ -405,3 +405,198 @@ def test_a_share_rejects_weights_that_do_not_lead_with_its_count():
     with pytest.raises(ValueError, match="routes to 16"):
         moe_dropless(x, route, relu2_expert, (w["up"][:8], w["down"][:8]),
                      held=(12, 8))
+
+
+# -- a share's walk over its sorted pairs in static tiles ----------------------
+
+@pytest.fixture
+def small_tiles(monkeypatch):
+    """Tiles a tiny layer walks in threes: the rule's rounding to the
+    grouped matmul's row tiles (512) would make 384 pairs one tile."""
+    from horovod_tpu.parallel import ep
+    monkeypatch.setattr(ep, "SHARE_TILE_MULTIPLE", 8)
+    return ep
+
+
+FIRST, COUNT = 4, 4
+K_T = K_SHARE * T
+TILE = 144  # 1.5 x the 96 pairs a balanced router sends 4 of 16 experts
+
+
+def _choices(n_held):
+    """[T, k] experts of which exactly the first ``n_held`` (token, slot)
+    pairs go to held experts, a token's k all different."""
+    elsewhere = np.array([e for e in range(E)
+                          if not FIRST <= e < FIRST + COUNT])
+    slot = np.tile(np.arange(K_SHARE), T)
+    held = np.arange(K_T) < n_held
+    return jnp.asarray(np.where(held, FIRST + slot, elsewhere[slot])
+                       .reshape(T, K_SHARE), jnp.int32)
+
+
+def _routed_as_told(x, w_router, experts):
+    """A router whose choice is given and whose weights are the chosen
+    experts' sigmoid scores."""
+    logits = x @ w_router
+    scores = jax.nn.sigmoid(logits)
+    return jnp.take_along_axis(scores, experts, axis=-1), experts, scores, \
+        logits
+
+
+def _told_share(x, w, experts):
+    route = functools.partial(_routed_as_told, w_router=w["router"],
+                              experts=experts)
+    return moe_dropless(
+        x, route, relu2_expert,
+        (w["up"][FIRST:FIRST + COUNT], w["down"][FIRST:FIRST + COUNT]),
+        held=(FIRST, COUNT))
+
+
+def _told_dense(x, w, experts):
+    scores = jax.nn.sigmoid(x @ w["router"])
+    picked = (experts[:, :, None] == jnp.arange(E)).any(axis=1)
+    gate = jnp.where(picked, scores, 0.0)[:, FIRST:FIRST + COUNT]
+    hidden = jnp.square(jnp.maximum(jnp.einsum(
+        "td,edf->tef", x, w["up"][FIRST:FIRST + COUNT]), 0.0))
+    return jnp.einsum("te,tef,efd->td", gate, hidden,
+                      w["down"][FIRST:FIRST + COUNT])
+
+
+@pytest.mark.parametrize(
+    "n_held", [0, TILE - 1, TILE, TILE + 1, 2 * TILE + 5, K_T],
+    ids=["none", "a-row-short-of-a-tile", "a-tile", "a-tile-and-a-row",
+         "three-tiles", "every-pair"])
+def test_a_walked_share_is_exact_wherever_the_held_pairs_end(small_tiles,
+                                                             n_held):
+    """Three tiles of 144 rows over 384 sorted pairs, the held pairs ending
+    before, on and after a tile's edge, nowhere and at the very end: the
+    output and the gradients of tokens, router and both expert matrices are
+    the dense reference's."""
+    assert small_tiles.share_tile_rows(K_T, COUNT, E) == TILE
+    w = _share_weights(n_held)
+    x = jnp.asarray(np.random.RandomState(30).randn(T, D), jnp.float32)
+    experts = _choices(n_held)
+    out, stats = jax.jit(_told_share)(x, w, experts)
+    assert int(stats.expert_tokens[FIRST:FIRST + COUNT].sum()) == n_held
+    assert small_tiles.share_tiles(stats.expert_tokens, (FIRST, COUNT),
+                                   K_T) == (-(-n_held // TILE), 3)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(_told_dense(x, w, experts)),
+                               rtol=2e-4, atol=2e-5)
+
+    def loss(layer, x, w):
+        return jnp.sum(jnp.tanh(layer(x, w, experts)) ** 2)
+    got = jax.jit(jax.grad(lambda x, w: loss(
+        lambda *a: _told_share(*a)[0], x, w), argnums=(0, 1)))(x, w)
+    want = jax.grad(lambda x, w: loss(_told_dense, x, w),
+                    argnums=(0, 1))(x, w)
+    for name, g, v in [("x", got[0], want[0])] + [
+            (key, got[1][key], want[1][key])
+            for key in ("router", "up", "down")]:
+        assert np.isfinite(np.asarray(g)).all(), name
+        assert (float(jnp.abs(v).sum()) > 0) == (n_held > 0), name
+        np.testing.assert_allclose(np.asarray(g), np.asarray(v), rtol=2e-3,
+                                   atol=2e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("shares", [2, 4, 8])
+def test_the_walked_shares_add_up_to_the_uncut_layer(small_tiles, shares):
+    """The shares of ``test_the_shares_add_up_to_the_uncut_layer`` again,
+    each now a walk of two, three or six tiles."""
+    assert -(-K_T // small_tiles.share_tile_rows(K_T, E // shares, E)) \
+        == {2: 2, 4: 3, 8: 6}[shares]
+    test_the_shares_add_up_to_the_uncut_layer(shares)
+
+
+@pytest.mark.parametrize("case", ["no-pair", "one-expert"])
+def test_a_walked_share_at_the_ends_of_imbalance(small_tiles, case):
+    """No pair at all, and every token's first choice to one held expert:
+    the two older tests, walked."""
+    if case == "no-pair":
+        test_a_share_that_is_sent_no_pair_gives_zeros_and_zero_gradients()
+    else:
+        test_a_share_drops_nothing_when_every_pair_goes_to_one_held_expert()
+
+
+def test_a_full_load_is_one_tile_and_traces_to_the_program_it_was():
+    """``held=None`` is the one-tile case with no loop and no condition:
+    ``moe_topk``'s jaxpr, forward and with its gradients, is to the letter
+    the one before the walk (PR 30's; sha256 of its text), and holds no
+    loop, no branch and no scatter."""
+    import hashlib
+    from horovod_tpu.parallel import ep
+    assert ep.share_tile_rows(8 * 8192, 64, 64) == 8 * 8192
+    weights = _gated_weights()
+    x = jnp.zeros((T, D), jnp.float32)
+    forward = str(jax.make_jaxpr(lambda *a: moe_topk(*a, 4))(x, *weights))
+    backward = str(jax.make_jaxpr(jax.grad(
+        lambda *a: moe_topk(*a, 4)[0].sum(), argnums=(0, 1, 2, 3, 4)))(
+            x, *weights))
+    for word in ("while", "cond", "scatter"):
+        assert word not in forward and word not in backward, word
+    assert hashlib.sha256(forward.encode()).hexdigest() == \
+        "e9d74b9a3112956ea385bf179bf83dd98ef40c20fde206bb721bf68d03778e7f"
+    assert hashlib.sha256(backward.encode()).hexdigest() == \
+        "9d2795442283f81f6d90c8d8100dc11d5f9b6227b72714fee9a13b2f4a9cfa4a"
+
+
+def test_a_walk_has_each_grouped_matmul_once_a_direction(small_tiles):
+    """Three tiles and still two ``ragged_dot``s forward and six in the
+    backward walk (the two again, and their four transposes): the walk is a
+    loop, not an unrolling, and has no fallback of its own."""
+    w = _share_weights()
+    x = jnp.zeros((T, D), jnp.float32)
+    forward = str(jax.make_jaxpr(lambda x, w: _share(x, w, 4, 4))(x, w))
+    assert forward.count("ragged_dot_general") == 2
+    assert forward.count("while[") == 1 and "cond[" not in forward
+    both = str(jax.make_jaxpr(jax.grad(
+        lambda x, w: _share(x, w, 4, 4)[0].sum(), argnums=(0, 1)))(x, w))
+    assert both.count("ragged_dot_general") == 2 + 6
+    assert both.count("while[") == 2 and "cond[" not in both
+
+
+def test_share_tile_rule_and_live_tiles_by_hand():
+    """The tile is 1.5 x the balanced share of the pairs in whole row tiles
+    of 512, at most all pairs; ``share_tiles`` counts the tiles that begin
+    before the last held pair."""
+    from horovod_tpu.parallel import ep
+    k_t = 6 * 8192
+    assert ep.share_tile_rows(k_t, 8, 128) == 4608  # 1.5 x 3072
+    assert ep.share_tile_rows(k_t, 16, 128) == 9216
+    assert ep.share_tile_rows(k_t, 128, 128) == k_t
+    assert ep.share_tile_rows(k_t, 96, 128) == k_t  # 1.5 x 3/4: all
+    assert ep.share_tile_rows(384, 4, 16) == 384    # a row tile holds all
+    assert ep.share_tile_rows(4096, 1, 128) == 512  # never under one
+    load = np.zeros(128)
+    load[8:] = (k_t - 3000) / 120
+    for held_rows, live in [(0, 0), (1, 1), (3000, 1), (4608, 1), (4609, 2),
+                            (13825, 4), (k_t, 11)]:
+        load[:8] = 0
+        load[3] = held_rows
+        assert ep.share_tiles(load, (0, 8), k_t) == (live, 11), held_rows
+    # a share that does not start at 0, a load as a device array
+    load = jnp.zeros(16).at[4:8].set(jnp.asarray([100., 0., 45., 0.]))
+    assert ep.share_tiles(load, (4, 4), K_T) == (1, 1)   # 384 rows: one tile
+    assert ep.share_tiles(load, (8, 4), K_T) == (0, 1)
+
+
+def test_the_walk_counts_its_tiles_in_the_registry(small_tiles):
+    """At trace time the tiles a layer call was built with and the tile's
+    rows; the live ones only when a caller reads a load back and asks."""
+    from horovod_tpu.metrics.registry import get_registry
+
+    def counter(kind):
+        return get_registry().counter("hvd_moe_share_tiles_total", kind=kind)
+    built, live = counter("built").value, counter("live").value
+    w = _share_weights()
+    x = jnp.zeros((T, D), jnp.float32)
+    _, stats = jax.jit(lambda x, w: _share(x, w, 4, 4))(x, w)
+    assert counter("built").value == built + 3
+    assert get_registry().gauge("hvd_moe_share_tile_rows").value == TILE
+    assert counter("live").value == live  # nothing is read inside a step
+    tiles = small_tiles.share_tiles(stats.expert_tokens, (4, 4), K_T,
+                                    record=True)
+    assert tiles[1] == 3 and counter("live").value == live + tiles[0]
+    # a full load builds no walk
+    jax.jit(lambda x, *w: moe_topk(x, *w, 2))(x, *_gated_weights())
+    assert counter("built").value == built + 3

@@ -265,6 +265,62 @@ def test_bias_rule_over_three_steps_through_the_stateful_step(devices):
     hvd.shutdown()
 
 
+def test_a_walked_share_trains_as_the_one_tile_program_does(devices,
+                                                            monkeypatch):
+    """A recomputed decoder whose expert layers hold 2 of 8 experts, through
+    ``dp.make_stateful_train_step``: with the share walked in three tiles,
+    two steps give the losses, the parameters and the router state that the
+    same steps give with the tile forced to all ``k T`` pairs (one tile: the
+    program before the walk), to float32 rounding."""
+    from horovod_tpu.metrics.registry import get_registry
+    hvd.init(devices=devices[:2])
+    mesh = hvd.mesh()
+    model = NemotronHTiny(pattern="ME*E", remat="ME", experts_held=(2, 2),
+                          dtype=jnp.float32)
+    tokens = jax.random.randint(jax.random.key(5), (4, SEQ), 0, model.vocab)
+    variables = model.init(jax.random.key(6), tokens[:1])
+    batch = dp.shard_batch(
+        {"tokens": tokens, "labels": jnp.roll(tokens, -1, axis=1)}, mesh)
+    optimizer = optax.adamw(1e-2)
+    built = get_registry().counter("hvd_moe_share_tiles_total", kind="built")
+
+    def loss_fn(p, s, b, rng):
+        return nemotron_h_loss(model, p, s, b["tokens"], b["labels"])
+
+    def two_steps():
+        step = dp.make_stateful_train_step(loss_fn, optimizer, mesh,
+                                           donate=False)
+        params = dp.replicate(variables["params"], mesh)
+        opt_state = dp.replicate(optimizer.init(params), mesh)
+        state = dp.replicate(variables["router_state"], mesh)
+        losses = []
+        for _ in range(2):
+            out = step(params, opt_state, state, batch, jax.random.key(0))
+            params, opt_state, state = \
+                out.params, out.opt_state, out.model_state
+            losses.append(float(out.loss))
+        return losses, params, state, out.aux["expert_tokens"]
+
+    k_t = model.experts_per_token * 2 * SEQ  # a device's pairs
+    monkeypatch.setattr(ep, "SHARE_TILE_MULTIPLE", 8)
+    assert -(-k_t // ep.share_tile_rows(k_t, 2, model.experts)) == 3
+    before = built.value
+    walked = two_steps()
+    assert built.value > before
+    monkeypatch.setattr(ep, "SHARE_TILE_HEADROOM", float(model.experts))
+    assert ep.share_tile_rows(k_t, 2, model.experts) == k_t
+    before = built.value
+    one_tile = two_steps()
+    assert built.value == before
+    np.testing.assert_allclose(walked[0], one_tile[0], rtol=1e-6)
+    np.testing.assert_array_equal(np.asarray(walked[3]),
+                                  np.asarray(one_tile[3]))
+    assert np.asarray(walked[3])[:, 2:4].sum() > 0
+    errors = jax.tree_util.tree_map(relative_l2, walked[1:3], one_tile[1:3])
+    assert max(jax.tree_util.tree_leaves(errors)) < 1e-5, errors
+    hvd.shutdown()
+
+
 def test_evaluation_uses_the_bias_as_it_stands():
     model, params, state, data = make("E")
     logits = model.apply({"params": params, "router_state": state},

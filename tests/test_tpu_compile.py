@@ -351,14 +351,20 @@ def test_nemotron_cell_fits_one_v5e_at_full_size(nemotron_cell):
     assert memory.argument_size_in_bytes == pytest.approx(8.0e9, rel=2e-3)
     recorded = traffic["memory_analysis"]
     assert recorded["argument_bytes"] == memory.argument_size_in_bytes
-    assert recorded["temp_bytes"] == pytest.approx(
-        memory.temp_size_in_bytes, rel=0.02)
+    # the record is PR 30's program, whose expert layers worked all 49 152
+    # pairs: the step's temporaries may shrink, they may not outgrow it
+    assert memory.temp_size_in_bytes <= 1.02 * recorded["temp_bytes"]
 
 
 def test_nemotron_cell_holds_its_kernels_and_scopes(nemotron_cell):
     """Three flash kernels (the attention block keeps its activations) and
     eleven ``ragged-dot`` calls in each of four expert layers whose forward
-    is recomputed; every ``ssm_*`` scope and ``moe_shared`` in the text."""
+    is recomputed: a share's walk is two loops a layer, not an unrolling and
+    not a fast path beside a fallback; every ``ssm_*`` scope and
+    ``moe_shared`` in the text. The rows of pairs sent elsewhere are gone:
+    the ``k T`` = 49 152 pairs (50 688 in eleven whole tiles) still index
+    vectors (the sort keys, the router weights' gradient), and no array has
+    that many rows of hidden or expert width."""
     from horovod_tpu.profiler.annotate import MOE_SCOPES, SSM_SCOPES
     job, _, compiled = nemotron_cell
     text = compiled.as_text()
@@ -369,5 +375,8 @@ def test_nemotron_cell_holds_its_kernels_and_scopes(nemotron_cell):
     assert len(ragged) == 44
     for scope in SSM_SCOPES + MOE_SCOPES:
         assert scope in text, scope
+    pairs = 6 * 8192
+    assert re.search(rf"\[{pairs}\]", text)
+    assert not re.search(rf"\[({pairs}|{-(-pairs // 4608) * 4608}),\d", text)
     opcodes = re.findall(r"[\s)]([a-z\-]+)\(", text)
     assert "all-reduce" not in opcodes  # one chip exchanges nothing
