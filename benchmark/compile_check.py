@@ -7,8 +7,10 @@ The third rehearsal of the on-chip-measurement guide: the TPU compiler that
 is installed here builds the step for ``v5e:2x2`` devices that are described
 and not attached. It prints one JSON line: bytes of arguments, outputs and
 temporaries on each chip (``memory_analysis()``), the ``tpu_custom_call``s
+(their count, and by name what ``harness/kernels.py`` finds and asks for)
 and the collectives of the compiled text. Nothing runs, so it gives no time.
-Record the line under ``memory_analysis`` in the cell's traffic file.
+Record the line under ``memory_analysis`` in the cell's traffic file: a
+description of the program as it stood, which no run reads as a limit.
 ``--text FILE`` also writes the compiled text.
 """
 
@@ -38,7 +40,7 @@ def main(argv=None):
     from jax.experimental import topologies
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from harness import hlo_text, spec as spec_lib
+    from harness import hlo_text, kernels, spec as spec_lib
     from horovod_tpu.ops import flash_attention as fa
     from horovod_tpu.parallel import dp, mesh as mesh_lib
 
@@ -90,6 +92,9 @@ def main(argv=None):
         "alias_bytes": memory.alias_size_in_bytes,
         "temp_bytes": memory.temp_size_in_bytes,
         "tpu_custom_calls": len(index.kernels()),
+        "kernels": kernels.inventory(index),
+        "kernels_missing": kernels.missing(job, index),
+        "kernels_not_asked_for": kernels.unasked(job, index),
         "collectives": index.collective_payload(),
         "model_flops_per_item": job.model_flops_per_item}))
 

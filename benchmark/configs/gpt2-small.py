@@ -84,7 +84,6 @@ def build(config: dict, traffic: dict) -> Job:
         ),
         sample_examples=int(traffic.get("reference_examples", 1)),
         tolerance=TOLERANCE,
-        expected_custom_calls=3 * layers if flash else 0,
         flash_call=(per_chip, seq, heads, hidden // heads, True)
         if flash else None,
         flash_layers=layers if flash else 0,

@@ -34,8 +34,9 @@ import optax
 from harness.flops import TRAIN_OVER_FORWARD, attended_pairs
 from harness.job import Job, Tolerance
 
-# Readings on the chip over 12 seeds (PERF.md §6, PR 30), relative L2 of a
-# gradient leaf and relative error of the loss, program against reference.
+# Readings on the chip over 12 seeds of PR 30's program (PERF.md §6, PR 30),
+# relative L2 of a gradient leaf and relative error of the loss, program
+# against reference; the limits below are set from PR 32's 20 seeds.
 #
 # The policy: the loss 1.1e-6 to 8.2e-5; the leaves off the routers' path
 # 2.3-12.8% (matrices 3.3-5.6%, the 64-element A_log and dt_bias up to
@@ -56,7 +57,7 @@ from harness.job import Job, Tolerance
 # seeds: (1) the scan's running sums kept in bf16 (the configuration states
 # float32): the largest leaf of a seed reads 41.7-110% (A_log 26-110%,
 # dt_bias 21-76%, the second router 36-45%): **fails** the gradients' limit
-# on every seed. (2) The blocks' inputs rounded to three significand bits
+# of 35% on every seed. (2) The blocks' inputs rounded to three significand bits
 # (float8_e4m3, the nearest precision below bf16 activations): the largest
 # leaf 50.4-62.7%, every matrix 10-16%: **fails** on every seed. (3) bf16
 # router logits and scores: 24.1-31.5%, the policy's own readings: passes.
@@ -67,28 +68,47 @@ from harness.job import Job, Tolerance
 # held by tests/test_nemotron_h.py (the rule over three steps through
 # dp.make_stateful_train_step, the state against the reference's).
 #
-# The gradients' limit lies between the policy's largest reading (29.6%)
-# and the smallest reading of a variant that has to fail (41.7%). The loss
-# resolves no precision (the variants read 1.8e-6 to 2.1e-4, as the
+# The gradients' limits, from 20 seeds on the chip on PR 32's tree, every
+# variant on every seed (PERF.md §6, PR 32). The four leaves on the routers'
+# path (the two gate weights, the two routed experts' matrices: near-ties
+# move rows between experts) read apart from the other fifteen, so each
+# class has its limit (``grad_rel_l2_under``):
+#   the routers' path   policy 22.9-32.4% (the last router's gate weight
+#                       every time); float8 inputs 49.8-61.0%; bf16 running
+#                       sums 34.2-46.0%.                         limit 35%
+#   every other leaf    policy 5.9-14.5% (A_log, dt_bias, the attention's
+#                       k_proj); bf16 running sums 34.2-126% (A_log);
+#                       float8 inputs 18.4-33.9%.                limit 25%
+# bf16 running sums fail the second on every seed by 9 points or more,
+# float8 inputs the first by 14. Until PR 32 one limit of 35% held every
+# leaf: there bf16 running sums read 36.5% on one seed of the 20, 1.5 points
+# over the limit and 1.1 x the largest sound reading. Neither upper reading
+# is the 3 x its lower one that the driver's contract wants (2.4 x, 1.5 x):
+# PERF.md §7. The loss
+# resolves no precision (the variants read 1.8e-6 to 2.7e-4, as the
 # policy); its limit is four times the largest sound reading and is there
 # for a missing term (the shared expert, the routed scale, a layer).
 TOLERANCE = Tolerance(
-    loss_rtol=3.3e-4, grad_rel_l2=0.35,
+    loss_rtol=3.3e-4, grad_rel_l2=0.25,
+    grad_rel_l2_under={"gate": 0.35, "experts": 0.35},
     reason="bf16 activations against float32 through four sigmoid "
            "top-6-of-128 routers over a share of 8 experts (384 rows "
            "each): near-ties move a few rows of an expert, which its "
-           "gradient and every leaf upstream see (12 seeds on the chip: "
-           "leaves off the routers' path 2.3-12.8%, on it 11.7-29.6%, "
-           "limit 35%; bf16 running sums in the scan read 41.7-110% and "
-           "float8 inputs 50.4-62.7% and fail); the loss is a mean over "
-           "tokens (8.2e-5 at most, limit 3.3e-4, for a missing term). NOT "
-           "covered: the router's float32 (bf16 scores pass here; "
-           "tests/test_nemotron_h.py guards it) and the returned state")
+           "gradient and the router's see (20 seeds on the chip: the gate "
+           "weights and the routed experts' matrices 22.9-32.4%, limit "
+           "35%, where float8 inputs read 49.8-61.0%; every other leaf "
+           "5.9-14.5%, limit 25%, where bf16 running sums in the scan "
+           "read 34.2-126%: both fail on every seed); the loss is a mean "
+           "over tokens (8.2e-5 at most, limit 3.3e-4, for a missing "
+           "term). NOT covered: the router's float32 (bf16 scores pass "
+           "here; tests/test_nemotron_h.py guards it) and the returned "
+           "state")
 
-# what the TPU compiler makes of one expert layer's ragged_dots: Mosaic
-# calls of its own (``ragged-dot-*``), counted with the flash kernels as
-# ``tpu_custom_call``s by compile_check.py. Forward and backward are 8;
-# where the block is recomputed its forward's come once more.
+# what the TPU compiler made of one expert layer's ragged_dots when this
+# file was written: Mosaic calls of its own (``ragged-dot-*``). Forward and
+# backward are 8; where the block is recomputed its forward's come once
+# more. A described fact, no limit: ``correct`` does not read it
+# (harness/kernels.py says what it asks), tests/test_tpu_compile.py does
 RAGGED_DOT_CALLS = {"kept": 8, "recomputed": 11}
 REFERENCE_QUERY_BLOCK = 256   # rows of scores, and of logits, held at once
 REFERENCE_TIME_BLOCK = 128    # positions of the recurrence between checkpoints
@@ -286,15 +306,15 @@ def build(config: dict, traffic: dict) -> Job:
         check_leaves=tuple(check_leaves),
         sample_examples=int(traffic.get("reference_examples", 1)),
         tolerance=TOLERANCE,
-        # the three flash kernels of each attention layer (the forward's
-        # once more where the block is recomputed), and what the TPU
-        # compiler makes of each expert layer's ragged_dots
-        expected_custom_calls=(3 + ("*" in recompute)) * flash
-        * len(attentions) + RAGGED_DOT_CALLS[
-            "recomputed" if "E" in recompute else "kept"] * len(moes),
         flash_call=(per_chip, seq, sizes["heads"], sizes["head_dim"], True)
         if flash else None,
         flash_layers=len(attentions) if flash else 0,
+        # described, not required (harness/job.py): the three flash kernels
+        # of each attention layer (the forward's once more where the block
+        # is recomputed) and the compiler's calls for the ragged_dots
+        expected_custom_calls=(3 + ("*" in recompute)) * flash
+        * len(attentions) + RAGGED_DOT_CALLS[
+            "recomputed" if "E" in recompute else "kept"] * len(moes),
         facts={"layers": len(pattern), "pattern": pattern, **sizes,
                "experts_held": list(held), "seq_len": seq,
                "tied_head": False, "recompute": recompute,

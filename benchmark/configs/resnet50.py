@@ -82,7 +82,7 @@ def build(config: dict, traffic: dict) -> Job:
         reference_loss=functools.partial(reference_loss, stages=stages),
         check_leaves=(("head", "kernel"), ("head", "bias")),
         sample_examples=int(traffic.get("reference_examples", 8)),
-        tolerance=TOLERANCE, expected_custom_calls=0,
+        tolerance=TOLERANCE,
         check_params=open_residual_branches,
         facts={"image_size": px, "classes": classes,
                "stage_sizes": list(stages)})

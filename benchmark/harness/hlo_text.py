@@ -115,12 +115,15 @@ class HloIndex:
         traced from; helpers cached from an earlier trace carry that one's
         names too, so of the ``*_kernel`` names in a body the one found in
         the fewest bodies of the module is the kernel's own. Falls back to
-        the instruction's last scope."""
+        the instruction's last scope, and for a call the compiler made
+        itself, whose ``op_name`` is one word (``ragged-dot-none``), to
+        that word: the same for every such call of the step."""
         names = self._kernel_names()
         own = names.get(ins.name, ())
         if not own:
-            return ins.op_name.rsplit("/", 2)[-2] if "/" in ins.op_name \
-                else ins.name
+            if "/" in ins.op_name:
+                return ins.op_name.rsplit("/", 2)[-2]
+            return ins.op_name or re.sub(r"\.\d+$", "", ins.name)
         spread = {}
         for found in names.values():
             for name in set(found):

@@ -12,6 +12,18 @@ class Tolerance:
     loss_rtol: float
     grad_rel_l2: float
     reason: str
+    # a key of a leaf's path -> the limit of the leaves under that key,
+    # where a class of leaves reads apart from the rest on sound runs (a
+    # router's near-ties); every other leaf takes ``grad_rel_l2``
+    grad_rel_l2_under: dict = field(default_factory=dict)
+
+    def gradient_limit(self, path) -> tuple:
+        """(the key of ``grad_rel_l2_under`` the leaf is under or "", the
+        leaf's limit)."""
+        for key in path:
+            if key in self.grad_rel_l2_under:
+                return key, self.grad_rel_l2_under[key]
+        return "", self.grad_rel_l2
 
 
 @dataclass
@@ -28,14 +40,21 @@ class Job:
     check_leaves: Sequence[tuple]  # key paths of the leaves whose gradients are compared
     sample_examples: int         # examples in the reference's sample
     tolerance: Tolerance
-    expected_custom_calls: int   # tpu_custom_call count of the compiled step (0: none)
-    # flash kernel calls of one step on one chip, for the roofline:
+    # flash kernel calls of one step on one chip, for the roofline and for
+    # harness/kernels.py, which asks the compiled step for them:
     # (batch, seq, heads, head_dim, causal) or None where no kernel runs
     flash_call: Optional[tuple] = None
     flash_layers: int = 0
     # the parameters the reference check runs on, from the initial ones
     check_params: Callable = lambda params: params
     facts: dict = field(default_factory=dict)  # printed on an earlier line
+    # A described fact and no limit: the tpu_custom_call count of the step
+    # as the configuration's author saw it compile. Nothing under benchmark/
+    # reads it (PR 32 took it out of ``correct``); the two expert
+    # configurations still state it for tests/test_olmoe.py and
+    # tests/test_tpu_compile.py, the program's own tests, which a benchmark
+    # PR may not edit. It goes when they hold their counts as literals.
+    expected_custom_calls: Optional[int] = None
 
 
 @dataclass

@@ -8,7 +8,7 @@ import statistics
 
 from harness import flops, trace_reduce
 
-FLASH_KERNELS = ("_fwd_kernel", "_bwd_dq_kernel", "_bwd_dkv_kernel")
+FLASH_KERNELS = tuple(flops.FLASH_PRODUCTS)  # the one table of their names
 
 
 def steps_traced(trace, run) -> int:

@@ -45,8 +45,8 @@ import numpy as np  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
 import horovod_tpu as hvd  # noqa: E402
-from harness import (compile_log, hlo_text, peaks, roofline,  # noqa: E402
-                     spec as spec_lib, timing, trace_reduce)
+from harness import (compile_log, hlo_text, kernels, peaks,  # noqa: E402
+                     roofline, spec as spec_lib, timing, trace_reduce)
 from harness.job import Run  # noqa: E402
 from horovod_tpu.common.compile_cache import (  # noqa: E402
     enable_compile_cache)
@@ -120,6 +120,7 @@ class Cell:
         self.programs_in_windows = 0
         self.dispatch_seconds = []
         self.checks = {}
+        self.compared = {}         # name -> [number, its limit]
 
     def mark(self, name, since):
         self.setup_marks[name] = round(
@@ -203,14 +204,26 @@ class Cell:
             errors["/".join(path)] = float(
                 np.linalg.norm(a - b) / np.linalg.norm(b))
         tol = job.tolerance
+        classes = {}  # name under ``compared`` -> (limit, the leaves' errors)
+        for path, error in zip(job.check_leaves, errors.values()):
+            under, limit = tol.gradient_limit(path)
+            name = "gradient_relative_l2_error" + (under and "." + under)
+            classes.setdefault(name, (limit, []))[1].append(error)
         ok = math.isfinite(got_loss) and loss_error <= tol.loss_rtol and \
-            all(e <= tol.grad_rel_l2 for e in errors.values())
+            all(e <= limit for limit, found in classes.values()
+                for e in found)
         self.checks["reference"] = ok
+        self.compared["loss_relative_error"] = [loss_error, tol.loss_rtol]
+        for name, (limit, found) in classes.items():  # a NaN is the largest
+            self.compared[name] = [
+                max(found, key=lambda e: (e != e, e)), limit]
         say(check="float32 reference", ok=ok,
             sample_examples=job.sample_examples, loss_program=got_loss,
             loss_reference=want_loss, loss_relative_error=loss_error,
             loss_rtol=tol.loss_rtol, gradient_relative_l2_error=errors,
-            gradient_tolerance=tol.grad_rel_l2, tolerance_reason=tol.reason)
+            gradient_tolerance=tol.grad_rel_l2,
+            gradient_tolerance_under=tol.grad_rel_l2_under,
+            tolerance_reason=tol.reason)
         del got, want, params, sample, args
         self.mark("reference_check_s", t0)
 
@@ -363,6 +376,8 @@ def run(args):
     # correctness (2): training moves, and stays finite
     cell.checks["warmup_loss"] = all(map(math.isfinite, warm_losses)) and \
         warm_losses[-1] < warm_losses[0]
+    cell.compared["warmup_loss_last_less_step_0"] = [
+        warm_losses[-1] - warm_losses[0], 0.0]
     say(check="loss after warm-up", ok=cell.checks["warmup_loss"],
         steps=len(warm_losses), loss_step_0=warm_losses[0],
         loss_last=warm_losses[-1])
@@ -371,17 +386,25 @@ def run(args):
         want = statistics.fmean(shard_losses)
         error = abs(warm_losses[0] - want) / abs(want)
         cell.checks["shard_mean"] = error <= SHARD_LOSS_RTOL
+        cell.compared["shard_mean_relative_error"] = [error, SHARD_LOSS_RTOL]
         cell.checks["all_reduce"] = args.rehearse or \
             "all-reduce" in main.hlo.collective_payload()
         say(check="step 0 against the shards' mean on one chip",
             ok=cell.checks["shard_mean"], loss=warm_losses[0],
             shard_mean=want, relative_error=error, rtol=SHARD_LOSS_RTOL,
             all_reduce_in_compiled_text=cell.checks["all_reduce"])
-    calls = len(main.hlo.kernels())
-    cell.checks["custom_calls"] = args.rehearse or \
-        calls == job.expected_custom_calls
-    say(check="tpu_custom_call count", ok=cell.checks["custom_calls"],
-        found=calls, expected=job.expected_custom_calls)
+    # correctness (4): the kernels this cell's metrics read are in the
+    # step, and no flash kernel where the job says XLA attention
+    missing = kernels.missing(job, main.hlo)
+    unasked = kernels.unasked(job, main.hlo)
+    cell.checks["kernels"] = args.rehearse or not (missing or unasked)
+    cell.compared.update(
+        required_kernels_missing=[0 if args.rehearse else len(missing), 0],
+        flash_kernels_not_asked_for=[len(unasked), 0])
+    say(check="the kernels this cell's metrics read are in the compiled "
+        "step", ok=cell.checks["kernels"], required=kernels.required(job),
+        missing=missing, not_asked_for=unasked,
+        tpu_custom_calls=kernels.inventory(main.hlo))
 
     setup_s = time.perf_counter() - T0 - cell.windows_s
     state, window = cell.timed(main, state, seconds)
@@ -403,6 +426,8 @@ def run(args):
     del state
     cell.checks["no_compile_in_window"] = cell.programs_in_windows == 0
     cell.checks["finite"] = failed == 0
+    cell.compared.update(programs_in_windows=[cell.programs_in_windows, 0],
+                         losses_not_finite=[failed, 0])
 
     q1, median, q3 = timing.quartiles(rates)
     values = {f"{job.unit}_per_s_per_chip": median,
@@ -443,7 +468,16 @@ def run(args):
                 "unit": metric["unit"]}
     if args.rehearse:
         result["rehearsal"] = True  # sizes and platform of the sandbox
+    # each number compared beside its limit: last on the line, and last on
+    # standard error
+    result["compared"] = {
+        name: [x if math.isfinite(x) else repr(x) for x in pair]
+        for name, pair in cell.compared.items()}  # "nan" keeps the line JSON
     hvd.shutdown()
+    for name, (value, limit) in result["compared"].items():
+        print(f"compared {name}: {value} limit {limit}", file=sys.stderr)
+    print(f"correct: {result['correct']} checks: {cell.checks}",
+          file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
 
 

@@ -41,3 +41,14 @@ def check_rehearsal_result(result, chips, metrics):
     for m in result["metrics"].values():
         assert set(m) == {"value", "unit"}
         assert isinstance(m["value"], float)
+    # each number compared beside its limit, last on the line (a rehearsal's
+    # two steps of warm-up need not lower the loss: ``correct`` may be false)
+    assert list(result)[-1] == "compared"
+    assert {"loss_relative_error", "gradient_relative_l2_error",
+            "warmup_loss_last_less_step_0", "required_kernels_missing",
+            "flash_kernels_not_asked_for", "programs_in_windows",
+            "losses_not_finite"} <= set(result["compared"])
+    assert result["compared"]["required_kernels_missing"] == [0, 0]
+    assert result["compared"]["flash_kernels_not_asked_for"] == [0, 0]
+    for pair in result["compared"].values():
+        assert len(pair) == 2

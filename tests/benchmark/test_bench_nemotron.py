@@ -55,6 +55,14 @@ def test_rehearsal_ends_correct_and_reports_the_end_to_end_metrics():
                      if e.get("check") == "float32 reference")
     assert reference["loss_relative_error"] <= reference["loss_rtol"]
     assert len(reference["gradient_relative_l2_error"]) == 14
+    # each class of leaves beside its own limit
+    compared = result["compared"]
+    assert [compared[name][1] for name in (
+        "gradient_relative_l2_error", "gradient_relative_l2_error.gate",
+        "gradient_relative_l2_error.experts")] == [0.25, 0.35, 0.35]
+    assert reference["gradient_tolerance"] == 0.25
+    assert reference["gradient_tolerance_under"] == {"gate": 0.35,
+                                                     "experts": 0.35}
 
 
 def test_traced_rehearsal_leaves_the_device_readers_out():
@@ -154,7 +162,9 @@ def test_configuration_is_at_the_published_widths():
     assert config["recompute"]["kinds"] == "ME"
     job = job_of()[1]
     assert job.stateful and job.flash_call == (1, 8192, 32, 128, True)
-    assert job.flash_layers == 1 and job.expected_custom_calls == 47
+    from harness import flops, kernels
+    assert job.flash_layers == 1  # one attention layer of the nine
+    assert kernels.required(job) == dict.fromkeys(flops.FLASH_PRODUCTS, 1)
     leaves = {"/".join(path[1:]) for path in job.check_leaves}
     for leaf in ("NemotronHMamba2Mixer_0/A_log",
                  "NemotronHMamba2Mixer_0/dt_bias",
@@ -172,48 +182,125 @@ def test_configuration_is_at_the_published_widths():
         "NemotronHBlock_8", "NemotronHBlock_5", "Embed_0", "LmHead"}
 
 
+def test_each_gradient_leaf_takes_its_classes_limit():
+    """The two gate weights and the two routed experts' matrices, which the
+    routers' near-ties move, are held to 35%, the other fifteen leaves to
+    25% (the shared expert is no routed one)."""
+    job = job_of()[1]
+    under = {"/".join(path): job.tolerance.gradient_limit(path)
+             for path in job.check_leaves}
+    assert len(under) == 19
+    routed = {leaf: got for leaf, got in under.items() if got[0]}
+    assert routed == {
+        "NemotronHBlock_1/NemotronHMoE_0/gate/weight": ("gate", 0.35),
+        "NemotronHBlock_8/NemotronHMoE_0/gate/weight": ("gate", 0.35),
+        "NemotronHBlock_1/NemotronHMoE_0/experts/up_proj": ("experts", 0.35),
+        "NemotronHBlock_8/NemotronHMoE_0/experts/down_proj":
+            ("experts", 0.35)}
+    assert set(under.values()) - set(routed.values()) == {("", 0.25)}
+    assert under["NemotronHBlock_1/NemotronHMoE_0/shared_experts/up_proj/"
+                 "kernel"] == ("", 0.25)
+    from harness.job import Tolerance
+    plain = Tolerance(1e-3, 0.1, "")  # one limit for every leaf
+    assert plain.gradient_limit(("a", "gate", "weight")) == ("", 0.1)
+
+
+@pytest.mark.parametrize("seed", [2147483659, 2147483701, 2147483743])
+def test_bf16_running_sums_read_apart_from_the_policy(seed, monkeypatch):
+    """The cell's control (PERF.md §6, PR 32: the scan's running sums in
+    bf16 where the configuration states float32) at a size a test run can
+    hold: the rehearse widths with the published 64 mixer heads (A reaches
+    -64) and chunk of 128, 256 tokens. The leaves off the routers' path read
+    1.0-1.2% under the policy and 13-16% under the control, by the
+    comparison ``run.py`` makes (a leaf's relative L2 against the config's
+    own float32 reference). At the cell's size they read 5.9-14.5% and
+    34.2-126% against a limit of 25% (20 seeds on the chip)."""
+    import functools
+
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import ssd
+    spec = spec_lib.load()
+    config, builder = spec_lib.config(spec, CONFIG, rehearse=True)
+    config.update(mamba_num_heads=64, chunk_size=128, num_layers=3,
+                  hybrid_override_pattern="ME*")
+    job = spec_lib.load_module(builder).build(
+        config, {"seq_len": 256, "per_chip_batch": 1})
+
+    def picked(loss):
+        def run(*args):
+            grads = jax.grad(loss)(*args)
+            return [functools.reduce(lambda leaf, k: leaf[k], path, grads)
+                    for path in job.check_leaves]
+        return jax.jit(run)
+
+    def worst_off_the_routers_path(got, want):
+        return max(
+            float(np.linalg.norm(np.asarray(a, np.float64) - b) /
+                  np.linalg.norm(b))
+            for path, a, b in zip(job.check_leaves, got, want)
+            if not job.tolerance.gradient_limit(path)[0]
+            for b in [np.asarray(b, np.float64)])
+
+    def program(p, state, batch, key):
+        return job.loss_fn(p, state, batch, key)[0]
+    key_params, key_batch = jax.random.split(jax.random.key(seed))
+    params, state = jax.jit(job.init)(key_params)
+    args = (params, state, job.make_batch(key_batch, 1), jax.random.key(1))
+    want = picked(lambda p, state, batch, key: job.reference_loss(
+        p, state, batch))(*args)
+    policy = worst_off_the_routers_path(picked(program)(*args), want)
+    monkeypatch.setattr(
+        ssd, "_running_sum_last", lambda a: jnp.cumsum(
+            a.astype(jnp.bfloat16), axis=-1).astype(jnp.float32))
+    control = worst_off_the_routers_path(picked(program)(*args), want)
+    assert policy < 0.03 < 0.08 < control, (policy, control)
+
+
 def test_benchmark_json_holds_the_cell_together():
     spec = spec_lib.load()
     cell = spec_lib.workload(spec, CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         CONFIG, TRAFFIC, 1)
-    assert spec["workloads"][-1] is cell
-    assert spec["configs"][-1]["name"] == CONFIG
+    assert CONFIG in [c["name"] for c in spec["configs"]]
     end_to_end = {m["name"] for m in spec_lib.metrics(spec, "end_to_end",
                                                       CELL)}
     assert end_to_end == {"tokens_per_s_per_chip", "peak_hbm_gb", "setup_s"}
     got = {m["name"] for m in spec_lib.metrics(spec, "per_layer", CELL)}
     like = {m["name"] for m in spec_lib.metrics(spec, "per_layer",
-                                                "olmoe-t4096")}
-    # the expert layer's readers under names of their own: the accepted
-    # entries' lists are pinned to ``olmoe-t4096`` by its tests
-    assert got == (like - {"moe_experts_mfu", "moe_time_share",
-                           "moe_dispatch_ms"}) | {
-        "ssm_time_share", "ssm_scan_ms", "ssm_scan_roofline",
-        "moe_time_share.share", "moe_dispatch_ms.share"}
-    added = spec["per_layer"][-5:]
-    assert [(m["name"], m["layer"]) for m in added] == [
-        ("ssm_time_share", "state-space mixer"),
-        ("ssm_scan_ms", "state-space mixer"),
-        ("ssm_scan_roofline", "state-space mixer"),
-        ("moe_time_share.share", "expert share"),
-        ("moe_dispatch_ms.share", "expert share")]
-    for m in added:
-        assert m["workloads"] == [CELL]
+                                                "gpt2s-t8192")}
+    # what the 8k GPT cell reports, the expert layer's two readers under
+    # their own names since PR 32 (PR 30 had them as ``.share`` entries of a
+    # layer of their own) and the mixer's three; a share's rows are data, so
+    # no fact gives ``moe_experts_mfu`` its FLOPs. Entries are found by name
+    # and the cell by membership: a later metric or cell is appended to
+    # ``BENCHMARK.json`` and edits nothing here.
+    ssm = ["ssm_time_share", "ssm_scan_ms", "ssm_scan_roofline"]
+    assert like | {"moe_time_share", "moe_dispatch_ms"} | set(ssm) <= got
+    assert "moe_experts_mfu" not in got
+    by_name = {m["name"]: m for m in spec["per_layer"]}
+    for name in ssm:
+        m = by_name[name]
+        assert m["layer"] == "state-space mixer"
+        assert CELL in m["workloads"]
         assert m["moves"] == "tokens_per_s_per_chip"
         assert m["source"] == "program_span"
-    assert added[2]["unit"] == "%" and added[2]["better"] == "higher"
-    assert spec_lib.layer_reader("moe_time_share.share").__module__ == \
-        spec_lib.layer_reader("moe_time_share").__module__
+    assert by_name["ssm_scan_roofline"]["unit"] == "%"
+    assert by_name["ssm_scan_roofline"]["better"] == "higher"
+    assert not [m["name"] for m in spec["per_layer"]
+                if m["layer"] == "expert share" or m["name"].endswith(".share")]
     traffic = spec_lib.traffic(TRAFFIC)
     assert (traffic["per_chip_batch"], traffic["seq_len"]) == (1, 8192)
     assert (traffic["block_steps"], traffic["warmup_blocks"],
             traffic["trace_blocks"], traffic["step"]) == (5, 2, 3, {})
     memory = traffic["memory_analysis"]
+    # described facts of the compile, which no run reads as a limit
     assert memory["workload"] == CELL and memory["tpu_custom_calls"] == 47
+    assert memory["kernels"] == {
+        "_bwd_dkv_kernel": 1, "_bwd_dq_kernel": 1, "_fwd_kernel": 1,
+        "ragged-dot-metadata": 12, "ragged-dot-none": 32}
+    assert memory["kernels_missing"] == {}
     assert 10.67e9 < memory["argument_bytes"] + memory["temp_bytes"] < 15.0e9
-    four = [c for c in spec["workloads"] if c["chips"] == 4]
-    assert len(spec["workloads"]) == 7 and len(four) == 1
 
 
 # -- the mixer's readers ---------------------------------------------------------

@@ -68,9 +68,26 @@ def test_the_new_cells_report_what_the_8k_cell_reports():
                if m["layer"] == "experts"}
     assert experts == {"moe_time_share", "moe_dispatch_ms",
                        "moe_experts_mfu"}
+    # the layer is read in cells of configurations with routed experts, and
+    # in no other: which those are is the configuration file's own count of
+    # experts, so a later routed cell or a later metric of the layer is an
+    # appended entry and no edit here. ``moe_experts_mfu`` wants a fact that
+    # gives the experts' FLOPs (a share's rows are data: nemotron's job
+    # states none).
+    files = {c["name"]: c["file"] for c in spec["configs"]}
+
+    def routed(cell):
+        sizes = json.load(open(spec_lib.ROOT / files[cell["config"]]))
+        return "num_experts" in sizes or "n_routed_experts" in sizes
+    routed_cells = {c["name"] for c in spec["workloads"] if routed(c)}
+    assert {"olmoe-t4096", "nemotron3n-t8192"} <= routed_cells
+    assert not {"gpt2s-t8192", "resnet50-b256"} & routed_cells
     for m in spec["per_layer"]:
         if m["layer"] == "experts":
-            assert m["workloads"] == ["olmoe-t4096"]
+            assert set(m["workloads"]) <= routed_cells
+            assert "olmoe-t4096" in m["workloads"]
+            if m["name"] != "moe_experts_mfu":
+                assert "nemotron3n-t8192" in m["workloads"]
             assert m["moves"] == "tokens_per_s_per_chip"
             assert m["source"] == "program_span"
     cells = {c["name"]: c for c in spec["workloads"]}

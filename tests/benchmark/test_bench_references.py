@@ -62,10 +62,17 @@ def test_gpt2_job_is_at_the_published_widths():
     assert job.facts == {"layers": 12, "hidden": 768, "heads": 12,
                          "mlp": 3072, "vocab": 50257, "positions": 1024,
                          "seq_len": 1024, "attention": "flash"}
-    assert job.expected_custom_calls == 36
+    from harness import flops, kernels
+    from horovod_tpu.ops.flash_attention import flash_min_seq
     assert job.flash_call == (16, 1024, 12, 64, True)
+    assert job.flash_layers == 12  # 36 flash kernels in the step
+    assert kernels.required(job) == dict.fromkeys(flops.FLASH_PRODUCTS, 12)
     _, short = build("gpt2-small", {"seq_len": 512, "per_chip_batch": 32})
-    assert short.expected_custom_calls == 0 and short.flash_call is None
+    # the job follows the program's router: no flash shapes below its
+    # threshold, and then the compiled step is asked for no kernel
+    assert (short.flash_call is None) == (512 < flash_min_seq())
+    assert short.flash_call is None and short.flash_layers == 0
+    assert kernels.required(short) == {}
     _, long = build("gpt2-small", {"seq_len": 8192, "per_chip_batch": 2})
     assert long.facts["positions"] == 8192
 

@@ -32,6 +32,11 @@ def test_rehearsal_reports_the_end_to_end_metrics(workload, rate):
     reference = next(e for e in earlier
                      if e.get("check") == "float32 reference")
     assert reference["loss_relative_error"] <= reference["loss_rtol"]
+    held = next(e for e in earlier
+                if e.get("check", "").startswith("the kernels"))
+    assert held == {"check": held["check"], "ok": True, "required": {},
+                    "missing": {}, "not_asked_for": {},
+                    "tpu_custom_calls": {}}
 
 
 def test_traced_rehearsal_reports_what_needs_no_device_plane():
@@ -49,6 +54,15 @@ def test_traced_rehearsal_reports_what_needs_no_device_plane():
     assert traced["device_planes"] == 0 and traced["host_spans"] > 0
     facts = earlier[0]
     assert facts["attention"] == "flash" and facts["hidden"] == 768
+    # the job asks for the three flash kernels; interpreted on the CPU they
+    # are no ``tpu_custom_call``, and a rehearsal passes the check all the same
+    held = next(e for e in earlier
+                if e.get("check", "").startswith("the kernels"))
+    assert held["ok"] is True and held["tpu_custom_calls"] == {}
+    assert set(held["required"]) == {"_fwd_kernel", "_bwd_dq_kernel",
+                                     "_bwd_dkv_kernel"}
+    assert set(held["missing"]) == set(held["required"])
+    assert held["not_asked_for"] == {}
 
 
 def test_without_a_tpu_it_fails_and_prints_no_result():

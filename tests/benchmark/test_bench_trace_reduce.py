@@ -4,13 +4,14 @@ them, nesting, launch gaps, exposed collective time, and the readers of the
 per-layer metrics on top of them."""
 
 import base64
+import dataclasses
 import importlib.util
 import os
 
 import pytest
 
 import bench_paths  # noqa: F401  (puts benchmark/ on sys.path)
-from harness import flops, hlo_text, trace_reduce as tr
+from harness import flops, hlo_text, kernels, roofline, trace_reduce as tr
 from harness.job import Run
 from harness.trace_reduce import DeviceTrace, Span, Trace
 
@@ -189,8 +190,7 @@ def make_run(hlo, **over):
               init=None, loss_fn=None, optimizer=None, make_batch=None,
               model_flops_per_item=1e9, reference_loss=None, check_leaves=(),
               sample_examples=1, tolerance=Tolerance(0, 0, ""),
-              expected_custom_calls=3, flash_call=(2, 1024, 12, 64, True),
-              flash_layers=1)
+              flash_call=(2, 1024, 12, 64, True), flash_layers=1)
     facts = dict(job=job, chips=1, block_steps=2,
                  peaks={"bf16_flops_per_s": 100e12, "hbm_bytes_per_s": 1e12},
                  hlo=hlo, program="jit__local_step", init_s=1.5,
@@ -222,6 +222,121 @@ def test_kernels_are_found_by_their_function_name(hlo):
                      "Attn.3": "_bwd_dkv_kernel"}
     assert not hlo.is_kernel(hlo.get("other.1"))
     assert hlo.module == "jit__local_step"
+
+
+# -- the kernels ``correct`` asks the compiled step for -------------------------
+
+FWD, DQ, DKV = "_fwd_kernel", "_bwd_dq_kernel", "_bwd_dkv_kernel"
+RAGGED = ["ragged-dot-none"] * 9 + ["ragged-dot-metadata"] * 2
+
+
+def step_text(calls):
+    """A step's text with one ``tpu_custom_call`` for each of ``calls``: a
+    ``*_kernel`` name is a Pallas kernel (the name in its Mosaic body, a
+    scope in its ``op_name``), any other the compiler's own call, whose
+    instruction and ``op_name`` carry that word and its body no name."""
+    lines = []
+    for i, name in enumerate(calls):
+        if name.endswith("_kernel"):
+            # the backward kernels' bodies carry the forward's name too
+            inner = (name,) if name == FWD else (FWD, name)
+            lines.append(
+                f'  %Attn.{i} = bf16[8,8]{{1,0}} custom-call(%a), '
+                f'custom_call_target="tpu_custom_call", metadata={{op_name='
+                f'"jit(_local_step)/jvp(M)/Attn/pallas_call"}}, '
+                f'backend_config={{"custom_call_config":{{"body":'
+                f'"{body(*inner)}"}}}}')
+        else:
+            lines.append(
+                f'  %{name}.{i} = bf16[8,8]{{1,0}} custom-call(%a), '
+                f'custom_call_target="tpu_custom_call", '
+                f'metadata={{op_name="{name}"}}, backend_config='
+                f'{{"custom_call_config":{{"body":"{body("tile")}"}}}}')
+    return ("HloModule jit__local_step, is_scheduled=true\n\n"
+            "ENTRY %main.1_spmd (a: bf16[8,8]) -> bf16[8,8] {\n"
+            "  %a = bf16[8,8]{1,0} parameter(0)\n" + "\n".join(lines) +
+            "\n  ROOT %out.1 = bf16[8,8]{1,0} copy(%a)\n}\n")
+
+
+@pytest.mark.parametrize("calls,flash_layers,want_missing,want_unasked", [
+    pytest.param([FWD, DQ, DKV, "_ssd_scan_kernel"], 1, {}, {},
+                 id="flash_kernels_and_one_of_another_name_pass"),
+    pytest.param([FWD, DQ], 1, {DKV: [0, 1]}, {},
+                 id="one_flash_name_missing_fails"),
+    pytest.param([FWD, FWD, DQ, DQ, DKV], 2, {DKV: [1, 2]}, {},
+                 id="fewer_than_flash_layers_of_one_name_fails"),
+    pytest.param(["_grouped_matmul_kernel", "_ssd_scan_kernel"] + RAGGED[:4],
+                 None, {}, {},
+                 id="no_flash_call_and_kernels_of_other_names_pass"),
+    pytest.param([], None, {}, {}, id="no_flash_call_and_no_kernel_passes"),
+    pytest.param([FWD, DQ, DKV, "_grouped_matmul_kernel"] + RAGGED[:4],
+                 None, {}, {FWD: 1, DQ: 1, DKV: 1},
+                 id="no_flash_call_and_the_flash_kernels_fails"),
+    pytest.param([FWD, FWD] + RAGGED, None, {}, {FWD: 2},
+                 id="no_flash_call_and_one_flash_name_fails"),
+    pytest.param(RAGGED, 1, {FWD: [0, 1], DQ: [0, 1], DKV: [0, 1]}, {},
+                 id="only_the_compilers_ragged_dots_with_flash_call_fails"),
+    pytest.param([FWD, DQ, DKV] + RAGGED, 1, {}, {},
+                 id="flash_kernels_beside_the_compilers_calls_pass"),
+    pytest.param([FWD, FWD, DQ, DKV] + RAGGED[:3], 1, {}, {},
+                 id="a_recomputed_forward_is_more_than_asked_and_passes"),
+    pytest.param([FWD, DQ, DKV] * 12, 12, {}, {},
+                 id="twelve_layers_thirty_six_kernels_pass"),
+])
+def test_the_kernels_a_cells_metrics_read_are_in_the_step(
+        calls, flash_layers, want_missing, want_unasked):
+    """``harness/kernels.missing`` and ``unasked`` are what ``run.py`` puts
+    into ``correct``: each name of ``flops.FLASH_PRODUCTS`` at least
+    ``flash_layers`` times where the job names flash shapes, none of them
+    where it names none; other kernels and other counts neither pass nor
+    fail it."""
+    index = hlo_text.HloIndex(step_text(calls))
+    over = {"flash_call": None, "flash_layers": 0} if flash_layers is None \
+        else {"flash_layers": flash_layers}
+    job = dataclasses.replace(make_run(index).job, **over)
+    assert len(index.kernels()) == len(calls)
+    assert kernels.inventory(index) == {
+        name: calls.count(name) for name in sorted(set(calls))}
+    assert kernels.required(job) == (
+        {} if flash_layers is None
+        else dict.fromkeys(flops.FLASH_PRODUCTS, flash_layers))
+    assert kernels.missing(job, index) == want_missing
+    assert kernels.unasked(job, index) == want_unasked
+
+
+def test_a_compilers_call_is_named_by_its_one_word(hlo):
+    """``ragged-dot-none.7`` and ``ragged-dot-none.12`` are one name in the
+    inventory and in the breakdown; a call with no ``op_name`` at all falls
+    back to its instruction's name without the number."""
+    index = hlo_text.HloIndex(step_text(RAGGED).replace(
+        'metadata={op_name="ragged-dot-metadata"}, ', "", 1))
+    names = [index.kernel_name(k) for k in index.kernels()]
+    assert names == RAGGED
+    assert kernels.inventory(hlo) == {FWD: 1, DQ: 1, DKV: 1}
+    assert roofline.FLASH_KERNELS == tuple(flops.FLASH_PRODUCTS) == \
+        (FWD, DQ, DKV)
+
+
+def test_flash_time_share_counts_the_flash_kernels_alone():
+    """A step of a flash kernel, one of the compiler's ``ragged-dot`` calls
+    and a kernel of another name, 10 ms each, and 10 ms of a copy: the
+    share is the flash kernel's 25%, not the custom calls' 75%."""
+    index = hlo_text.HloIndex(step_text(
+        [FWD, "ragged-dot-none", "_ssd_scan_kernel"]))
+    spans = ["Attn.0", "ragged-dot-none.1", "Attn.2", "out.1"]
+    dev = DeviceTrace(0, ops=[
+        Span(name, i * 10 * MS, (i + 1) * 10 * MS)
+        for i, name in enumerate(spans)],
+        modules=[Span("jit__local_step(1)", 0, 40 * MS)])
+    trace = Trace([dev], [Span("bench.block", 0, 40 * MS)])
+    run = make_run(index)
+    assert reader("flash_time_share")(trace, run) == pytest.approx(25.0)
+    cost = flops.flash_kernel_cost(FWD, 2, 1024, 12, 64, True)
+    assert reader("flash_roofline")(trace, run) == pytest.approx(
+        100 * max(cost[0] / 100e12, cost[1] / 1e12) / 0.010)
+    share = dataclasses.replace(run.job, flash_call=None, flash_layers=0)
+    assert reader("flash_time_share")(
+        trace, dataclasses.replace(run, job=share)) == pytest.approx(25.0)
 
 
 def test_collectives_scopes_and_payload(hlo):
