@@ -18,7 +18,7 @@ positions, zeros before the sequence; ``xBC`` splits into ``x`` [H, P] and
 ``B``, ``C`` [G, N], head ``h`` reading group ``h // (H / G)``;
 ``dt = softplus(dt + dt_bias)`` (no clamp), ``A = -exp(A_log)``; the scan
 ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x) B_t``, ``y_t = S_t C_t + D x_t``
-(``ops/ssd.ssd_chunked``); ``y <- RMSNorm_groups(y * silu(z))``, the gate
+(``ops/ssd.ssd_scan``); ``y <- RMSNorm_groups(y * silu(z))``, the gate
 first, then RMSNorm over each of the ``G`` groups of ``H P / G`` channels with
 one scale of ``H P``; ``out = y W_out``.
 
@@ -79,7 +79,7 @@ import jax.numpy as jnp
 import optax
 
 from horovod_tpu.ops.flash_attention import attention
-from horovod_tpu.ops.ssd import ssd_chunked
+from horovod_tpu.ops.ssd import ssd_scan
 from horovod_tpu.parallel import ep
 from horovod_tpu.profiler.annotate import moe_scope, ssm_scope
 
@@ -189,7 +189,7 @@ class NemotronHMamba2Mixer(nn.Module):
                                name="conv1d")(xbc)
             x, bmat, cmat = jnp.split(xbc, [d_in, d_in + d_bc], axis=-1)
             dt = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
-        y, _ = ssd_chunked(
+        y = ssd_scan(
             x.reshape(b, t, h, p), dt, -jnp.exp(a_log),
             bmat.reshape(b, t, g, n), cmat.reshape(b, t, g, n), d_skip,
             chunk=self.chunk)

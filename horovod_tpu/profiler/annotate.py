@@ -53,7 +53,7 @@ PHASE_PREFIX = "phase_"
 MOE_SCOPES = ("moe_router", "moe_dispatch", "moe_experts", "moe_combine",
               "moe_shared")
 # The parts of one Mamba-2 mixer (``models/nemotron_h.py``; ``ssm_scan`` is
-# ``ops/ssd.ssd_chunked``), under the same phase and with a prefix of their
+# ``ops/ssd.ssd_scan``), under the same phase and with a prefix of their
 # own for the same reason.
 SSM_SCOPES = ("ssm_in_proj", "ssm_conv", "ssm_scan", "ssm_gate_norm",
               "ssm_out_proj")

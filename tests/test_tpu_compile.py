@@ -356,27 +356,53 @@ def test_nemotron_cell_fits_one_v5e_at_full_size(nemotron_cell):
     assert memory.temp_size_in_bytes <= 1.02 * recorded["temp_bytes"]
 
 
+def _kernel_calls(text):
+    """{kernel: count} of the step's ``tpu_custom_call``s as the benchmark
+    names them (``harness.kernels.inventory``: a Pallas kernel by its
+    function, the compiler's grouped matmuls by their one-word ``op_name``),
+    and {kernel: the ``op_name`` of each of its calls}."""
+    from harness import hlo_text, kernels
+    hlo = hlo_text.HloIndex(text)
+    op_names = {}
+    for ins in hlo.kernels():
+        op_names.setdefault(hlo.kernel_name(ins), []).append(ins.op_name)
+    return kernels.inventory(hlo), op_names
+
+
 def test_nemotron_cell_holds_its_kernels_and_scopes(nemotron_cell):
-    """Three flash kernels (the attention block keeps its activations) and
+    """Three flash kernels (the attention block keeps its activations);
     eleven ``ragged-dot`` calls in each of four expert layers whose forward
     is recomputed: a share's walk is two loops a layer, not an unrolling and
-    not a fast path beside a fallback; every ``ssm_*`` scope and
-    ``moe_shared`` in the text. The rows of pairs sent elsewhere are gone:
-    the ``k T`` = 49 152 pairs (50 688 in eleven whole tiles) still index
-    vectors (the sort keys, the router weights' gradient), and no array has
-    that many rows of hidden or expert width."""
+    not a fast path beside a fallback; and the scan's kernels once a mixer
+    layer and pass they are traced for: the forward twice a layer (the pass
+    itself and the recomputation, which also writes the chunks' end states)
+    and the backward once, every one under ``ssm_scan``, the backward's
+    under ``transpose(jvp(...))``. Every ``ssm_*`` scope and ``moe_shared``
+    in the text. The rows of pairs sent elsewhere are gone: the ``k T`` =
+    49 152 pairs (50 688 in eleven whole tiles) still index vectors (the
+    sort keys, the router weights' gradient), and no array has that many
+    rows of hidden or expert width; nor does any array hold a chunk's
+    [128, 128] decays a head (``ssd_chunked`` wrote [1, 64, 8, 8, 128,
+    128])."""
     from horovod_tpu.profiler.annotate import MOE_SCOPES, SSM_SCOPES
     job, _, compiled = nemotron_cell
     text = compiled.as_text()
-    names = re.findall(r'custom_call_target="tpu_custom_call".*?'
-                       r'op_name="([^"]*)"', text)
-    ragged = [n for n in names if n.startswith("ragged-dot")]
-    assert len(names) == job.expected_custom_calls == 47
-    assert len(ragged) == 44
+    calls, op_names = _kernel_calls(text)
+    mixers = job.facts["ssm_layers"]
+    assert mixers == 4
+    assert calls == {
+        "_fwd_kernel": 1, "_bwd_dq_kernel": 1, "_bwd_dkv_kernel": 1,
+        "ragged-dot-none": 32, "ragged-dot-metadata": 12,
+        "_ssd_fwd_kernel": 2 * mixers, "_ssd_bwd_kernel": mixers}
+    for kernel in ("_ssd_fwd_kernel", "_ssd_bwd_kernel"):
+        assert all("ssm_scan" in name for name in op_names[kernel]), kernel
+    assert all("transpose(jvp(" in name
+               for name in op_names["_ssd_bwd_kernel"])
     for scope in SSM_SCOPES + MOE_SCOPES:
         assert scope in text, scope
     pairs = 6 * 8192
     assert re.search(rf"\[{pairs}\]", text)
     assert not re.search(rf"\[({pairs}|{-(-pairs // 4608) * 4608}),\d", text)
+    assert not re.search(r"\[1,64,8,8,128,128\]", text)
     opcodes = re.findall(r"[\s)]([a-z\-]+)\(", text)
     assert "all-reduce" not in opcodes  # one chip exchanges nothing
