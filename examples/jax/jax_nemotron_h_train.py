@@ -20,6 +20,7 @@ import numpy as np
 import optax
 
 import horovod_tpu as hvd
+from horovod_tpu.metrics.registry import get_registry
 from horovod_tpu.models import NemotronHTiny, nemotron_h_loss
 from horovod_tpu.parallel import dp, ep
 
@@ -71,15 +72,22 @@ def main():
     largest = max(np.abs(b).max() for b in biases)
     # the state carries the mean load over the replicas: of the tiles a
     # share's walk was built with, the ones a replica worked in
-    pairs = model.experts_per_token * args.batch_per_replica * args.seq_len
-    tiles = [ep.share_tiles(layer, model.experts_held, pairs, record=True)
+    tiles = [ep.share_tiles(layer, model.experts_held,
+                            model.experts_per_token,
+                            args.batch_per_replica * args.seq_len,
+                            record=True)
              for layer in load]
+    rows = {kind: get_registry().counter(
+        "hvd_moe_share_rows_total", kind=kind).value
+        for kind in ("held", "computed")}
     if hvd.rank() == 0:
         print(f"replicas {replicas}; loss {first:.4f} -> {last:.4f}; "
               f"largest correction bias {largest:.4f}; "
               f"expert load of the last step, first expert layer: "
               f"{load[0].astype(int).tolist()}; live tiles of those built, "
-              f"by expert layer: {tiles}")
+              f"by expert layer: {tiles}; rows of the held experts' pairs "
+              f"{rows['held']:.0f}, rows the grouped matmuls computed for "
+              f"them (every slot of the live tiles) {rows['computed']:.0f}")
     assert last < first, (first, last)
     if hvd.rank() == 0:
         print(f"done: final loss {last:.4f}")

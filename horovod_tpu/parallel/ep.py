@@ -31,8 +31,9 @@ operation was:
 - ``moe_dispatch``: the ``k T`` (token, slot) pairs are sorted by expert
   (a stable argsort), and the tokens' rows are gathered into that order;
 - ``moe_experts``: one grouped matmul per projection over the sorted rows
-  (``jax.lax.ragged_dot``, which the TPU compiler lowers to its own Mosaic
-  kernel);
+  (a full load: ``jax.lax.ragged_dot``, which the TPU compiler lowers to
+  its own Mosaic kernel; a share's walk: a batched ``dot_general`` over
+  slots, an expert a batch entry);
 - ``moe_combine``: the rows are gathered back into token order and summed
   with their router weights, in float32.
 
@@ -54,29 +55,40 @@ exchange, and nothing here stands in for the absent chips.
 
 The held experts' pairs sort *first*, in expert order, so they are a prefix
 of the sorted pairs whose length, the sum of the held experts' counts, the
-layer has. A share touches only that prefix: it **walks the sorted pairs in
-static tiles** of ``C`` rows (:func:`_walk`) and works only in the tiles
-that begin before the last held pair. ``C`` is :func:`share_tile_rows`:
-1.5 x the ``k T count / E`` pairs a balanced router sends the held experts,
-in whole row tiles of 512, computed from ``k``, ``T``, ``count`` and ``E``
-alone: on a balanced step one tile is live and the rest cost a loop's exit
-(4608 of 49 152 rows for 8 of 128 experts under top-6 of 8192 tokens). No
-capacity and no drop: if every pair chooses a held expert every tile is
-live. Everything done to rows happens inside the walk, a tile at a time:
-the gather of the tile's tokens' rows (``moe_dispatch``), the expert
-function over ``ragged_dot`` with the tile's own group sizes, the held
-experts' cumulative counts clipped to the tile's range (``moe_experts``),
-and the way back: the rows times their router weights are *scatter-added*
-into a float32 ``[T, d]`` (``moe_combine``: a tile's rows are a sparse
-subset of the ``[T, k]`` slots, so there is no bijection to invert). In the
-one tile that the last held pair cuts, the rows past it belong to no group.
-``ragged_dot`` promises nothing about such rows (the TPU's kernel spends no
-time on them and leaves them unwritten: whatever the buffer held, NaNs in a
-training step), so the tile's ``dot`` zeroes them by row index on the way
-in and on the way out: zero output, zero gradient.
+layer has. A share touches only that prefix: it **walks the pairs in static
+tiles** (:func:`_walk`) in which **every held expert has a slot of its own**:
+tile ``i`` is ``count`` slots of ``S`` rows, and slot ``e`` holds pairs
+``[i S, (i + 1) S)`` of held expert ``e`` (a row's sorted position is its
+expert's first pair plus its place, arithmetic on the tile's indices from
+the held counts alone, :func:`_share_tiles_of`). Every row block of a tile
+is thus one expert's, at a place known when the program is built, and the
+grouped matmul over a tile is XLA's own batched product ``[count, S, k] x
+[count, k, n]``, an expert a batch entry, whose time follows its rows
+(``lax.ragged_dot``'s TPU kernel is paced by the (group, 512-row tile)
+pairs it visits: 2.0 ms a call for 0.2 ms of arithmetic at Nemotron-H's
+widths, where the batched product takes 0.3; ``PERF.md`` §6, PRs 31 and
+34). ``S`` is :func:`share_slot_rows`: 1.5 x the ``k T / E`` pairs a
+balanced router sends one expert, in whole row blocks of 128, and the tile
+:func:`share_tile_rows` = ``count S``, computed from ``k``, ``T``, ``count``
+and ``E`` alone (640 and 5120 of 49 152 rows for 8 of 128 experts under
+top-6 of 8192 tokens). The walk works in the tiles the *fullest* held
+expert's pairs reach into: on a balanced step one tile is live and the
+rest cost a loop's exit. No capacity and no drop: an expert sent more than
+a slot makes the next tile live, and if one expert is sent every token
+every tile is. Everything done to rows happens inside the walk, a tile at
+a time: the gather of the tile's tokens' rows (``moe_dispatch``), the
+expert function over the batched product (``moe_experts``), and the way
+back: the rows times their router weights are *scatter-added* into a
+float32 ``[T, d]`` (``moe_combine``: a tile's rows are a sparse subset of
+the ``[T, k]`` slots, so there is no bijection to invert). A row past its
+expert's last pair gathers some token in bounds, is computed like any
+other (a product writes every row: nothing is left unwritten, nothing
+needs zeroing) and carries router weight 0: it adds nothing to the
+output, and its rows of every gradient are zero because the gradient that
+reaches them is.
 
 The walk is a ``jax.custom_vjp`` over two loops whose trip count is read
-from the data, one forward and one backward, so each ``ragged_dot`` is in
+from the data, one forward and one backward, so each grouped matmul is in
 the program once a direction whatever the number of tiles, and a tile that
 is not live costs nothing at all. It keeps no activation: the backward loop
 gathers a live tile's rows and takes them through the experts again (under
@@ -87,11 +99,17 @@ gradients into a float32 ``[T, d]``, and sums the expert weights' gradients
 over the live tiles in their own dtype (one live tile: exact; float32 sums
 were a gigabyte of the step's temporaries). At trace time
 ``hvd_moe_share_tiles_total{kind="built"}`` counts the tiles a layer call
-was built with and ``hvd_moe_share_tile_rows`` holds ``C``; which tiles were
-live is data, read back outside the step: :func:`share_tiles` over the load
-a model's state carries. A share whose rule gives ``C = k T`` (1.5 x its
-part of the experts reaches all pairs) is the one-tile program with the
-zeroing by row index over all ``k T`` rows.
+can come to and ``hvd_moe_share_tile_rows`` holds a tile's rows; which
+tiles were live, how many rows the held experts were sent and how many the
+live tiles computed for them (``hvd_moe_share_rows_total``) is data, read
+back outside the step: :func:`share_tiles` over the load a model's state
+carries. A share whose rule gives a tile of all ``k T`` pairs (its slots
+reach them all) is the one-tile program below the walk: ``ragged_dot``
+over the rows where the sort leaves them. There the rows past the last
+held pair belong to no group, and ``ragged_dot`` promises nothing about
+such rows (the TPU's kernel spends no time on them and leaves them
+unwritten: whatever the buffer held, NaNs in a training step), so they are
+zeroed by row index on the way in and on the way out.
 
 **:func:`moe_layer` — the exchange over the ``expert`` axis** (unit tests and
 the CPU dry run only; on no measured path). GShard-style top-1 routing into
@@ -245,18 +263,24 @@ def _group_dot(sizes: jax.Array, in_a_group: jax.Array) -> Callable:
 
 # -- a share's walk over its sorted pairs -------------------------------------
 
-SHARE_TILE_HEADROOM = 1.5  # a tile, over the pairs a balanced router sends
-SHARE_TILE_MULTIPLE = 512  # rows: whole row tiles of the grouped matmul
+SHARE_TILE_HEADROOM = 1.5  # a slot, over the pairs a balanced router sends
+SHARE_BLOCK_ROWS = 128  # a slot is whole row blocks of the matrix unit
+
+
+def share_slot_rows(k_t: int, n_experts: int) -> int:
+    """Rows a tile of the walk gives each held expert: ``SHARE_TILE_HEADROOM``
+    x the ``k T / E`` pairs a balanced router sends one expert, in whole
+    ``SHARE_BLOCK_ROWS``."""
+    balanced = int(SHARE_TILE_HEADROOM * k_t / n_experts)
+    return max(1, -(-balanced // SHARE_BLOCK_ROWS)) * SHARE_BLOCK_ROWS
 
 
 def share_tile_rows(k_t: int, count: int, n_experts: int) -> int:
-    """Rows of one tile of the walk: ``SHARE_TILE_HEADROOM`` x the
-    ``k T count / E`` pairs a balanced router sends ``count`` held experts
-    of ``n_experts``, rounded up to ``SHARE_TILE_MULTIPLE``; never more than
-    the ``k T`` pairs there are (a full load is one tile: no walk)."""
-    balanced = SHARE_TILE_HEADROOM * k_t * count / n_experts
-    multiples = max(1, -(-int(balanced) // SHARE_TILE_MULTIPLE))
-    return min(k_t, multiples * SHARE_TILE_MULTIPLE)
+    """Rows of one tile of the walk: a slot of :func:`share_slot_rows` for
+    each of the ``count`` held experts, computed from ``k``, ``T``,
+    ``count`` and ``E`` alone; never more than the ``k T`` pairs there are
+    (a full load is one tile: no walk, no slots)."""
+    return min(k_t, count * share_slot_rows(k_t, n_experts))
 
 
 def _share_tiles_counter(kind: str):
@@ -277,21 +301,37 @@ def _count_built_tiles(tiles: int, rows: int):
         "rows of one tile of the share's walk traced last").set(rows)
 
 
-def share_tiles(load, held: Tuple[int, int], k_t: int, record: bool = False
-                ) -> Tuple[int, int]:
-    """(live, built): of the ``built`` tiles a share's walk is compiled
-    with, the ``live`` ones a step that routed this ``load`` worked in.
-    Host side, outside the step: ``load`` is one expert layer's pairs per
-    expert over all E (``MoeStats.expert_tokens``, or the ``load`` a
-    model's ``router_state`` carries to the next step), ``k_t`` its
-    (token, slot) pairs. ``record`` adds ``live`` to
-    ``hvd_moe_share_tiles_total{kind="live"}``."""
+def share_tiles(load, held: Tuple[int, int], k: int, tokens: int,
+                record: bool = False) -> Tuple[int, int]:
+    """(live, built): of the ``built`` tiles a share's walk can come to
+    (one held expert sent every one of the ``tokens``), the ``live`` ones a
+    step that routed this ``load`` worked in: as many as the fullest held
+    expert's pairs fill slots. Host side, outside the step: ``load`` is one
+    expert layer's pairs per expert over all E (``MoeStats.expert_tokens``,
+    or the ``load`` a model's ``router_state`` carries to the next step) of
+    a top-``k`` router over ``tokens`` tokens. ``record`` adds ``live`` to
+    ``hvd_moe_share_tiles_total{kind="live"}``, and to
+    ``hvd_moe_share_rows_total`` the held experts' pairs (``kind="held"``)
+    and the rows the live tiles computed for them (``kind="computed"``:
+    whole tiles, every slot of them)."""
     first, count = held
-    rows = share_tile_rows(k_t, count, len(load))
-    held_rows = int(sum(float(n) for n in load[first:first + count]))
-    live, built = -(-held_rows // rows), -(-k_t // rows)
+    rows = share_tile_rows(k * tokens, count, len(load))
+    held_rows = [int(n) for n in load[first:first + count]]
+    if rows < k * tokens:
+        slot = share_slot_rows(k * tokens, len(load))
+        live, built = -(-max(held_rows) // slot), -(-tokens // slot)
+        computed = live * rows
+    else:  # the one-tile program below the walk works the held pairs alone
+        live, built, computed = -(-sum(held_rows) // rows), 1, sum(held_rows)
     if record:
+        from horovod_tpu.metrics.registry import get_registry
         _share_tiles_counter("live").inc(live)
+        for kind, n in (("held", sum(held_rows)), ("computed", computed)):
+            get_registry().counter(
+                "hvd_moe_share_rows_total",
+                "rows of a share's walk in the steps read back: the held "
+                "experts' pairs, and the rows of the live tiles that the "
+                "grouped matmuls computed for them", kind=kind).inc(n)
     return live, built
 
 
@@ -302,48 +342,60 @@ def _rows_of(x: jax.Array, index: jax.Array) -> jax.Array:
 
 def _share_tiles_of(tile, x, order, weights, sizes, expert):
     """What both directions of a share's walk read: (the number of tiles
-    that begin before the last held pair, the function of a tile's index
-    that gives (its tokens, its pairs' router weights, its tokens' rows,
-    the experts as a function of (rows, *expert_weights))). A tile's
-    grouped matmul has the held experts' cumulative counts clipped to the
-    tile's range as group sizes, and a row past the last held pair is in no
-    group."""
-    k = weights.shape[-1]
-    order = jnp.pad(order, (0, -order.shape[0] % tile))  # whole tiles
+    the fullest held expert's pairs reach into, the function of a tile's
+    index that gives (its tokens, its rows' sorted positions (``k T`` for a
+    row that is no pair), its rows' router weights, its tokens' rows, the
+    experts as a function of (rows, *expert_weights))). A tile is one slot
+    of ``tile / count`` rows a held expert: slot ``e`` of tile ``i`` holds
+    expert ``e``'s pairs ``[i S, (i + 1) S)``, so the grouped matmul over a
+    tile is one batched product ``[count, S, k] x [count, k, n]``. A row
+    past its expert's last pair gathers some token in bounds and carries
+    weight 0."""
+    k, count = weights.shape[-1], sizes.shape[0]
+    slot = tile // count
+    pairs_in_all = order.shape[0]
     by_pair = weights.reshape(-1)
-    ends = jnp.cumsum(sizes)
+
+    def by_row(of_expert):
+        """[count] -> [tile]: every row of a slot has its expert's value."""
+        return jnp.broadcast_to(of_expert[:, None], (count, slot)).reshape(-1)
+    within = jnp.tile(jnp.arange(slot), count)  # a row's place in its slot
+    held_pairs, first_pair = by_row(sizes), by_row(jnp.cumsum(sizes) - sizes)
+
+    def dot(a, w):
+        out = jnp.einsum("esk,ekn->esn", a.reshape(count, slot, -1), w,
+                         preferred_element_type=jnp.float32)
+        return out.astype(a.dtype).reshape(tile, -1)
 
     def at(i):
-        lo = i * tile
-
-        def inside(bounds):
-            return jnp.clip(bounds, lo, lo + tile)
-        pairs = lax.dynamic_slice(order, (lo,), (tile,))
+        nth = i * slot + within  # which of its expert's pairs a row holds
+        real = nth < held_pairs
+        position = first_pair + nth
+        pairs = _rows_of(order, jnp.minimum(position, pairs_in_all - 1))
         tokens = lax.div(pairs, k)
-        dot = _group_dot(inside(ends) - inside(ends - sizes),
-                         (lo + jnp.arange(tile) < ends[-1])[:, None])
         with moe_scope("moe_dispatch"):
             rows = _rows_of(x, tokens)
 
         def experts(rows, *expert_weights):
             with moe_scope("moe_experts"):
                 return expert(dot, rows, *expert_weights)
-        return tokens, _rows_of(by_pair, pairs), rows, experts
-    return lax.div(ends[-1] + (tile - 1), tile), at
+        return tokens, jnp.where(real, position, pairs_in_all), \
+            jnp.where(real, _rows_of(by_pair, pairs), 0.0), rows, experts
+    return lax.div(jnp.max(sizes) + (slot - 1), slot), at
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
 def _walk(x, order, inverse, weights, sizes, expert_weights, expert, tile):
     """A share's dispatch, experts and combine as one walk over the sorted
-    pairs in tiles of ``tile`` rows, of which only those that begin before
-    the last held pair do anything: [T, d] float32, the held experts' part
-    of the weighted sum. Two loops with a trip count read from the data,
-    one forward and one backward, so each ``ragged_dot`` is in the program
-    once per direction, whatever the number of tiles."""
+    pairs in tiles of ``tile`` rows, of which only those the fullest held
+    expert's pairs reach into do anything: [T, d] float32, the held
+    experts' part of the weighted sum. Two loops with a trip count read
+    from the data, one forward and one backward, so each grouped matmul is
+    in the program once per direction, whatever the number of tiles."""
     live, at = _share_tiles_of(tile, x, order, weights, sizes, expert)
 
     def one_tile(i, out):
-        tokens, weight, rows, experts = at(i)
+        tokens, _, weight, rows, experts = at(i)
         rows = experts(rows, *expert_weights)
         with moe_scope("moe_combine"):
             return out.at[tokens].add(
@@ -364,21 +416,20 @@ def _walk_bwd(expert, tile, saved, g):
     again (under a recomputed block that is the one recomputation: the
     forward walk's result is not needed there, and goes). What the tiles
     add to the tokens' gradient is summed in float32, to the expert
-    weights' in the weights' dtype, as ``ragged_dot`` hands it over: exact
-    with one live tile (float32 sums were a gigabyte of the step's
-    temporaries at Nemotron-H's widths)."""
+    weights' in the weights' dtype: exact with one live tile (float32 sums
+    were a gigabyte of the step's temporaries at Nemotron-H's widths)."""
     x, order, inverse, weights, sizes, expert_weights = saved
     live, at = _share_tiles_of(tile, x, order, weights, sizes, expert)
 
     def one_tile(i, carry):
-        d_x, d_by_row, d_experts = carry
-        tokens, weight, rows, experts = at(i)
+        d_x, d_by_pair, d_experts = carry
+        tokens, position, weight, rows, experts = at(i)
         rows, pull = jax.vjp(experts, rows, *expert_weights)
         with moe_scope("moe_combine"):
             g_rows = _rows_of(g, tokens)
-            d_by_row = lax.dynamic_update_slice(
-                d_by_row, jnp.sum(g_rows * rows.astype(jnp.float32), axis=-1),
-                (i * tile,))
+            d_by_pair = d_by_pair.at[position].set(
+                jnp.sum(g_rows * rows.astype(jnp.float32), axis=-1),
+                mode="drop")
             d_rows = (weight[:, None] * g_rows).astype(rows.dtype)
         d_rows, *d_tile = pull(d_rows)
         with moe_scope("moe_experts"):
@@ -386,14 +437,14 @@ def _walk_bwd(expert, tile, saved, g):
         with moe_scope("moe_dispatch"):
             d_x = d_x.at[tokens].add(d_rows.astype(jnp.float32),
                                      mode="promise_in_bounds")
-        return d_x, d_by_row, d_experts
+        return d_x, d_by_pair, d_experts
 
-    d_x, d_by_row, d_experts = lax.fori_loop(0, live, one_tile, (
+    d_x, d_by_pair, d_experts = lax.fori_loop(0, live, one_tile, (
         jnp.zeros(x.shape, jnp.float32),
-        jnp.zeros(-(-order.shape[0] // tile) * tile, jnp.float32),
+        jnp.zeros(order.shape[0], jnp.float32),  # by sorted position
         tuple(jnp.zeros_like(w) for w in expert_weights)))
     with moe_scope("moe_combine"):
-        d_weights = _rows_of(d_by_row, inverse).reshape(weights.shape)
+        d_weights = _rows_of(d_by_pair, inverse).reshape(weights.shape)
     return d_x.astype(x.dtype), None, None, d_weights, None, d_experts
 
 
@@ -417,13 +468,15 @@ def moe_dropless(x: jax.Array, route: Callable, expert: Callable,
     ``k T``.
 
     The sorted pairs are worked in tiles of :func:`share_tile_rows` rows, a
-    number computed from ``k``, ``T`` and ``count / E`` alone. A full load
-    is one tile of ``k T`` rows, and that is the program below the walk:
-    the rows gathered into expert order, one grouped matmul per projection,
-    the rows permuted back by the inverse gather. A share of fewer experts
-    is :func:`_walk`, which does nothing for a tile past the last held pair
-    and returns a live tile's weighted rows to their tokens by a
-    scatter-add into float32 (module text, "A share").
+    number computed from ``k``, ``T``, ``count`` and ``E`` alone. A full
+    load is one tile of ``k T`` rows, and that is the program below the
+    walk: the rows gathered into expert order, one ``lax.ragged_dot`` per
+    projection, the rows permuted back by the inverse gather. A share of
+    fewer experts is :func:`_walk`: a tile is one slot of
+    :func:`share_slot_rows` rows a held expert, the product over it one
+    batched ``dot_general``, nothing is done for a tile past the fullest
+    held expert's last pair, and a live tile's weighted rows return to
+    their tokens by a scatter-add into float32 (module text, "A share").
     """
     t, d = x.shape
     with moe_scope("moe_router"):
@@ -452,7 +505,7 @@ def moe_dropless(x: jax.Array, route: Callable, expert: Callable,
     sizes = stats.expert_tokens[first:first + count]
     tile = share_tile_rows(k * t, count, n_experts)
     if tile < k * t:
-        _count_built_tiles(-(-k * t // tile), tile)
+        _count_built_tiles(-(-t // (tile // count)), tile)  # T in slots
         out = _walk(x, order, inverse, weights, sizes, tuple(expert_weights),
                     expert, tile)
         return out.astype(x.dtype), stats

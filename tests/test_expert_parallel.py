@@ -411,25 +411,29 @@ def test_a_share_rejects_weights_that_do_not_lead_with_its_count():
 
 @pytest.fixture
 def small_tiles(monkeypatch):
-    """Tiles a tiny layer walks in threes: the rule's rounding to the
-    grouped matmul's row tiles (512) would make 384 pairs one tile."""
+    """Row blocks of 8, so that a tiny layer walks tiles of four slots of
+    40 rows in threes: blocks of 128 would make 384 pairs one tile."""
     from horovod_tpu.parallel import ep
-    monkeypatch.setattr(ep, "SHARE_TILE_MULTIPLE", 8)
+    monkeypatch.setattr(ep, "SHARE_BLOCK_ROWS", 8)
     return ep
 
 
 FIRST, COUNT = 4, 4
 K_T = K_SHARE * T
-TILE = 144  # 1.5 x the 96 pairs a balanced router sends 4 of 16 experts
+# a slot: 1.5 x the 24 pairs a balanced router sends one of 16 experts, in
+# whole blocks of 8; a tile: a slot for each of the 4 held experts
+SLOT = 40
+TILE = COUNT * SLOT
 
 
-def _choices(n_held):
-    """[T, k] experts of which exactly the first ``n_held`` (token, slot)
-    pairs go to held experts, a token's k all different."""
+def _choices(sizes):
+    """[T, k] experts by which held expert ``FIRST + e`` is sent exactly
+    ``sizes[e]`` pairs (slot ``e`` of the first ``sizes[e]`` tokens), a
+    token's k all different; every other pair goes elsewhere."""
     elsewhere = np.array([e for e in range(E)
                           if not FIRST <= e < FIRST + COUNT])
-    slot = np.tile(np.arange(K_SHARE), T)
-    held = np.arange(K_T) < n_held
+    token, slot = np.divmod(np.arange(K_T), K_SHARE)
+    held = token < np.asarray(sizes)[slot]
     return jnp.asarray(np.where(held, FIRST + slot, elsewhere[slot])
                        .reshape(T, K_SHARE), jnp.int32)
 
@@ -443,53 +447,49 @@ def _routed_as_told(x, w_router, experts):
         logits
 
 
-def _told_share(x, w, experts):
+def _told_share(x, w, experts, first=FIRST, count=COUNT):
     route = functools.partial(_routed_as_told, w_router=w["router"],
                               experts=experts)
     return moe_dropless(
         x, route, relu2_expert,
-        (w["up"][FIRST:FIRST + COUNT], w["down"][FIRST:FIRST + COUNT]),
-        held=(FIRST, COUNT))
+        (w["up"][first:first + count], w["down"][first:first + count]),
+        held=(first, count))
 
 
-def _told_dense(x, w, experts):
+def _told_dense(x, w, experts, first=FIRST, count=COUNT):
     scores = jax.nn.sigmoid(x @ w["router"])
     picked = (experts[:, :, None] == jnp.arange(E)).any(axis=1)
-    gate = jnp.where(picked, scores, 0.0)[:, FIRST:FIRST + COUNT]
+    gate = jnp.where(picked, scores, 0.0)[:, first:first + count]
     hidden = jnp.square(jnp.maximum(jnp.einsum(
-        "td,edf->tef", x, w["up"][FIRST:FIRST + COUNT]), 0.0))
+        "td,edf->tef", x, w["up"][first:first + count]), 0.0))
     return jnp.einsum("te,tef,efd->td", gate, hidden,
-                      w["down"][FIRST:FIRST + COUNT])
+                      w["down"][first:first + count])
 
 
-@pytest.mark.parametrize(
-    "n_held", [0, TILE - 1, TILE, TILE + 1, 2 * TILE + 5, K_T],
-    ids=["none", "a-row-short-of-a-tile", "a-tile", "a-tile-and-a-row",
-         "three-tiles", "every-pair"])
-def test_a_walked_share_is_exact_wherever_the_held_pairs_end(small_tiles,
-                                                             n_held):
-    """Three tiles of 144 rows over 384 sorted pairs, the held pairs ending
-    before, on and after a tile's edge, nowhere and at the very end: the
-    output and the gradients of tokens, router and both expert matrices are
-    the dense reference's."""
-    assert small_tiles.share_tile_rows(K_T, COUNT, E) == TILE
-    w = _share_weights(n_held)
+def _walked_share_against_dense(ep, sizes, first, count, tile, tiles):
+    """Output and gradients (tokens, router, both expert matrices) of the
+    share ``(first, count)`` under ``_choices(sizes)`` against the dense
+    reference's; ``tiles`` = (live, built) by ``share_tiles``."""
+    assert ep.share_tile_rows(K_T, count, E) == tile
+    w = _share_weights(sum(sizes))
     x = jnp.asarray(np.random.RandomState(30).randn(T, D), jnp.float32)
-    experts = _choices(n_held)
-    out, stats = jax.jit(_told_share)(x, w, experts)
-    assert int(stats.expert_tokens[FIRST:FIRST + COUNT].sum()) == n_held
-    assert small_tiles.share_tiles(stats.expert_tokens, (FIRST, COUNT),
-                                   K_T) == (-(-n_held // TILE), 3)
+    experts = _choices(sizes)
+    share = functools.partial(_told_share, first=first, count=count)
+    dense = functools.partial(_told_dense, first=first, count=count)
+    out, stats = jax.jit(share)(x, w, experts)
+    n_held = int(stats.expert_tokens[first:first + count].sum())
+    assert n_held == sum(sizes[first - FIRST:first - FIRST + count])
+    assert ep.share_tiles(stats.expert_tokens, (first, count), K_SHARE,
+                          T) == tiles
     np.testing.assert_allclose(np.asarray(out),
-                               np.asarray(_told_dense(x, w, experts)),
+                               np.asarray(dense(x, w, experts)),
                                rtol=2e-4, atol=2e-5)
 
     def loss(layer, x, w):
         return jnp.sum(jnp.tanh(layer(x, w, experts)) ** 2)
     got = jax.jit(jax.grad(lambda x, w: loss(
-        lambda *a: _told_share(*a)[0], x, w), argnums=(0, 1)))(x, w)
-    want = jax.grad(lambda x, w: loss(_told_dense, x, w),
-                    argnums=(0, 1))(x, w)
+        lambda *a: share(*a)[0], x, w), argnums=(0, 1)))(x, w)
+    want = jax.grad(lambda x, w: loss(dense, x, w), argnums=(0, 1))(x, w)
     for name, g, v in [("x", got[0], want[0])] + [
             (key, got[1][key], want[1][key])
             for key in ("router", "up", "down")]:
@@ -499,12 +499,49 @@ def test_a_walked_share_is_exact_wherever_the_held_pairs_end(small_tiles,
                                    atol=2e-5, err_msg=name)
 
 
+@pytest.mark.parametrize("sizes,live", [
+    ([0, 0, 0, 0], 0), ([39, 12, 39, 0], 1), ([40, 40, 40, 40], 1),
+    ([41, 3, 40, 17], 2), ([96, 81, 5, 80], 3), ([96, 96, 96, 96], 3),
+    ([39, 39, 39, 39], 1), ([7, 0, 1, 0], 1), ([3, 0, 90, 0], 3)],
+    ids=["none", "a-row-short-of-a-slot", "every-slot-full",
+         "a-slot-and-a-row", "three-tiles", "every-pair",
+         "each-a-row-short-of-a-slot", "two-experts-with-no-pair",
+         "one-expert-in-three-tiles-beside-empty-slots"])
+def test_a_walked_share_is_exact_wherever_the_held_pairs_end(small_tiles,
+                                                             sizes, live):
+    """Three tiles of 160 rows (four slots of 40) over 384 sorted pairs.
+    Slot ``e`` of tile ``i`` holds pairs ``[40 i, 40 (i + 1))`` of held
+    expert ``e``, so the live tiles are those the fullest expert reaches:
+    its pairs ending before, on and after a slot's edge, nowhere, and every
+    pair there is; every expert a row short of its slot, experts with no
+    pair between experts with few, one expert far ahead of the others. The
+    output and the gradients of tokens, router and both expert matrices are
+    the dense reference's."""
+    assert -(-max(sizes) // SLOT) == live
+    _walked_share_against_dense(small_tiles, sizes, FIRST, COUNT, TILE,
+                                (live, 3))
+
+
+@pytest.mark.parametrize("pairs,live", [(96, 3), (41, 2), (40, 1)],
+                         ids=["three-tiles", "a-tile-and-a-row", "a-tile"])
+def test_a_walked_share_of_one_expert_with_more_than_a_tile(small_tiles,
+                                                            pairs, live):
+    """A share of one expert walks tiles of its one slot of 40 rows: it is
+    sent three tiles full, a tile and a row, a tile; every pair is
+    computed."""
+    sizes = [0, 0, pairs, 0]
+    _walked_share_against_dense(small_tiles, sizes, FIRST + 2, 1, SLOT,
+                                (live, 3))
+
+
 @pytest.mark.parametrize("shares", [2, 4, 8])
 def test_the_walked_shares_add_up_to_the_uncut_layer(small_tiles, shares):
     """The shares of ``test_the_shares_add_up_to_the_uncut_layer`` again,
-    each now a walk of two, three or six tiles."""
-    assert -(-K_T // small_tiles.share_tile_rows(K_T, E // shares, E)) \
-        == {2: 2, 4: 3, 8: 6}[shares]
+    each now a walk of up to three tiles of eight, four or two slots."""
+    assert small_tiles.share_tile_rows(K_T, E // shares, E) \
+        == E // shares * SLOT < K_T
+    assert small_tiles.share_tiles(np.zeros(E), (0, E // shares), K_SHARE,
+                                   T) == (0, 3)
     test_the_shares_add_up_to_the_uncut_layer(shares)
 
 
@@ -541,43 +578,59 @@ def test_a_full_load_is_one_tile_and_traces_to_the_program_it_was():
 
 
 def test_a_walk_has_each_grouped_matmul_once_a_direction(small_tiles):
-    """Three tiles and still two ``ragged_dot``s forward and six in the
+    """Three tiles and still two grouped matmuls forward and six in the
     backward walk (the two again, and their four transposes): the walk is a
-    loop, not an unrolling, and has no fallback of its own."""
+    loop, not an unrolling, and has no fallback of its own. Its product is
+    one batched ``dot_general`` over the tile's slots, an expert a batch
+    entry; no ``ragged_dot`` is left in it."""
+    batched = "([0], [0]))"  # dot_generals with an expert a batch entry
     w = _share_weights()
     x = jnp.zeros((T, D), jnp.float32)
     forward = str(jax.make_jaxpr(lambda x, w: _share(x, w, 4, 4))(x, w))
-    assert forward.count("ragged_dot_general") == 2
+    assert forward.count(batched) == 2
     assert forward.count("while[") == 1 and "cond[" not in forward
     both = str(jax.make_jaxpr(jax.grad(
         lambda x, w: _share(x, w, 4, 4)[0].sum(), argnums=(0, 1)))(x, w))
-    assert both.count("ragged_dot_general") == 2 + 6
+    assert both.count(batched) == 2 + 6
     assert both.count("while[") == 2 and "cond[" not in both
+    assert "ragged_dot_general" not in forward + both
 
 
 def test_share_tile_rule_and_live_tiles_by_hand():
-    """The tile is 1.5 x the balanced share of the pairs in whole row tiles
-    of 512, at most all pairs; ``share_tiles`` counts the tiles that begin
-    before the last held pair."""
+    """A slot is 1.5 x the pairs a balanced router sends one expert, in
+    whole row blocks of 128; a tile a slot a held expert, at most all
+    pairs; ``share_tiles`` counts the tiles the fullest held expert's pairs
+    reach into."""
     from horovod_tpu.parallel import ep
-    k_t = 6 * 8192
-    assert ep.share_tile_rows(k_t, 8, 128) == 4608  # 1.5 x 3072
-    assert ep.share_tile_rows(k_t, 16, 128) == 9216
+    k, tokens = 6, 8192
+    k_t = k * tokens
+    assert ep.SHARE_BLOCK_ROWS == 128
+    assert ep.share_slot_rows(k_t, 128) == 640      # 1.5 x 384 in 128s
+    assert ep.share_tile_rows(k_t, 8, 128) == 5120
+    assert ep.share_tile_rows(k_t, 16, 128) == 10240
     assert ep.share_tile_rows(k_t, 128, 128) == k_t
     assert ep.share_tile_rows(k_t, 96, 128) == k_t  # 1.5 x 3/4: all
-    assert ep.share_tile_rows(384, 4, 16) == 384    # a row tile holds all
-    assert ep.share_tile_rows(4096, 1, 128) == 512  # never under one
+    assert ep.share_tile_rows(384, 4, 16) == 384    # four blocks hold all
+    assert ep.share_tile_rows(4096, 1, 128) == 128  # never under a block
     load = np.zeros(128)
     load[8:] = (k_t - 3000) / 120
-    for held_rows, live in [(0, 0), (1, 1), (3000, 1), (4608, 1), (4609, 2),
-                            (13825, 4), (k_t, 11)]:
+    # one held expert's pairs: 13 tiles take all 8192 tokens
+    for held_rows, live in [(0, 0), (1, 1), (640, 1), (641, 2), (3000, 5),
+                            (tokens, 13)]:
         load[:8] = 0
         load[3] = held_rows
-        assert ep.share_tiles(load, (0, 8), k_t) == (live, 11), held_rows
-    # a share that does not start at 0, a load as a device array
+        assert ep.share_tiles(load, (0, 8), k, tokens) == (live, 13), \
+            held_rows
+    # the fullest expert alone counts: 5118 pairs in eight slots of 640
+    load[:8] = [640, 639, 640, 640, 640, 640, 639, 640]
+    assert ep.share_tiles(load, (0, 8), k, tokens) == (1, 13)
+    load[5] = 641
+    assert ep.share_tiles(load, (0, 8), k, tokens) == (2, 13)
+    # a share that does not start at 0, a load as a device array; 384
+    # pairs are one tile, the program below the walk
     load = jnp.zeros(16).at[4:8].set(jnp.asarray([100., 0., 45., 0.]))
-    assert ep.share_tiles(load, (4, 4), K_T) == (1, 1)   # 384 rows: one tile
-    assert ep.share_tiles(load, (8, 4), K_T) == (0, 1)
+    assert ep.share_tiles(load, (4, 4), K_SHARE, T) == (1, 1)
+    assert ep.share_tiles(load, (8, 4), K_SHARE, T) == (0, 1)
 
 
 def test_the_walk_counts_its_tiles_in_the_registry(small_tiles):
@@ -589,14 +642,23 @@ def test_the_walk_counts_its_tiles_in_the_registry(small_tiles):
         return get_registry().counter("hvd_moe_share_tiles_total", kind=kind)
     built, live = counter("built").value, counter("live").value
     w = _share_weights()
-    x = jnp.zeros((T, D), jnp.float32)
+    x = jnp.asarray(np.random.RandomState(3).randn(T, D), jnp.float32)
     _, stats = jax.jit(lambda x, w: _share(x, w, 4, 4))(x, w)
     assert counter("built").value == built + 3
     assert get_registry().gauge("hvd_moe_share_tile_rows").value == TILE
     assert counter("live").value == live  # nothing is read inside a step
-    tiles = small_tiles.share_tiles(stats.expert_tokens, (4, 4), K_T,
-                                    record=True)
+    def rows(kind):
+        return get_registry().counter("hvd_moe_share_rows_total", kind=kind)
+    held_rows, computed = rows("held").value, rows("computed").value
+    tiles = small_tiles.share_tiles(stats.expert_tokens, (4, 4), K_SHARE,
+                                    T, record=True)
     assert tiles[1] == 3 and counter("live").value == live + tiles[0]
+    # the held experts' pairs, and every slot of the tiles they made live:
+    # their ratio is what the slots cost the grouped matmuls
+    sizes = np.asarray(stats.expert_tokens)[4:8]
+    assert rows("held").value == held_rows + sizes.sum() > held_rows
+    assert tiles[0] == -(-sizes.max() // SLOT)
+    assert rows("computed").value == computed + tiles[0] * TILE
     # a full load builds no walk
     jax.jit(lambda x, *w: moe_topk(x, *w, 2))(x, *_gated_weights())
     assert counter("built").value == built + 3

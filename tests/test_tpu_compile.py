@@ -370,30 +370,44 @@ def _kernel_calls(text):
 
 
 def test_nemotron_cell_holds_its_kernels_and_scopes(nemotron_cell):
-    """Three flash kernels (the attention block keeps its activations);
-    eleven ``ragged-dot`` calls in each of four expert layers whose forward
-    is recomputed: a share's walk is two loops a layer, not an unrolling and
-    not a fast path beside a fallback; and the scan's kernels once a mixer
-    layer and pass they are traced for: the forward twice a layer (the pass
-    itself and the recomputation, which also writes the chunks' end states)
-    and the backward once, every one under ``ssm_scan``, the backward's
-    under ``transpose(jvp(...))``. Every ``ssm_*`` scope and ``moe_shared``
-    in the text. The rows of pairs sent elsewhere are gone: the ``k T`` =
-    49 152 pairs (50 688 in eleven whole tiles) still index vectors (the
-    sort keys, the router weights' gradient), and no array has that many
-    rows of hidden or expert width; nor does any array hold a chunk's
-    [128, 128] decays a head (``ssd_chunked`` wrote [1, 64, 8, 8, 128,
-    128])."""
+    """Three flash kernels (the attention block keeps its activations) and
+    the scan's kernels once a mixer layer and pass they are traced for: the
+    forward twice a layer (the pass itself and the recomputation, which
+    also writes the chunks' end states) and the backward once, every one
+    under ``ssm_scan``, the backward's under ``transpose(jvp(...))``; and
+    no call of the compiler's own: a share's walk multiplies by XLA's
+    batched product, ``[8, 640, k] x [8, k, n]`` over a tile's eight slots
+    of 640 rows, eight times in each of four expert layers whose forward is
+    recomputed (the two projections forward, recomputed, towards the rows
+    and towards the matrices), every one under ``moe_experts``: two loops a
+    layer, not an unrolling and not a fast path beside a fallback, and no
+    ``ragged-dot`` call (32 of them, and 12 of their metadata, before PR
+    34). Every ``ssm_*`` scope and ``moe_shared`` in the text. The rows of
+    pairs sent elsewhere are gone: the ``k T`` = 49 152 pairs still index
+    vectors (the sort keys, the router weights' gradient), and no array
+    has that many rows of hidden or expert width; nor does any array hold a
+    chunk's [128, 128] decays a head (``ssd_chunked`` wrote [1, 64, 8, 8,
+    128, 128])."""
+    from horovod_tpu.parallel import ep
     from horovod_tpu.profiler.annotate import MOE_SCOPES, SSM_SCOPES
     job, _, compiled = nemotron_cell
     text = compiled.as_text()
     calls, op_names = _kernel_calls(text)
-    mixers = job.facts["ssm_layers"]
+    mixers, expert_layers = job.facts["ssm_layers"], 4
     assert mixers == 4
     assert calls == {
         "_fwd_kernel": 1, "_bwd_dq_kernel": 1, "_bwd_dkv_kernel": 1,
-        "ragged-dot-none": 32, "ragged-dot-metadata": 12,
         "_ssd_fwd_kernel": 2 * mixers, "_ssd_bwd_kernel": mixers}
+    assert "ragged-dot" not in text
+    slot = ep.share_slot_rows(6 * 8192, 128)
+    assert slot == 640 and ep.share_tile_rows(6 * 8192, 8, 128) == 8 * slot
+    products = re.findall(
+        r"= f32(\[8,\d+,\d+\])\S* convolution\([^\n]*"
+        r"moe_experts\)*/esk,ekn->esn/dot_general", text)
+    assert len(products) == 8 * expert_layers
+    assert sorted(set(products)) == sorted(
+        f"[8,{a},{b}]" for a, b in [(slot, 1856), (slot, 2688),
+                                    (1856, 2688), (2688, 1856)])
     for kernel in ("_ssd_fwd_kernel", "_ssd_bwd_kernel"):
         assert all("ssm_scan" in name for name in op_names[kernel]), kernel
     assert all("transpose(jvp(" in name
@@ -402,7 +416,7 @@ def test_nemotron_cell_holds_its_kernels_and_scopes(nemotron_cell):
         assert scope in text, scope
     pairs = 6 * 8192
     assert re.search(rf"\[{pairs}\]", text)
-    assert not re.search(rf"\[({pairs}|{-(-pairs // 4608) * 4608}),\d", text)
+    assert not re.search(rf"\[{pairs},\d", text)
     assert not re.search(r"\[1,64,8,8,128,128\]", text)
     opcodes = re.findall(r"[\s)]([a-z\-]+)\(", text)
     assert "all-reduce" not in opcodes  # one chip exchanges nothing
