@@ -81,31 +81,14 @@ def _check(cond, message):
         raise RuntimeError(message)
 
 
-class _CompileLog:
-    """Counts what JAX compiles, from its own monitoring events."""
-
-    def __init__(self):
-        self.seconds = 0.0
-        self.programs = 0
-        self.hits = 0
-        self.misses = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event, seconds, **_):
-        # one event for each program XLA builds or loads from the cache
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.seconds += seconds
-            self.programs += 1
-
-    def _event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.misses += 1
-
-    def snapshot(self):
-        return self.seconds, self.programs, self.hits, self.misses
+def _compiled():
+    """(backend-compile seconds, programs, cache hits, cache misses) so
+    far, from the program's own compile log."""
+    from horovod_tpu.metrics import compile_log
+    found = compile_log.report()
+    return (found["stages"]["backend"]["seconds"],
+            found["stages"]["backend"]["count"],
+            found["programs"]["hit"], found["programs"]["miss"])
 
 
 def _host(tree):
@@ -213,11 +196,14 @@ def gpt2_small_job(sizes, n_chips, seed, optimizer) -> Job:
 # -- the run ----------------------------------------------------------------
 
 class Smoke:
-    """One run: its sizes, its seed and its compile log."""
+    """One run: its sizes and its seed. The program's compile log listens
+    from here on, before ``hvd.init()`` would register it: the device phase
+    comes first."""
 
     def __init__(self, sizes, seed, rehearse):
+        from horovod_tpu.metrics import compile_log
         self.sizes, self.seed, self.rehearse = sizes, seed, rehearse
-        self.log = _CompileLog()
+        compile_log.install()
 
     def check_on_chip(self, cond, message):
         """A check only the TPU backend can meet: a kernel's custom call,
@@ -228,10 +214,10 @@ class Smoke:
     def phase(self, name):
         """Time one phase; print its JSON line if it ends without raising."""
         checked = {}
-        before, t0 = self.log.snapshot(), time.perf_counter()
+        before, t0 = _compiled(), time.perf_counter()
         yield checked
         seconds = time.perf_counter() - t0
-        delta = [a - b for a, b in zip(self.log.snapshot(), before)]
+        delta = [a - b for a, b in zip(_compiled(), before)]
         print(json.dumps({
             "phase": name, "seconds": round(seconds, 3),
             "compile_seconds": round(delta[0], 3),
@@ -247,10 +233,10 @@ class Smoke:
         key = jax.random.key(1)
         losses, later = [], 0
         for i in range(1 + self.sizes.steps):
-            before = self.log.programs
+            before = _compiled()[1]
             out = jax.block_until_ready(step(*state, batch, key))
             if i:
-                later += self.log.programs - before
+                later += _compiled()[1] - before
             state = tuple(out[:len(state)])
             losses.append(float(out.loss))
             _check(math.isfinite(losses[-1]),

@@ -17,9 +17,13 @@ Layer map (TPU analog of reference SURVEY §1):
   fused/pallas ops.
 """
 
-from horovod_tpu.version import __version__  # noqa: F401
+import time as _time
 
-from horovod_tpu.common.basics import (  # noqa: F401
+_import_began = (_time.time(), _time.perf_counter())  # hvd.import, below
+
+from horovod_tpu.version import __version__  # noqa: F401,E402
+
+from horovod_tpu.common.basics import (  # noqa: F401,E402
     ccl_built,
     cross_rank,
     cross_size,
@@ -48,11 +52,11 @@ from horovod_tpu.common.basics import (  # noqa: F401
     start_timeline,
     stop_timeline,
 )
-from horovod_tpu.common.exceptions import (  # noqa: F401
+from horovod_tpu.common.exceptions import (  # noqa: F401,E402
     HorovodInternalError,
     HostsUpdatedInterrupt,
 )
-from horovod_tpu.parallel import (  # noqa: F401
+from horovod_tpu.parallel import (  # noqa: F401,E402
     Adasum,
     Average,
     Max,
@@ -68,3 +72,11 @@ from horovod_tpu.parallel import (  # noqa: F401
 
 # Programmatic launcher (reference: horovod.run, runner/__init__.py:206).
 from horovod_tpu.runner import run  # noqa: F401,E402
+
+# The package's import as the span hvd.import of the program's compile log
+# (what this file's imports cost, with whatever of theirs the process had
+# not imported yet; benchmark/run.py imports jax first).
+from horovod_tpu.metrics import compile_log as _compile_log  # noqa: E402
+
+_compile_log.record("hvd.import", _import_began[0],
+                    _time.perf_counter() - _import_began[1])

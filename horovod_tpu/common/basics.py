@@ -53,6 +53,18 @@ class _HorovodTpuContext:
              devices: Optional[Sequence[jax.Device]] = None,
              start_engine: Optional[bool] = None,
              comm: Optional[Sequence[int]] = None):
+        """The compile log (metrics/compile_log.py) listens from here on and
+        holds this call as the span ``hvd.init``, with a child for each part
+        that runs: rendezvous, mesh, engine, exporter."""
+        if self.initialized:
+            return
+        from horovod_tpu.metrics import compile_log
+        compile_log.install()
+        with compile_log.span("hvd.init"):
+            self._init(mesh_spec, devices, start_engine, comm)
+
+    def _init(self, mesh_spec, devices, start_engine, comm):
+        from horovod_tpu.metrics.compile_log import span
         with self._lock:
             if self.initialized:
                 return
@@ -67,7 +79,8 @@ class _HorovodTpuContext:
                 # (READY/go barrier) before reading the env it rewrites —
                 # both on first spawn and on elastic re-init (reference:
                 # gloo_context.cc:154-200 re-init scope query).
-                elastic_worker.rendezvous()
+                with span("hvd.init.rendezvous"):
+                    elastic_worker.rendezvous()
             # Topology: launcher env contract first; failing that, a live
             # jax.distributed job defines the process world — otherwise a
             # multi-host job launched outside hvdrun-tpu would read size=1
@@ -150,7 +163,8 @@ class _HorovodTpuContext:
                     self._has_host_map = False
                 set_rank_context(self.rank, self.local_rank)
             try:
-                self.mesh = mesh_lib.build_mesh(mesh_spec, devices)
+                with span("hvd.init.mesh"):
+                    self.mesh = mesh_lib.build_mesh(mesh_spec, devices)
                 if start_engine is None:
                     # The engine serves the eager multi-process path
                     # (broadcast_object, metric_average, elastic State.sync).
@@ -170,20 +184,21 @@ class _HorovodTpuContext:
                         HorovodInternalError
                     from horovod_tpu.engine import bindings
                     try:
-                        self.engine = bindings.EngineSession(
-                            rank=self.rank, size=self.size,
-                            local_rank=self.local_rank,
-                            local_size=self.local_size,
-                            # Locality map for the topology-aware data
-                            # plane: the launcher's host index, or -1
-                            # (flat) for single-host jobs and jobs whose
-                            # cross dims are synthetic defaults.
-                            host_id=self.cross_rank
-                            if self._has_host_map and self.cross_size > 1
-                            else -1,
-                            port=subset_ports[0] if subset_ports else None,
-                            data_port=subset_ports[1] if subset_ports
-                            else None)
+                        with span("hvd.init.engine"):
+                            self.engine = bindings.EngineSession(
+                                rank=self.rank, size=self.size,
+                                local_rank=self.local_rank,
+                                local_size=self.local_size,
+                                # Locality map for the topology-aware data
+                                # plane: the launcher's host index, or -1
+                                # (flat) for single-host jobs and jobs whose
+                                # cross dims are synthetic defaults.
+                                host_id=self.cross_rank
+                                if self._has_host_map and self.cross_size > 1
+                                else -1,
+                                port=subset_ports[0] if subset_ports else None,
+                                data_port=subset_ports[1] if subset_ports
+                                else None)
                     except (ImportError, OSError, ValueError,
                             HorovodInternalError,
                             subprocess.CalledProcessError) as e:
@@ -203,9 +218,11 @@ class _HorovodTpuContext:
                             f"Cause: {e}") from e
                 # Prometheus endpoint — off by default, one per worker when
                 # HOROVOD_METRICS_PORT is set (metrics/exporter.py).
-                from horovod_tpu.metrics import start_exporter_from_env
-                self.metrics_exporter = start_exporter_from_env(
-                    rank=self.rank, engine=self.engine)
+                if env_is_set("HOROVOD_METRICS_PORT"):
+                    from horovod_tpu.metrics import start_exporter_from_env
+                    with span("hvd.init.exporter"):
+                        self.metrics_exporter = start_exporter_from_env(
+                            rank=self.rank, engine=self.engine)
                 self.initialized = True
             except BaseException:
                 self.mesh = None
@@ -224,6 +241,8 @@ class _HorovodTpuContext:
                 self.engine = None
             self.mesh = None
             self.initialized = False
+            from horovod_tpu.metrics import compile_log
+            compile_log.uninstall()
 
 
 _ctx = _HorovodTpuContext()

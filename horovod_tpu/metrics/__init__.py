@@ -38,6 +38,7 @@ from horovod_tpu.metrics.registry import (  # noqa: F401
     engine_collector,
     get_registry,
 )
+from horovod_tpu.metrics import compile_log
 from horovod_tpu.metrics.straggler import StragglerDetector  # noqa: F401
 from horovod_tpu.profiler.annotate import (
     STEP_DISPATCH_SPAN,
@@ -74,17 +75,34 @@ class _TimedStep:
     While a ``jax.profiler`` trace is being collected each invocation also
     writes an ``hvd.step`` span (``step_num`` = this wrapper's call count)
     holding an ``hvd.step.dispatch`` span around the wrapped call; with no
-    trace running both are an object and a flag test."""
+    trace running both are an object and a flag test.
+
+    The compile log (:mod:`horovod_tpu.metrics.compile_log`) learns from
+    here which functions are steps (``framework="jax"`` is
+    ``dp._jit_step``'s alone) and which call is open on this thread, so a
+    program compiled inside a later call is a recompile that names its
+    step."""
 
     def __init__(self, fn, framework: str):
         self._fn = fn
+        self.framework = framework
         self._hist = get_registry().histogram(STEP_SECONDS,
                                               framework=framework)
         self._steps = get_registry().counter(STEPS_TOTAL,
                                              framework=framework)
+        # there from the start, so that 0 recompiles reads 0 and not absent
+        get_registry().counter(compile_log.RECOMPILES_TOTAL,
+                               framework=framework)
+        if framework == "jax":
+            compile_log.step_function(getattr(fn, "__name__", None))
         self._attr = None
         self._attr_resolved = False
         self._calls = 0  # step_num of the next hvd.step span
+
+    @property
+    def step_num(self) -> int:
+        """Number of the call that is open (the last one, between calls)."""
+        return self._calls - 1
 
     def __call__(self, *args, **kwargs):
         if not self._attr_resolved:
@@ -102,8 +120,12 @@ class _TimedStep:
             if attr is not None:
                 attr.step_begin(sid)
             t0 = time.perf_counter()
-            with host_annotation(STEP_DISPATCH_SPAN):
-                out = self._fn(*args, **kwargs)
+            compile_log.OPEN.step = self
+            try:
+                with host_annotation(STEP_DISPATCH_SPAN):
+                    out = self._fn(*args, **kwargs)
+            finally:
+                compile_log.OPEN.step = None
             dt = time.perf_counter() - t0
             self._hist.observe(dt)
             self._steps.inc()
@@ -234,32 +256,3 @@ def step_stats(snapshot: dict) -> Optional[tuple]:
             total_sum += float(s.get("sum", 0.0))
     return (total_count, total_sum) if total_count else None
 
-
-def bench_snapshot() -> dict:
-    """Compact engine + frontend telemetry as one dict: cache hit rate and
-    fusion efficiency beside the step counts. (It was the old whole-repo
-    benchmark's ``engine_metrics`` field; nothing calls it today.)"""
-    out: dict = {"engine": None}
-    reg_snap = get_registry().snapshot()
-    st = step_stats(reg_snap)
-    if st:
-        out["frontend_steps"] = st[0]
-        out["frontend_step_seconds_mean"] = round(st[1] / st[0], 6)
-    try:
-        from horovod_tpu.common import basics
-        engine = basics._context().engine
-    except Exception:  # noqa: BLE001
-        engine = None
-    if engine is not None:
-        snap = engine.metrics()
-        c = snap.get("counters", {})
-        hits, misses = c.get("cache_hits", 0), c.get("cache_misses", 0)
-        resp, tensors = c.get("responses", 0), c.get("fused_tensors", 0)
-        out["engine"] = {
-            "counters": c,
-            "cache_hit_rate": round(hits / (hits + misses), 4)
-            if hits + misses else None,
-            "fusion_mean_tensors_per_response": round(tensors / resp, 3)
-            if resp else None,
-        }
-    return out

@@ -20,7 +20,6 @@ from __future__ import annotations
 from horovod_tpu.obs.attribution import (  # noqa: F401
     StepAttributor,
     attribute,
-    bench_block,
     decompose_rank,
     get_attributor,
     step_windows,

@@ -36,9 +36,9 @@ analyzer's shared CYCLE anchors, and the rank whose window ends last on
 the aligned axis is the step's **critical-path rank** — its last-completing
 collective is the gating tensor.
 
-The ``step_attribution`` record this module emits (:func:`attribute`,
-:func:`bench_block`) is the input contract for the ROADMAP autotuner PR:
-stable keys, seconds, fractions of step time.
+The ``step_attribution`` record this module emits (:func:`attribute`) is
+the input contract for the ROADMAP autotuner PR: stable keys, seconds,
+fractions of step time.
 """
 
 from __future__ import annotations
@@ -529,92 +529,3 @@ def get_attributor() -> Optional[StepAttributor]:
             _attributor = StepAttributor()
         return _attributor
 
-
-# ---------------------------------------------------------------------------
-# the per-model record
-
-
-def bench_block(step_seconds_by_model: Dict[str, float]) -> dict:
-    """The ``step_attribution`` record: per-model decomposition plus a
-    measured attribution-overhead figure (read by
-    ``tests/test_attribution.py``; the chip's step phases are the
-    benchmark's, ``PERF.md`` section 3).
-
-    ``step_seconds_by_model`` maps model name → measured per-step wall
-    seconds. With a live engine session the per-model buckets come from
-    the flight ring's summary fractions; a single-process run (no
-    engine — XLA owns the overlap inside the jitted step) decomposes as
-    100% compute with the source field saying so. Overhead: the
-    attributor's per-step observe cost (anomaly window + gauge update),
-    measured directly, as a percentage of each model's step — the <1%
-    acceptance budget."""
-    from horovod_tpu.metrics.registry import MetricsRegistry
-    probe = StepAttributor(registry=MetricsRegistry(), use_engine=False,
-                           flight_dir="")
-    iters = 5000
-    t0 = time.perf_counter()
-    for _ in range(iters):
-        probe.observe(0.1)
-    per_observe_s = (time.perf_counter() - t0) / iters
-
-    from horovod_tpu.common import basics
-    engine = basics._context().engine
-    record = None
-    refresh_s = None
-    if engine is not None:
-        t0 = time.perf_counter()
-        dump = engine.flight_dump()
-        if dump:
-            record = attribute({int(dump.get("rank", 0)): dump})
-        # one full dump + decomposition — the background refresh's cost
-        # (paid off the training thread, HOROVOD_ATTRIBUTION_EVERY apart)
-        refresh_s = time.perf_counter() - t0
-    summary = record["summary"] if record else None
-    live = bool(summary and summary["steps"])
-    source = ("flight-ring decomposition (this rank's engine; cross-rank "
-              "critical path needs every rank's dump — see "
-              "horovod_tpu.obs.attribute)" if live else
-              "frontend-only: no engine session in this process, in-jit "
-              "collectives are overlapped by XLA and invisible to the "
-              "engine, so the step decomposes as compute")
-
-    per_model = {}
-    for model, step_s in step_seconds_by_model.items():
-        if not step_s or step_s <= 0:
-            continue
-        if live:
-            entry = {
-                "step_seconds": round(step_s, 6),
-                "compute_s": round(step_s * summary["compute_frac"], 6),
-                "exposed_comm_s": round(
-                    step_s * summary["exposed_comm_frac"], 6),
-                "stall_s": round(step_s * summary["stall_frac"], 6),
-                "host_s": round(step_s * summary["host_frac"], 6),
-                "critical_rank": max(
-                    summary["critical_rank_counts"],
-                    key=summary["critical_rank_counts"].get),
-            }
-        else:
-            entry = {"step_seconds": round(step_s, 6),
-                     "compute_s": round(step_s, 6),
-                     "exposed_comm_s": 0.0, "stall_s": 0.0, "host_s": 0.0,
-                     "critical_rank": 0}
-        entry["attribution_overhead_pct_of_step"] = round(
-            100.0 * per_observe_s / step_s, 5)
-        per_model[model] = entry
-
-    return {
-        "source": source,
-        "per_model": per_model,
-        "summary": summary,
-        "attribution_overhead": {
-            "seconds_per_step_observe": round(per_observe_s, 9),
-            "seconds_per_ring_refresh": (round(refresh_s, 6)
-                                         if refresh_s is not None else None),
-            "refresh_note": "ring refresh runs on a background thread "
-                            "every HOROVOD_ATTRIBUTION_EVERY steps; the "
-                            "training thread pays only the per-step "
-                            "observe cost",
-            "budget_pct": 1.0,
-        },
-    }

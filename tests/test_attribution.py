@@ -20,7 +20,6 @@ from horovod_tpu.obs import attribution as attr_mod
 from horovod_tpu.obs.attribution import (
     StepAttributor,
     attribute,
-    bench_block,
     decompose_rank,
     step_windows,
 )
@@ -373,23 +372,3 @@ def test_frontend_step_timer_feeds_attributor(monkeypatch):
     assert calls == [0, 1, 2]
     assert eng.begins == eng.ends == [1, 2, 3]
     assert len(a._window) == 3
-
-
-# ---------------------------------------------------------------------------
-# BENCH block
-
-
-def test_bench_block_without_engine_is_pure_compute():
-    b = bench_block({"resnet50": 0.25})
-    entry = b["per_model"]["resnet50"]
-    assert entry["compute_s"] == pytest.approx(0.25)
-    assert entry["exposed_comm_s"] == 0.0
-    assert entry["attribution_overhead_pct_of_step"] < 1.0, \
-        "attribution must cost <1% of step time (acceptance budget)"
-    assert b["attribution_overhead"]["seconds_per_step_observe"] < 1e-4
-    assert "frontend-only" in b["source"]
-
-
-def test_bench_block_skips_nonpositive_step_times():
-    b = bench_block({"bad": 0.0, "ok": 0.5})
-    assert set(b["per_model"]) == {"ok"}

@@ -11,6 +11,7 @@ generation.
 """
 
 import os
+import re
 import subprocess
 import sys
 import time
@@ -1069,11 +1070,16 @@ def test_elastic_blacklist_cooldown_rejoin_subprocess(tmp_path):
     assert done, text
     # the job finished in a generation AFTER the one that was running when
     # the host was blacklisted — i.e. the host re-joined post-cooldown
-    final_gens = [int(line.split("gen=")[1].split()[0]) for line in done]
+    # (the driver writes to the same pipe: its "[elastic-driver]" can follow
+    # a worker's number without a space, so read the digits alone)
+    def number(line, key):
+        return int(re.search(key + r"=(\d+)", line).group(1))
+
+    final_gens = [number(line, "gen") for line in done]
     assert all(g >= 1 for g in final_gens), text
     # committed state survived: nobody restarted from step 0 post-rejoin
-    post = [int(line.split("step=")[1].split()[0])
+    post = [number(line, "step")
             for line in text.splitlines()
             if "progress" in line and "gen=" in line and
-            int(line.split("gen=")[1].split()[0]) >= 1]
+            number(line, "gen") >= 1]
     assert post and min(post) > 0, text
