@@ -1,0 +1,236 @@
+"""A grouped matmul whose groups start on row blocks, paced by its rows.
+
+``jax.lax.ragged_dot`` multiplies runs of rows by one matrix a run, the runs
+wherever their sizes put them. The TPU compiler's kernel for it is paced by
+the (group, 512-row tile) pairs it visits and not by the rows (``PERF.md``
+§6, PRs 31, 34 and 36: 3.1-3.3 ms for a 1.4 ms product over 64 groups of
+about 1024 rows of 2048 x 1024, 2.0 ms for 0.2 ms over eight groups of
+280-500 rows of 2688 x 1856). :func:`grouped_matmul` takes the other
+contract: the rows come in equal *blocks* and **every block belongs to one
+group**, named by a table,
+
+    out[rows of block b] = a[rows of block b] @ w[group_of_block[b]]
+
+so the product is a tiled matmul whose weight block is chosen a row block.
+The table and the count of *live* blocks are scalar-prefetch operands: the
+index maps read them, so consecutive blocks of one group keep its matrix in
+VMEM (a block index that does not change is not fetched again), and a block
+past the live count is not computed, fetched or written: the rows of such a
+block hold whatever the buffer held, as ``ragged_dot`` leaves rows in no
+group, and a caller reads the rows of live blocks only. Who lays rows out
+like that: ``parallel/ep.moe_dropless`` below its walk (a full load), which
+starts each expert's pairs on a row block of 128.
+
+Two kernels under one ``jax.custom_vjp``:
+
+- ``_gmm_kernel``, grid (column tiles of the result, row blocks): one
+  ``[block_rows, k] x [k, n]`` product a step, float32 accumulation on the
+  MXU, the contraction held whole, the result's columns whole too where the
+  group's matrix fits a few MiB of VMEM twice (2048 x 1024 in bf16 does),
+  else in tiles of 128s. ``transposed=True`` multiplies by ``w[g]^T`` (the
+  matrices as ``[groups, n, k]``): the product towards the rows is this
+  kernel over the same matrices, read the other way.
+- ``_gmm_dw_kernel``, the transpose towards the matrices, ``dw[g] =
+  a_g^T @ dout_g``: grid (row tiles of ``dw[g]``, row blocks), the row
+  blocks sequential; a float32 VMEM tile is started at a group's first
+  block and written out, in the matrices' dtype, at its last. The blocks of
+  a group must be consecutive. A group with no live block is never visited;
+  the wrapper gives it zeros. (Two and four blocks a step, a run of one
+  group's blocks inside a window with the window's other rows selected out,
+  were built and measured on the chip in PR 36: the same 2.85-2.93 ms a
+  call as one block a step, with forty more lines.)
+
+Operands in the dtype they come in (bf16 in the training step), products
+accumulated in float32, results in ``a``'s dtype and ``dw`` in ``w``'s: the
+arithmetic of ``ragged_dot`` and its transposes. ``lax.platform_dependent``
+lowers the kernels for the TPU and runs the same kernels in interpret mode
+elsewhere (``ops/ssd.py``'s arrangement: nothing chooses between paths and
+a compile for a described chip holds the kernels), and the calls are under
+``jax.jit`` so a kernel is traced once a process and variant.
+
+Shapes: ``a`` [rows, k] with ``rows`` a multiple of the number of blocks;
+``w`` [groups, k, n] (``transposed``: [groups, n, k]); ``group_of_block``
+int32 [blocks], every entry a group's index, live or not; ``live`` int32
+[1], the blocks from the first on that are computed.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from horovod_tpu.ops.flash_attention import _NN, _NT, _TN, _dot
+from horovod_tpu.ops.ssd import _on_this_platform
+
+_MATRIX_BYTES = 12 << 20  # a group's matrix block in VMEM, held twice
+_ACCUMULATOR_BYTES = 8 << 20  # the float32 tile of dw[g] in VMEM
+
+
+def _tile_of(width: int, tiles: int) -> int:
+    """``width`` cut into ``tiles`` pieces of whole 128s (the last may run
+    short), or whole."""
+    if tiles <= 1:
+        return width
+    return min(width, -(-width // (128 * tiles)) * 128)
+
+
+def _compiler_params(*block_bytes: int) -> pltpu.CompilerParams:
+    """The row blocks in order (``dw``'s accumulator lives across them),
+    and room for the blocks named (the caller counts those of the pipeline
+    twice) beside the compiler's own temporaries."""
+    need = int(1.25 * sum(block_bytes)) + (8 << 20)
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"),
+        vmem_limit_bytes=min(max(need, 32 << 20), 100 << 20))
+
+
+def _last_live(block, live_ref):
+    """A block past the live count maps where the last live one did, so the
+    pipeline fetches and writes nothing for it."""
+    return jnp.minimum(block, jnp.maximum(live_ref[0] - 1, 0))
+
+
+def _gmm_kernel(group_ref, live_ref, a_ref, w_ref, out_ref, *, transposed):
+    @pl.when(pl.program_id(1) < live_ref[0])
+    def _():
+        out_ref[...] = _dot(a_ref[...], w_ref[0],
+                            _NT if transposed else _NN).astype(out_ref.dtype)
+
+
+def _gmm_dw_kernel(group_ref, live_ref, a_ref, d_ref, out_ref, acc_ref):
+    b, blocks = pl.program_id(1), pl.num_programs(1)
+    live = live_ref[0]
+
+    @pl.when(b < live)
+    def _():
+        group = group_ref[b]
+        first = (b == 0) | (group_ref[jnp.maximum(b - 1, 0)] != group)
+        last = (b == live - 1) | (
+            group_ref[jnp.minimum(b + 1, blocks - 1)] != group)
+        # a group's first block starts the sum: no pass that zeroes the tile
+        acc_ref[...] = jnp.where(first, 0.0, acc_ref[...]) \
+            + _dot(a_ref[...], d_ref[...], _TN)
+
+        @pl.when(last)
+        def _():
+            out_ref[0] = acc_ref[...].astype(out_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "block_rows", "transposed", "interpret"))
+def _gmm_call(group_of_block, live, a, w, *, block_rows, transposed,
+              interpret):
+    rows, k = a.shape
+    n = w.shape[1] if transposed else w.shape[2]
+    tn = _tile_of(n, -(-k * n * w.dtype.itemsize // _MATRIX_BYTES))
+
+    def a_block(j, b, group, live):
+        return _last_live(b, live), 0
+
+    def w_block(j, b, group, live):
+        g = group[_last_live(b, live)]
+        return (g, j, 0) if transposed else (g, 0, j)
+
+    def out_block(j, b, group, live):
+        return _last_live(b, live), j
+    return pl.pallas_call(
+        functools.partial(_gmm_kernel, transposed=transposed),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(pl.cdiv(n, tn), rows // block_rows),
+            in_specs=[
+                pl.BlockSpec((block_rows, k), a_block),
+                pl.BlockSpec((1, tn, k) if transposed else (1, k, tn),
+                             w_block)],
+            out_specs=pl.BlockSpec((block_rows, tn), out_block)),
+        out_shape=jax.ShapeDtypeStruct((rows, n), a.dtype),
+        compiler_params=_compiler_params(
+            2 * k * tn * w.dtype.itemsize,
+            2 * block_rows * k * a.dtype.itemsize,
+            2 * block_rows * tn * a.dtype.itemsize, 2 * block_rows * tn * 4),
+        interpret=interpret,
+    )(group_of_block, live, a, w)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "groups", "dtype", "block_rows", "interpret"))
+def _gmm_dw_call(group_of_block, live, a, d, *, groups, dtype, block_rows,
+                 interpret):
+    """``dw[g] = a_g^T @ d_g`` [groups, k, n]; the matrix of a group with no
+    live block is not written."""
+    rows, k = a.shape
+    n = d.shape[1]
+    tk = _tile_of(k, -(-k * n * 4 // _ACCUMULATOR_BYTES))
+
+    def out_block(j, b, group, live):
+        return group[_last_live(b, live)], j, 0
+    return pl.pallas_call(
+        _gmm_dw_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(pl.cdiv(k, tk), rows // block_rows),
+            in_specs=[
+                pl.BlockSpec((block_rows, tk), lambda j, b, group, live: (
+                    _last_live(b, live), j)),
+                pl.BlockSpec((block_rows, n), lambda j, b, group, live: (
+                    _last_live(b, live), 0))],
+            out_specs=pl.BlockSpec((1, tk, n), out_block),
+            scratch_shapes=[pltpu.VMEM((tk, n), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((groups, k, n), dtype),
+        compiler_params=_compiler_params(
+            # the tile, a step's product and their sum, in float32
+            3 * tk * n * 4, 2 * tk * n * jnp.dtype(dtype).itemsize,
+            2 * block_rows * (tk + n) * a.dtype.itemsize),
+        interpret=interpret,
+    )(group_of_block, live, a, d)
+
+
+def _towards_the_matrices(a, d, group_of_block, live, groups, dtype):
+    """``dw[g] = a_g^T @ d_g`` over the live blocks, zeros for a group that
+    has none (a selection XLA fuses into whatever reads the gradient)."""
+    blocks = group_of_block.shape[0]
+    dw = _on_this_platform(
+        functools.partial(_gmm_dw_call, groups=groups, dtype=dtype,
+                          block_rows=a.shape[0] // blocks),
+        group_of_block, live, a, d)
+    visited = jnp.any(
+        (group_of_block == jnp.arange(groups)[:, None])
+        & (jnp.arange(blocks) < live[0]), axis=1)
+    return jnp.where(visited[:, None, None], dw, jnp.zeros((), dw.dtype))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def grouped_matmul(a, w, group_of_block, live, transposed=False):
+    """``out[rows of block b] = a[rows of block b] @ w[group_of_block[b]]``
+    (``transposed``: ``@ w[...]^T``) for the first ``live[0]`` of the
+    ``len(group_of_block)`` equal row blocks of ``a``; the rows of the
+    others are not written (module text). Differentiable in ``a`` (the
+    gradient's rows past the live blocks are not written either) and in
+    ``w``."""
+    return _on_this_platform(
+        functools.partial(_gmm_call, transposed=transposed,
+                          block_rows=a.shape[0] // group_of_block.shape[0]),
+        group_of_block, live, a, w)
+
+
+def _grouped_matmul_fwd(a, w, group_of_block, live, transposed):
+    return grouped_matmul(a, w, group_of_block, live, transposed), (
+        a, w, group_of_block, live)
+
+
+def _grouped_matmul_bwd(transposed, saved, d_out):
+    a, w, group_of_block, live = saved
+    d_out = d_out.astype(a.dtype)
+    d_a = grouped_matmul(d_out, w, group_of_block, live, not transposed)
+    left, right = (d_out, a) if transposed else (a, d_out)
+    d_w = _towards_the_matrices(left, right, group_of_block, live,
+                                w.shape[0], w.dtype)
+    return d_a, d_w, None, None
+
+
+grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)

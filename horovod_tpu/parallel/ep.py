@@ -31,18 +31,54 @@ operation was:
 - ``moe_dispatch``: the ``k T`` (token, slot) pairs are sorted by expert
   (a stable argsort), and the tokens' rows are gathered into that order;
 - ``moe_experts``: one grouped matmul per projection over the sorted rows
-  (a full load: ``jax.lax.ragged_dot``, which the TPU compiler lowers to
-  its own Mosaic kernel; a share's walk: a batched ``dot_general`` over
-  slots, an expert a batch entry);
+  (a full load: :func:`~horovod_tpu.ops.grouped_matmul.grouped_matmul`, the
+  repo's own Pallas kernels over row blocks of 128; a share's walk: a
+  batched ``dot_general`` over slots, an expert a batch entry);
 - ``moe_combine``: the rows are gathered back into token order and summed
   with their router weights, in float32.
 
-On a full load (``held=None``: every expert here) both permutations are
-bijections of the ``k T`` rows and their backward passes are the inverse
-gathers: nothing on that path is a scatter-add. The layer returns
+**A full load** (``held=None``: every expert here). The sorted pairs are
+laid out so that **each expert's pairs start on a multiple of
+``SHARE_BLOCK_ROWS`` = 128 rows** (:func:`_blocks_of`): a pair's row is its
+sorted position plus the padding of the experts before its own, arithmetic
+on the per-expert counts (a cumulative sum of the counts rounded up to
+128s) and no second sort. The rows are a static ``k T`` in whole blocks
+plus a block an expert (:func:`grouped_blocks_built`: 65 536 + 64 x 128 =
+73 728 rows, 576 blocks, for top-8 of 64 experts over 8192 tokens), enough
+for any routing, one expert sent every token included; the blocks that hold
+a pair are a prefix of them (about 544 under a balanced router: an expert's
+last block is half slack on average), and their count and the
+block-to-expert table are data, handed to the kernels as scalar-prefetch
+operands. Every row block is thus one expert's and the product over the
+blocks is a tiled matmul whose matrix is chosen a block: its time follows
+the rows (the TPU compiler's kernel for ``jax.lax``'s ragged dot is paced by
+the (group, 512-row tile) pairs it visits and ran these products at 42-45%
+of the chip's peak;
+``PERF.md`` §6, PRs 31, 34 and 36). Rows move by gathers alone: dispatch
+gathers ``x[token of a row]`` (a row of padding gathers some token in
+bounds and is computed like any other in a live block; a block past the
+live ones is neither fetched, computed nor written), combine gathers the
+pairs' rows from where they lie, and both backward passes are the inverse
+gathers, with a select that gives the rows that hold no pair a zero
+gradient, so nothing they hold reaches a weight's gradient (zero times a
+finite row). Nothing on that path is a scatter-add. The layer returns
 :class:`MoeStats`: the per-expert pair counts over all ``E`` (they sum to
 ``k T``: no capacity, no drop, under any imbalance) and what auxiliary losses
-need (:func:`load_balancing_loss`, the router z-loss).
+need (:func:`load_balancing_loss`, the router z-loss). At trace time
+``hvd_moe_grouped_blocks_total{kind="built"}`` counts the blocks a layer
+call is built with; how many were live and what the padding cost
+(``hvd_moe_grouped_rows_total{kind="held"|"computed"}``) is data, read back
+outside the step: :func:`grouped_blocks` over the load.
+
+**Why a full load takes the kernel and a share keeps slots.** A share's
+tile is slack by design (8 slots of 640 rows for 384 pairs an expert: XLA's
+batched product runs them at 79-95% of peak, sums the weights' gradient in
+its own epilogue and needs no table; the kernel was within 1.8% there and
+400 lines longer: ``PERF.md`` §6, PR 34). A full load has no slack to give
+slots: 64 experts x 1.5 headroom is +50% rows through every gather and
+every product, where starting each expert on a block of 128 costs 64 half
+blocks, +6% (``PERF.md`` §6, PR 36). Which side a call is on is the static
+shape ``share_tile_rows(k T, count, E) < k T``, nothing else.
 
 **A share** (``held = (first, count)``): the layer holds ``count`` of the
 router's ``E`` experts, ``first .. first + count - 1``, as one chip of an
@@ -64,8 +100,8 @@ the held counts alone, :func:`_share_tiles_of`). Every row block of a tile
 is thus one expert's, at a place known when the program is built, and the
 grouped matmul over a tile is XLA's own batched product ``[count, S, k] x
 [count, k, n]``, an expert a batch entry, whose time follows its rows
-(``lax.ragged_dot``'s TPU kernel is paced by the (group, 512-row tile)
-pairs it visits: 2.0 ms a call for 0.2 ms of arithmetic at Nemotron-H's
+(the compiler's ragged dot is paced by the (group, 512-row tile) pairs it
+visits: 2.0 ms a call for 0.2 ms of arithmetic at Nemotron-H's
 widths, where the batched product takes 0.3; ``PERF.md`` §6, PRs 31 and
 34). ``S`` is :func:`share_slot_rows`: 1.5 x the ``k T / E`` pairs a
 balanced router sends one expert, in whole row blocks of 128, and the tile
@@ -104,12 +140,9 @@ tiles were live, how many rows the held experts were sent and how many the
 live tiles computed for them (``hvd_moe_share_rows_total``) is data, read
 back outside the step: :func:`share_tiles` over the load a model's state
 carries. A share whose rule gives a tile of all ``k T`` pairs (its slots
-reach them all) is the one-tile program below the walk: ``ragged_dot``
-over the rows where the sort leaves them. There the rows past the last
-held pair belong to no group, and ``ragged_dot`` promises nothing about
-such rows (the TPU's kernel spends no time on them and leaves them
-unwritten: whatever the buffer held, NaNs in a training step), so they are
-zeroed by row index on the way in and on the way out.
+reach them all; tests only) is the program below the walk too: the held
+experts' pairs in row blocks, nothing laid out for a pair of an expert held
+elsewhere, whose row in (token, slot) order is zero by a select.
 
 **:func:`moe_layer` — the exchange over the ``expert`` axis** (unit tests and
 the CPU dry run only; on no measured path). GShard-style top-1 routing into
@@ -135,6 +168,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from horovod_tpu.ops.grouped_matmul import grouped_matmul
 from horovod_tpu.parallel import collectives
 from horovod_tpu.profiler.annotate import moe_scope
 
@@ -192,46 +226,6 @@ def _chosen_mask(experts: jax.Array, n_experts: int) -> jax.Array:
     return experts[:, :, None] == jnp.arange(n_experts, dtype=jnp.int32)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _gather_sorted(x, order, inverse, k):
-    """Row i of the result is token ``order[i] // k``: the (token, slot)
-    pairs in expert order. ``inverse`` undoes ``order``."""
-    return jnp.take(x, lax.div(order, k), axis=0)
-
-
-def _gather_sorted_fwd(x, order, inverse, k):
-    return _gather_sorted(x, order, inverse, k), (order, inverse)
-
-
-def _gather_sorted_bwd(k, saved, g):
-    # back in (token, slot) order a token's k rows lie side by side
-    order, inverse = saved
-    g = jnp.take(g, inverse, axis=0)
-    return g.reshape(-1, k, g.shape[-1]).sum(axis=1), None, None
-
-
-_gather_sorted.defvjp(_gather_sorted_fwd, _gather_sorted_bwd)
-
-
-@jax.custom_vjp
-def _permute(x, perm, inverse):
-    """``x[perm]`` for a bijection ``perm``, whose backward pass is the
-    gather by ``inverse`` (autodiff would write a scatter-add)."""
-    return jnp.take(x, perm, axis=0)
-
-
-def _permute_fwd(x, perm, inverse):
-    return _permute(x, perm, inverse), (perm, inverse)
-
-
-def _permute_bwd(saved, g):
-    perm, inverse = saved
-    return jnp.take(g, inverse, axis=0), None, None
-
-
-_permute.defvjp(_permute_fwd, _permute_bwd)
-
-
 def swiglu_expert(dot, rows, w_gate, w_up, w_down):
     """``w_down(silu(w_gate x) * w_up x)``: OLMoE's gated expert."""
     return dot(jax.nn.silu(dot(rows, w_gate)) * dot(rows, w_up), w_down)
@@ -248,17 +242,6 @@ def _held_first(experts: jax.Array, first: int, count: int) -> jax.Array:
     ``count`` for every pair of an expert that lives elsewhere."""
     local = experts - first
     return jnp.where((local >= 0) & (local < count), local, count)
-
-
-def _group_dot(sizes: jax.Array, in_a_group: jax.Array) -> Callable:
-    """The grouped matmul over rows of which only those ``in_a_group``
-    ([rows, 1] bool) belong to one of the ``sizes`` groups: the others are
-    zeroed on the way in and on the way out."""
-    def dot(a, w):
-        a = jnp.where(in_a_group, a, jnp.zeros((), a.dtype))
-        out = lax.ragged_dot(a, w, sizes)
-        return jnp.where(in_a_group, out, jnp.zeros((), out.dtype))
-    return dot
 
 
 # -- a share's walk over its sorted pairs -------------------------------------
@@ -321,8 +304,9 @@ def share_tiles(load, held: Tuple[int, int], k: int, tokens: int,
         slot = share_slot_rows(k * tokens, len(load))
         live, built = -(-max(held_rows) // slot), -(-tokens // slot)
         computed = live * rows
-    else:  # the one-tile program below the walk works the held pairs alone
-        live, built, computed = -(-sum(held_rows) // rows), 1, sum(held_rows)
+    else:  # the one-tile program below the walk: whole row blocks
+        live, built = -(-sum(held_rows) // rows), 1
+        computed = SHARE_BLOCK_ROWS * grouped_blocks(load, k, tokens, held)[0]
     if record:
         from horovod_tpu.metrics.registry import get_registry
         _share_tiles_counter("live").inc(live)
@@ -451,6 +435,150 @@ def _walk_bwd(expert, tile, saved, g):
 _walk.defvjp(_walk_fwd, _walk_bwd)
 
 
+# -- below the walk: every expert's pairs from a row block on -------------------
+
+def grouped_blocks_built(k_t: int, count: int) -> int:
+    """Row blocks that hold all ``k T`` pairs with each of ``count`` experts'
+    pairs started on a block, whatever the routing: the pairs in whole
+    blocks and a block of padding an expert."""
+    return -(-k_t // SHARE_BLOCK_ROWS) + count
+
+
+_GROUPED_COUNTERS = {
+    "blocks": ("hvd_moe_grouped_blocks_total",
+               "row blocks of the grouped matmuls below the walk: built into "
+               "a layer call (at trace time), live in a step (read back from "
+               "its load)"),
+    "rows": ("hvd_moe_grouped_rows_total",
+             "rows of the grouped matmuls below the walk in the steps read "
+             "back: the held experts' pairs, and the rows of the live blocks "
+             "that the kernels computed for them"),
+}
+
+
+def _grouped_counter(name: str, kind: str):
+    from horovod_tpu.metrics.registry import get_registry
+    return get_registry().counter(*_GROUPED_COUNTERS[name], kind=kind)
+
+
+def grouped_blocks(load, k: int, tokens: int,
+                   held: Optional[Tuple[int, int]] = None,
+                   record: bool = False) -> Tuple[int, int]:
+    """(live, built): of the ``built`` row blocks the program below the walk
+    is compiled with, the ``live`` ones a step that routed this ``load``
+    computed: each held expert's pairs in whole blocks. Host side, outside
+    the step, as :func:`share_tiles`: ``load`` is one expert layer's pairs
+    per expert over all E of a top-``k`` router over ``tokens`` tokens,
+    ``held`` the share (every expert without it). ``record`` adds ``live``
+    to ``hvd_moe_grouped_blocks_total{kind="live"}``, and to
+    ``hvd_moe_grouped_rows_total`` the held experts' pairs
+    (``kind="held"``) and the rows of the live blocks (``kind="computed"``):
+    computed / held is what starting every expert on a block costs."""
+    first, count = held if held is not None else (0, len(load))
+    held_rows = [int(n) for n in load[first:first + count]]
+    live = sum(-(-n // SHARE_BLOCK_ROWS) for n in held_rows)
+    if record:
+        _grouped_counter("blocks", "live").inc(live)
+        _grouped_counter("rows", "held").inc(sum(held_rows))
+        _grouped_counter("rows", "computed").inc(live * SHARE_BLOCK_ROWS)
+    return live, grouped_blocks_built(k * tokens, count)
+
+
+class _Blocks(NamedTuple):
+    """Where the pairs lie when each held expert's start on a row block."""
+    group_of_block: jax.Array  # int32 [blocks]: the held expert of a block
+    live: jax.Array            # int32 [1]: the blocks that hold a pair
+    pair_of_row: jax.Array     # int32 [rows]: the (token, slot) pair of a
+    #                            row; of a row of padding, some pair
+    real: jax.Array            # bool [rows]: the row holds a pair
+    row_of_pair: jax.Array     # int32 [k T]: the row of a (token, slot) pair
+    held: Optional[jax.Array]  # bool [k T]: the pair's expert is held here;
+    #                            None where every expert is
+
+
+def _blocks_of(sizes, keys, order, inverse, is_share: bool) -> _Blocks:
+    """The sorted pairs with each held expert's pairs started on a multiple
+    of ``SHARE_BLOCK_ROWS`` rows: a pair's row is its sorted position plus
+    the padding of the experts before its own, arithmetic on the held
+    counts ``sizes`` and no second sort. ``keys`` [k T] are the pairs' sort
+    keys (a held expert's index among the held, ``len(sizes)`` for an
+    expert held elsewhere), ``order`` their stable argsort, ``inverse``
+    its inverse. The blocks that hold a pair are a prefix of
+    :func:`grouped_blocks_built`'s, a static number."""
+    count, block, pairs = sizes.shape[0], SHARE_BLOCK_ROWS, order.shape[0]
+    built = grouped_blocks_built(pairs, count)
+    blocks = lax.div(sizes + (block - 1), block)
+    block_ends, pair_ends = jnp.cumsum(blocks), jnp.cumsum(sizes)
+    # rows of padding before an expert's first pair
+    padding = (block_ends - blocks) * block - (pair_ends - sizes)
+    group_of_block = jnp.minimum(count - 1, jnp.sum(
+        jnp.arange(built)[:, None] >= block_ends, axis=1, dtype=jnp.int32))
+
+    def by_row(of_expert):
+        """[count] -> [rows]: every row of a block has its expert's value."""
+        return jnp.broadcast_to(_rows_of(of_expert, group_of_block)[:, None],
+                                (built, block)).reshape(-1)
+    # a row past its expert's last pair, or of a block past the live ones
+    # (whose table entry is the last expert), lies at or past that
+    # expert's last sorted position
+    position = jnp.arange(built * block) - by_row(padding)
+    return _Blocks(
+        group_of_block, block_ends[-1:], real=position < by_row(pair_ends),
+        pair_of_row=_rows_of(order, jnp.minimum(position, pairs - 1)),
+        row_of_pair=inverse + jnp.sum(jnp.where(
+            keys[:, None] == jnp.arange(count), padding, 0), axis=1),
+        held=keys < count if is_share else None)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _rows_to_blocks(x, blocks: _Blocks, k):
+    """Row r of the result is the token of pair ``blocks.pair_of_row[r]``
+    (a row of padding: some token in bounds). The backward pass is the
+    gather of the pairs' rows, a token's k side by side, summed."""
+    return _rows_of(x, lax.div(blocks.pair_of_row, k))
+
+
+def _rows_to_blocks_fwd(x, blocks, k):
+    return _rows_to_blocks(x, blocks, k), blocks
+
+
+def _pairs_of(rows, blocks: _Blocks):
+    """The rows of the (token, slot) pairs, zeros for a pair whose expert
+    is held elsewhere."""
+    rows = _rows_of(rows, blocks.row_of_pair)
+    if blocks.held is None:
+        return rows
+    return jnp.where(blocks.held[:, None], rows, jnp.zeros((), rows.dtype))
+
+
+def _rows_to_blocks_bwd(k, blocks, g):
+    g = _pairs_of(g, blocks)
+    return g.reshape(-1, k, g.shape[-1]).sum(axis=1), None
+
+
+_rows_to_blocks.defvjp(_rows_to_blocks_fwd, _rows_to_blocks_bwd)
+
+
+@jax.custom_vjp
+def _rows_from_blocks(rows, blocks: _Blocks):
+    """The rows in (token, slot) order. The backward pass is the inverse
+    gather, with zeros for the rows that hold no pair: nothing reaches a
+    weight's gradient through a row of padding."""
+    return _pairs_of(rows, blocks)
+
+
+def _rows_from_blocks_fwd(rows, blocks):
+    return _rows_from_blocks(rows, blocks), blocks
+
+
+def _rows_from_blocks_bwd(blocks, g):
+    g = _rows_of(g, blocks.pair_of_row)
+    return jnp.where(blocks.real[:, None], g, jnp.zeros((), g.dtype)), None
+
+
+_rows_from_blocks.defvjp(_rows_from_blocks_fwd, _rows_from_blocks_bwd)
+
+
 def moe_dropless(x: jax.Array, route: Callable, expert: Callable,
                  expert_weights: Sequence[jax.Array],
                  held: Optional[Tuple[int, int]] = None
@@ -470,11 +598,13 @@ def moe_dropless(x: jax.Array, route: Callable, expert: Callable,
     The sorted pairs are worked in tiles of :func:`share_tile_rows` rows, a
     number computed from ``k``, ``T``, ``count`` and ``E`` alone. A full
     load is one tile of ``k T`` rows, and that is the program below the
-    walk: the rows gathered into expert order, one ``lax.ragged_dot`` per
-    projection, the rows permuted back by the inverse gather. A share of
-    fewer experts is :func:`_walk`: a tile is one slot of
-    :func:`share_slot_rows` rows a held expert, the product over it one
-    batched ``dot_general``, nothing is done for a tile past the fullest
+    walk: the rows gathered to where each expert's pairs start on a row
+    block (:func:`_blocks_of`), one
+    :func:`~horovod_tpu.ops.grouped_matmul.grouped_matmul` per projection
+    over the live blocks, the pairs' rows gathered back (module text, "A
+    full load"). A share of fewer experts is :func:`_walk`: a tile is one
+    slot of :func:`share_slot_rows` rows a held expert, the product over it
+    one batched ``dot_general``, nothing is done for a tile past the fullest
     held expert's last pair, and a live tile's weighted rows return to
     their tokens by a scatter-add into float32 (module text, "A share").
     """
@@ -510,17 +640,15 @@ def moe_dropless(x: jax.Array, route: Callable, expert: Callable,
                     expert, tile)
         return out.astype(x.dtype), stats
     with moe_scope("moe_dispatch"):
-        rows = _gather_sorted(x, order, inverse, k)
+        blocks = _blocks_of(sizes, keys, order, inverse, held is not None)
+        rows = _rows_to_blocks(x, blocks, k)
+    _grouped_counter("blocks", "built").inc(blocks.group_of_block.shape[0])
     with moe_scope("moe_experts"):
-        if held is None:
-            def dot(a, w):
-                return lax.ragged_dot(a, w, sizes)
-        else:
-            # the rows past the held experts' pairs are in no group
-            dot = _group_dot(sizes, (jnp.arange(k * t) < sizes.sum())[:, None])
+        def dot(a, w):
+            return grouped_matmul(a, w, blocks.group_of_block, blocks.live)
         rows = expert(dot, rows, *expert_weights)
     with moe_scope("moe_combine"):
-        rows = _permute(rows, inverse, order).reshape(t, k, d)
+        rows = _rows_from_blocks(rows, blocks).reshape(t, k, d)
         out = jnp.einsum("tk,tkd->td", weights, rows,
                          preferred_element_type=jnp.float32)
     return out.astype(x.dtype), stats

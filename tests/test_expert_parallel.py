@@ -17,7 +17,7 @@ from horovod_tpu.parallel import mesh as mesh_lib
 from horovod_tpu.parallel.ep import (load_balancing_loss, moe_dropless,
                                      moe_layer, moe_topk, relu2_expert,
                                      route_sigmoid_topk, route_topk,
-                                     top1_dispatch)
+                                     swiglu_expert, top1_dispatch)
 
 N = 8  # expert-axis extent
 D, H = 16, 32
@@ -555,11 +555,14 @@ def test_a_walked_share_at_the_ends_of_imbalance(small_tiles, case):
         test_a_share_drops_nothing_when_every_pair_goes_to_one_held_expert()
 
 
-def test_a_full_load_is_one_tile_and_traces_to_the_program_it_was():
-    """``held=None`` is the one-tile case with no loop and no condition:
-    ``moe_topk``'s jaxpr, forward and with its gradients, is to the letter
-    the one before the walk (PR 30's; sha256 of its text), and holds no
-    loop, no branch and no scatter."""
+def test_a_full_load_is_one_tile_and_traces_to_the_kernels_alone():
+    """``held=None`` is the one-tile case with no loop and no scatter:
+    ``moe_topk``'s jaxpr, forward and with its gradients, holds the repo's
+    grouped matmul three times forward and nine times with the gradients
+    (the three, towards the rows, towards the matrices; each traced for the
+    TPU and for interpret mode, ``lax.platform_dependent``'s two branches)
+    and no ``ragged_dot_general``; it is to the letter the program PR 36
+    wrote (sha256 of its text)."""
     import hashlib
     from horovod_tpu.parallel import ep
     assert ep.share_tile_rows(8 * 8192, 64, 64) == 8 * 8192
@@ -569,12 +572,126 @@ def test_a_full_load_is_one_tile_and_traces_to_the_program_it_was():
     backward = str(jax.make_jaxpr(jax.grad(
         lambda *a: moe_topk(*a, 4)[0].sum(), argnums=(0, 1, 2, 3, 4)))(
             x, *weights))
-    for word in ("while", "cond", "scatter"):
+    for word in ("while", "scatter", "ragged_dot_general"):
         assert word not in forward and word not in backward, word
+    assert forward.count("name=_gmm_call") == 2 * 3
+    assert "name=_gmm_dw_call" not in forward
+    assert backward.count("name=_gmm_call") == 2 * 6
+    assert backward.count("name=_gmm_dw_call") == 2 * 3
     assert hashlib.sha256(forward.encode()).hexdigest() == \
-        "e9d74b9a3112956ea385bf179bf83dd98ef40c20fde206bb721bf68d03778e7f"
+        "f203b1e570856a8e797ddb4eb759c89e628d1fc10c5f24c3fa69da911f36ba0c"
     assert hashlib.sha256(backward.encode()).hexdigest() == \
-        "9d2795442283f81f6d90c8d8100dc11d5f9b6227b72714fee9a13b2f4a9cfa4a"
+        "1658fd8c9f6516024bf19d2dd18b4b04a30e2e6a706dcea9e8067b7b719b83ca"
+
+
+# -- a full load: every expert's pairs from a row block on ----------------------
+
+def _told_topk(x, weights, experts):
+    """``moe_dropless`` over every expert with the choice given, the
+    weights the chosen experts' softmax probabilities."""
+    w_router, *expert_weights = weights
+
+    def route(x):
+        logits = x @ w_router
+        probs = jax.nn.softmax(logits, axis=-1)
+        return jnp.take_along_axis(probs, experts, axis=-1), experts, \
+            probs, logits
+    return moe_dropless(x, route, swiglu_expert, expert_weights)
+
+
+def _told_dense_gated(x, weights, experts):
+    w_router, w_gate, w_up, w_down = weights
+    picked = (experts[:, :, None] == jnp.arange(E)).any(axis=1)
+    gate = jnp.where(picked, jax.nn.softmax(x @ w_router, axis=-1), 0.0)
+    hidden = jax.nn.silu(jnp.einsum("td,edf->tef", x, w_gate)) * \
+        jnp.einsum("td,edf->tef", x, w_up)
+    return jnp.einsum("te,tef,efd->td", gate, hidden, w_down)
+
+
+@pytest.mark.parametrize("sizes", [
+    [7, 8, 9, 0], [1, 16, 17, 95], [96, 0, 96, 0], [8, 8, 8, 8],
+    [0, 0, 0, 0]],
+    ids=["a-row-short-of-a-block-on-it-and-past-it", "one-pair-and-two-blocks",
+         "every-token-or-none", "whole-blocks", "half-the-experts-unused"])
+def test_a_full_load_is_exact_wherever_an_experts_pairs_end(small_tiles,
+                                                            sizes):
+    """Row blocks of 8 over 384 pairs of 16 experts. Expert ``4 + e`` is
+    sent ``sizes[e]`` pairs and expert ``e`` the other ``96 - sizes[e]`` of
+    slot ``e``; experts 8 to 15 none: an expert's pairs end a row short of
+    a block, on it, a row past it and nowhere, one expert is sent every
+    token, experts with no pair lie between experts with many. The output
+    and the gradients of tokens, router and all three expert matrices are
+    the dense reference's, and an expert with no pair gets exactly zero in
+    every matrix: nothing reaches a weight through a row of padding."""
+    weights = _gated_weights(sum(sizes))
+    x = jnp.asarray(np.random.RandomState(40).randn(T, D), jnp.float32)
+    experts = _choices(sizes)
+    out, stats = jax.jit(_told_topk)(x, weights, experts)
+    counts = np.asarray(stats.expert_tokens)
+    assert list(counts[4:8]) == sizes and counts.sum() == K_T
+    live, built = small_tiles.grouped_blocks(counts, K_SHARE, T)
+    assert live == sum(-(-n // 8) for n in counts) <= built == K_T // 8 + E
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(_told_dense_gated(x, weights, experts)),
+        rtol=2e-4, atol=2e-5)
+
+    def loss(layer, x, weights):
+        return jnp.sum(jnp.tanh(layer(x, weights, experts)) ** 2)
+    got = jax.jit(jax.grad(lambda x, w: loss(
+        lambda *a: _told_topk(*a)[0], x, w), argnums=(0, 1)))(x, weights)
+    want = jax.grad(lambda x, w: loss(_told_dense_gated, x, w),
+                    argnums=(0, 1))(x, weights)
+    for name, g, v in zip(("x", "router", "gate", "up", "down"),
+                          (got[0],) + tuple(got[1]),
+                          (want[0],) + tuple(want[1])):
+        assert np.isfinite(np.asarray(g)).all(), name
+        np.testing.assert_allclose(np.asarray(g), np.asarray(v), rtol=2e-3,
+                                   atol=2e-5, err_msg=name)
+    for name, g in zip(("gate", "up", "down"), got[1][1:]):
+        unused = np.abs(np.asarray(g)).reshape(E, -1).sum(axis=1) == 0
+        np.testing.assert_array_equal(unused, counts == 0, err_msg=name)
+
+
+def test_rows_of_padding_gather_in_bounds_and_return_zero_gradients(
+        small_tiles):
+    """The layout by hand: counts 3, 0, 9, 8 in blocks of 8 lie at rows
+    0-2, 8-16, 24-31 of 4 live blocks (of 7 built: 20 pairs in 3 blocks and
+    one an expert); the block-to-expert table skips the expert with no pair
+    and ends with the last expert's index; a row of padding names a pair in
+    bounds; and the gradient that returns to the blocks from the pairs is
+    exactly zero on every row that holds no pair."""
+    ep = small_tiles
+    keys = jnp.asarray([2, 3, 0, 2, 2, 3, 0, 2, 3, 3, 2, 2, 0, 3, 2, 3, 2,
+                        3, 2, 3], jnp.int32)
+    sizes = jnp.asarray([3, 0, 9, 8], jnp.int32)
+    order = jnp.argsort(keys, stable=True)
+    blocks = ep._blocks_of(sizes, keys, order, jnp.argsort(order), False)
+    assert blocks.held is None and int(blocks.live[0]) == 4
+    assert list(np.asarray(blocks.group_of_block)) == [0, 2, 2, 3, 3, 3, 3]
+    real = np.asarray(blocks.real)
+    assert real.shape == (7 * 8,)
+    assert sorted(np.flatnonzero(real)) == [0, 1, 2] + list(range(8, 17)) \
+        + list(range(24, 32))
+    pair_of_row = np.asarray(blocks.pair_of_row)
+    assert pair_of_row.min() >= 0 and pair_of_row.max() < 20
+    row_of_pair = np.asarray(blocks.row_of_pair)
+    np.testing.assert_array_equal(pair_of_row[row_of_pair], np.arange(20))
+    np.testing.assert_array_equal(np.sort(row_of_pair), np.flatnonzero(real))
+    rows = jnp.asarray(np.random.RandomState(0).randn(56, 5), jnp.float32)
+    pairs, pull = jax.vjp(lambda r: ep._rows_from_blocks(r, blocks), rows)
+    np.testing.assert_array_equal(np.asarray(pairs),
+                                  np.asarray(rows)[row_of_pair])
+    back = np.asarray(pull(jnp.ones_like(pairs))[0])
+    np.testing.assert_array_equal(
+        back, np.broadcast_to(np.where(real[:, None], 1.0, 0.0), back.shape))
+    x = jnp.asarray(np.random.RandomState(1).randn(10, 5), jnp.float32)
+    rows, pull = jax.vjp(lambda x: ep._rows_to_blocks(x, blocks, 2), x)
+    np.testing.assert_array_equal(np.asarray(rows)[row_of_pair],
+                                  np.asarray(x)[np.arange(20) // 2])
+    # a token's gradient is the sum of its k pairs' rows; padding adds none
+    back = np.asarray(pull(jnp.broadcast_to(
+        jnp.where(blocks.real[:, None], 1.0, 9.0), rows.shape))[0])
+    np.testing.assert_array_equal(back, np.full((10, 5), 2.0))
 
 
 def test_a_walk_has_each_grouped_matmul_once_a_direction(small_tiles):
@@ -659,6 +776,27 @@ def test_the_walk_counts_its_tiles_in_the_registry(small_tiles):
     assert rows("held").value == held_rows + sizes.sum() > held_rows
     assert tiles[0] == -(-sizes.max() // SLOT)
     assert rows("computed").value == computed + tiles[0] * TILE
-    # a full load builds no walk
-    jax.jit(lambda x, *w: moe_topk(x, *w, 2))(x, *_gated_weights())
+    # a full load builds no walk: it counts its row blocks instead
+    def grouped(name, kind):
+        return get_registry().counter(f"hvd_moe_grouped_{name}_total",
+                                      kind=kind)
+    before = {key: grouped(*key).value for key in (
+        ("blocks", "built"), ("blocks", "live"), ("rows", "held"),
+        ("rows", "computed"))}
+    _, stats = jax.jit(lambda x, *w: moe_topk(x, *w, 2))(
+        x, *_gated_weights())
     assert counter("built").value == built + 3
+    blocks_built = 2 * T // 8 + E  # the pairs in blocks of 8, one an expert
+    assert grouped("blocks", "built").value == \
+        before["blocks", "built"] + blocks_built
+    assert grouped("blocks", "live").value == before["blocks", "live"]
+    load = np.asarray(stats.expert_tokens)
+    live = sum(-(-n // 8) for n in load)
+    assert small_tiles.grouped_blocks(load, 2, T, record=True) == \
+        (live, blocks_built)
+    assert grouped("blocks", "live").value == before["blocks", "live"] + live
+    assert grouped("rows", "held").value == before["rows", "held"] + 2 * T
+    # computed / held: what starting every expert on a block costs
+    assert grouped("rows", "computed").value == \
+        before["rows", "computed"] + 8 * live
+    assert 2 * T <= 8 * live < 2 * T + 8 * E

@@ -255,6 +255,16 @@ def test_zero1_step_compiles_for_four_v5e_with_the_options(
     assert "all-reduce" in zero1 and "phase_param_gather" in zero1
 
 
+def _benchmark_on_path():
+    """``benchmark/`` importable: its ``harness`` names a step's kernels."""
+    import sys
+    benchmark = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "benchmark")
+    if benchmark not in sys.path:
+        sys.path.insert(0, benchmark)
+
+
 # -- the expert layer at OLMoE's widths ----------------------------------------
 
 @pytest.fixture(scope="module")
@@ -279,14 +289,30 @@ def olmoe_layer_text(topo):
 
 
 def test_expert_layer_compiles_to_grouped_matmul_kernels(olmoe_layer_text):
-    """The TPU compiler lowers each ``ragged_dot`` (three forward, six
-    backward) to a Mosaic call of its own, with two metadata calls: what
-    ``benchmark/configs/olmoe-1b-7b.py`` counts as ``RAGGED_DOT_CALLS`` and
-    ``benchmark/harness/moe.py`` names ``moe_experts``."""
-    names = re.findall(r'custom_call_target="tpu_custom_call".*?'
-                       r'op_name="([^"]*)"', olmoe_layer_text)
-    assert sorted(set(names)) == ["ragged-dot-metadata", "ragged-dot-none"]
-    assert names.count("ragged-dot-none") == 9 and len(names) == 11
+    """The nine products of a full load are the repo's own kernels
+    (``ops/grouped_matmul.py``): ``_gmm_kernel`` six times (the three
+    projections forward and towards the rows) and ``_gmm_dw_kernel`` three
+    (towards the matrices), each under ``moe_experts`` in its ``op_name``,
+    the backward's under ``transpose(jvp(...))``: what
+    ``benchmark/harness/moe.py`` reads by the scope. No call of the
+    compiler's own: a ``ragged_dot`` was Mosaic calls named ``ragged-dot-*``
+    (nine and two of metadata before PR 36), paced by the (group, tile)
+    pairs they visited. The rows are the ``k T`` pairs and a block of
+    padding an expert: 576 blocks of 128."""
+    from horovod_tpu.parallel import ep
+    calls, op_names = _kernel_calls(olmoe_layer_text)
+    assert calls == {"_gmm_kernel": 6, "_gmm_dw_kernel": 3}
+    assert "ragged-dot" not in olmoe_layer_text
+    for kernel, names in op_names.items():
+        assert all("moe_experts" in name for name in names), kernel
+    assert all("transpose(jvp(" in name
+               for name in op_names["_gmm_dw_kernel"])
+    assert sum("transpose(jvp(" in name
+               for name in op_names["_gmm_kernel"]) == 3
+    rows = ep.grouped_blocks_built(8 * 8192, 64) * ep.SHARE_BLOCK_ROWS
+    assert rows == 73728
+    assert re.search(rf"bf16\[{rows},2048\]", olmoe_layer_text)
+    assert re.search(rf"bf16\[{rows},1024\]", olmoe_layer_text)
 
 
 def test_expert_layer_moves_rows_by_gathers_alone(olmoe_layer_text):
@@ -307,9 +333,7 @@ def nemotron_cell(topo):
     the configuration's own job (nine layers at the published widths, 8192
     tokens, blocks M and E recomputed) through
     ``dp.make_stateful_train_step`` for one described chip."""
-    import sys
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    sys.path[:0] = [os.path.join(repo, "benchmark")]
+    _benchmark_on_path()
     from harness import spec as spec_lib
     from horovod_tpu.parallel import dp, mesh as mesh_lib
     spec = spec_lib.load()
@@ -361,6 +385,7 @@ def _kernel_calls(text):
     names them (``harness.kernels.inventory``: a Pallas kernel by its
     function, the compiler's grouped matmuls by their one-word ``op_name``),
     and {kernel: the ``op_name`` of each of its calls}."""
+    _benchmark_on_path()
     from harness import hlo_text, kernels
     hlo = hlo_text.HloIndex(text)
     op_names = {}
