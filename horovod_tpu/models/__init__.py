@@ -15,6 +15,12 @@ from horovod_tpu.models.olmoe import (  # noqa: F401
     OlmoeDecoder,
     olmoe_loss,
 )
+from horovod_tpu.models.smallthinker import (  # noqa: F401
+    SmallThinker21BA3B,
+    SmallThinkerDecoder,
+    SmallThinkerTiny,
+    smallthinker_loss,
+)
 from horovod_tpu.models.transformer import (  # noqa: F401
     BertBase,
     BertEncoder,

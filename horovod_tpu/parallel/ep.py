@@ -17,10 +17,16 @@ functions, not as flags:
   renormalised); :func:`route_sigmoid_topk` is the DeepSeek-V3 / Nemotron-H
   one (sigmoid scores, the choice made on ``scores + bias`` where ``bias`` is
   a correction no gradient reaches, the weights the chosen *scores* without
-  the bias, renormalised and scaled);
+  the bias, renormalised and scaled); :func:`route_topk_softmax` takes the k
+  largest *logits* and then the softmax of those k (SmallThinker). The
+  router's rows need not be the experts': :func:`moe_routing` makes the
+  :class:`Routing` from any rows of the same tokens (a router that reads the
+  layer's input before attention) and the layer takes it in the router's
+  place;
 - **an expert**, ``expert(dot, rows, *weights) -> rows``, written over the
   grouped matmul ``dot(rows, w)`` the layer hands it: :func:`swiglu_expert`
   (``w_down(silu(w_gate x) * w_up x)``, three matrices),
+  :func:`reglu_expert` (the same with ``relu`` for ``silu``),
   :func:`relu2_expert` (``w_down relu(w_up x)^2``, two).
 
 The layer's own four parts are ``jax.named_scope``s
@@ -221,6 +227,25 @@ def route_sigmoid_topk(x: jax.Array, w_router: jax.Array, bias: jax.Array,
     return weights, experts, scores, logits
 
 
+def route_topk_softmax(x: jax.Array, w_router: jax.Array, k: int
+                       ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """Top k of the logits, then the softmax of those k
+    (``moe_primary_router_apply_softmax`` of SmallThinker: the weights sum to
+    one, so a ``norm_topk_prob`` after it is the identity), in float32 at the
+    highest matmul precision as :func:`route_topk`, the chosen logits read
+    through the choice's one-hot mask as there. x: [T, d]; w_router: [d, E].
+    Returns (weights [T, k], experts [T, k] int32, the softmax over all E
+    [T, E], which is what :class:`MoeStats` averages and not what weighs the
+    experts, logits [T, E])."""
+    logits = jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    experts = lax.top_k(lax.stop_gradient(logits), k)[1].astype(jnp.int32)
+    chosen = jnp.sum(jnp.where(_chosen_mask(experts, logits.shape[-1]),
+                               logits[:, None, :], 0.0), axis=-1)
+    return jax.nn.softmax(chosen, axis=-1), experts, \
+        jax.nn.softmax(logits, axis=-1), logits
+
+
 def _chosen_mask(experts: jax.Array, n_experts: int) -> jax.Array:
     """[T, k, E] bool: slot j of token t chose expert e."""
     return experts[:, :, None] == jnp.arange(n_experts, dtype=jnp.int32)
@@ -229,6 +254,11 @@ def _chosen_mask(experts: jax.Array, n_experts: int) -> jax.Array:
 def swiglu_expert(dot, rows, w_gate, w_up, w_down):
     """``w_down(silu(w_gate x) * w_up x)``: OLMoE's gated expert."""
     return dot(jax.nn.silu(dot(rows, w_gate)) * dot(rows, w_up), w_down)
+
+
+def reglu_expert(dot, rows, w_gate, w_up, w_down):
+    """``w_down(relu(w_gate x) * w_up x)``: SmallThinker's gated expert."""
+    return dot(jax.nn.relu(dot(rows, w_gate)) * dot(rows, w_up), w_down)
 
 
 def relu2_expert(dot, rows, w_up, w_down):
@@ -579,14 +609,38 @@ def _rows_from_blocks_bwd(blocks, g):
 _rows_from_blocks.defvjp(_rows_from_blocks_fwd, _rows_from_blocks_bwd)
 
 
-def moe_dropless(x: jax.Array, route: Callable, expert: Callable,
+class Routing(NamedTuple):
+    """A router's choice for T tokens, made by :func:`moe_routing`."""
+    weights: jax.Array  # float32 [T, k]
+    experts: jax.Array  # int32 [T, k]
+    stats: MoeStats
+
+
+def moe_routing(route: Callable, x: jax.Array) -> Routing:
+    """``route(x)`` and the per-expert counts, under ``moe_router``.
+    :func:`moe_dropless` calls it on the experts' own rows; a model whose
+    router reads other rows of the same tokens (the layer's input, before
+    attention) calls it there and hands the layer the result."""
+    with moe_scope("moe_router"):
+        weights, experts, scores, logits = route(x)
+        return Routing(weights, experts, MoeStats(
+            expert_tokens=jnp.sum(_chosen_mask(experts, scores.shape[-1]),
+                                  axis=(0, 1), dtype=jnp.int32),
+            router_prob_mean=scores.mean(axis=0),
+            router_z_loss=jnp.mean(
+                jax.nn.logsumexp(logits, axis=-1) ** 2)))
+
+
+def moe_dropless(x: jax.Array, route, expert: Callable,
                  expert_weights: Sequence[jax.Array],
                  held: Optional[Tuple[int, int]] = None
                  ) -> Tuple[jax.Array, MoeStats]:
     """One dropless top-k expert layer over the tokens it is given.
 
     x: [T, d] in the compute dtype. ``route(x)`` is the router over all E
-    experts and ``expert(dot, rows, *expert_weights)`` one expert's function
+    experts, or ``route`` is the :class:`Routing` of these T tokens that
+    :func:`moe_routing` made from other rows, and
+    ``expert(dot, rows, *expert_weights)`` one expert's function
     over the grouped matmul ``dot`` (the module text has both contracts);
     ``expert_weights`` lead with the experts held here: all E, or with
     ``held = (first, count)`` the ``count`` from ``first`` on. Returns
@@ -609,15 +663,12 @@ def moe_dropless(x: jax.Array, route: Callable, expert: Callable,
     their tokens by a scatter-add into float32 (module text, "A share").
     """
     t, d = x.shape
-    with moe_scope("moe_router"):
-        weights, experts, scores, logits = route(x)
-        k, n_experts = experts.shape[-1], scores.shape[-1]
-        stats = MoeStats(
-            expert_tokens=jnp.sum(_chosen_mask(experts, n_experts),
-                                  axis=(0, 1), dtype=jnp.int32),
-            router_prob_mean=scores.mean(axis=0),
-            router_z_loss=jnp.mean(
-                jax.nn.logsumexp(logits, axis=-1) ** 2))
+    weights, experts, stats = route if isinstance(route, Routing) else \
+        moe_routing(route, x)
+    if experts.shape[0] != t:
+        raise ValueError(f"a routing of {experts.shape[0]} tokens for "
+                         f"{t} rows")
+    k, n_experts = experts.shape[-1], stats.expert_tokens.shape[0]
     first, count = held if held is not None else (0, n_experts)
     if not 0 <= first <= first + count <= n_experts or any(
             w.shape[0] != count for w in expert_weights):

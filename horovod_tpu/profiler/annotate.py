@@ -14,6 +14,9 @@ Two distinct mechanisms, matching where the work actually happens:
 - :func:`ssm_scope` — the parts of a Mamba-2 mixer (:data:`SSM_SCOPES`:
   in-projection, causal conv, the chunked scan of ``ops/ssd.py``, gated
   group norm, out-projection), written by ``models/nemotron_h.py``.
+- :func:`attn_scope` — the kind of an attention call (:data:`ATTN_SCOPES`:
+  full causal or window), written by ``models/smallthinker.py``, whose
+  layers mix the two.
 - :func:`collective_scope` — ``jax.named_scope`` for code that runs INSIDE a
   jitted program (the in-jit collectives of ``parallel/collectives.py``).
   The scope becomes HLO op-name metadata, so the device trace of a
@@ -57,6 +60,10 @@ MOE_SCOPES = ("moe_router", "moe_dispatch", "moe_experts", "moe_combine",
 # own for the same reason.
 SSM_SCOPES = ("ssm_in_proj", "ssm_conv", "ssm_scan", "ssm_gate_norm",
               "ssm_out_proj")
+# The kind of an attention call in a model that mixes them
+# (``models/smallthinker.py``): rotary, the key heads' repeat and the
+# kernels of a full-causal or of a window layer.
+ATTN_SCOPES = ("attn_full", "attn_window")
 # Host spans the step wrapper (``metrics.timed_step``) writes.
 STEP_SPAN = "hvd.step"
 STEP_DISPATCH_SPAN = "hvd.step.dispatch"
@@ -83,6 +90,14 @@ def ssm_scope(name: str):
     if name not in SSM_SCOPES:
         raise ValueError(f"unknown state-space mixer scope {name!r}; one of "
                          f"{SSM_SCOPES}")
+    return collective_scope(name)
+
+
+def attn_scope(name: str):
+    """Name the enclosed traced ops as an attention call of one kind."""
+    if name not in ATTN_SCOPES:
+        raise ValueError(f"unknown attention scope {name!r}; one of "
+                         f"{ATTN_SCOPES}")
     return collective_scope(name)
 
 

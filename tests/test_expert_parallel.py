@@ -15,9 +15,11 @@ from jax.sharding import PartitionSpec as P
 
 from horovod_tpu.parallel import mesh as mesh_lib
 from horovod_tpu.parallel.ep import (load_balancing_loss, moe_dropless,
-                                     moe_layer, moe_topk, relu2_expert,
+                                     moe_layer, moe_routing, moe_topk,
+                                     reglu_expert, relu2_expert,
                                      route_sigmoid_topk, route_topk,
-                                     swiglu_expert, top1_dispatch)
+                                     route_topk_softmax, swiglu_expert,
+                                     top1_dispatch)
 
 N = 8  # expert-axis extent
 D, H = 16, 32
@@ -800,3 +802,136 @@ def test_the_walk_counts_its_tiles_in_the_registry(small_tiles):
     assert grouped("rows", "computed").value == \
         before["rows", "computed"] + 8 * live
     assert 2 * T <= 8 * live < 2 * T + 8 * E
+
+
+# -- a SmallThinker layer: top k of the logits then their softmax, ReGLU
+# experts, and a routing made from other rows than the experts' ----------------
+
+K_ST = 3
+
+
+def test_route_topk_softmax_weights_sum_to_one_over_the_chosen_logits():
+    """The choice is the k largest logits, the weights their softmax (they
+    sum to one: a renormalisation after it is the identity), in float32;
+    the scores handed to ``MoeStats`` are the softmax over all E. The
+    gradient reaches the router through the chosen logits alone."""
+    rng = np.random.RandomState(60)
+    x = jnp.asarray(rng.randn(T, D), jnp.float32)
+    w_router = jnp.asarray(rng.randn(D, E), jnp.float32)
+    weights, experts, scores, logits = route_topk_softmax(x, w_router, K_ST)
+    want_logits = np.asarray(x, np.float64) @ np.asarray(w_router, np.float64)
+    np.testing.assert_allclose(np.asarray(logits), want_logits, rtol=1e-5,
+                               atol=1e-5)
+    want_experts = np.argsort(-want_logits, axis=-1)[:, :K_ST]
+    np.testing.assert_array_equal(np.asarray(experts), want_experts)
+    chosen = np.take_along_axis(want_logits, want_experts, axis=-1)
+    want = np.exp(chosen - chosen.max(-1, keepdims=True))
+    want /= want.sum(-1, keepdims=True)
+    np.testing.assert_allclose(np.asarray(weights), want, rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(weights.sum(-1)), 1.0, rtol=1e-6)
+    assert weights.dtype == scores.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(scores),
+                               np.asarray(jax.nn.softmax(logits, -1)))
+    # not route_topk's weights: those are the softmax over all E
+    assert not np.allclose(np.asarray(weights),
+                           np.asarray(route_topk(x, w_router, K_ST)[0]))
+
+    def first_weight(w_router):
+        return route_topk_softmax(x, w_router, K_ST)[0][:, 0].sum()
+    grad = np.asarray(jax.grad(first_weight)(w_router))
+    picked = np.zeros((T, E), bool)
+    np.put_along_axis(picked, want_experts, True, axis=-1)
+    never = ~picked.any(axis=0)  # experts no token chose: no gradient
+    assert never.any() or T >= E  # (with T >> E every expert is chosen)
+    assert not grad[:, never].any() and grad[:, ~never].any()
+
+
+def test_reglu_expert_is_relu_gate_times_up():
+    rng = np.random.RandomState(61)
+    rows, w_gate, w_up, w_down = (
+        jnp.asarray(a, jnp.float32) for a in (
+            rng.randn(T, D), rng.randn(D, F), rng.randn(D, F),
+            rng.randn(F, D)))
+    got = reglu_expert(jnp.dot, rows, w_gate, w_up, w_down)
+    want = (np.maximum(rows @ w_gate, 0.0) * (rows @ w_up)) @ w_down
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5,
+                               atol=1e-5)
+    assert not np.allclose(np.asarray(got), np.asarray(
+        swiglu_expert(jnp.dot, rows, w_gate, w_up, w_down)), atol=1e-3)
+
+
+def _smallthinker_weights(seed=0):
+    rng = np.random.RandomState(seed)
+    return {k: jnp.asarray(v, jnp.float32) for k, v in dict(
+        router=rng.randn(D, E) * 0.5, gate=rng.randn(E, D, F) * 0.2,
+        up=rng.randn(E, D, F) * 0.2, down=rng.randn(E, F, D) * 0.2).items()}
+
+
+def _uncut_smallthinker_layer(r, x, w):
+    """Dense: the router reads ``r``, every expert computes every row of
+    ``x``, masked by the choice and weighed by the softmax of the chosen
+    logits."""
+    logits = r @ w["router"]
+    chosen = jax.lax.top_k(logits, K_ST)[1]
+    picked = (chosen[:, :, None] == jnp.arange(E)).any(axis=1)
+    gate = jax.nn.softmax(jnp.where(picked, logits, -jnp.inf), axis=-1)
+    hidden = jnp.maximum(jnp.einsum("td,edf->tef", x, w["gate"]), 0.0) * \
+        jnp.einsum("td,edf->tef", x, w["up"])
+    return jnp.einsum("te,tef,efd->td", gate, hidden, w["down"])
+
+
+def _smallthinker_share(r, x, w, first, count):
+    routing = moe_routing(functools.partial(
+        route_topk_softmax, w_router=w["router"], k=K_ST), r)
+    return moe_dropless(
+        x, routing, reglu_expert,
+        tuple(w[name][first:first + count]
+              for name in ("gate", "up", "down")), held=(first, count))
+
+
+@pytest.mark.parametrize("shares", [1, 2, 8])
+def test_the_shares_of_a_smallthinker_layer_add_up_to_the_uncut_layer(
+        shares):
+    """The router reads other rows (``r``: the layer's input) than the
+    experts (``x``: the stream after attention). 16 experts in 1, 2 and 8
+    shares: the shares' parts add up to the dense layer, outputs and the
+    gradients of both kinds of rows, the router and the experts; a routing
+    from the experts' own rows is another layer."""
+    w = _smallthinker_weights(shares)
+    rng = np.random.RandomState(70 + shares)
+    r, x = (jnp.asarray(rng.randn(T, D), jnp.float32) for _ in range(2))
+    count = E // shares
+
+    def cut_layer(r, x, w):
+        parts = [_smallthinker_share(r, x, w, first, count)
+                 for first in range(0, E, count)]
+        return sum(out for out, _ in parts), parts[0][1]
+    got, stats = jax.jit(cut_layer)(r, x, w)
+    want = _uncut_smallthinker_layer(r, x, w)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-4,
+                               atol=2e-5)
+    assert int(stats.expert_tokens.sum()) == K_ST * T
+    own_rows = _uncut_smallthinker_layer(x, x, w)
+    assert float(jnp.abs(want - own_rows).max()) > 0.1
+
+    def loss(layer, r, x, w):
+        return jnp.sum(jnp.tanh(layer(r, x, w)) ** 2)
+    got = jax.jit(jax.grad(lambda *a: loss(
+        lambda *b: cut_layer(*b)[0], *a), argnums=(0, 1, 2)))(r, x, w)
+    want = jax.grad(lambda *a: loss(_uncut_smallthinker_layer, *a),
+                    argnums=(0, 1, 2))(r, x, w)
+    for g, v in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert float(jnp.abs(v).sum()) > 0
+        np.testing.assert_allclose(np.asarray(g), np.asarray(v), rtol=2e-3,
+                                   atol=2e-5)
+
+
+def test_a_routing_of_other_tokens_is_refused():
+    w = _smallthinker_weights()
+    routing = moe_routing(functools.partial(
+        route_topk_softmax, w_router=w["router"], k=K_ST),
+        jnp.zeros((T // 2, D), jnp.float32))
+    with pytest.raises(ValueError, match="a routing of 48 tokens for 96"):
+        moe_dropless(jnp.zeros((T, D), jnp.float32), routing, reglu_expert,
+                     (w["gate"], w["up"], w["down"]))

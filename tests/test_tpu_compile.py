@@ -52,15 +52,15 @@ def _no_persistent_cache():
     cc.reset_cache()
 
 
-def _forward(causal, scale, q, k, v, o, lse, do, off):
+def _forward(causal, scale, q, k, v, o, lse, do, off, window=None):
     return fa._flash_fwd(q, k, v, off, off, causal, scale, BLOCK, BLOCK,
-                         False)[:2]
+                         False, window)[:2]
 
 
-def _backward(pick, causal, scale, q, k, v, o, lse, do, off):
+def _backward(pick, causal, scale, q, k, v, o, lse, do, off, window=None):
     # the two backward kernels share one function; the one whose outputs
     # are dropped is dead code to the compiler
-    grads = fa._flash_bwd(causal, scale, BLOCK, BLOCK, False,
+    grads = fa._flash_bwd(causal, scale, BLOCK, BLOCK, False, window,
                           (q, k, v, o, lse, off, off), (do, None))
     return pick(grads)
 
@@ -75,9 +75,17 @@ KERNELS = {
 @pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
 @pytest.mark.parametrize("seq,head_dim", [(1024, 64), (8192, 64),
                                           (8192, 128), (2048, 64),
-                                          (4096, 128)])
+                                          (4096, 128), (16384, 128)])
 @pytest.mark.parametrize("kernel", list(KERNELS))
 def test_flash_kernel_compiles_for_v5e(topo, kernel, seq, head_dim, causal):
+    """(16384, 128) holds 4 MiB each of k and v (dk/dv: q and do) twice:
+    past the compiler's default scoped VMEM, so the call asks for its own
+    (``fa._vmem_params``); the shorter ones ask for nothing, as before."""
+    text = _kernel_text(topo, kernel, seq, head_dim, causal)
+    assert text.count("tpu_custom_call") == 1, text.count("tpu_custom_call")
+
+
+def _kernel_text(topo, kernel, seq, head_dim, causal, window=None):
     one_chip = SingleDeviceSharding(topo.devices[0])
 
     def arg(shape, dtype):
@@ -86,9 +94,29 @@ def test_flash_kernel_compiles_for_v5e(topo, kernel, seq, head_dim, causal):
     x = arg((1, seq, HEADS, head_dim), jnp.bfloat16)
     lse = arg((HEADS, 1, seq), jnp.float32)
     off = arg((1,), jnp.float32)
-    fn = functools.partial(KERNELS[kernel], causal, head_dim ** -0.5)
-    text = jax.jit(fn).lower(x, x, x, x, lse, x, off).compile().as_text()
-    assert text.count("tpu_custom_call") == 1, text.count("tpu_custom_call")
+    fn = functools.partial(KERNELS[kernel], causal, head_dim ** -0.5,
+                           window=window)
+    return jax.jit(fn).lower(x, x, x, x, lse, x, off).compile().as_text()
+
+
+WINDOW_KERNELS = {"forward": "_fwd_window_kernel",
+                  "dq": "_bwd_dq_window_kernel",
+                  "dkv": "_bwd_dkv_window_kernel"}
+
+
+@pytest.mark.parametrize("seq,head_dim,window", [
+    (16384, 128, 4096), (8192, 64, 1000), (2048, 128, 1)])
+@pytest.mark.parametrize("kernel", list(KERNELS))
+def test_window_kernel_compiles_for_v5e_under_its_own_name(
+        topo, kernel, seq, head_dim, window):
+    """The window's loop bounds and second mask compile for the chip (the
+    new cell's shape first), one custom call each, and the compiled text
+    names the call by the window kernel's function: never by a name of
+    ``flops.FLASH_PRODUCTS``, whose readers cost a call at the causal pair
+    count."""
+    text = _kernel_text(topo, kernel, seq, head_dim, True, window)
+    calls, _ = _kernel_calls(text)
+    assert calls == {WINDOW_KERNELS[kernel]: 1}
 
 
 @pytest.fixture(scope="module")
@@ -327,17 +355,15 @@ def test_expert_layer_moves_rows_by_gathers_alone(olmoe_layer_text):
 
 # -- the Nemotron-H cell at its real size ----------------------------------------
 
-@pytest.fixture(scope="module")
-def nemotron_cell(topo):
-    """``nemotron3n-t8192`` as ``benchmark/compile_check.py`` compiles it:
-    the configuration's own job (nine layers at the published widths, 8192
-    tokens, blocks M and E recomputed) through
-    ``dp.make_stateful_train_step`` for one described chip."""
+def _compiled_cell(topo, workload):
+    """(job, traffic, compiled): a cell as ``benchmark/compile_check.py``
+    compiles it: the configuration's own job at its real size through
+    ``dp.make_*train_step(donate=True)`` for one described chip."""
     _benchmark_on_path()
     from harness import spec as spec_lib
     from horovod_tpu.parallel import dp, mesh as mesh_lib
     spec = spec_lib.load()
-    cell = spec_lib.workload(spec, "nemotron3n-t8192")
+    cell = spec_lib.workload(spec, workload)
     traffic = spec_lib.traffic(cell["traffic"])
     config, builder = spec_lib.config(spec, cell["config"])
     job = spec_lib.load_module(builder).build(config, traffic)
@@ -351,19 +377,27 @@ def nemotron_cell(topo):
     key = jax.eval_shape(lambda: jax.random.key(0))
     params, state = jax.eval_shape(job.init, key)
     batch = jax.eval_shape(functools.partial(job.make_batch, n=1), key)
-    step = dp.make_stateful_train_step(job.loss_fn, job.optimizer, mesh,
-                                       donate=True)
-    old = fa.flash_attention
-    fa.flash_attention = functools.partial(old, interpret=False)
-    try:
-        compiled = step.lower(
-            on_mesh(params, P()),
-            on_mesh(jax.eval_shape(job.optimizer.init, params), P()),
-            on_mesh(state, P()), on_mesh(batch, P(dp.DP_AXES)),
-            on_mesh(key, P())).compile()
-    finally:
-        fa.flash_attention = old
+    make = dp.make_stateful_train_step if job.stateful else \
+        dp.make_train_step
+    step = make(job.loss_fn, job.optimizer, mesh, donate=True)
+    arguments = [on_mesh(params, P()),
+                 on_mesh(jax.eval_shape(job.optimizer.init, params), P())]
+    if job.stateful:
+        arguments.append(on_mesh(state, P()))
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(fa, "flash_attention", functools.partial(
+            fa.flash_attention, interpret=False))
+        compiled = step.lower(*arguments, on_mesh(batch, P(dp.DP_AXES)),
+                              on_mesh(key, P())).compile()
     return job, traffic, compiled
+
+
+@pytest.fixture(scope="module")
+def nemotron_cell(topo):
+    """``nemotron3n-t8192``: nine layers at the published widths, 8192
+    tokens, blocks M and E recomputed, through
+    ``dp.make_stateful_train_step``."""
+    return _compiled_cell(topo, "nemotron3n-t8192")
 
 
 def test_nemotron_cell_fits_one_v5e_at_full_size(nemotron_cell):
@@ -445,3 +479,68 @@ def test_nemotron_cell_holds_its_kernels_and_scopes(nemotron_cell):
     assert not re.search(r"\[1,64,8,8,128,128\]", text)
     opcodes = re.findall(r"[\s)]([a-z\-]+)\(", text)
     assert "all-reduce" not in opcodes  # one chip exchanges nothing
+
+
+# -- the SmallThinker cell at its real size ----------------------------------------
+
+@pytest.fixture(scope="module")
+def smallthinker_cell(topo):
+    """``smallthinker-t16384``: eight layers at the published widths,
+    16 384 tokens, every block recomputed but for its attention's output,
+    through ``dp.make_train_step``."""
+    return _compiled_cell(topo, "smallthinker-t16384")
+
+
+def test_smallthinker_cell_fits_one_v5e_at_full_size(smallthinker_cell):
+    job, traffic, compiled = smallthinker_cell
+    memory = compiled.memory_analysis()
+    total = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    assert 4e9 < total < 15.0e9, total
+    # 643.85 M parameters and AdamW's moments at 12 bytes
+    assert memory.argument_size_in_bytes == pytest.approx(7.726e9, rel=1e-3)
+    recorded = traffic["memory_analysis"]
+    assert recorded["argument_bytes"] == memory.argument_size_in_bytes
+    assert memory.temp_size_in_bytes <= 1.02 * recorded["temp_bytes"]
+
+
+def test_smallthinker_cell_holds_causal_and_window_kernels_side_by_side(
+        smallthinker_cell):
+    """The two full layers under the causal kernels' names and the six
+    window layers under the window kernels', each name once a layer: the
+    blocks are recomputed, but the attention's output and row statistics are
+    kept by name, so no forward kernel runs twice (``"blocks"`` would hold
+    4 and 12). Every causal call under ``attn_full`` and every window call
+    under ``attn_window``, the backward's under ``transpose(jvp(...))``; the
+    routers under ``moe_router`` before their layer's attention; the share
+    walks its pairs by XLA's batched product over eight slots of 2304 rows
+    (1.5 x 6 x 16 384 / 64), no ``ragged-dot`` and no grouped-matmul
+    kernel; one chip exchanges nothing."""
+    from horovod_tpu.parallel import ep
+    job, _, compiled = smallthinker_cell
+    text = compiled.as_text()
+    calls, op_names = _kernel_calls(text)
+    assert calls == {
+        "_fwd_kernel": 2, "_bwd_dq_kernel": 2, "_bwd_dkv_kernel": 2,
+        "_fwd_window_kernel": 6, "_bwd_dq_window_kernel": 6,
+        "_bwd_dkv_window_kernel": 6}
+    assert job.flash_layers == 2 and job.facts["window_layers"] == 6
+    for kernel, names in op_names.items():
+        scope = "attn_window" if "window" in kernel else "attn_full"
+        assert all(scope in name for name in names), kernel
+        backward = [("transpose(jvp(" in name) for name in names]
+        assert all(backward) if "bwd" in kernel else not any(backward)
+    full = {name.split("SmallThinkerBlock_")[1][0]
+            for name in op_names["_fwd_kernel"]}
+    windowed = {name.split("SmallThinkerBlock_")[1][0]
+                for name in op_names["_fwd_window_kernel"]}
+    assert full == {"0", "4"} and windowed == set("123567")
+    assert "ragged-dot" not in text
+    slot = ep.share_slot_rows(6 * 16384, 64)
+    assert slot == 2304 and ep.share_tile_rows(6 * 16384, 8, 64) == 8 * slot
+    assert re.search(rf"= f32\[8,{slot},768\]\S* convolution\([^\n]*"
+                     r"moe_experts\)*/esk,ekn->esn/dot_general", text)
+    for scope in ("moe_router", "moe_dispatch", "moe_experts",
+                  "moe_combine", "attn_full", "attn_window"):
+        assert scope in text, scope
+    opcodes = re.findall(r"[\s)]([a-z\-]+)\(", text)
+    assert "all-reduce" not in opcodes
