@@ -79,7 +79,7 @@ def main():
              for layer in load]
     rows = {kind: get_registry().counter(
         "hvd_moe_share_rows_total", kind=kind).value
-        for kind in ("held", "computed")}
+        for kind in ("held", "computed", "fetched")}
     if hvd.rank() == 0:
         print(f"replicas {replicas}; loss {first:.4f} -> {last:.4f}; "
               f"largest correction bias {largest:.4f}; "
@@ -87,7 +87,9 @@ def main():
               f"{load[0].astype(int).tolist()}; live tiles of those built, "
               f"by expert layer: {tiles}; rows of the held experts' pairs "
               f"{rows['held']:.0f}, rows the grouped matmuls computed for "
-              f"them (every slot of the live tiles) {rows['computed']:.0f}")
+              f"them (every slot of the live tiles) {rows['computed']:.0f}, "
+              f"rows the way back to the tokens fetched to place them, at "
+              f"most {rows['fetched']:.0f}")
     assert last < first, (first, last)
     if hvd.rank() == 0:
         print(f"done: final loss {last:.4f}")

@@ -80,7 +80,9 @@ outside the step: :func:`grouped_blocks` over the load.
 tile is slack by design (8 slots of 640 rows for 384 pairs an expert: XLA's
 batched product runs them at 79-95% of peak, sums the weights' gradient in
 its own epilogue and needs no table; the kernel was within 1.8% there and
-400 lines longer: ``PERF.md`` §6, PR 34). A full load has no slack to give
+400 lines longer: ``PERF.md`` §6, PR 34), and its rows go back to their
+tokens by additions, in a kernel of their own (below, "A share"), where a
+full load's go back by gathers. A full load has no slack to give
 slots: 64 experts x 1.5 headroom is +50% rows through every gather and
 every product, where starting each expert on a block of 128 costs 64 half
 blocks, +6% (``PERF.md`` §6, PR 36). Which side a call is on is the static
@@ -119,15 +121,25 @@ rest cost a loop's exit. No capacity and no drop: an expert sent more than
 a slot makes the next tile live, and if one expert is sent every token
 every tile is. Everything done to rows happens inside the walk, a tile at
 a time: the gather of the tile's tokens' rows (``moe_dispatch``), the
-expert function over the batched product (``moe_experts``), and the way
-back: the rows times their router weights are *scatter-added* into a
-float32 ``[T, d]`` (``moe_combine``: a tile's rows are a sparse subset of
-the ``[T, k]`` slots, so there is no bijection to invert). A row past its
-expert's last pair gathers some token in bounds, is computed like any
-other (a product writes every row: nothing is left unwritten, nothing
-needs zeroing) and carries router weight 0: it adds nothing to the
-output, and its rows of every gradient are zero because the gradient that
-reaches them is.
+expert function over the batched product (``moe_experts``), and **the way
+back** (``moe_combine``): the rows times their router weights, added into a
+float32 ``[T, d]`` at their tokens by
+:func:`~horovod_tpu.ops.rows_to_tokens.add_rows_at_tokens`. A tile's rows
+are a sparse subset of the ``[T, k]`` slots, so there is no bijection to
+invert, and XLA's ``scatter-add`` takes them a row at a time (8.2-8.6 ms
+for a tile of 18 432 rows of 2560, 23 GB/s, sixteen times a step of
+SmallThinker's cell; one scatter a slot told its indices are sorted and
+unique took nine times that; ``PERF.md`` §6, PRs 38 and 39). But the sort
+is stable, so **inside a slot the pairs' tokens ascend**, and the rows of
+one slot that fall in one block of 256 tokens are a contiguous run: the
+kernel builds a token block's result in VMEM from those runs, fetched in
+chunks of 32 rows, and writes it once, the first live tile without reading
+what was there (1.08 ms for the same tile, 0.66 into a fresh result; the
+bytes ask for 0.4-0.6). A row past its expert's last pair gathers some
+token in bounds, is computed like any other (a product writes every row:
+nothing is left unwritten, nothing needs zeroing), carries router weight 0
+and goes back to no token: it adds nothing to the output, and its rows of
+every gradient are zero because the gradient that reaches them is.
 
 The walk is a ``jax.custom_vjp`` over two loops whose trip count is read
 from the data, one forward and one backward, so each grouped matmul is in
@@ -136,16 +148,20 @@ is not live costs nothing at all. It keeps no activation: the backward loop
 gathers a live tile's rows and takes them through the experts again (under
 a recomputed block that is the block's one recomputation: the recomputed
 forward walk's result is needed by nothing and the compiler removes it),
-gathers the tile's rows of the output's gradient, scatter-adds the rows'
-gradients into a float32 ``[T, d]``, and sums the expert weights' gradients
+gathers the tile's rows of the output's gradient, adds the rows' gradients
+into a float32 ``[T, d]`` at their tokens (``moe_dispatch``: the forward's
+way back again, every weight one), and sums the expert weights' gradients
 over the live tiles in their own dtype (one live tile: exact; float32 sums
-were a gigabyte of the step's temporaries). At trace time
+were a gigabyte of the step's temporaries). The one scatter left in the
+walk is of a scalar a pair (the router weights' gradient by sorted
+position). At trace time
 ``hvd_moe_share_tiles_total{kind="built"}`` counts the tiles a layer call
 can come to and ``hvd_moe_share_tile_rows`` holds a tile's rows; which
 tiles were live, how many rows the held experts were sent and how many the
-live tiles computed for them (``hvd_moe_share_rows_total``) is data, read
-back outside the step: :func:`share_tiles` over the load a model's state
-carries. A share whose rule gives a tile of all ``k T`` pairs (its slots
+live tiles computed for them and the way back fetched to place them
+(``hvd_moe_share_rows_total{kind="held"|"computed"|"fetched"}``) is data,
+read back outside the step: :func:`share_tiles` over the load a model's
+state carries. A share whose rule gives a tile of all ``k T`` pairs (its slots
 reach them all; tests only) is the program below the walk too: the held
 experts' pairs in row blocks, nothing laid out for a pair of an expert held
 elsewhere, whose row in (token, slot) order is zero by a select.
@@ -175,6 +191,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from horovod_tpu.ops.grouped_matmul import grouped_matmul
+from horovod_tpu.ops.rows_to_tokens import (add_rows_at_tokens,
+                                            block_tokens_of, chunk_rows_of)
 from horovod_tpu.parallel import collectives
 from horovod_tpu.profiler.annotate import moe_scope
 
@@ -314,6 +332,14 @@ def _count_built_tiles(tiles: int, rows: int):
         "rows of one tile of the share's walk traced last").set(rows)
 
 
+_SHARE_ROWS = (
+    "hvd_moe_share_rows_total",
+    "rows of a share's walk in the steps read back: the held experts' "
+    "pairs, the rows of the live tiles that the grouped matmuls computed "
+    "for them, and the rows of the chunks the way back to the tokens "
+    "fetched to place them (at most: every run taken to straddle a chunk)")
+
+
 def share_tiles(load, held: Tuple[int, int], k: int, tokens: int,
                 record: bool = False) -> Tuple[int, int]:
     """(live, built): of the ``built`` tiles a share's walk can come to
@@ -324,28 +350,37 @@ def share_tiles(load, held: Tuple[int, int], k: int, tokens: int,
     or the ``load`` a model's ``router_state`` carries to the next step) of
     a top-``k`` router over ``tokens`` tokens. ``record`` adds ``live`` to
     ``hvd_moe_share_tiles_total{kind="live"}``, and to
-    ``hvd_moe_share_rows_total`` the held experts' pairs (``kind="held"``)
-    and the rows the live tiles computed for them (``kind="computed"``:
-    whole tiles, every slot of them)."""
+    ``hvd_moe_share_rows_total`` the held experts' pairs (``kind="held"``),
+    the rows the live tiles computed for them (``kind="computed"``: whole
+    tiles, every slot of them) and the rows the way back fetches to place
+    them, a direction (``kind="fetched"``, the walk only: a slot's rows in
+    a tile come in whole chunks of ``chunk_rows_of(slot)``, and a chunk is
+    fetched once for each token block it holds rows of, so at most one more
+    chunk for each of the ``tokens / block_tokens_of(tokens)`` blocks the
+    rows can lie in; fetched / held is what the way back reads for a row it
+    places)."""
     first, count = held
     rows = share_tile_rows(k * tokens, count, len(load))
     held_rows = [int(n) for n in load[first:first + count]]
+    counted = {"held": sum(held_rows)}
     if rows < k * tokens:
         slot = share_slot_rows(k * tokens, len(load))
         live, built = -(-max(held_rows) // slot), -(-tokens // slot)
-        computed = live * rows
+        counted["computed"] = live * rows
+        chunk, blocks = chunk_rows_of(slot), tokens // block_tokens_of(tokens)
+        in_tiles = [min(slot, n - i * slot) for n in held_rows
+                    for i in range(-(-n // slot))]
+        counted["fetched"] = chunk * sum(
+            -(-n // chunk) + min(n, blocks) - 1 for n in in_tiles)
     else:  # the one-tile program below the walk: whole row blocks
         live, built = -(-sum(held_rows) // rows), 1
-        computed = SHARE_BLOCK_ROWS * grouped_blocks(load, k, tokens, held)[0]
+        counted["computed"] = SHARE_BLOCK_ROWS * grouped_blocks(
+            load, k, tokens, held)[0]
     if record:
         from horovod_tpu.metrics.registry import get_registry
         _share_tiles_counter("live").inc(live)
-        for kind, n in (("held", sum(held_rows)), ("computed", computed)):
-            get_registry().counter(
-                "hvd_moe_share_rows_total",
-                "rows of a share's walk in the steps read back: the held "
-                "experts' pairs, and the rows of the live tiles that the "
-                "grouped matmuls computed for them", kind=kind).inc(n)
+        for kind, n in counted.items():
+            get_registry().counter(*_SHARE_ROWS, kind=kind).inc(n)
     return live, built
 
 
@@ -357,14 +392,16 @@ def _rows_of(x: jax.Array, index: jax.Array) -> jax.Array:
 def _share_tiles_of(tile, x, order, weights, sizes, expert):
     """What both directions of a share's walk read: (the number of tiles
     the fullest held expert's pairs reach into, the function of a tile's
-    index that gives (its tokens, its rows' sorted positions (``k T`` for a
-    row that is no pair), its rows' router weights, its tokens' rows, the
-    experts as a function of (rows, *expert_weights))). A tile is one slot
-    of ``tile / count`` rows a held expert: slot ``e`` of tile ``i`` holds
+    index that gives (its tokens, the token a row goes back to (``T`` for a
+    row that is no pair: nowhere), its rows' sorted positions (``k T`` for
+    such a row), its rows' router weights, its tokens' rows, the experts as
+    a function of (rows, *expert_weights))). A tile is one slot of
+    ``tile / count`` rows a held expert: slot ``e`` of tile ``i`` holds
     expert ``e``'s pairs ``[i S, (i + 1) S)``, so the grouped matmul over a
-    tile is one batched product ``[count, S, k] x [count, k, n]``. A row
-    past its expert's last pair gathers some token in bounds and carries
-    weight 0."""
+    tile is one batched product ``[count, S, k] x [count, k, n]``, and the
+    tokens of a slot's pairs ascend (the sort is stable). A row past its
+    expert's last pair gathers some token in bounds and carries weight
+    0."""
     k, count = weights.shape[-1], sizes.shape[0]
     slot = tile // count
     pairs_in_all = order.shape[0]
@@ -393,7 +430,8 @@ def _share_tiles_of(tile, x, order, weights, sizes, expert):
         def experts(rows, *expert_weights):
             with moe_scope("moe_experts"):
                 return expert(dot, rows, *expert_weights)
-        return tokens, jnp.where(real, position, pairs_in_all), \
+        return tokens, jnp.where(real, tokens, x.shape[0]), \
+            jnp.where(real, position, pairs_in_all), \
             jnp.where(real, _rows_of(by_pair, pairs), 0.0), rows, experts
     return lax.div(jnp.max(sizes) + (slot - 1), slot), at
 
@@ -409,12 +447,11 @@ def _walk(x, order, inverse, weights, sizes, expert_weights, expert, tile):
     live, at = _share_tiles_of(tile, x, order, weights, sizes, expert)
 
     def one_tile(i, out):
-        tokens, _, weight, rows, experts = at(i)
+        _, back, _, weight, rows, experts = at(i)
         rows = experts(rows, *expert_weights)
         with moe_scope("moe_combine"):
-            return out.at[tokens].add(
-                weight[:, None] * rows.astype(jnp.float32),
-                mode="promise_in_bounds")
+            return add_rows_at_tokens(out, rows, weight, back,
+                                      sizes.shape[0], fresh=i == 0)
     return lax.fori_loop(0, live, one_tile, jnp.zeros(x.shape, jnp.float32))
 
 
@@ -437,7 +474,7 @@ def _walk_bwd(expert, tile, saved, g):
 
     def one_tile(i, carry):
         d_x, d_by_pair, d_experts = carry
-        tokens, position, weight, rows, experts = at(i)
+        tokens, back, position, weight, rows, experts = at(i)
         rows, pull = jax.vjp(experts, rows, *expert_weights)
         with moe_scope("moe_combine"):
             g_rows = _rows_of(g, tokens)
@@ -449,8 +486,8 @@ def _walk_bwd(expert, tile, saved, g):
         with moe_scope("moe_experts"):
             d_experts = tuple(a + d for a, d in zip(d_experts, d_tile))
         with moe_scope("moe_dispatch"):
-            d_x = d_x.at[tokens].add(d_rows.astype(jnp.float32),
-                                     mode="promise_in_bounds")
+            d_x = add_rows_at_tokens(d_x, d_rows, jnp.ones_like(weight),
+                                     back, sizes.shape[0], fresh=i == 0)
         return d_x, d_by_pair, d_experts
 
     d_x, d_by_pair, d_experts = lax.fori_loop(0, live, one_tile, (
@@ -659,8 +696,10 @@ def moe_dropless(x: jax.Array, route, expert: Callable,
     full load"). A share of fewer experts is :func:`_walk`: a tile is one
     slot of :func:`share_slot_rows` rows a held expert, the product over it
     one batched ``dot_general``, nothing is done for a tile past the fullest
-    held expert's last pair, and a live tile's weighted rows return to
-    their tokens by a scatter-add into float32 (module text, "A share").
+    held expert's last pair, and a live tile's weighted rows are added to
+    their tokens in float32 by
+    :func:`~horovod_tpu.ops.rows_to_tokens.add_rows_at_tokens`, token block
+    by token block in VMEM and not by a scatter (module text, "A share").
     """
     t, d = x.shape
     weights, experts, stats = route if isinstance(route, Routing) else \

@@ -696,23 +696,170 @@ def test_rows_of_padding_gather_in_bounds_and_return_zero_gradients(
     np.testing.assert_array_equal(back, np.full((10, 5), 2.0))
 
 
+def _walk_operands(ep, x, experts, weights, first, count, n_experts):
+    """What ``moe_dropless`` hands ``ep._walk``: the stable sort of the held
+    keys, its inverse, the held counts, the tile."""
+    k = experts.shape[-1]
+    keys = ep._held_first(experts.reshape(-1), first, count)
+    order = jnp.argsort(keys, stable=True)
+    sizes = jnp.sum(keys[:, None] == jnp.arange(count), axis=0,
+                    dtype=jnp.int32)
+    return order, jnp.argsort(order), weights, sizes, \
+        ep.share_tile_rows(k * x.shape[0], count, n_experts)
+
+
+def _walk_by_scatter_add(ep, x, order, weights, sizes, expert_weights,
+                         expert, tile):
+    """The walk as it was before PR 39, every built tile written out: a
+    tile's weighted rows return by ``jnp.zeros(...).at[tokens].add(...)`` in
+    float32, and ``d_x`` is JAX's own transpose of the gather: a
+    scatter-add, in float32 too since the rows are gathered from ``x`` in
+    float32 and only then take the experts' dtype."""
+    dtype, x = x.dtype, x.astype(jnp.float32)
+    at = ep._share_tiles_of(tile, x, order, weights, sizes, expert)[1]
+    out = jnp.zeros(x.shape, jnp.float32)
+    for i in range(-(-x.shape[0] // (tile // sizes.shape[0]))):
+        tokens, _, _, weight, rows, experts = at(i)
+        rows = experts(rows.astype(dtype), *expert_weights)
+        out = out + jnp.zeros(x.shape, jnp.float32).at[tokens].add(
+            weight[:, None] * rows.astype(jnp.float32))
+    return out
+
+
+def _walk_against_scatter_add(ep, x, order, inverse, weights, sizes,
+                              expert_weights, expert, tile, any_pair):
+    """The tokens' gradient under one seeded cotangent and the output of
+    ``ep._walk``, each against :func:`_walk_by_scatter_add`'s, float32."""
+    def walked(x):
+        return ep._walk(x, order, inverse, weights, sizes, expert_weights,
+                        expert, tile)
+
+    def scattered(x):
+        return _walk_by_scatter_add(ep, x, order, weights, sizes,
+                                    expert_weights, expert, tile)
+    cotangent = jnp.asarray(np.random.RandomState(8).randn(*x.shape),
+                            jnp.float32)
+    for got, want in zip(*(
+            jax.jit(lambda x, f=f: jax.vjp(f, x)[1](cotangent) + (f(x),))(x)
+            for f in (walked, scattered))):
+        assert got.dtype == want.dtype == jnp.float32
+        assert (float(jnp.abs(want).sum()) > 0) == any_pair
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5)
+
+
+WAY_BACK_CASES = {
+    # name: per-expert pairs of ``_choices`` (FIRST .. FIRST + 3 held)
+    "a-balanced-load": [24, 24, 24, 24],
+    "one-held-expert-sent-every-token": [96, 0, 0, 0],
+    "every-token-holds-k-held-experts": [96, 96, 96, 96],
+    "a-held-expert-sent-nothing": [30, 0, 41, 7],
+    "pairs-end-on-a-slots-edge": [40, 80, 40, 40],
+    "pairs-end-one-past-a-slots-edge": [41, 81, 1, 40],
+    "pairs-end-on-a-token-blocks-edge": [32, 64, 33, 31],
+}
+
+
+@pytest.mark.parametrize("case", sorted(WAY_BACK_CASES))
+def test_the_walks_way_back_is_the_scatter_adds(small_tiles, case):
+    """``ep._walk``, forward and the tokens' gradient, against the same
+    walk with its rows returned by ``at[tokens].add`` in float32: a balanced
+    load, one held expert sent every token (every tile live, each adding to
+    the one before), every token sending all its k pairs to held experts
+    (k rows a token), a held expert with no pair, pairs that end on and one
+    past a slot's edge (40) and a token block's (32)."""
+    ep, sizes = small_tiles, WAY_BACK_CASES[case]
+    w = _share_weights(5)
+    x = jnp.asarray(np.random.RandomState(6).randn(T, D), jnp.float32)
+    experts = _choices(sizes)
+    weights = jnp.take_along_axis(jax.nn.sigmoid(x @ w["router"]), experts,
+                                  axis=-1)
+    order, inverse, weights, held_sizes, tile = _walk_operands(
+        ep, x, experts, weights, FIRST, COUNT, E)
+    assert tile == TILE and list(held_sizes) == sizes
+    held = (w["up"][FIRST:FIRST + COUNT], w["down"][FIRST:FIRST + COUNT])
+
+    _walk_against_scatter_add(ep, x, order, inverse, weights, held_sizes,
+                              held, relu2_expert, tile, sum(sizes) > 0)
+
+
+@pytest.mark.parametrize("cell", ["smallthinker", "nemotron"])
+def test_the_way_back_at_both_cells_shapes_scaled_down(cell):
+    """Eight of the router's experts held, top-6, row blocks of 128 as
+    built: SmallThinker's layer (64 experts, ReGLU, the softmax of the
+    chosen logits) at a hidden size of two 128s and Nemotron-H's (128
+    experts, relu^2, sigmoid scores) at three; the output and the tokens'
+    gradient of the walk against the scatter-add's (in float32: two
+    programs in bf16 differ by where the compiler rounds, not by the way
+    back; ``tests/test_rows_to_tokens.py`` returns bf16 rows)."""
+    from horovod_tpu.parallel import ep
+    rng = np.random.RandomState(11)
+    if cell == "smallthinker":
+        tokens, d, f, n_experts = 512, 256, 64, 64
+        expert, shapes = reglu_expert, [(d, f), (d, f), (f, d)]
+        route = functools.partial(route_topk_softmax, k=6)
+    else:
+        tokens, d, f, n_experts = 1024, 384, 48, 128
+        expert, shapes = relu2_expert, [(d, f), (f, d)]
+        route = functools.partial(route_sigmoid_topk, k=6, scale=2.5,
+                                  bias=jnp.zeros(n_experts))
+    x = jnp.asarray(rng.randn(tokens, d), jnp.float32)
+    router = jnp.asarray(rng.randn(d, n_experts) * 0.1, jnp.float32)
+    held = tuple(jnp.asarray(rng.randn(8, *shape) * 0.1, jnp.float32)
+                 for shape in shapes)
+    weights, experts, _, _ = route(x, router)
+    order, inverse, weights, sizes, tile = _walk_operands(
+        ep, x, experts, weights, 0, 8, n_experts)
+    assert tile == 8 * 128 < 6 * tokens and int(sizes.max()) <= 128
+
+    _walk_against_scatter_add(ep, x, order, inverse, weights, sizes, held,
+                              expert, tile, True)
+
+
+def _primitives(jaxpr, seen=None):
+    """{primitive name: [eqn, ...]} of a jaxpr and all it encloses, a Pallas
+    kernel's body left out (its loops are the kernel's, not the walk's)."""
+    seen = {} if seen is None else seen
+    for eqn in jaxpr.eqns:
+        seen.setdefault(eqn.primitive.name, []).append(eqn)
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _primitives(sub, seen)
+    return seen
+
+
 def test_a_walk_has_each_grouped_matmul_once_a_direction(small_tiles):
     """Three tiles and still two grouped matmuls forward and six in the
     backward walk (the two again, and their four transposes): the walk is a
     loop, not an unrolling, and has no fallback of its own. Its product is
     one batched ``dot_general`` over the tile's slots, an expert a batch
-    entry; no ``ragged_dot`` is left in it."""
+    entry; no ``ragged_dot`` is left in it. **No row goes back to its token
+    by a scatter**: the way back is ``ops/rows_to_tokens``'s kernel, once
+    forward (the weighted rows) and once backward (the rows' gradient), each
+    lowered for the TPU and in interpret mode elsewhere (a ``cond`` on the
+    platform, traced both ways), and the one scatter left is the backward
+    walk's, of a scalar a pair."""
     batched = "([0], [0]))"  # dot_generals with an expert a batch entry
     w = _share_weights()
     x = jnp.zeros((T, D), jnp.float32)
-    forward = str(jax.make_jaxpr(lambda x, w: _share(x, w, 4, 4))(x, w))
-    assert forward.count(batched) == 2
-    assert forward.count("while[") == 1 and "cond[" not in forward
-    both = str(jax.make_jaxpr(jax.grad(
-        lambda x, w: _share(x, w, 4, 4)[0].sum(), argnums=(0, 1)))(x, w))
-    assert both.count(batched) == 2 + 6
-    assert both.count("while[") == 2 and "cond[" not in both
-    assert "ragged_dot_general" not in forward + both
+    forward = jax.make_jaxpr(lambda x, w: _share(x, w, 4, 4))(x, w)
+    assert str(forward).count(batched) == 2
+    both = jax.make_jaxpr(jax.grad(
+        lambda x, w: _share(x, w, 4, 4)[0].sum(), argnums=(0, 1)))(x, w)
+    assert str(both).count(batched) == 2 + 6
+    assert "ragged_dot_general" not in str(forward) + str(both)
+    for jaxpr, loops, ways_back in ((forward, 1, 1), (both, 2, 2)):
+        seen = _primitives(jaxpr.jaxpr)
+        assert len(seen["while"]) == loops
+        assert len(seen["cond"]) == ways_back  # the platform's, no other
+        assert sorted(eqn.params["interpret"] for eqn in
+                      seen["pallas_call"]) == \
+            [False] * ways_back + [True] * ways_back
+        assert "scatter-add" not in seen and "scatter_add" not in seen
+        scattered = [eqn.outvars[0].aval.shape
+                     for eqn in seen.get("scatter", [])]
+        assert scattered == ([(K_T,)] if jaxpr is both else [])
 
 
 def test_share_tile_rule_and_live_tiles_by_hand():
@@ -750,6 +897,45 @@ def test_share_tile_rule_and_live_tiles_by_hand():
     load = jnp.zeros(16).at[4:8].set(jnp.asarray([100., 0., 45., 0.]))
     assert ep.share_tiles(load, (4, 4), K_SHARE, T) == (1, 1)
     assert ep.share_tiles(load, (8, 4), K_SHARE, T) == (0, 1)
+
+
+@pytest.mark.parametrize("sizes,fetched", [
+    ([39, 12, 39, 0], 8 * (7 + 4 + 7)), ([0, 0, 0, 0], 0),
+    ([96, 81, 5, 80], 8 * (7 + 7 + 4 + 7 + 7 + 1 + 3 + 7 + 7)),
+    ([1, 0, 0, 2], 8 * (1 + 2))],
+    ids=["a-tile", "no-pair", "three-tiles", "three-pairs"])
+def test_the_way_back_counts_the_rows_it_fetches(small_tiles, sizes,
+                                                 fetched):
+    """``hvd_moe_share_rows_total{kind="fetched"}``, from the load alone: a
+    slot's ``n`` rows in a live tile are ``ceil(n / 8)`` chunks of 8 rows,
+    and a chunk is fetched again for each of the 3 token blocks of 32 it
+    holds rows of, ``min(n, 3) - 1`` more at most. The jobs
+    ``ops/rows_to_tokens`` makes of the same routing (expert ``e``'s pairs
+    are the first ``sizes[e]`` tokens' here) fetch no more than that."""
+    from horovod_tpu.metrics.registry import get_registry
+    from horovod_tpu.ops import rows_to_tokens as rt
+
+    def rows(kind):
+        return get_registry().counter("hvd_moe_share_rows_total",
+                                      kind=kind).value
+    load = np.zeros(E)
+    load[FIRST:FIRST + COUNT] = sizes
+    before = {kind: rows(kind) for kind in ("held", "computed", "fetched")}
+    live, _ = small_tiles.share_tiles(load, (FIRST, COUNT), K_SHARE, T,
+                                      record=True)
+    assert rows("held") == before["held"] + sum(sizes)
+    assert rows("computed") == before["computed"] + live * TILE
+    assert rows("fetched") == before["fetched"] + fetched
+    assert rt.chunk_rows_of(SLOT) == 8 and rt.block_tokens_of(T) == 32
+    jobs = 0
+    for i in range(live):
+        at = np.full((COUNT, SLOT), T, np.int32)
+        for e, n in enumerate(sizes):
+            mine = np.arange(i * SLOT, min(n, (i + 1) * SLOT))
+            at[e, :len(mine)] = mine
+        _, _, r0, r1, _ = rt._jobs_of(jnp.asarray(at.reshape(-1)), T, COUNT)
+        jobs += int(np.sum(np.asarray(r1) > np.asarray(r0)))
+    assert 8 * jobs <= fetched and (jobs > 0) == (sum(sizes) > 0)
 
 
 def test_the_walk_counts_its_tiles_in_the_registry(small_tiles):

@@ -428,6 +428,47 @@ def _kernel_calls(text):
     return kernels.inventory(hlo), op_names
 
 
+@pytest.mark.parametrize("rows_dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16-rows", "float32-rows"])
+@pytest.mark.parametrize("tokens,d,tile", [
+    (16384, 2560, 18432), (8192, 2688, 5120)],
+    ids=["smallthinker-t16384", "nemotron3n-t8192"])
+def test_rows_to_tokens_kernel_compiles_for_v5e(topo, tokens, d, tile,
+                                                rows_dtype):
+    """A live tile's way back at both share cells' shapes (eight slots; 20
+    and 21 lanes of 128): one custom call, the float32 result aliased to
+    the operand it adds to (no copy of ``[T, d]`` beside the kernel), the
+    tokens and weights of a tile and the job list in scalar memory, and the
+    job list made without a sort or a scatter."""
+    from horovod_tpu.ops import rows_to_tokens as rt
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    compiled = jax.jit(
+        lambda out, rows, weight, at, fresh: rt.add_rows_at_tokens(
+            out, rows, weight, at, 8, fresh), donate_argnums=0).lower(
+        arg((tokens, d), jnp.float32), arg((tile, d), rows_dtype),
+        arg((tile,), jnp.float32), arg((tile,), jnp.int32),
+        arg((), jnp.bool_)).compile()
+    text = compiled.as_text()
+    calls, _ = _kernel_calls(text)
+    assert calls == {"_add_rows_kernel": 1}
+    opcodes = set(re.findall(r"[\s)]([a-z\-]+)\(", text))
+    assert not opcodes & {"sort", "scatter", "while"}, opcodes
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == memory.output_size_in_bytes \
+        == tokens * d * 4
+    assert memory.temp_size_in_bytes < 1 << 20
+
+
+def _row_scatters(text):
+    """The shapes of the scatters of rows under an expert layer's scopes:
+    a share's walk has none (its one scatter is of a scalar a pair)."""
+    return [shape for shape in re.findall(
+        r"= \w+(\[[\d,]*\])\S* scatter\([^\n]*moe_", text) if "," in shape]
+
+
 def test_nemotron_cell_holds_its_kernels_and_scopes(nemotron_cell):
     """Three flash kernels (the attention block keeps its activations) and
     the scan's kernels once a mixer layer and pass they are traced for: the
@@ -456,8 +497,17 @@ def test_nemotron_cell_holds_its_kernels_and_scopes(nemotron_cell):
     assert mixers == 4
     assert calls == {
         "_fwd_kernel": 1, "_bwd_dq_kernel": 1, "_bwd_dkv_kernel": 1,
-        "_ssd_fwd_kernel": 2 * mixers, "_ssd_bwd_kernel": mixers}
-    assert "ragged-dot" not in text
+        "_ssd_fwd_kernel": 2 * mixers, "_ssd_bwd_kernel": mixers,
+        # a live tile's rows back to their tokens: the weighted rows
+        # forward and the rows' gradient backward, once a layer each (the
+        # recomputed forward walk's result is needed by nothing, and goes)
+        "_add_rows_kernel": 2 * expert_layers}
+    way_back = op_names["_add_rows_kernel"]
+    assert sorted("transpose(jvp(" in name for name in way_back) == \
+        [False] * expert_layers + [True] * expert_layers
+    assert all(("moe_dispatch" if "transpose(jvp(" in name
+                else "moe_combine") in name for name in way_back)
+    assert "ragged-dot" not in text and not _row_scatters(text)
     slot = ep.share_slot_rows(6 * 8192, 128)
     assert slot == 640 and ep.share_tile_rows(6 * 8192, 8, 128) == 8 * slot
     products = re.findall(
@@ -522,8 +572,14 @@ def test_smallthinker_cell_holds_causal_and_window_kernels_side_by_side(
     assert calls == {
         "_fwd_kernel": 2, "_bwd_dq_kernel": 2, "_bwd_dkv_kernel": 2,
         "_fwd_window_kernel": 6, "_bwd_dq_window_kernel": 6,
-        "_bwd_dkv_window_kernel": 6}
+        "_bwd_dkv_window_kernel": 6, "_add_rows_kernel": 2 * 8}
     assert job.flash_layers == 2 and job.facts["window_layers"] == 6
+    way_back = op_names.pop("_add_rows_kernel")
+    assert sum("moe_combine" in name and "transpose(" not in name
+               for name in way_back) == 8
+    assert sum("moe_dispatch" in name and "transpose(jvp(" in name
+               for name in way_back) == 8
+    assert not _row_scatters(text)
     for kernel, names in op_names.items():
         scope = "attn_window" if "window" in kernel else "attn_full"
         assert all(scope in name for name in names), kernel
