@@ -15,6 +15,13 @@ from horovod_tpu.models.olmoe import (  # noqa: F401
     OlmoeDecoder,
     olmoe_loss,
 )
+from horovod_tpu.models.sdar import (  # noqa: F401
+    Sdar30BA3B,
+    SdarMoeDecoder,
+    SdarTiny,
+    sdar_loss,
+    sdar_noise,
+)
 from horovod_tpu.models.smallthinker import (  # noqa: F401
     SmallThinker21BA3B,
     SmallThinkerDecoder,

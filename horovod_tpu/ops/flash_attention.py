@@ -32,6 +32,18 @@ composes with:
   ``_bwd_dkv_window_kernel``), so the compiled text and a device trace tell
   window layers from causal ones; without a window the traced bodies are
   what they were.
+- **a causal edge rounded to blocks of tokens**: ``block_mask=(G, edge)``
+  (static) compares block indices ``b(i) = i // G`` instead of positions:
+  ``"le"`` keeps ``b(k) <= b(q)`` (block-causal), ``"lt"`` keeps
+  ``b(k) < b(q)`` (the blocks before the query's own). They are the two
+  masks a block-diffusion objective lays over the clean stream's keys
+  (:func:`blockdiff_attention`, which adds the ``G x G`` tiles of a noised
+  block on itself). The same bodies again, under
+  ``_fwd_blockdiff_kernel``, ``_bwd_dq_blockdiff_kernel`` and
+  ``_bwd_dkv_blockdiff_kernel``: the edge is a row of ``block_q`` values
+  compared with a column of key positions, and the loops' bounds are the
+  causal ones moved by a block (``"lt"``), so a tile no pair of which is
+  visible is never loaded.
 - **custom VJP**: backward is two Pallas kernels (dq gridded over q tiles,
   dk/dv gridded over k tiles) recomputing probabilities from the saved lse,
   the standard flash backward. The lse output is differentiable too
@@ -140,6 +152,50 @@ def _window_num_q(q_off, k_off, kj, block_q, block_k, num_q, window):
     return jnp.clip(eff, 0, num_q).astype(jnp.int32)
 
 
+BLOCK_EDGES = ("le", "lt")  # b(k) <= b(q), b(k) < b(q)
+
+
+def _behind(block_mask) -> int:
+    """How far the last key a query tile sees lies behind the tile's last
+    position, where the tile ends on a block's end: 0 keys under ``"le"``
+    (the causal bound), ``G`` under ``"lt"``."""
+    return 0 if block_mask[1] == "le" else block_mask[0]
+
+
+def _visible_blocks(q_base, k_base, shape, q_dim, block_mask):
+    """``b(k) <= b(q)`` (``"le"``) or ``b(k) < b(q)`` (``"lt"``), ``b(i) = i
+    // G``, over a score tile laid out as :func:`_visible`'s: the last key a
+    query sees, ``G b(q) + G - 1`` or ``G b(q) - 1``, is computed along the
+    tile's q axis alone (a row or a column of the tile, not the tile) and
+    compared with the keys' positions along the other. Positions are fp32
+    (exact below 2^24); ``floor((q + 0.5) / G)`` is ``b(q)`` for whatever
+    ``G`` up to 2^22 positions, an inexact reciprocal included."""
+    group, edge = block_mask
+    q_shape = tuple(n if d == q_dim else 1 for d, n in enumerate(shape))
+    k_shape = tuple(1 if d == q_dim else n for d, n in enumerate(shape))
+    q_pos = (q_base + lax.broadcasted_iota(jnp.int32, q_shape, q_dim)
+             ).astype(jnp.float32)
+    k_pos = (k_base + lax.broadcasted_iota(jnp.int32, k_shape, 1 - q_dim)
+             ).astype(jnp.float32)
+    first = jnp.floor((q_pos + 0.5) * (1.0 / group)) * group
+    return k_pos <= first + (group - 1 if edge == "le" else -1)
+
+
+def _blocks_num_k(qi, block_q, block_k, num_k, block_mask):
+    """:func:`_causal_num_k` under a block mask (no offsets, ``G`` divides
+    the tiles): the k blocks up to the one that holds the last key the q
+    tile's last block sees."""
+    last = (qi + 1) * block_q - 1 - _behind(block_mask)
+    return jnp.clip(last // block_k + 1, 0, num_k).astype(jnp.int32)
+
+
+def _blocks_first_q(kj, block_q, block_k, num_q, block_mask):
+    """The first q tile one of whose blocks sees the k tile's first key
+    (the dk/dv kernel's lower bound under a block mask)."""
+    first = (kj * block_k + _behind(block_mask)) // block_q
+    return jnp.clip(first, 0, num_q).astype(jnp.int32)
+
+
 def _scale_operand(x, sm_scale: float):
     """``(x * sm_scale, True)`` where that is exact: a power of two (heads
     of 64: 0.125) multiplies an operand of any float dtype without rounding,
@@ -173,7 +229,8 @@ def _dot(a, b, dims):
 
 def _fwd_body(qo_ref, ko_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *,
               block_q: int, block_k: int, causal: bool, sm_scale: float,
-              kv_len: int, window: Optional[int] = None):
+              kv_len: int, window: Optional[int] = None,
+              block_mask: Optional[tuple] = None):
     """One q tile against the k blocks it sees, on transposed scores
     ``k q^T`` [block_k, block_q]: a q row's statistics then lie along lanes
     ([1, block_q], four registers where a [block_q, 1] column takes 64), the
@@ -191,7 +248,10 @@ def _fwd_body(qo_ref, ko_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *,
         s = _dot(k, q, _NT)
         if not scaled:
             s = s * sm_scale
-        if causal:
+        if block_mask is not None:  # static, like the window
+            s = jnp.where(_visible_blocks(qi * block_q, kj * block_k,
+                                          s.shape, 1, block_mask), s, NEG_INF)
+        elif causal:
             s = jnp.where(_visible(q_off, k_off, qi * block_q, kj * block_k,
                                    s.shape, 1, window), s, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
@@ -206,7 +266,9 @@ def _fwd_body(qo_ref, ko_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *,
     if window is not None:  # static: without one the body is what it was
         first_k = _window_first_k(q_off, k_off, qi, block_q, block_k, num_k,
                                   window)
-    if causal:
+    if block_mask is not None:
+        num_k = _blocks_num_k(qi, block_q, block_k, num_k, block_mask)
+    elif causal:
         num_k = _causal_num_k(q_off, k_off, qi, block_q, block_k, num_k)
     m, l, acc = lax.fori_loop(
         first_k, num_k, body,
@@ -229,7 +291,8 @@ def _fwd_body(qo_ref, ko_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *,
 def _bwd_dq_body(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                  corr_ref, dq_ref, *, block_q: int, block_k: int,
                  causal: bool, sm_scale: float, kv_len: int,
-                 window: Optional[int] = None):
+                 window: Optional[int] = None,
+                 block_mask: Optional[tuple] = None):
     """dq for one q tile: loop k tiles, recompute p from lse, accumulate
     ``k^T ds^T`` [d, block_q], all on transposed scores like the forward.
     ``corr`` is (dlse - delta) precomputed on host-side JAX; it and ``lse``
@@ -249,7 +312,10 @@ def _bwd_dq_body(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         if not scaled:
             s = s * sm_scale
         p = jnp.exp(s - lse)
-        if causal:
+        if block_mask is not None:
+            p = jnp.where(_visible_blocks(qi * block_q, kj * block_k,
+                                          s.shape, 1, block_mask), p, 0.0)
+        elif causal:
             p = jnp.where(_visible(q_off, k_off, qi * block_q, kj * block_k,
                                    s.shape, 1, window), p, 0.0)
         ds = p * (_dot(v, do, _NT) + corr)
@@ -260,7 +326,9 @@ def _bwd_dq_body(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     if window is not None:
         first_k = _window_first_k(q_off, k_off, qi, block_q, block_k, num_k,
                                   window)
-    if causal:
+    if block_mask is not None:
+        num_k = _blocks_num_k(qi, block_q, block_k, num_k, block_mask)
+    elif causal:
         num_k = _causal_num_k(q_off, k_off, qi, block_q, block_k, num_k)
     dq = lax.fori_loop(first_k, num_k, body,
                        jnp.zeros((q.shape[-1], block_q), jnp.float32))
@@ -270,7 +338,8 @@ def _bwd_dq_body(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 def _bwd_dkv_body(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                   corr_ref, dk_ref, dv_ref, *, block_q: int, block_k: int,
                   causal: bool, sm_scale: float, q_len: int,
-                  window: Optional[int] = None):
+                  window: Optional[int] = None,
+                  block_mask: Optional[tuple] = None):
     """dk/dv for one k tile: loop q tiles (starting past fully-causal-masked
     ones), recompute p, accumulate p^T @ do and ds^T @ q.
 
@@ -310,7 +379,11 @@ def _bwd_dkv_body(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         if not scaled:
             s = s * sm_scale
         p = jnp.exp(s - lse)
-        if causal:
+        if block_mask is not None:
+            p = jnp.where(_visible_blocks(i * block_q, kj * block_k, s.shape,
+                                          0 if narrow else 1, block_mask),
+                          p, 0.0)
+        elif causal:
             p = jnp.where(_visible(q_off, k_off, i * block_q, kj * block_k,
                                    s.shape, 0 if narrow else 1, window),
                           p, 0.0)
@@ -320,7 +393,9 @@ def _bwd_dkv_body(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
     num_q = q_len // block_q
     start = 0
-    if causal:
+    if block_mask is not None:
+        start = _blocks_first_q(kj, block_q, block_k, num_q, block_mask)
+    elif causal:
         # first q tile whose max q position reaches this k tile's start
         min_k_pos = k_off + kj * block_k
         s0 = jnp.floor((min_k_pos - q_off) / block_q)
@@ -340,9 +415,10 @@ def _bwd_dkv_body(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 # The kernel functions proper are thin: a call's name in the compiled text
 # and in a device trace is that of the ``*_kernel`` function it was traced
 # through (the Mosaic bytecode carries the frames), and a windowed call has a
-# name of its own. A trace then tells window layers from causal ones, and a
-# reader that costs ``_fwd_kernel`` at the causal pair count never meets a
-# window call. The bodies above are shared; ``window`` is static in them.
+# name of its own, as has one under a block mask. A trace then tells window
+# and block-diffusion layers from causal ones, and a reader that costs
+# ``_fwd_kernel`` at the causal pair count never meets their calls. The
+# bodies above are shared; ``window`` and ``block_mask`` are static in them.
 def _fwd_kernel(*refs, **static):
     _fwd_body(*refs, **static)
 
@@ -364,6 +440,18 @@ def _bwd_dq_window_kernel(*refs, **static):
 
 
 def _bwd_dkv_window_kernel(*refs, **static):
+    _bwd_dkv_body(*refs, **static)
+
+
+def _fwd_blockdiff_kernel(*refs, **static):
+    _fwd_body(*refs, **static)
+
+
+def _bwd_dq_blockdiff_kernel(*refs, **static):
+    _bwd_dq_body(*refs, **static)
+
+
+def _bwd_dkv_blockdiff_kernel(*refs, **static):
     _bwd_dkv_body(*refs, **static)
 
 
@@ -394,31 +482,34 @@ def _vmem_params(*blocks):
         vmem_limit_bytes=min(int(1.25 * held) + (8 << 20), 100 << 20))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
 def _flash(q, k, v, q_off, k_off, causal, sm_scale, block_q, block_k,
-           interpret, window=None):
+           interpret, window=None, block_mask=None):
     o, lse, _ = _flash_fwd(q, k, v, q_off, k_off, causal, sm_scale,
-                           block_q, block_k, interpret, window)
+                           block_q, block_k, interpret, window, block_mask)
     return o, lse
 
 
-def _kernel(plain, windowed, window, **static):
-    """The kernel function of a call: ``plain`` as it always was, or with a
-    window ``windowed``, the same body under its own name."""
+def _kernel(plain, windowed, blockdiff, window, block_mask, **static):
+    """The kernel function of a call: ``plain`` as it always was, with a
+    window ``windowed``, under a block mask ``blockdiff``: the same body
+    under a name of its own."""
+    if block_mask is not None:
+        return functools.partial(blockdiff, block_mask=block_mask, **static)
     if window is None:
         return functools.partial(plain, **static)
     return functools.partial(windowed, window=window, **static)
 
 
 def _flash_fwd(q, k, v, q_off, k_off, causal, sm_scale, block_q, block_k,
-               interpret, window=None):
+               interpret, window=None, block_mask=None):
     b, tq, h, d = q.shape
     tk, dv = k.shape[1], v.shape[-1]
     qb, kb, vb = _bh_first(q), _bh_first(k), _bh_first(v)
     grid = (b * h, tq // block_q)
-    kernel = _kernel(_fwd_kernel, _fwd_window_kernel, window,
-                     block_q=block_q, block_k=block_k, causal=causal,
-                     sm_scale=sm_scale, kv_len=tk)
+    kernel = _kernel(_fwd_kernel, _fwd_window_kernel, _fwd_blockdiff_kernel,
+                     window, block_mask, block_q=block_q, block_k=block_k,
+                     causal=causal, sm_scale=sm_scale, kv_len=tk)
     o, lse = pl.pallas_call(
         kernel,
         grid=grid,
@@ -449,14 +540,15 @@ def _flash_fwd(q, k, v, q_off, k_off, causal, sm_scale, block_q, block_k,
 
 
 def _flash_fwd_vjp(q, k, v, q_off, k_off, causal, sm_scale, block_q,
-                   block_k, interpret, window=None):
+                   block_k, interpret, window=None, block_mask=None):
     o, lse_out, res = _flash_fwd(q, k, v, q_off, k_off, causal, sm_scale,
-                                 block_q, block_k, interpret, window)
+                                 block_q, block_k, interpret, window,
+                                 block_mask)
     return (o, lse_out), res
 
 
-def _flash_bwd(causal, sm_scale, block_q, block_k, interpret, window, res,
-               cots):
+def _flash_bwd(causal, sm_scale, block_q, block_k, interpret, window,
+               block_mask, res, cots):
     q, k, v, o, lse, q_off, k_off = res
     do, dlse = cots
     b, tq, h, d = q.shape
@@ -473,7 +565,8 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, interpret, window, res,
     qb, kb, vb = _bh_first(q), _bh_first(k), _bh_first(v)
 
     dq = pl.pallas_call(
-        _kernel(_bwd_dq_kernel, _bwd_dq_window_kernel, window,
+        _kernel(_bwd_dq_kernel, _bwd_dq_window_kernel,
+                _bwd_dq_blockdiff_kernel, window, block_mask,
                 block_q=block_q, block_k=block_k, causal=causal,
                 sm_scale=sm_scale, kv_len=tk),
         grid=(b * h, tq // block_q),
@@ -496,7 +589,8 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, interpret, window, res,
     )(q_off, k_off, qb, kb, vb, dob, lse, corr)
 
     dk, dvv = pl.pallas_call(
-        _kernel(_bwd_dkv_kernel, _bwd_dkv_window_kernel, window,
+        _kernel(_bwd_dkv_kernel, _bwd_dkv_window_kernel,
+                _bwd_dkv_blockdiff_kernel, window, block_mask,
                 block_q=block_q, block_k=block_k, causal=causal,
                 sm_scale=sm_scale, q_len=tq),
         grid=(b * h, tk // block_k),
@@ -550,7 +644,8 @@ def _pick_block(t: int, preferred: int) -> int:
 
 def block_plan(tq: int, tk: int, block_q: int, block_k: int, causal: bool,
                q_offset: int = 0, k_offset: int = 0,
-               window: Optional[int] = None) -> dict:
+               window: Optional[int] = None,
+               block_mask: Optional[tuple] = None) -> dict:
     """Block visits of one (batch, head), the same for all three kernels:
     every score of an ``interior`` block is visible, the diagonal crosses a
     ``diagonal`` block (half its work is masked away), ``skipped`` blocks
@@ -561,8 +656,15 @@ def block_plan(tq: int, tk: int, block_q: int, block_k: int, causal: bool,
     With a ``window`` two kinds more: a ``window_edge`` block is crossed by
     the window's far edge alone (one the causal edge crosses too counts as
     ``diagonal``), a ``skipped_behind`` block lies wholly behind the window
-    and is never loaded either. The five counts sum to the grid."""
+    and is never loaded either. The five counts sum to the grid.
+
+    Under a ``block_mask`` the three kinds of the causal plan, by the mask's
+    own edge: ``interior`` where every key's block is visible to every
+    query's, ``diagonal`` where the edge crosses, ``skipped`` where no pair
+    is visible (never loaded); they sum to the grid."""
     num_q, num_k = tq // block_q, tk // block_k
+    if block_mask is not None:
+        return _blocks_block_plan(num_q, num_k, block_q, block_k, block_mask)
     if window is not None:
         return _window_block_plan(num_q, num_k, block_q, block_k,
                                   q_offset - k_offset, window)
@@ -603,19 +705,67 @@ def _window_block_plan(num_q: int, num_k: int, block_q: int, block_k: int,
     return plan
 
 
+def _blocks_block_plan(num_q: int, num_k: int, block_q: int, block_k: int,
+                       block_mask: tuple) -> dict:
+    """:func:`block_plan` under a block mask, a tile at a time from the last
+    key its first and its last query see."""
+    group, edge = block_mask
+    shift = group - 1 if edge == "le" else -1
+    plan = dict.fromkeys(("interior", "diagonal", "skipped"), 0)
+    for qi in range(num_q):
+        least = qi * block_q // group * group + shift
+        largest = ((qi + 1) * block_q - 1) // group * group + shift
+        for kj in range(num_k):
+            if kj * block_k > largest:
+                kind = "skipped"
+            elif (kj + 1) * block_k - 1 <= least:
+                kind = "interior"
+            else:
+                kind = "diagonal"
+            plan[kind] += 1
+    return plan
+
+
 WINDOW_KIND = "window_"  # a window call's blocks, in the counter below
+BLOCKDIFF_KIND = "blockdiff_"  # and a call's under a block mask
 
 
-def _count_block_visits(plan: dict, batch_heads: int, windowed: bool):
+def _noised_key_tiles(seq: int, block_q: int, block_k: int) -> int:
+    """Tiles of the two quadrants of the ``[2 seq, 2 seq]`` grid whose keys
+    are the noised stream's."""
+    return 2 * (seq // block_q) * (seq // block_k)
+
+
+def blockdiff_block_plan(seq: int, block_q: int, block_k: int,
+                         group: int) -> dict:
+    """Tiles of the ``[2 seq, 2 seq]`` grid of one (batch, head) of
+    :func:`blockdiff_attention`, by what its two kernel calls do with them:
+    the clean queries' ``"le"`` call and the noised queries' ``"lt"`` call
+    over the clean keys (``interior``, ``diagonal``, ``skipped`` of
+    :func:`block_plan`, summed), and ``noised_keys``: the two quadrants whose
+    keys are the noised stream's, which no kernel loads (clean queries see
+    none of them; a noised query sees its own block's ``group`` keys, through
+    :func:`block_diagonal_attention`). The four counts sum to the grid."""
+    plan = {"noised_keys": _noised_key_tiles(seq, block_q, block_k)}
+    for edge in BLOCK_EDGES:
+        for kind, n in block_plan(seq, seq, block_q, block_k, True,
+                                  block_mask=(group, edge)).items():
+            plan[kind] = plan.get(kind, 0) + n
+    return plan
+
+
+def _count_block_visits(plan: dict, batch_heads: int, prefix: str = ""):
     """Monitoring, at trace time like ``collectives._count_trace``: the
     blocks of each kind in what was just traced. A windowed call counts
     under kinds of its own (``window_interior`` ... ``window_edge`` ...
     ``window_skipped_behind``): the share of its grid it never loads is then
-    read apart from the causal calls'."""
+    read apart from the causal calls'; so does a call under a block mask
+    (``blockdiff_interior``, ``blockdiff_diagonal``, ``blockdiff_skipped``,
+    and ``blockdiff_noised_keys`` from :func:`blockdiff_attention`)."""
     from horovod_tpu.metrics.registry import get_registry
     for kind, visits in plan.items():
-        if windowed and not kind.startswith(WINDOW_KIND):
-            kind = WINDOW_KIND + kind
+        if prefix and not kind.startswith(prefix):
+            kind = prefix + kind
         get_registry().counter(
             "hvd_flash_block_visits",
             "flash-attention block visits traced, by kind of block",
@@ -638,7 +788,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     interpret: Optional[bool] = None,
                     q_offset=None, k_offset=None,
                     return_lse: bool = False,
-                    window: Optional[int] = None):
+                    window: Optional[int] = None,
+                    block_mask: Optional[Tuple[int, str]] = None):
     """softmax(QK^T)V without materializing the score matrix.
 
     q: [B, Tq, H, D]; k/v: [B, Tk, H, D(v)]. Block sizes shrink to divisors
@@ -657,14 +808,28 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     that still sees its k tile (:func:`block_plan` counts the kinds), under
     kernel names of their own (``_fwd_window_kernel``, ...). ``window=None``
     is the program without one.
+
+    ``block_mask=(G, edge)`` (static, causal only, no window and no offsets)
+    rounds the causal edge to blocks of ``G`` positions, ``b(i) = i // G``:
+    ``"le"`` keeps ``b(k) <= b(q)``, ``"lt"`` keeps ``b(k) < b(q)`` (a query
+    of block 0 then sees no key: output 0, ``lse = NEG_INF``). ``G`` divides
+    both tiles. The kernels run under ``_fwd_blockdiff_kernel``, ...;
+    ``(1, "le")`` is the causal mask. ``None`` is the program without one.
     """
     b, tq, h, d = q.shape
     scale = sm_scale if sm_scale is not None else d ** -0.5
     window = _checked_window(window, causal)
+    block_mask = _checked_block_mask(
+        block_mask, causal, window, q_offset is None and k_offset is None)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     block_q = _pick_block(tq, block_q)
     block_k = _pick_block(k.shape[1], block_k)
+    if block_mask is not None and (block_q % block_mask[0]
+                                   or block_k % block_mask[0]):
+        raise ValueError(
+            f"blocks of {block_mask[0]} positions do not divide the tiles "
+            f"({block_q} queries, {block_k} keys) of {tq} x {k.shape[1]}")
     q_off = (jnp.zeros((1,), jnp.float32) if q_offset is None
              else jnp.asarray(q_offset, jnp.float32).reshape(1))
     k_off = (jnp.zeros((1,), jnp.float32) if k_offset is None
@@ -673,10 +838,29 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     if None not in offsets:
         _count_block_visits(
             block_plan(tq, k.shape[1], block_q, block_k, causal, *offsets,
-                       window=window), b * h, window is not None)
+                       window=window, block_mask=block_mask), b * h,
+            BLOCKDIFF_KIND if block_mask is not None else
+            WINDOW_KIND if window is not None else "")
     o, lse = _flash(q, k, v, q_off, k_off, causal, scale, block_q, block_k,
-                    interpret, window)
+                    interpret, window, block_mask)
     return (o, lse) if return_lse else o
+
+
+def _checked_block_mask(block_mask, causal: bool, window,
+                        no_offsets: bool = True) -> Optional[tuple]:
+    """The block mask as a static ``(G, edge)``, or None; it rounds a causal
+    edge, in positions that start at 0 on both sides."""
+    if block_mask is None:
+        return None
+    group, edge = block_mask
+    if not causal or window is not None or not no_offsets \
+            or int(group) < 1 or edge not in BLOCK_EDGES:
+        raise ValueError(
+            f"block_mask={block_mask!r} keeps b(k) <= b(q) ('le') or b(k) < "
+            f"b(q) ('lt') with b(i) = i // G, G >= 1: it needs causal=True "
+            f"(got {causal}), no window (got {window}) and no q_offset or "
+            f"k_offset")
+    return int(group), edge
 
 
 def _checked_window(window, causal: bool) -> Optional[int]:
@@ -693,18 +877,21 @@ def _checked_window(window, causal: bool) -> Optional[int]:
 def xla_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                   causal: bool = False,
                   sm_scale: Optional[float] = None,
-                  window: Optional[int] = None) -> jax.Array:
+                  window: Optional[int] = None,
+                  block_mask: Optional[Tuple[int, str]] = None) -> jax.Array:
     """Plain XLA dot attention — the short-sequence winner.
 
     Same [B, T, H, D] layout and numerics contract as
     :func:`flash_attention` (matmuls in the input dtype, fp32 softmax), so
     the router can swap between them freely. At short T the [T, T] score
     matrix is small enough that XLA's fused softmax beats the Pallas
-    kernel's grid setup cost. ``window`` as :func:`flash_attention`'s.
+    kernel's grid setup cost. ``window`` and ``block_mask`` as
+    :func:`flash_attention`'s.
     """
     d = q.shape[-1]
     scale = sm_scale if sm_scale is not None else d ** -0.5
     window = _checked_window(window, causal)
+    block_mask = _checked_block_mask(block_mask, causal, window)
     # Matmuls stay in the input dtype (bf16 rides the fast MXU path, same
     # as the flash kernel) with fp32 accumulation; only the softmax runs
     # in fp32. Upcasting the operands would cost ~4x MXU throughput and 2x
@@ -719,11 +906,19 @@ def xla_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                 "xla_attention supports causal only for self-attention "
                 f"(Tq == Tk), got {tq} vs {tk}; use flash_attention with "
                 "q_offset/k_offset for sharded causal blocks")
-        mask = jnp.tril(jnp.ones((tq, tk), bool))
+        if block_mask is not None:  # the edge, by blocks of G positions
+            group, edge = block_mask
+            blocks = jnp.arange(tq) // group
+            mask = blocks[None, :] <= blocks[:, None] if edge == "le" \
+                else blocks[None, :] < blocks[:, None]
+        else:
+            mask = jnp.tril(jnp.ones((tq, tk), bool))
         if window is not None:  # the window's far edge: a second diagonal
             mask &= ~jnp.tril(jnp.ones((tq, tk), bool), -window)
         s = jnp.where(mask[None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
+    if block_mask is not None:  # block 0 under "lt" sees no key: output 0
+        p = jnp.where(jnp.any(mask, axis=-1)[None, None, :, None], p, 0.0)
     return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v,
                       preferred_element_type=jnp.float32).astype(q.dtype)
 
@@ -734,11 +929,25 @@ def flash_min_seq() -> int:
     return env_int("HOROVOD_FLASH_MIN_SEQ", DEFAULT_FLASH_MIN_SEQ)
 
 
+def _repeat_kv(q, k, v):
+    """The key and value heads repeated to the query heads (grouped-query
+    attention: query head ``j`` on key head ``j // (Hq / Hkv)``)."""
+    group, rest = divmod(q.shape[2], k.shape[2])
+    if rest or v.shape[2] != k.shape[2]:
+        raise ValueError(
+            f"{q.shape[2]} query heads are no multiple of {k.shape[2]} key "
+            f"and {v.shape[2]} value heads")
+    if group == 1:
+        return k, v
+    return tuple(jnp.repeat(x, group, axis=2) for x in (k, v))
+
+
 def attention(q: jax.Array, k: jax.Array, v: jax.Array,
               causal: bool = False,
               sm_scale: Optional[float] = None,
               min_flash_seq: Optional[int] = None,
               window: Optional[int] = None,
+              block_mask: Optional[Tuple[int, str]] = None,
               **flash_kwargs) -> jax.Array:
     """Length-routed attention: XLA dot attention below the crossover,
     the Pallas flash kernel at/above it.
@@ -753,7 +962,8 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array,
     path regardless of length — the XLA path cannot honor them, and
     silently dropping them would change the return contract or the causal
     mask (ring attention relies on exactly these). ``window`` (see
-    :func:`flash_attention`) is part of the mask, and both paths honour it.
+    :func:`flash_attention`) is part of the mask, and both paths honour it,
+    as they do ``block_mask``.
 
     Grouped-query attention: ``k`` and ``v`` may hold fewer heads than
     ``q`` where ``q``'s are a multiple; query head ``j`` attends key head
@@ -763,26 +973,23 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array,
     group inside them is ``ROADMAP.md`` work.
     """
     if k.shape[2] != q.shape[2]:
-        group, rest = divmod(q.shape[2], k.shape[2])
-        if rest or v.shape[2] != k.shape[2]:
-            raise ValueError(
-                f"{q.shape[2]} query heads are no multiple of "
-                f"{k.shape[2]} key and {v.shape[2]} value heads")
-        k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
+        k, v = _repeat_kv(q, k, v)
     if flash_kwargs.get("return_lse") or \
             flash_kwargs.get("q_offset") is not None or \
             flash_kwargs.get("k_offset") is not None:
         return flash_attention(q, k, v, causal=causal, sm_scale=sm_scale,
-                               window=window, **flash_kwargs)
+                               window=window, block_mask=block_mask,
+                               **flash_kwargs)
     threshold = min_flash_seq if min_flash_seq is not None else \
         flash_min_seq()
     if k.shape[1] < threshold:
         # flash_kwargs here can only hold tuning knobs (block sizes /
         # interpret), which have no meaning for the XLA formulation.
         return xla_attention(q, k, v, causal=causal, sm_scale=sm_scale,
-                             window=window)
+                             window=window, block_mask=block_mask)
     return flash_attention(q, k, v, causal=causal, sm_scale=sm_scale,
-                           window=window, **flash_kwargs)
+                           window=window, block_mask=block_mask,
+                           **flash_kwargs)
 
 
 def merge_attention(o_a: jax.Array, lse_a: jax.Array,
@@ -802,3 +1009,107 @@ def merge_attention(o_a: jax.Array, lse_a: jax.Array,
     o = o_a.astype(jnp.float32) * fa + o_b.astype(jnp.float32) * fb
     lse = jnp.where(m > NEG_INF / 2, m + jnp.log(denom), NEG_INF)
     return o.astype(o_a.dtype), lse
+
+
+def block_diagonal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                             group: int, sm_scale: Optional[float] = None
+                             ) -> Tuple[jax.Array, jax.Array]:
+    """Attention of every block of ``group`` positions on itself, both
+    directions: ``b(k) == b(q)``. ``T / group`` tiles of ``group x group``
+    scores, a few einsums and no kernel. q: [B, T, H, D]; k, v: [B, T, Hkv,
+    D(v)] with ``H`` a multiple of ``Hkv`` (query head ``j`` on key head
+    ``j // (H / Hkv)``, nothing repeated). Returns (o [B, T, H, Dv] in q's
+    dtype, lse [B, H, T] float32) as ``flash_attention(return_lse=True)``
+    does, for :func:`merge_attention`; matmuls in the input dtype with
+    float32 accumulation, the softmax in float32."""
+    b, t, h, d = q.shape
+    hk = k.shape[2]
+    if t % group or h % hk or v.shape[2] != hk:
+        raise ValueError(
+            f"{t} positions in blocks of {group}, {h} query heads over "
+            f"{hk} key and {v.shape[2]} value heads")
+    scale = sm_scale if sm_scale is not None else d ** -0.5
+    n = t // group
+    qb = q.reshape(b, n, group, hk, h // hk, d)
+    kb, vb = (x.reshape(b, n, group, hk, x.shape[-1]) for x in (k, v))
+    s = jnp.einsum("bnqhgd,bnkhd->bnhgqk", qb, kb,
+                   preferred_element_type=jnp.float32) * scale
+    lse = jax.nn.logsumexp(s, axis=-1)
+    p = jnp.exp(s - lse[..., None])
+    o = jnp.einsum("bnhgqk,bnkhd->bnqhgd", p.astype(v.dtype), vb,
+                   preferred_element_type=jnp.float32)
+    return (o.reshape(b, t, h, v.shape[-1]).astype(q.dtype),
+            lse.transpose(0, 2, 3, 1, 4).reshape(b, h, t))
+
+
+def blockdiff_mask(seq: int, group: int) -> jax.Array:
+    """[2 seq, 2 seq] bool, the block-diffusion mask over the rows of one
+    sequence's two streams (the noised one first): a noised query sees its
+    own noised block and the clean blocks before it, a clean query the
+    clean blocks up to its own, nobody else a noised key."""
+    block = jnp.arange(2 * seq) % seq // group
+    noised = jnp.arange(2 * seq) < seq
+    q_b, k_b = block[:, None], block[None, :]
+    q_n, k_n = noised[:, None], noised[None, :]
+    return (q_n & k_n & (k_b == q_b)) | (q_n & ~k_n & (k_b < q_b)) | \
+        (~q_n & ~k_n & (k_b <= q_b))
+
+
+def blockdiff_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                        group: int, sm_scale: Optional[float] = None,
+                        min_flash_seq: Optional[int] = None,
+                        **flash_kwargs) -> jax.Array:
+    """Attention of a block-diffusion training pass (BD3-LM,
+    arXiv:2503.09573): the two streams of every sequence in one array.
+
+    q: [B, 2L, H, D]; k, v: [B, 2L, Hkv, D(v)]. Rows ``:L`` are the noised
+    stream ``xt``, rows ``L:`` the clean stream ``x0`` of the same ``L``
+    positions, in blocks of ``group``: ``b(i) = i // group``.
+
+        query in xt, key in xt :  visible iff b(k) == b(q)
+        query in xt, key in x0 :  visible iff b(k) <  b(q)
+        query in x0, key in x0 :  visible iff b(k) <= b(q)
+        query in x0, key in xt :  never
+
+    At or above the router's crossover (``L`` keys a call) this is two
+    calls of the kernels over the clean keys, the clean queries under
+    ``block_mask=(group, "le")`` and the noised ones under ``(group,
+    "lt")``, and :func:`block_diagonal_attention` of the noised stream on
+    itself, merged with the second call's ``(o, lse)`` by
+    :func:`merge_attention` (a noised row of block 0 has ``lse = NEG_INF``
+    from the kernel and keeps its own block's result). No ``[2L, 2L]`` or
+    ``[L, L]`` array exists; the noised stream's keys are never handed to a
+    kernel. Below the crossover one :func:`xla_attention`-like pass under
+    :func:`blockdiff_mask`. Returns [B, 2L, H, Dv].
+    """
+    b, rows, h, d = q.shape
+    seq = rows // 2
+    if rows % 2 or seq % group:
+        raise ValueError(f"{rows} rows are not two streams of whole blocks "
+                         f"of {group}")
+    scale = sm_scale if sm_scale is not None else d ** -0.5
+    threshold = min_flash_seq if min_flash_seq is not None else \
+        flash_min_seq()
+    if seq < threshold:
+        kr, vr = _repeat_kv(q, k, v)
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, kr,
+                       preferred_element_type=jnp.float32) * scale
+        s = jnp.where(blockdiff_mask(seq, group)[None, None], s, NEG_INF)
+        return jnp.einsum("bhqk,bkhd->bqhd",
+                          jax.nn.softmax(s, axis=-1).astype(v.dtype), vr,
+                          preferred_element_type=jnp.float32).astype(q.dtype)
+    k_clean, v_clean = _repeat_kv(q, k[:, seq:], v[:, seq:])
+    flash = functools.partial(flash_attention, causal=True, sm_scale=scale,
+                              **flash_kwargs)
+    clean = flash(q[:, seq:], k_clean, v_clean, block_mask=(group, "le"))
+    past, past_lse = flash(q[:, :seq], k_clean, v_clean, return_lse=True,
+                           block_mask=(group, "lt"))
+    own, own_lse = block_diagonal_attention(q[:, :seq], k[:, :seq],
+                                            v[:, :seq], group, scale)
+    noised, _ = merge_attention(past, past_lse, own, own_lse)
+    _count_block_visits(
+        {"noised_keys": _noised_key_tiles(
+            seq, _pick_block(seq, flash_kwargs.get("block_q", 512)),
+            _pick_block(seq, flash_kwargs.get("block_k", 512)))},
+        b * h, BLOCKDIFF_KIND)
+    return jnp.concatenate([noised, clean], axis=1)

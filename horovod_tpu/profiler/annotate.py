@@ -15,8 +15,12 @@ Two distinct mechanisms, matching where the work actually happens:
   in-projection, causal conv, the chunked scan of ``ops/ssd.py``, gated
   group norm, out-projection), written by ``models/nemotron_h.py``.
 - :func:`attn_scope` — the kind of an attention call (:data:`ATTN_SCOPES`:
-  full causal or window), written by ``models/smallthinker.py``, whose
-  layers mix the two.
+  full causal or window, written by ``models/smallthinker.py``, whose
+  layers mix the two; the two streams of a block-diffusion pass, by
+  ``models/sdar.py``).
+- :func:`diffusion_scope` — the two ends of a block-diffusion objective
+  (:data:`DIFFUSION_SCOPES`: the noising of a batch and the weighted loss
+  over its masked positions), written by ``models/sdar.py``.
 - :func:`collective_scope` — ``jax.named_scope`` for code that runs INSIDE a
   jitted program (the in-jit collectives of ``parallel/collectives.py``).
   The scope becomes HLO op-name metadata, so the device trace of a
@@ -62,8 +66,13 @@ SSM_SCOPES = ("ssm_in_proj", "ssm_conv", "ssm_scan", "ssm_gate_norm",
               "ssm_out_proj")
 # The kind of an attention call in a model that mixes them
 # (``models/smallthinker.py``): rotary, the key heads' repeat and the
-# kernels of a full-causal or of a window layer.
-ATTN_SCOPES = ("attn_full", "attn_window")
+# kernels of a full-causal or of a window layer; ``attn_blockdiff`` is the
+# noised and the clean stream of a block-diffusion pass
+# (``models/sdar.py``: per-stream rotary, the repeat, the kernels under the
+# block mask, a noised block on itself, the merge).
+ATTN_SCOPES = ("attn_full", "attn_window", "attn_blockdiff")
+# The two ends of a block-diffusion objective (``models/sdar.py``).
+DIFFUSION_SCOPES = ("diffusion_noise", "diffusion_loss")
 # Host spans the step wrapper (``metrics.timed_step``) writes.
 STEP_SPAN = "hvd.step"
 STEP_DISPATCH_SPAN = "hvd.step.dispatch"
@@ -98,6 +107,14 @@ def attn_scope(name: str):
     if name not in ATTN_SCOPES:
         raise ValueError(f"unknown attention scope {name!r}; one of "
                          f"{ATTN_SCOPES}")
+    return collective_scope(name)
+
+
+def diffusion_scope(name: str):
+    """Name the enclosed traced ops as one end of a diffusion objective."""
+    if name not in DIFFUSION_SCOPES:
+        raise ValueError(f"unknown diffusion scope {name!r}; one of "
+                         f"{DIFFUSION_SCOPES}")
     return collective_scope(name)
 
 

@@ -52,16 +52,18 @@ def _no_persistent_cache():
     cc.reset_cache()
 
 
-def _forward(causal, scale, q, k, v, o, lse, do, off, window=None):
+def _forward(causal, scale, q, k, v, o, lse, do, off, window=None,
+             block_mask=None):
     return fa._flash_fwd(q, k, v, off, off, causal, scale, BLOCK, BLOCK,
-                         False, window)[:2]
+                         False, window, block_mask)[:2]
 
 
-def _backward(pick, causal, scale, q, k, v, o, lse, do, off, window=None):
+def _backward(pick, causal, scale, q, k, v, o, lse, do, off, window=None,
+              block_mask=None):
     # the two backward kernels share one function; the one whose outputs
     # are dropped is dead code to the compiler
     grads = fa._flash_bwd(causal, scale, BLOCK, BLOCK, False, window,
-                          (q, k, v, o, lse, off, off), (do, None))
+                          block_mask, (q, k, v, o, lse, off, off), (do, None))
     return pick(grads)
 
 
@@ -85,7 +87,8 @@ def test_flash_kernel_compiles_for_v5e(topo, kernel, seq, head_dim, causal):
     assert text.count("tpu_custom_call") == 1, text.count("tpu_custom_call")
 
 
-def _kernel_text(topo, kernel, seq, head_dim, causal, window=None):
+def _kernel_text(topo, kernel, seq, head_dim, causal, window=None,
+                 block_mask=None):
     one_chip = SingleDeviceSharding(topo.devices[0])
 
     def arg(shape, dtype):
@@ -95,7 +98,7 @@ def _kernel_text(topo, kernel, seq, head_dim, causal, window=None):
     lse = arg((HEADS, 1, seq), jnp.float32)
     off = arg((1,), jnp.float32)
     fn = functools.partial(KERNELS[kernel], causal, head_dim ** -0.5,
-                           window=window)
+                           window=window, block_mask=block_mask)
     return jax.jit(fn).lower(x, x, x, x, lse, x, off).compile().as_text()
 
 
@@ -117,6 +120,30 @@ def test_window_kernel_compiles_for_v5e_under_its_own_name(
     text = _kernel_text(topo, kernel, seq, head_dim, True, window)
     calls, _ = _kernel_calls(text)
     assert calls == {WINDOW_KERNELS[kernel]: 1}
+
+
+BLOCKDIFF_KERNELS = {"forward": "_fwd_blockdiff_kernel",
+                     "dq": "_bwd_dq_blockdiff_kernel",
+                     "dkv": "_bwd_dkv_blockdiff_kernel"}
+
+
+@pytest.mark.parametrize("seq,head_dim,block_mask", [
+    (8192, 128, (4, "le")), (8192, 128, (4, "lt")), (2048, 64, (16, "lt")),
+    (1024, 128, (3, "le"))],
+    ids=["sdar_clean", "sdar_noised", "g16_heads_of_64", "g3"])
+@pytest.mark.parametrize("kernel", list(KERNELS))
+def test_blockdiff_kernel_compiles_for_v5e_under_its_own_name(
+        topo, kernel, seq, head_dim, block_mask):
+    """The block mask's row-against-column compare and its loop bounds
+    compile for the chip (the new cell's two calls first; a block length
+    that is no power of two: the edge is ``floor((q + 0.5) / G)`` in
+    float32), one custom call each, named by the block-diffusion kernel's
+    function: never by a name of ``flops.FLASH_PRODUCTS`` nor of the window
+    kernels, whose readers cost a call at their own pair counts."""
+    text = _kernel_text(topo, kernel, seq, head_dim, True,
+                        block_mask=block_mask)
+    calls, _ = _kernel_calls(text)
+    assert calls == {BLOCKDIFF_KERNELS[kernel]: 1}
 
 
 @pytest.fixture(scope="module")
@@ -597,6 +624,74 @@ def test_smallthinker_cell_holds_causal_and_window_kernels_side_by_side(
                      r"moe_experts\)*/esk,ekn->esn/dot_general", text)
     for scope in ("moe_router", "moe_dispatch", "moe_experts",
                   "moe_combine", "attn_full", "attn_window"):
+        assert scope in text, scope
+    opcodes = re.findall(r"[\s)]([a-z\-]+)\(", text)
+    assert "all-reduce" not in opcodes
+
+
+@pytest.fixture(scope="module")
+def sdar_cell(topo):
+    """``sdar-t8192-bd4``: the configuration's layers at the published
+    widths, 8192 data tokens as 16 384 rows a layer, every block recomputed
+    but for its attention calls' outputs, through ``dp.make_train_step``."""
+    return _compiled_cell(topo, "sdar-t8192-bd4")
+
+
+def test_sdar_cell_fits_one_v5e_at_full_size(sdar_cell):
+    job, traffic, compiled = sdar_cell
+    memory = compiled.memory_analysis()
+    total = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    assert 4e9 < total < 15.0e9, total
+    # the parameters and AdamW's moments at 12 bytes
+    layers = job.facts["layers"]
+    parameters = layers * 94638336 + 2 * 18992 * 2048 + 2048
+    assert memory.argument_size_in_bytes == pytest.approx(12 * parameters,
+                                                          rel=1e-3)
+    recorded = traffic["memory_analysis"]
+    assert recorded["argument_bytes"] == memory.argument_size_in_bytes
+    assert memory.temp_size_in_bytes <= 1.02 * recorded["temp_bytes"]
+
+
+def test_sdar_cell_holds_the_block_mask_kernels_and_no_score_array(
+        sdar_cell):
+    """Two calls of each role a layer (the clean queries' and the noised
+    queries', both over the clean keys), every one under ``attn_blockdiff``,
+    the backward's under ``transpose(jvp(...))``; the attention calls'
+    outputs are kept by name, so no forward kernel runs twice. No call under
+    a name of ``flops.FLASH_PRODUCTS`` or of the window kernels (the job
+    names no flash shapes: ``harness/kernels.unasked`` would fail the run).
+    No array of the step has [2L, 2L] or [L, L] elements a head: the mask
+    and the scores exist in VMEM tiles alone (a noised block on itself is
+    ``[.., 2048, 4, 8, 4, 4]``). The share walks by XLA's batched product
+    over sixteen slots of 1536 rows; one chip exchanges nothing."""
+    from horovod_tpu.parallel import ep
+    job, _, compiled = sdar_cell
+    text = compiled.as_text()
+    layers = job.facts["layers"]
+    calls, op_names = _kernel_calls(text)
+    assert calls == {"_fwd_blockdiff_kernel": 2 * layers,
+                     "_bwd_dq_blockdiff_kernel": 2 * layers,
+                     "_bwd_dkv_blockdiff_kernel": 2 * layers,
+                     "_add_rows_kernel": 2 * layers}
+    assert job.flash_call is None
+    op_names.pop("_add_rows_kernel")
+    for kernel, names in op_names.items():
+        assert all("attn_blockdiff" in name for name in names), kernel
+        backward = [("transpose(jvp(" in name) for name in names]
+        assert all(backward) if "bwd" in kernel else not any(backward)
+        assert {name.split("SdarBlock_")[1][0] for name in names} == \
+            set(map(str, range(layers)))
+    seq = job.facts["seq_len"]
+    for shape in set(re.findall(r"= \w+\[([\d,]+)\]", text)):
+        dims = [int(d) for d in shape.split(",")]
+        assert sum(d in (seq, 2 * seq) for d in dims) < 2, shape
+    assert "ragged-dot" not in text and not _row_scatters(text)
+    slot = ep.share_slot_rows(8 * 16384, 128)
+    assert slot == 1536 and ep.share_tile_rows(8 * 16384, 16, 128) == 16 * slot
+    assert re.search(rf"= f32\[16,{slot},768\]\S* convolution\([^\n]*"
+                     r"moe_experts\)*/esk,ekn->esn/dot_general", text)
+    for scope in ("moe_router", "moe_dispatch", "moe_experts",
+                  "moe_combine", "attn_blockdiff", "diffusion_loss"):
         assert scope in text, scope
     opcodes = re.findall(r"[\s)]([a-z\-]+)\(", text)
     assert "all-reduce" not in opcodes
