@@ -63,10 +63,14 @@ composes with:
   otherwise) and the fp32 ``dq``/``dk`` sums instead of ``ds``; a
   fully-masked row is one select on its ``lse``.
 
-Layout: [batch, seq, heads, head_dim] in, same out; internally each
-(batch, head) pair is one grid row. Pure-JAX reference semantics are tested
-against in interpret mode (CPU) and the kernel compile-checks on the real
-chip.
+Layout: [batch, seq, heads, head_dim] in, same out; k and v may hold fewer
+heads than q (grouped-query attention). Internally every array is
+transposed to [batch * heads, seq, head_dim] and each (batch, query head)
+pair is one grid row; k and v keep their own heads and the index maps send a
+group's query heads to one key head, so no key head is written out once a
+query head and a kernel fetches it once a group (:func:`_head_spec`).
+Pure-JAX reference semantics are tested against in interpret mode (CPU) and
+the kernel compile-checks on the real chip.
 """
 
 from __future__ import annotations
@@ -460,6 +464,39 @@ def _bh_first(x):  # [B, T, H, D] -> [B*H, T, D]
     return x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
 
 
+def _head_spec(rows: int, d: int, tiled: bool, group: int = 1):
+    """The ``(1, rows, d)`` block of a grid row's head in a ``[B * heads, T,
+    d]`` array (:func:`_bh_first`). A grid row is one batch element's one
+    query head, ``bh = b * H + j``; the grid's second index counts blocks of
+    ``rows`` positions where ``tiled``, else the block is the head's whole
+    length. ``group``: the array is k or v at its own ``Hkv = H / group``
+    heads, and query head ``j`` reads key head ``j // group``, row ``b * Hkv
+    + j // group = bh // group``. Consecutive grid rows of a group ask for
+    the same block, which the pipeline then does not fetch again: a key head
+    is read once for its whole group. With equal heads the map computes
+    nothing, the call those models always made."""
+    return pl.BlockSpec(
+        (1, rows, d),
+        lambda bh, t: (bh if group == 1 else lax.div(bh, group),
+                       t if tiled else 0, 0))
+
+
+def _bh_last(x, batch: int):  # [B*H, T, D] -> [B, T, H, D]
+    _, t, d = x.shape
+    return x.reshape(batch, -1, t, d).transpose(0, 2, 1, 3)
+
+
+def _sum_groups(x, group: int):
+    """dk or dv as the dk/dv kernel wrote it, one ``[Tk, d]`` a query head
+    (``[B * H, Tk, d]``), as the key heads' ``[B * H / group, Tk, d]``: one
+    float32 sum over each group's ``group`` neighbouring rows."""
+    if group == 1:
+        return x
+    _, t, d = x.shape
+    return jnp.sum(x.reshape(-1, group, t, d), axis=1,
+                   dtype=jnp.float32).astype(x.dtype)
+
+
 def _scalar_spec():
     return pl.BlockSpec(memory_space=pltpu.SMEM)
 
@@ -505,6 +542,7 @@ def _flash_fwd(q, k, v, q_off, k_off, causal, sm_scale, block_q, block_k,
                interpret, window=None, block_mask=None):
     b, tq, h, d = q.shape
     tk, dv = k.shape[1], v.shape[-1]
+    group = h // k.shape[2]  # k and v at their own heads: see _head_spec
     qb, kb, vb = _bh_first(q), _bh_first(k), _bh_first(v)
     grid = (b * h, tq // block_q)
     kernel = _kernel(_fwd_kernel, _fwd_window_kernel, _fwd_blockdiff_kernel,
@@ -515,13 +553,13 @@ def _flash_fwd(q, k, v, q_off, k_off, causal, sm_scale, block_q, block_k,
         grid=grid,
         in_specs=[
             _scalar_spec(), _scalar_spec(),
-            pl.BlockSpec((1, block_q, d), lambda bh, i: (bh, i, 0)),
-            pl.BlockSpec((1, tk, d), lambda bh, i: (bh, 0, 0)),
-            pl.BlockSpec((1, tk, dv), lambda bh, i: (bh, 0, 0)),
+            _head_spec(block_q, d, tiled=True),
+            _head_spec(tk, d, tiled=False, group=group),
+            _head_spec(tk, dv, tiled=False, group=group),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, dv), lambda bh, i: (bh, i, 0)),
-            pl.BlockSpec((1, 1, tq), lambda bh, i: (bh, 0, 0)),
+            _head_spec(block_q, dv, tiled=True),
+            _head_spec(1, tq, tiled=False),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b * h, tq, dv), q.dtype),
@@ -532,8 +570,7 @@ def _flash_fwd(q, k, v, q_off, k_off, causal, sm_scale, block_q, block_k,
             ((block_q, dv), q.dtype), ((1, tq), jnp.float32)),
         interpret=interpret,
     )(q_off, k_off, qb, kb, vb)
-    o_out = checkpoint_name(o.reshape(b, h, tq, dv).transpose(0, 2, 1, 3),
-                            FLASH_RESIDUALS[0])
+    o_out = checkpoint_name(_bh_last(o, b), FLASH_RESIDUALS[0])
     lse = checkpoint_name(lse, FLASH_RESIDUALS[1])
     lse_out = lse.reshape(b, h, tq)
     return o_out, lse_out, (q, k, v, o_out, lse, q_off, k_off)
@@ -553,6 +590,7 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, interpret, window,
     do, dlse = cots
     b, tq, h, d = q.shape
     tk, dv = k.shape[1], v.shape[-1]
+    group = h // k.shape[2]
     dob = _bh_first(do.astype(q.dtype))
     ob = _bh_first(o)
     # delta_i = sum_j do_ij o_ij;  ds = p * (dp + dlse - delta) * scale
@@ -563,6 +601,7 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, interpret, window,
             if dlse is not None else -delta)
     corr = corr.reshape(b * h, 1, tq)  # full-row blocks, like lse
     qb, kb, vb = _bh_first(q), _bh_first(k), _bh_first(v)
+    row = _head_spec(1, tq, tiled=False)  # lse, corr
 
     dq = pl.pallas_call(
         _kernel(_bwd_dq_kernel, _bwd_dq_window_kernel,
@@ -572,14 +611,13 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, interpret, window,
         grid=(b * h, tq // block_q),
         in_specs=[
             _scalar_spec(), _scalar_spec(),
-            pl.BlockSpec((1, block_q, d), lambda bh, i: (bh, i, 0)),
-            pl.BlockSpec((1, tk, d), lambda bh, i: (bh, 0, 0)),
-            pl.BlockSpec((1, tk, dv), lambda bh, i: (bh, 0, 0)),
-            pl.BlockSpec((1, block_q, dv), lambda bh, i: (bh, i, 0)),
-            pl.BlockSpec((1, 1, tq), lambda bh, i: (bh, 0, 0)),
-            pl.BlockSpec((1, 1, tq), lambda bh, i: (bh, 0, 0)),
+            _head_spec(block_q, d, tiled=True),
+            _head_spec(tk, d, tiled=False, group=group),
+            _head_spec(tk, dv, tiled=False, group=group),
+            _head_spec(block_q, dv, tiled=True),
+            row, row,
         ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda bh, i: (bh, i, 0)),
+        out_specs=_head_spec(block_q, d, tiled=True),
         out_shape=jax.ShapeDtypeStruct((b * h, tq, d), q.dtype),
         compiler_params=_vmem_params(
             ((2 * block_q, d), q.dtype), ((tk, d), k.dtype),
@@ -588,6 +626,9 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, interpret, window,
         interpret=interpret,
     )(q_off, k_off, qb, kb, vb, dob, lse, corr)
 
+    # one (batch, query head) a grid row here too, q and do of that head
+    # resident: a key head's tile is read by each row of its group, which
+    # writes a dk and dv of its own
     dk, dvv = pl.pallas_call(
         _kernel(_bwd_dkv_kernel, _bwd_dkv_window_kernel,
                 _bwd_dkv_blockdiff_kernel, window, block_mask,
@@ -596,16 +637,15 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, interpret, window,
         grid=(b * h, tk // block_k),
         in_specs=[
             _scalar_spec(), _scalar_spec(),
-            pl.BlockSpec((1, tq, d), lambda bh, j: (bh, 0, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, j: (bh, j, 0)),
-            pl.BlockSpec((1, block_k, dv), lambda bh, j: (bh, j, 0)),
-            pl.BlockSpec((1, tq, dv), lambda bh, j: (bh, 0, 0)),
-            pl.BlockSpec((1, 1, tq), lambda bh, j: (bh, 0, 0)),
-            pl.BlockSpec((1, 1, tq), lambda bh, j: (bh, 0, 0)),
+            _head_spec(tq, d, tiled=False),
+            _head_spec(block_k, d, tiled=True, group=group),
+            _head_spec(block_k, dv, tiled=True, group=group),
+            _head_spec(tq, dv, tiled=False),
+            row, row,
         ],
         out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda bh, j: (bh, j, 0)),
-            pl.BlockSpec((1, block_k, dv), lambda bh, j: (bh, j, 0)),
+            _head_spec(block_k, d, tiled=True),
+            _head_spec(block_k, dv, tiled=True),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b * h, tk, d), k.dtype),
@@ -618,10 +658,8 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, interpret, window,
         interpret=interpret,
     )(q_off, k_off, qb, kb, vb, dob, lse, corr)
 
-    def back(x, t):  # [BH, T, D] -> [B, T, H, D]
-        return x.reshape(b, h, t, x.shape[-1]).transpose(0, 2, 1, 3)
-
-    return (back(dq, tq), back(dk, tk), back(dvv, tk),
+    return (_bh_last(dq, b), _bh_last(_sum_groups(dk, group), b),
+            _bh_last(_sum_groups(dvv, group), b),
             jnp.zeros_like(q_off), jnp.zeros_like(k_off))
 
 
@@ -772,6 +810,17 @@ def _count_block_visits(plan: dict, batch_heads: int, prefix: str = ""):
             kind=kind).inc(visits * batch_heads)
 
 
+def _count_call(group: int):
+    """Monitoring, at trace time beside :func:`_count_block_visits`: one
+    count a :func:`flash_attention` call traced, by the query heads a key
+    head serves (1: as many key heads as query heads)."""
+    from horovod_tpu.metrics.registry import get_registry
+    get_registry().counter(
+        "hvd_flash_calls_total",
+        "flash-attention calls traced, by query heads to a key head",
+        kv_group=str(group)).inc()
+
+
 def _static_offset(offset) -> Optional[int]:
     """The offset as a Python int, or None where it is traced."""
     if offset is None:
@@ -792,11 +841,24 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     block_mask: Optional[Tuple[int, str]] = None):
     """softmax(QK^T)V without materializing the score matrix.
 
-    q: [B, Tq, H, D]; k/v: [B, Tk, H, D(v)]. Block sizes shrink to divisors
-    of the sequence lengths automatically (static shapes are the XLA
-    contract anyway). ``q_offset``/``k_offset`` are global sequence
-    positions of element 0 (traced scalars allowed) for causal masking of
-    sequence-sharded blocks. ``return_lse=True`` also returns the per-row
+    q: [B, Tq, H, D]; k/v: [B, Tk, Hkv, D(v)], ``H`` a multiple of ``Hkv``
+    (grouped-query attention: query head ``j`` on key head ``j // (H /
+    Hkv)``). k and v are handed to the kernels at their own heads: the index
+    maps send a group's query heads to one key head, which a kernel then
+    fetches once a group, and nothing repeats them in HBM; dk and dv are
+    written a query head and summed over each group in float32
+    (``hvd_flash_calls_total{kv_group}`` counts the calls traced). Whatever
+    the head width, every array is transposed to ``[B * H, T, D]`` around a
+    call: the TPU compiler keeps the activations between the matmuls with
+    the positions in lanes, so an operand crosses to the kernels' row-major
+    blocks once whichever way the heads lie, and these transposes ride in
+    the neighbouring fusions (``PERF.md`` §6, PR 41: reading ``[B, T, H *
+    D]`` in place was built and compiled to more copies, not fewer). Block
+    sizes shrink to divisors of the sequence lengths automatically (static
+    shapes are the XLA contract anyway). ``q_offset``/``k_offset`` are global
+    sequence positions of element 0 (traced scalars allowed) for causal
+    masking of sequence-sharded blocks. ``return_lse=True`` also returns the
+    per-row
     log-sum-exp, shaped [B, H, Tq], for online-softmax merging; both
     outputs are differentiable. ``interpret=None`` auto-selects interpret
     mode off-TPU so the same call runs in CPU tests.
@@ -821,6 +883,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     window = _checked_window(window, causal)
     block_mask = _checked_block_mask(
         block_mask, causal, window, q_offset is None and k_offset is None)
+    _count_call(_kv_group(q, k, v))
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     block_q = _pick_block(tq, block_q)
@@ -929,14 +992,22 @@ def flash_min_seq() -> int:
     return env_int("HOROVOD_FLASH_MIN_SEQ", DEFAULT_FLASH_MIN_SEQ)
 
 
-def _repeat_kv(q, k, v):
-    """The key and value heads repeated to the query heads (grouped-query
-    attention: query head ``j`` on key head ``j // (Hq / Hkv)``)."""
+def _kv_group(q, k, v) -> int:
+    """Query heads to a key head (grouped-query attention: query head ``j``
+    on key head ``j // group``)."""
     group, rest = divmod(q.shape[2], k.shape[2])
     if rest or v.shape[2] != k.shape[2]:
         raise ValueError(
             f"{q.shape[2]} query heads are no multiple of {k.shape[2]} key "
             f"and {v.shape[2]} value heads")
+    return group
+
+
+def _repeat_kv(q, k, v):
+    """The key and value heads repeated to the query heads, for the XLA
+    paths, which take equal heads only; their gradient is the sum over each
+    group."""
+    group = _kv_group(q, k, v)
     if group == 1:
         return k, v
     return tuple(jnp.repeat(x, group, axis=2) for x in (k, v))
@@ -967,13 +1038,11 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
     Grouped-query attention: ``k`` and ``v`` may hold fewer heads than
     ``q`` where ``q``'s are a multiple; query head ``j`` attends key head
-    ``j // (Hq / Hkv)``. The key heads are repeated to the query heads here,
-    before either path (their gradient is the sum over each group): the
-    kernels take equal heads only, and reading a key head once for its whole
-    group inside them is ``ROADMAP.md`` work.
+    ``j // (Hq / Hkv)``. The flash path takes them as they are: the kernels
+    read a key head once for its whole group and nothing repeats it in HBM
+    (:func:`flash_attention`). The XLA path repeats the key heads to the
+    query heads (their gradient is the sum over each group).
     """
-    if k.shape[2] != q.shape[2]:
-        k, v = _repeat_kv(q, k, v)
     if flash_kwargs.get("return_lse") or \
             flash_kwargs.get("q_offset") is not None or \
             flash_kwargs.get("k_offset") is not None:
@@ -985,8 +1054,9 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array,
     if k.shape[1] < threshold:
         # flash_kwargs here can only hold tuning knobs (block sizes /
         # interpret), which have no meaning for the XLA formulation.
-        return xla_attention(q, k, v, causal=causal, sm_scale=sm_scale,
-                             window=window, block_mask=block_mask)
+        return xla_attention(q, *_repeat_kv(q, k, v), causal=causal,
+                             sm_scale=sm_scale, window=window,
+                             block_mask=block_mask)
     return flash_attention(q, k, v, causal=causal, sm_scale=sm_scale,
                            window=window, block_mask=block_mask,
                            **flash_kwargs)
@@ -1072,10 +1142,11 @@ def blockdiff_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         query in x0, key in xt :  never
 
     At or above the router's crossover (``L`` keys a call) this is two
-    calls of the kernels over the clean keys, the clean queries under
-    ``block_mask=(group, "le")`` and the noised ones under ``(group,
-    "lt")``, and :func:`block_diagonal_attention` of the noised stream on
-    itself, merged with the second call's ``(o, lse)`` by
+    calls of the kernels over the clean keys (at their own ``Hkv`` heads:
+    :func:`flash_attention` reads a key head once a group), the clean
+    queries under ``block_mask=(group, "le")`` and the noised ones under
+    ``(group, "lt")``, and :func:`block_diagonal_attention` of the noised
+    stream on itself, merged with the second call's ``(o, lse)`` by
     :func:`merge_attention` (a noised row of block 0 has ``lse = NEG_INF``
     from the kernel and keeps its own block's result). No ``[2L, 2L]`` or
     ``[L, L]`` array exists; the noised stream's keys are never handed to a
@@ -1098,7 +1169,7 @@ def blockdiff_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         return jnp.einsum("bhqk,bkhd->bqhd",
                           jax.nn.softmax(s, axis=-1).astype(v.dtype), vr,
                           preferred_element_type=jnp.float32).astype(q.dtype)
-    k_clean, v_clean = _repeat_kv(q, k[:, seq:], v[:, seq:])
+    k_clean, v_clean = k[:, seq:], v[:, seq:]  # their own heads, as held
     flash = functools.partial(flash_attention, causal=True, sm_scale=scale,
                               **flash_kwargs)
     clean = flash(q[:, seq:], k_clean, v_clean, block_mask=(group, "le"))

@@ -88,18 +88,19 @@ def test_flash_kernel_compiles_for_v5e(topo, kernel, seq, head_dim, causal):
 
 
 def _kernel_text(topo, kernel, seq, head_dim, causal, window=None,
-                 block_mask=None):
+                 block_mask=None, heads=HEADS, kv_heads=None):
     one_chip = SingleDeviceSharding(topo.devices[0])
 
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    x = arg((1, seq, HEADS, head_dim), jnp.bfloat16)
-    lse = arg((HEADS, 1, seq), jnp.float32)
+    x = arg((1, seq, heads, head_dim), jnp.bfloat16)
+    kv = arg((1, seq, kv_heads or heads, head_dim), jnp.bfloat16)
+    lse = arg((heads, 1, seq), jnp.float32)
     off = arg((1,), jnp.float32)
     fn = functools.partial(KERNELS[kernel], causal, head_dim ** -0.5,
                            window=window, block_mask=block_mask)
-    return jax.jit(fn).lower(x, x, x, x, lse, x, off).compile().as_text()
+    return jax.jit(fn).lower(x, kv, kv, x, lse, x, off).compile().as_text()
 
 
 WINDOW_KERNELS = {"forward": "_fwd_window_kernel",
@@ -120,6 +121,31 @@ def test_window_kernel_compiles_for_v5e_under_its_own_name(
     text = _kernel_text(topo, kernel, seq, head_dim, True, window)
     calls, _ = _kernel_calls(text)
     assert calls == {WINDOW_KERNELS[kernel]: 1}
+
+
+GROUPED = {  # heads, key heads, head width, positions, the mask
+    "smallthinker_window": (28, 4, 128, 16384, dict(window=4096)),
+    "smallthinker_full": (28, 4, 128, 16384, dict()),
+    "sdar": (32, 4, 128, 8192, dict(block_mask=(4, "lt"))),
+    "nemotron": (32, 2, 128, 8192, dict()),
+    "heads_of_64": (12, 4, 64, 2048, dict()),
+}
+
+
+@pytest.mark.parametrize("cell", list(GROUPED))
+@pytest.mark.parametrize("kernel", list(KERNELS))
+def test_grouped_heads_kernel_compiles_for_v5e(topo, kernel, cell):
+    """k and v at their own heads, found by ``bh // group`` in the index
+    maps, compile for the chip at the grouped cells' shapes (a group of 7 is
+    no power of two): one custom call each, and no array of a key head
+    repeated to the query heads exists beside it (the dk/dv kernel's own
+    results, one a query head, are the only ones of that shape)."""
+    heads, kv_heads, head_dim, seq, mask = GROUPED[cell]
+    text = _kernel_text(topo, kernel, seq, head_dim, True, heads=heads,
+                        kv_heads=kv_heads, **mask)
+    assert text.count("tpu_custom_call") == 1
+    assert not re.search(r"= bf16\[[0-9,]+\]\S* broadcast\(",
+                         text.split("ENTRY")[1])
 
 
 BLOCKDIFF_KERNELS = {"forward": "_fwd_blockdiff_kernel",
