@@ -80,6 +80,7 @@ import optax
 
 from horovod_tpu.ops.flash_attention import attention
 from horovod_tpu.ops.ssd import ssd_scan
+from horovod_tpu.ops.ssm_ends import causal_conv_silu, gated_group_norm
 from horovod_tpu.parallel import ep
 from horovod_tpu.profiler.annotate import moe_scope, ssm_scope
 
@@ -116,43 +117,39 @@ def _conv_init(kernel: int):
 class CausalConv1d(nn.Module):
     """Depthwise causal convolution over time, then silu:
     ``y_t[c] = silu(b[c] + sum_j w[j, c] x_{t-K+1+j}[c])``, zeros before
-    ``t = 0``. The sum runs in float32."""
+    ``t = 0``; the sum runs in float32. Over the channels of ``x`` from
+    ``at`` on, returned in runs of ``widths`` (``ops/ssm_ends.
+    causal_conv_silu``: a kernel a pass and run, which reads its channels
+    where they lie in ``x`` and writes each run as the array the scan
+    takes)."""
     kernel: int
     dtype: Any = jnp.bfloat16
 
     @nn.compact
-    def __call__(self, x):
-        channels = x.shape[-1]
+    def __call__(self, x, at: int, widths: Tuple[int, ...]):
+        channels = sum(widths)
         w = self.param("kernel", _conv_init(self.kernel),
                        (self.kernel, channels), jnp.float32)
         b = self.param("bias", _conv_init(self.kernel), (channels,),
                        jnp.float32)
-        t = x.shape[1]
-        padded = jnp.pad(x, ((0, 0), (self.kernel - 1, 0), (0, 0)))
-        y = b + sum(w[j] * padded[:, j:j + t].astype(jnp.float32)
-                    for j in range(self.kernel))
-        return jax.nn.silu(y).astype(self.dtype)
+        return causal_conv_silu(x, w, b, self.dtype, at, widths)
 
 
 class GatedGroupRMSNorm(nn.Module):
     """``RMSNorm_groups(y * silu(z))``: the gate first, then RMSNorm over
     each group of channels, one scale over all of them (float32
-    statistics)."""
+    statistics); ``z`` is the channels of its argument from ``at`` on
+    (``ops/ssm_ends.gated_group_norm``: a kernel a pass)."""
     groups: int
     eps: float = 1e-5
     dtype: Any = jnp.bfloat16
 
     @nn.compact
-    def __call__(self, y, z):
-        channels = y.shape[-1]
-        scale = self.param("scale", nn.initializers.ones, (channels,),
+    def __call__(self, y, z, at: int = 0):
+        scale = self.param("scale", nn.initializers.ones, (y.shape[-1],),
                            jnp.float32)
-        y = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
-        grouped = y.reshape(*y.shape[:-1], self.groups,
-                            channels // self.groups)
-        grouped = grouped * jax.lax.rsqrt(
-            jnp.mean(grouped * grouped, axis=-1, keepdims=True) + self.eps)
-        return (grouped.reshape(y.shape) * scale).astype(self.dtype)
+        return gated_group_norm(y, z, scale, self.groups, self.eps,
+                                self.dtype, at)
 
 
 class NemotronHMamba2Mixer(nn.Module):
@@ -180,22 +177,24 @@ class NemotronHMamba2Mixer(nn.Module):
         dt_bias = self.param(
             "dt_bias", _dt_bias_init(self.dt_min, self.dt_max, self.dt_floor),
             (h,), jnp.float32)
+        # [z | x | B | C | dt] along the channels; the two ends read their
+        # runs of it in place
         with ssm_scope("ssm_in_proj"):
-            z, xbc, dt = jnp.split(
-                _dense(2 * d_in + 2 * d_bc + h, self.dtype, "in_proj")(u),
-                [d_in, 2 * d_in + 2 * d_bc], axis=-1)
+            proj = _dense(2 * d_in + 2 * d_bc + h, self.dtype, "in_proj")(u)
         with ssm_scope("ssm_conv"):
-            xbc = CausalConv1d(self.conv_kernel, self.dtype,
-                               name="conv1d")(xbc)
-            x, bmat, cmat = jnp.split(xbc, [d_in, d_in + d_bc], axis=-1)
-            dt = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
+            x, bmat, cmat = CausalConv1d(
+                self.conv_kernel, self.dtype, name="conv1d")(
+                proj, d_in, (d_in, d_bc, d_bc))
+            dt = jax.nn.softplus(
+                proj[..., 2 * d_in + 2 * d_bc:].astype(jnp.float32)
+                + dt_bias)
         y = ssd_scan(
             x.reshape(b, t, h, p), dt, -jnp.exp(a_log),
             bmat.reshape(b, t, g, n), cmat.reshape(b, t, g, n), d_skip,
             chunk=self.chunk)
         with ssm_scope("ssm_gate_norm"):
             y = GatedGroupRMSNorm(g, self.eps, self.dtype, name="norm")(
-                y.reshape(b, t, d_in), z)
+                y.reshape(b, t, d_in), proj)
         with ssm_scope("ssm_out_proj"):
             return _dense(hidden, self.dtype, "out_proj")(y)
 

@@ -540,7 +540,8 @@ def test_nemotron_cell_holds_its_kernels_and_scopes(nemotron_cell):
     vectors (the sort keys, the router weights' gradient), and no array
     has that many rows of hidden or expert width; nor does any array hold a
     chunk's [128, 128] decays a head (``ssd_chunked`` wrote [1, 64, 8, 8,
-    128, 128])."""
+    128, 128]). Since PR 42 the mixer's conv and gated norm are kernels
+    too (``ops/ssm_ends.py``), under ``ssm_conv`` and ``ssm_gate_norm``."""
     from horovod_tpu.parallel import ep
     from horovod_tpu.profiler.annotate import MOE_SCOPES, SSM_SCOPES
     job, _, compiled = nemotron_cell
@@ -551,6 +552,10 @@ def test_nemotron_cell_holds_its_kernels_and_scopes(nemotron_cell):
     assert calls == {
         "_fwd_kernel": 1, "_bwd_dq_kernel": 1, "_bwd_dkv_kernel": 1,
         "_ssd_fwd_kernel": 2 * mixers, "_ssd_bwd_kernel": mixers,
+        # the mixer's two ends (PR 42): the conv a call for each of x, B
+        # and C, the gated norm one; forward, recomputed, backward
+        "_conv_fwd_kernel": 3 * 2 * mixers, "_conv_bwd_kernel": 3 * mixers,
+        "_norm_fwd_kernel": 2 * mixers, "_norm_bwd_kernel": mixers,
         # a live tile's rows back to their tokens: the weighted rows
         # forward and the rows' gradient backward, once a layer each (the
         # recomputed forward walk's result is needed by nothing, and goes)
@@ -570,10 +575,16 @@ def test_nemotron_cell_holds_its_kernels_and_scopes(nemotron_cell):
     assert sorted(set(products)) == sorted(
         f"[8,{a},{b}]" for a, b in [(slot, 1856), (slot, 2688),
                                     (1856, 2688), (2688, 1856)])
-    for kernel in ("_ssd_fwd_kernel", "_ssd_bwd_kernel"):
-        assert all("ssm_scan" in name for name in op_names[kernel]), kernel
-    assert all("transpose(jvp(" in name
-               for name in op_names["_ssd_bwd_kernel"])
+    for kernel, scope in (("_ssd_fwd_kernel", "ssm_scan"),
+                          ("_ssd_bwd_kernel", "ssm_scan"),
+                          ("_conv_fwd_kernel", "ssm_conv"),
+                          ("_conv_bwd_kernel", "ssm_conv"),
+                          ("_norm_fwd_kernel", "ssm_gate_norm"),
+                          ("_norm_bwd_kernel", "ssm_gate_norm")):
+        assert all(scope in name for name in op_names[kernel]), kernel
+        if "bwd" in kernel:
+            assert all("transpose(jvp(" in name
+                       for name in op_names[kernel]), kernel
     for scope in SSM_SCOPES + MOE_SCOPES:
         assert scope in text, scope
     pairs = 6 * 8192
@@ -582,6 +593,132 @@ def test_nemotron_cell_holds_its_kernels_and_scopes(nemotron_cell):
     assert not re.search(r"\[1,64,8,8,128,128\]", text)
     opcodes = re.findall(r"[\s)]([a-z\-]+)\(", text)
     assert "all-reduce" not in opcodes  # one chip exchanges nothing
+
+
+def _entry_instructions(text):
+    """(the text's index, the instructions of its entry computation that
+    are no bookkeeping)."""
+    _benchmark_on_path()
+    from harness import hlo_text
+    hlo = hlo_text.HloIndex(text)
+    entry = re.search(r"^ENTRY\s+%?([\w.\-]+)", text, re.M).group(1)
+    free = {"bitcast", "get-tuple-element", "tuple", "parameter", "constant"}
+    return hlo, [i for i in hlo.bodies[entry] if i.opcode not in free]
+
+
+def _scope_bytes(text, scopes, positions=8192):
+    """{scope: bytes in + out} of the entry computation's instructions whose
+    ``op_name`` holds the scope: each instruction's results and its distinct
+    operands, whole (a fusion that reads a slice of an operand is counted as
+    reading all of it: an upper bound). But a kernel works one run of
+    channels of its sequences (arrays whose last axis is the ``positions``):
+    the in-projection's whole output is an operand it addresses a run of,
+    a ``dx`` several calls fill is a result it writes a run of. Each such
+    array of a kernel is counted at the smallest of them."""
+    hlo, instructions = _entry_instructions(text)
+    from harness import hlo_text
+
+    def arrays(shape):   # (elements, bytes an element, is a sequence)
+        return [(hlo_text.shape_bytes(f"s8[{dims}]"),
+                 hlo_text.DTYPE_BYTES[dtype],
+                 dims.endswith(f",{positions}"))
+                for dtype, dims in hlo_text._ARRAY.findall(shape)
+                if dtype in hlo_text.DTYPE_BYTES]
+    total = dict.fromkeys(scopes, 0)
+    for ins in instructions:
+        scope = next((s for s in scopes if s in ins.op_name), None)
+        if scope is None:
+            continue
+        operands = ins.attributes.split("(", 1)[1].split("), ")[0]
+        moved = arrays(ins.shape)
+        for name in set(re.findall(r"%([\w.\-]+)", operands)):
+            moved += arrays(hlo.instructions[name].shape)
+        run = min((n for n, _, sequence in moved if sequence), default=0)
+        total[scope] += sum(
+            (min(n, run) if sequence and hlo.is_kernel(ins) else n) * size
+            for n, size, sequence in moved)
+    return total
+
+
+def _activation_copies(text):
+    """The entry instructions that only move a sequence's activations
+    (8192 positions by some thousand channels): none is wanted beside a
+    kernel."""
+    instructions = _entry_instructions(text)[1]
+    from harness import hlo_text
+    return [(i.name, i.shape) for i in instructions
+            if i.opcode in ("slice", "copy", "pad", "concatenate")
+            and hlo_text.shape_bytes(i.shape) > 8192 * 1024]
+
+
+def test_nemotron_cell_moves_the_two_ends_once_a_pass(nemotron_cell):
+    """Under ``ssm_conv`` + ``ssm_gate_norm`` the step's instructions read
+    and write under 8 GB (18.6 before PR 42; 4 layers x (two forward passes
+    and a backward) of x, y, z, their gradients and the results once each
+    are 5.8). Nothing writes the norm's statistics out a channel
+    (``f32[8192,8,512]``), the gated product in float32, or a cotangent a
+    tap of the conv (a tuple of four ``bf16[1,8192,6144]``); and no
+    ``slice`` copies a run of the in-projection's output for a kernel: they
+    read it in place."""
+    _, _, compiled = nemotron_cell
+    text = compiled.as_text()
+    moved = _scope_bytes(text, ("ssm_conv", "ssm_gate_norm"))
+    assert 4e9 < sum(moved.values()) < 8e9, moved
+    assert "f32[8192,8,512]" not in text
+    assert "f32[1,8192,4096]" not in text
+    assert not re.search(
+        r"\((bf16\[1,8192,6144\]\S*, ){3}bf16\[1,8192,6144\]", text)
+    hlo = _entry_instructions(text)[0]
+    copies = [found for found in _activation_copies(text)
+              if "ssm_" in hlo.instructions[found[0]].op_name]
+    assert not copies, copies
+
+
+ENDS = {  # channels of the array, of the run, where the run starts
+    "conv_6144": ("conv", 6144, 6144, 0, jnp.bfloat16),
+    "conv_x_in_place": ("conv", 10304, 4096, 4096, jnp.bfloat16),
+    "conv_C_in_place": ("conv", 10304, 1024, 9216, jnp.bfloat16),
+    "conv_x_float32": ("conv", 10304, 4096, 4096, jnp.float32),
+    "norm_4096": ("norm", 4096, 4096, 0, jnp.bfloat16),
+    "norm_z_in_place": ("norm", 10304, 4096, 0, jnp.bfloat16),
+    "norm_z_float32": ("norm", 10304, 4096, 0, jnp.float32),
+}
+
+
+@pytest.mark.parametrize("case", list(ENDS))
+def test_mixer_end_kernels_compile_for_v5e(topo, case):
+    """The conv's and the gated norm's forward and backward alone at the
+    cell's shapes, 8192 positions: on the whole of an array and on a run
+    of the in-projection's output where it lies, in bf16 and in float32 (a
+    tile is as many bytes either way, or the backward's five float32 tiles,
+    each held twice, pass the kernel's VMEM). One custom call a pass (the
+    arguments of a program of their own come row-major, so what surrounds
+    the kernels is the cell test's to say)."""
+    from horovod_tpu.ops import ssm_ends as se
+    stage, wide, channels, at, dtype = ENDS[case]
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    x = arg((1, 8192, wide), dtype)
+    y = arg((1, 8192, channels), dtype)
+    vector = arg((channels,), jnp.float32)
+    if stage == "conv":
+        def loss(x, w, b, cotangent):
+            return jnp.sum(se.causal_conv_silu(x, w, b, at=at) * cotangent)
+        compiled = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2))).lower(
+            x, arg((4, channels), jnp.float32), vector, y).compile()
+        expected = {"_conv_fwd_kernel": 1, "_conv_bwd_kernel": 1}
+    else:
+        def loss(y, z, scale, cotangent):
+            return jnp.sum(se.gated_group_norm(y, z, scale, 8, at=at)
+                           * cotangent)
+        compiled = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2))).lower(y, x, vector, y).compile()
+        expected = {"_norm_fwd_kernel": 1, "_norm_bwd_kernel": 1}
+    calls, _ = _kernel_calls(compiled.as_text())
+    assert calls == expected
 
 
 # -- the SmallThinker cell at its real size ----------------------------------------
