@@ -86,7 +86,8 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -1e30  # large-negative instead of -inf: keeps exp() NaN-free
+from horovod_tpu.ops.kernel_call import (NEG_INF, NN, NT, TN, dot,
+                                         on_this_platform, scalar_spec)
 
 # Short-sequence crossover for the auto-router (:func:`attention`). An
 # earlier chip run, no longer on file, had plain XLA dot attention ahead of
@@ -218,19 +219,6 @@ def _safe_lse(lse):
     return jnp.where(lse > NEG_INF / 2, lse, -NEG_INF)
 
 
-_NT = (((1,), (1,)), ((), ()))   # a @ b^T
-_NN = (((1,), (0,)), ((), ()))   # a @ b
-_TN = (((0,), (0,)), ((), ()))   # a^T @ b
-
-
-def _dot(a, b, dims):
-    # Matmuls run in the input dtype (bf16 rides the fast MXU path; fp32
-    # inputs keep full precision) and accumulate in fp32 via
-    # preferred_element_type — casting inputs up to fp32 would force 3-pass
-    # fp32 MXU matmuls and ~30% more step time.
-    return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
-
-
 def _fwd_body(qo_ref, ko_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *,
               block_q: int, block_k: int, causal: bool, sm_scale: float,
               kv_len: int, window: Optional[int] = None,
@@ -249,7 +237,7 @@ def _fwd_body(qo_ref, ko_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *,
         m, l, acc = carry  # [1, block_q], [1, block_q], [d_v, block_q]
         k = k_ref[0, pl.ds(kj * block_k, block_k), :]
         v = v_ref[0, pl.ds(kj * block_k, block_k), :]
-        s = _dot(k, q, _NT)
+        s = dot(k, q, NT)
         if not scaled:
             s = s * sm_scale
         if block_mask is not None:  # static, like the window
@@ -262,7 +250,7 @@ def _fwd_body(qo_ref, ko_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *,
         p = jnp.exp(s - m_new)  # q rows fully at NEG_INF decay to ~0
         alpha = jnp.exp(m - m_new)
         l = l * alpha + jnp.sum(p, axis=0, keepdims=True)
-        acc = acc * alpha + _dot(v, p.astype(v.dtype), _TN)
+        acc = acc * alpha + dot(v, p.astype(v.dtype), TN)
         return m_new, l, acc
 
     num_k = kv_len // block_k
@@ -312,7 +300,7 @@ def _bwd_dq_body(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     def body(kj, dq):
         k = k_ref[0, pl.ds(kj * block_k, block_k), :]
         v = v_ref[0, pl.ds(kj * block_k, block_k), :]
-        s = _dot(k, q, _NT)
+        s = dot(k, q, NT)
         if not scaled:
             s = s * sm_scale
         p = jnp.exp(s - lse)
@@ -322,8 +310,8 @@ def _bwd_dq_body(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         elif causal:
             p = jnp.where(_visible(q_off, k_off, qi * block_q, kj * block_k,
                                    s.shape, 1, window), p, 0.0)
-        ds = p * (_dot(v, do, _NT) + corr)
-        return dq + _dot(k, ds.astype(k.dtype), _TN)
+        ds = p * (dot(v, do, NT) + corr)
+        return dq + dot(k, ds.astype(k.dtype), TN)
 
     num_k = kv_len // block_k
     first_k = 0
@@ -363,7 +351,7 @@ def _bwd_dkv_body(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     def summed(probs, rows):
         """``probs^T @ rows`` over the q positions, as the sums lie:
         [d, block_k] for narrow heads, [block_k, d] otherwise."""
-        return _dot(rows, probs, _TN) if narrow else _dot(probs, rows, _NN)
+        return dot(rows, probs, TN) if narrow else dot(probs, rows, NN)
 
     def zeros(d):
         return jnp.zeros((d, block_k) if narrow else (block_k, d),
@@ -377,9 +365,9 @@ def _bwd_dkv_body(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         corr = corr_ref[0, :, pl.ds(i * block_q, block_q)]  # [1, block_q]
         if narrow:  # scores [block_q, block_k]
             lse, corr = lse[0][:, None], corr[0][:, None]
-            s, dp = _dot(q, k, _NT), _dot(do, v, _NT)
+            s, dp = dot(q, k, NT), dot(do, v, NT)
         else:       # scores [block_k, block_q]
-            s, dp = _dot(k, q, _NT), _dot(v, do, _NT)
+            s, dp = dot(k, q, NT), dot(v, do, NT)
         if not scaled:
             s = s * sm_scale
         p = jnp.exp(s - lse)
@@ -497,10 +485,6 @@ def _sum_groups(x, group: int):
                    dtype=jnp.float32).astype(x.dtype)
 
 
-def _scalar_spec():
-    return pl.BlockSpec(memory_space=pltpu.SMEM)
-
-
 _DEFAULT_VMEM_BLOCKS = 10 << 20  # what the compiler's own limit is left to
 
 
@@ -538,21 +522,29 @@ def _kernel(plain, windowed, blockdiff, window, block_mask, **static):
     return functools.partial(windowed, window=window, **static)
 
 
-def _flash_fwd(q, k, v, q_off, k_off, causal, sm_scale, block_q, block_k,
-               interpret, window=None, block_mask=None):
-    b, tq, h, d = q.shape
-    tk, dv = k.shape[1], v.shape[-1]
-    group = h // k.shape[2]  # k and v at their own heads: see _head_spec
-    qb, kb, vb = _bh_first(q), _bh_first(k), _bh_first(v)
-    grid = (b * h, tq // block_q)
-    kernel = _kernel(_fwd_kernel, _fwd_window_kernel, _fwd_blockdiff_kernel,
-                     window, block_mask, block_q=block_q, block_k=block_k,
-                     causal=causal, sm_scale=sm_scale, kv_len=tk)
-    o, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
+# The three calls proper, each under ``jax.jit`` and reached through
+# ``kernel_call.on_this_platform``: a kernel's body is traced once a
+# signature (shapes, dtypes and the static values below) a process, however
+# many layers call it, and Mosaic or interpret mode is the choice of the
+# platform lowered for. They take and return ``[B * heads, T, D]``
+# (:func:`_bh_first`); a group's size is read from the heads of q and k.
+_STATIC = ("causal", "sm_scale", "block_q", "block_k", "window", "block_mask",
+           "interpret")
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def _fwd_call(q_off, k_off, qb, kb, vb, *, causal, sm_scale, block_q,
+              block_k, window, block_mask, interpret):
+    bh, tq, d = qb.shape
+    tk, dv = kb.shape[1], vb.shape[-1]
+    group = bh // kb.shape[0]  # k and v at their own heads: see _head_spec
+    return pl.pallas_call(
+        _kernel(_fwd_kernel, _fwd_window_kernel, _fwd_blockdiff_kernel,
+                window, block_mask, block_q=block_q, block_k=block_k,
+                causal=causal, sm_scale=sm_scale, kv_len=tk),
+        grid=(bh, tq // block_q),
         in_specs=[
-            _scalar_spec(), _scalar_spec(),
+            scalar_spec(), scalar_spec(),
             _head_spec(block_q, d, tiled=True),
             _head_spec(tk, d, tiled=False, group=group),
             _head_spec(tk, dv, tiled=False, group=group),
@@ -562,14 +554,97 @@ def _flash_fwd(q, k, v, q_off, k_off, causal, sm_scale, block_q, block_k,
             _head_spec(1, tq, tiled=False),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, tq, dv), q.dtype),
-            jax.ShapeDtypeStruct((b * h, 1, tq), jnp.float32),
+            jax.ShapeDtypeStruct((bh, tq, dv), qb.dtype),
+            jax.ShapeDtypeStruct((bh, 1, tq), jnp.float32),
         ],
         compiler_params=_vmem_params(
-            ((block_q, d), q.dtype), ((tk, d), k.dtype), ((tk, dv), v.dtype),
-            ((block_q, dv), q.dtype), ((1, tq), jnp.float32)),
+            ((block_q, d), qb.dtype), ((tk, d), kb.dtype),
+            ((tk, dv), vb.dtype), ((block_q, dv), qb.dtype),
+            ((1, tq), jnp.float32)),
         interpret=interpret,
     )(q_off, k_off, qb, kb, vb)
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def _bwd_dq_call(q_off, k_off, qb, kb, vb, dob, lse, corr, *, causal,
+                 sm_scale, block_q, block_k, window, block_mask, interpret):
+    bh, tq, d = qb.shape
+    tk, dv = kb.shape[1], vb.shape[-1]
+    group = bh // kb.shape[0]
+    row = _head_spec(1, tq, tiled=False)  # lse, corr
+    return pl.pallas_call(
+        _kernel(_bwd_dq_kernel, _bwd_dq_window_kernel,
+                _bwd_dq_blockdiff_kernel, window, block_mask,
+                block_q=block_q, block_k=block_k, causal=causal,
+                sm_scale=sm_scale, kv_len=tk),
+        grid=(bh, tq // block_q),
+        in_specs=[
+            scalar_spec(), scalar_spec(),
+            _head_spec(block_q, d, tiled=True),
+            _head_spec(tk, d, tiled=False, group=group),
+            _head_spec(tk, dv, tiled=False, group=group),
+            _head_spec(block_q, dv, tiled=True),
+            row, row,
+        ],
+        out_specs=_head_spec(block_q, d, tiled=True),
+        out_shape=jax.ShapeDtypeStruct((bh, tq, d), qb.dtype),
+        compiler_params=_vmem_params(
+            ((2 * block_q, d), qb.dtype), ((tk, d), kb.dtype),
+            ((tk, dv), vb.dtype), ((block_q, dv), qb.dtype),
+            ((2, tq), jnp.float32)),
+        interpret=interpret,
+    )(q_off, k_off, qb, kb, vb, dob, lse, corr)
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def _bwd_dkv_call(q_off, k_off, qb, kb, vb, dob, lse, corr, *, causal,
+                  sm_scale, block_q, block_k, window, block_mask, interpret):
+    """One (batch, query head) a grid row here too, q and do of that head
+    resident: a key head's tile is read by each row of its group, which
+    writes a dk and dv of its own."""
+    bh, tq, d = qb.shape
+    tk, dv = kb.shape[1], vb.shape[-1]
+    group = bh // kb.shape[0]
+    row = _head_spec(1, tq, tiled=False)
+    return pl.pallas_call(
+        _kernel(_bwd_dkv_kernel, _bwd_dkv_window_kernel,
+                _bwd_dkv_blockdiff_kernel, window, block_mask,
+                block_q=block_q, block_k=block_k, causal=causal,
+                sm_scale=sm_scale, q_len=tq),
+        grid=(bh, tk // block_k),
+        in_specs=[
+            scalar_spec(), scalar_spec(),
+            _head_spec(tq, d, tiled=False),
+            _head_spec(block_k, d, tiled=True, group=group),
+            _head_spec(block_k, dv, tiled=True, group=group),
+            _head_spec(tq, dv, tiled=False),
+            row, row,
+        ],
+        out_specs=[
+            _head_spec(block_k, d, tiled=True),
+            _head_spec(block_k, dv, tiled=True),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, tk, d), kb.dtype),
+            jax.ShapeDtypeStruct((bh, tk, dv), vb.dtype),
+        ],
+        compiler_params=_vmem_params(
+            ((tq, d), qb.dtype), ((2 * block_k, d), kb.dtype),
+            ((2 * block_k, dv), vb.dtype), ((tq, dv), qb.dtype),
+            ((2, tq), jnp.float32)),
+        interpret=interpret,
+    )(q_off, k_off, qb, kb, vb, dob, lse, corr)
+
+
+def _flash_fwd(q, k, v, q_off, k_off, causal, sm_scale, block_q, block_k,
+               interpret, window=None, block_mask=None):
+    b, tq, h, _ = q.shape
+    o, lse = on_this_platform(
+        functools.partial(_fwd_call, causal=causal, sm_scale=sm_scale,
+                          block_q=block_q, block_k=block_k, window=window,
+                          block_mask=block_mask),
+        q_off, k_off, _bh_first(q), _bh_first(k), _bh_first(v),
+        interpret=interpret)
     o_out = checkpoint_name(_bh_last(o, b), FLASH_RESIDUALS[0])
     lse = checkpoint_name(lse, FLASH_RESIDUALS[1])
     lse_out = lse.reshape(b, h, tq)
@@ -588,8 +663,7 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, interpret, window,
                block_mask, res, cots):
     q, k, v, o, lse, q_off, k_off = res
     do, dlse = cots
-    b, tq, h, d = q.shape
-    tk, dv = k.shape[1], v.shape[-1]
+    b, tq, h, _ = q.shape
     group = h // k.shape[2]
     dob = _bh_first(do.astype(q.dtype))
     ob = _bh_first(o)
@@ -600,64 +674,14 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, interpret, window,
     corr = (dlse.reshape(b * h, tq).astype(jnp.float32) - delta
             if dlse is not None else -delta)
     corr = corr.reshape(b * h, 1, tq)  # full-row blocks, like lse
-    qb, kb, vb = _bh_first(q), _bh_first(k), _bh_first(v)
-    row = _head_spec(1, tq, tiled=False)  # lse, corr
-
-    dq = pl.pallas_call(
-        _kernel(_bwd_dq_kernel, _bwd_dq_window_kernel,
-                _bwd_dq_blockdiff_kernel, window, block_mask,
-                block_q=block_q, block_k=block_k, causal=causal,
-                sm_scale=sm_scale, kv_len=tk),
-        grid=(b * h, tq // block_q),
-        in_specs=[
-            _scalar_spec(), _scalar_spec(),
-            _head_spec(block_q, d, tiled=True),
-            _head_spec(tk, d, tiled=False, group=group),
-            _head_spec(tk, dv, tiled=False, group=group),
-            _head_spec(block_q, dv, tiled=True),
-            row, row,
-        ],
-        out_specs=_head_spec(block_q, d, tiled=True),
-        out_shape=jax.ShapeDtypeStruct((b * h, tq, d), q.dtype),
-        compiler_params=_vmem_params(
-            ((2 * block_q, d), q.dtype), ((tk, d), k.dtype),
-            ((tk, dv), v.dtype), ((block_q, dv), q.dtype),
-            ((2, tq), jnp.float32)),
-        interpret=interpret,
-    )(q_off, k_off, qb, kb, vb, dob, lse, corr)
-
-    # one (batch, query head) a grid row here too, q and do of that head
-    # resident: a key head's tile is read by each row of its group, which
-    # writes a dk and dv of its own
-    dk, dvv = pl.pallas_call(
-        _kernel(_bwd_dkv_kernel, _bwd_dkv_window_kernel,
-                _bwd_dkv_blockdiff_kernel, window, block_mask,
-                block_q=block_q, block_k=block_k, causal=causal,
-                sm_scale=sm_scale, q_len=tq),
-        grid=(b * h, tk // block_k),
-        in_specs=[
-            _scalar_spec(), _scalar_spec(),
-            _head_spec(tq, d, tiled=False),
-            _head_spec(block_k, d, tiled=True, group=group),
-            _head_spec(block_k, dv, tiled=True, group=group),
-            _head_spec(tq, dv, tiled=False),
-            row, row,
-        ],
-        out_specs=[
-            _head_spec(block_k, d, tiled=True),
-            _head_spec(block_k, dv, tiled=True),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b * h, tk, d), k.dtype),
-            jax.ShapeDtypeStruct((b * h, tk, dv), v.dtype),
-        ],
-        compiler_params=_vmem_params(
-            ((tq, d), q.dtype), ((2 * block_k, d), k.dtype),
-            ((2 * block_k, dv), v.dtype), ((tq, dv), q.dtype),
-            ((2, tq), jnp.float32)),
-        interpret=interpret,
-    )(q_off, k_off, qb, kb, vb, dob, lse, corr)
-
+    static = dict(causal=causal, sm_scale=sm_scale, block_q=block_q,
+                  block_k=block_k, window=window, block_mask=block_mask)
+    args = (q_off, k_off, _bh_first(q), _bh_first(k), _bh_first(v), dob, lse,
+            corr)
+    dq = on_this_platform(functools.partial(_bwd_dq_call, **static), *args,
+                          interpret=interpret)
+    dk, dvv = on_this_platform(functools.partial(_bwd_dkv_call, **static),
+                               *args, interpret=interpret)
     return (_bh_last(dq, b), _bh_last(_sum_groups(dk, group), b),
             _bh_last(_sum_groups(dvv, group), b),
             jnp.zeros_like(q_off), jnp.zeros_like(k_off))
@@ -860,8 +884,10 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     masking of sequence-sharded blocks. ``return_lse=True`` also returns the
     per-row
     log-sum-exp, shaped [B, H, Tq], for online-softmax merging; both
-    outputs are differentiable. ``interpret=None`` auto-selects interpret
-    mode off-TPU so the same call runs in CPU tests.
+    outputs are differentiable. ``interpret=None`` leaves Mosaic or interpret
+    mode to the platform the program is lowered for (``ops/kernel_call.py``:
+    the same call runs in CPU tests, and a compile for a described chip holds
+    the kernels); a bool forces one.
 
     ``window=W`` (static, causal only) narrows the mask to
     ``0 <= q_pos - k_pos < W`` in global positions: a query sees ``W`` keys,
@@ -884,8 +910,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     block_mask = _checked_block_mask(
         block_mask, causal, window, q_offset is None and k_offset is None)
     _count_call(_kv_group(q, k, v))
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     block_q = _pick_block(tq, block_q)
     block_k = _pick_block(k.shape[1], block_k)
     if block_mask is not None and (block_q % block_mask[0]

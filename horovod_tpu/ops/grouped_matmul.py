@@ -42,11 +42,11 @@ Two kernels under one ``jax.custom_vjp``:
 
 Operands in the dtype they come in (bf16 in the training step), products
 accumulated in float32, results in ``a``'s dtype and ``dw`` in ``w``'s: the
-arithmetic of ``ragged_dot`` and its transposes. ``lax.platform_dependent``
-lowers the kernels for the TPU and runs the same kernels in interpret mode
-elsewhere (``ops/ssd.py``'s arrangement: nothing chooses between paths and
-a compile for a described chip holds the kernels), and the calls are under
-``jax.jit`` so a kernel is traced once a process and variant.
+arithmetic of ``ragged_dot`` and its transposes. The kernels are called by
+``ops/kernel_call.py``'s rule (Mosaic where the program is lowered for a TPU,
+interpret mode elsewhere, under ``jax.jit``: nothing chooses between paths, a
+compile for a described chip holds the kernels, and a kernel is traced once
+a process and variant).
 
 Shapes: ``a`` [rows, k] with ``rows`` a multiple of the number of blocks;
 ``w`` [groups, k, n] (``transposed``: [groups, n, k]); ``group_of_block``
@@ -60,12 +60,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from horovod_tpu.ops.flash_attention import _NN, _NT, _TN, _dot
-from horovod_tpu.ops.ssd import _on_this_platform
+from horovod_tpu.ops.kernel_call import NN, NT, TN, dot, on_this_platform
 
 _MATRIX_BYTES = 12 << 20  # a group's matrix block in VMEM, held twice
 _ACCUMULATOR_BYTES = 8 << 20  # the float32 tile of dw[g] in VMEM
@@ -98,8 +96,8 @@ def _last_live(block, live_ref):
 def _gmm_kernel(group_ref, live_ref, a_ref, w_ref, out_ref, *, transposed):
     @pl.when(pl.program_id(1) < live_ref[0])
     def _():
-        out_ref[...] = _dot(a_ref[...], w_ref[0],
-                            _NT if transposed else _NN).astype(out_ref.dtype)
+        out_ref[...] = dot(a_ref[...], w_ref[0],
+                           NT if transposed else NN).astype(out_ref.dtype)
 
 
 def _gmm_dw_kernel(group_ref, live_ref, a_ref, d_ref, out_ref, acc_ref):
@@ -114,7 +112,7 @@ def _gmm_dw_kernel(group_ref, live_ref, a_ref, d_ref, out_ref, acc_ref):
             group_ref[jnp.minimum(b + 1, blocks - 1)] != group)
         # a group's first block starts the sum: no pass that zeroes the tile
         acc_ref[...] = jnp.where(first, 0.0, acc_ref[...]) \
-            + _dot(a_ref[...], d_ref[...], _TN)
+            + dot(a_ref[...], d_ref[...], TN)
 
         @pl.when(last)
         def _():
@@ -194,7 +192,7 @@ def _towards_the_matrices(a, d, group_of_block, live, groups, dtype):
     """``dw[g] = a_g^T @ d_g`` over the live blocks, zeros for a group that
     has none (a selection XLA fuses into whatever reads the gradient)."""
     blocks = group_of_block.shape[0]
-    dw = _on_this_platform(
+    dw = on_this_platform(
         functools.partial(_gmm_dw_call, groups=groups, dtype=dtype,
                           block_rows=a.shape[0] // blocks),
         group_of_block, live, a, d)
@@ -212,7 +210,7 @@ def grouped_matmul(a, w, group_of_block, live, transposed=False):
     others are not written (module text). Differentiable in ``a`` (the
     gradient's rows past the live blocks are not written either) and in
     ``w``."""
-    return _on_this_platform(
+    return on_this_platform(
         functools.partial(_gmm_call, transposed=transposed,
                           block_rows=a.shape[0] // group_of_block.shape[0]),
         group_of_block, live, a, w)

@@ -40,9 +40,9 @@ one more job for every (token block, slot) since a chunk that straddles
 blocks is fetched for each); the jobs past the last real one repeat its
 block and chunk with no row, so nothing is fetched or written for them.
 
-``lax.platform_dependent`` lowers the kernel for the TPU and runs it in
-interpret mode elsewhere, and the call is under ``jax.jit`` so it is traced
-once a shape (``ops/grouped_matmul.py``'s arrangement).
+The kernel is called by ``ops/kernel_call.py``'s rule: lowered for the TPU,
+in interpret mode elsewhere, and under ``jax.jit`` so it is traced once a
+shape.
 """
 
 from __future__ import annotations
@@ -56,7 +56,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from horovod_tpu.ops.ssd import _on_this_platform
+from horovod_tpu.ops.kernel_call import on_this_platform
 
 BLOCK_TOKENS = 256  # a token block's float32 rows: 2.6 MB of VMEM at d 2560
 CHUNK_ROWS = 32  # rows a job fetches: a run is 12-24 rows under balance
@@ -188,6 +188,6 @@ def add_rows_at_tokens(out, rows, weight, tokens, slots: int, fresh=False):
     read. ``T`` and ``R / slots`` decide the block and chunk sizes
     (:func:`block_tokens_of`, :func:`chunk_rows_of`); on the TPU ``T`` is a
     multiple of 8 and ``R / slots`` of 16, ``d`` of 128."""
-    return _on_this_platform(
+    return on_this_platform(
         functools.partial(_add_rows_call, slots=slots), out, rows,
         weight.astype(jnp.float32), tokens, jnp.asarray(fresh))

@@ -34,8 +34,9 @@ takes the gradient of: four ``einsum``s and a ``lax.scan``, whose ``[L, L]``
 decays and per-chunk states XLA writes to HBM. It is what the kernels are
 held to and is reached by tests only. :func:`ssd_scan` is what the mixer
 calls, on every backend: two Pallas kernels under a ``jax.custom_vjp``
-(``lax.platform_dependent`` lowers them for the TPU and runs the same
-kernels in interpret mode elsewhere, so nothing chooses between paths).
+(called by ``ops/kernel_call.py``'s rule: Mosaic where the program is lowered
+for a TPU, the same kernels in interpret mode elsewhere, so nothing chooses
+between paths).
 
 *Positions along the lanes.* The kernels take ``x``, ``B`` and ``C`` as
 ``[B, H P, T]`` and ``[B, G N, T]`` and work on tiles ``[channels, L]``: a
@@ -96,8 +97,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from horovod_tpu.ops.flash_attention import (NEG_INF, _NN, _NT, _TN, _dot,
-                                             _scalar_spec)
+from horovod_tpu.ops.kernel_call import (GRID_ORDER, NEG_INF, NN, NT, TN, dot,
+                                         on_this_platform, scalar_spec)
 from horovod_tpu.profiler.annotate import ssm_scope
 
 
@@ -284,7 +285,7 @@ def _ssd_fwd_kernel(d_ref, x_ref, b_ref, c_ref, dt_ref, a_ref, y_ref, *rest,
         state_ref[...] = jnp.zeros_like(state_ref)
 
     b, c = b_ref[0], c_ref[0]                         # [N, L]
-    cb = _dot(c, b, _TN)                              # [L (t), L (s)]
+    cb = dot(c, b, TN)                                # [L (t), L (s)]
     dt_rows, a_rows, a_cols, from_start, to_end, whole = _chunk_terms(
         dt_ref, a_ref)
     for first in range(0, r, hs):
@@ -295,13 +296,13 @@ def _ssd_fwd_kernel(d_ref, x_ref, b_ref, c_ref, dt_ref, a_ref, y_ref, *rest,
         xdt_in = xdt.astype(dtype)
         state = state_ref[at, :]                      # [hs P, N]
         y = slab.pick([
-            _dot(xdt_in, (cb * _masked_decay(a_cols, a_rows, first + j))
-                 .astype(dtype), _NT) for j in range(hs)])
-        y += slab.spread(from_start) * _dot(state.astype(dtype), c, _NN)
+            dot(xdt_in, (cb * _masked_decay(a_cols, a_rows, first + j))
+                .astype(dtype), NT) for j in range(hs)])
+        y += slab.spread(from_start) * dot(state.astype(dtype), c, NN)
         y += slab.scalars(d_ref, pl.program_id(1) * r) * x
         y_ref[0, at, :] = y.astype(y_ref.dtype)
-        state = slab.spread(whole) * state + _dot(
-            (xdt * slab.spread(to_end)).astype(dtype), b, _NT)
+        state = slab.spread(whole) * state + dot(
+            (xdt * slab.spread(to_end)).astype(dtype), b, NT)
         state_ref[at, :] = state
         if save_ends:
             ends_ref[0, 0, at, :] = state.astype(ends_ref.dtype)
@@ -335,7 +336,7 @@ def _ssd_bwd_kernel(d_ref, x_ref, dy_ref, b_ref, c_ref, dt_ref, a_ref,
 
     first_chunk = pl.program_id(2) == pl.num_programs(2) - 1
     b, c = b_ref[0], c_ref[0]                          # [N, L]
-    cb = _dot(c, b, _TN)
+    cb = dot(c, b, TN)
     dt_rows, a_rows, a_cols, from_start, to_end, whole = _chunk_terms(
         dt_ref, a_ref)
     last_position = lax.broadcasted_iota(
@@ -363,16 +364,16 @@ def _ssd_bwd_kernel(d_ref, x_ref, dy_ref, b_ref, c_ref, dt_ref, a_ref,
             decay = _masked_decay(a_cols, a_rows, first + j)
             m = cb * decay
             # dy_t . dt_s x_s over the head's channels
-            dm = _dot(slab.only(j, dy).astype(dtype), xdt_in, _TN)
+            dm = dot(slab.only(j, dy).astype(dtype), xdt_in, TN)
             dcb += dm * decay
             pull = dm * m          # what exp(a_t - a_s) passes to a_t, a_s
             da_to.append(jnp.sum(pull, axis=1, keepdims=True))
             da_ref[0, 0, first + j:first + j + 1, :] = -jnp.sum(
                 pull, axis=0, keepdims=True)
-            back.append(_dot(dy_in, m.astype(dtype), _NN))
+            back.append(dot(dy_in, m.astype(dtype), NN))
         # dx before its dt: the chunk's own pairs, then the end state's side
         to_state = slab.spread(to_end)
-        from_grad = _dot(grad_in, b, _NN)              # G B_s, [hs P, L]
+        from_grad = dot(grad_in, b, NN)               # G B_s, [hs P, L]
         dx = slab.pick(back) + to_state * from_grad
         dx_ref[0, at, :] = (dt * dx + slab.scalars(
             d_ref, pl.program_id(1) * r) * dy).astype(dx_ref.dtype)
@@ -380,12 +381,12 @@ def _ssd_bwd_kernel(d_ref, x_ref, dy_ref, b_ref, c_ref, dt_ref, a_ref,
         reach = dy * slab.spread(from_start)           # dy_t exp(a_t)
         reach_in = reach.astype(dtype)
         carried = xdt * to_state                       # what the state took
-        dc += _dot(start, reach_in, _TN)
-        db += _dot(grad_in, carried.astype(dtype), _TN)
+        dc += dot(start, reach_in, TN)
+        db += dot(grad_in, carried.astype(dtype), TN)
         # a_t: what the start state gave position t, less what the end
         # state took of it; a_L: all that the end state is made of
         end_pull = carried * from_grad
-        through_a = reach * _dot(start, c, _NN) - end_pull
+        through_a = reach * dot(start, c, NN) - end_pull
         through_dt = x * dx
         start_pull = grad * start.astype(f32)          # [hs P, N]
         for j in range(hs):
@@ -397,11 +398,11 @@ def _ssd_bwd_kernel(d_ref, x_ref, dy_ref, b_ref, c_ref, dt_ref, a_ref,
             da_ref[0, 0, row, :] += slab.summed(j, through_a) + jnp.where(
                 last_position, at_end, 0.0)
             da_cols_ref[:, row] = da_to[j]
-        grad_ref[at, :] = slab.spread(whole) * grad + _dot(reach_in, c, _NT)
+        grad_ref[at, :] = slab.spread(whole) * grad + dot(reach_in, c, NT)
 
     dcb_in = dcb.astype(dtype)
-    dc_ref[0] = (dc + _dot(b, dcb_in, _NT)).astype(dc_ref.dtype)
-    db_ref[0] = (db + _dot(c, dcb_in, _NN)).astype(db_ref.dtype)
+    dc_ref[0] = (dc + dot(b, dcb_in, NT)).astype(dc_ref.dtype)
+    db_ref[0] = (db + dot(c, dcb_in, NN)).astype(db_ref.dtype)
     da_ref[0, 0] += da_cols_ref[...].T
 
 
@@ -418,20 +419,6 @@ def _rows_of_decay(dt, A, g: int, chunk: int):
     a = _running_sum_last(
         dt_rows * A.astype(jnp.float32).reshape(g, r, 1, 1))
     return dt_rows.reshape(b, g, r, t), a.reshape(b, g, r, t)
-
-
-def _on_this_platform(call, *args):
-    """The kernels compiled for the TPU where the program is lowered for
-    one, in interpret mode anywhere else (the CPU of tier-1): decided by the
-    platform lowered for, so a compile for a described chip holds the
-    kernels too."""
-    return lax.platform_dependent(
-        *args, tpu=functools.partial(call, interpret=False),
-        default=functools.partial(call, interpret=True))
-
-
-_GRID_ORDER = pltpu.CompilerParams(
-    dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
 def _sizes(x, B, dt_rows, chunk: int) -> tuple:
@@ -464,11 +451,11 @@ def _forward_call(D, x, B, C, dt_rows, a_rows, *, chunk, ends_dtype,
         functools.partial(_ssd_fwd_kernel, r=r, p=p,
                           hs=_heads_per_slab(r, p), save_ends=save_ends),
         grid=(b, g, nc),
-        in_specs=[_scalar_spec(), by_chunk, by_chunk_n, by_chunk_n, a_head,
+        in_specs=[scalar_spec(), by_chunk, by_chunk_n, by_chunk_n, a_head,
                   a_head],
         out_specs=out_specs, out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((r * p, n), jnp.float32)],
-        compiler_params=_GRID_ORDER, interpret=interpret,
+        compiler_params=GRID_ORDER, interpret=interpret,
     )(D, x, B, C, dt_rows, a_rows)
 
 
@@ -491,7 +478,7 @@ def _backward_call(D, x, dy, B, C, dt_rows, a_rows, ends, *, chunk,
                           hs=_heads_per_slab(r, p)),
         grid=(b, g, nc),
         in_specs=[
-            _scalar_spec(), by_chunk, by_chunk, by_chunk_n, by_chunk_n, a_head,
+            scalar_spec(), by_chunk, by_chunk, by_chunk_n, by_chunk_n, a_head,
             a_head,
             # the state a chunk starts from is the one before it ended with
             pl.BlockSpec(state, lambda i, j, c: (
@@ -510,11 +497,11 @@ def _backward_call(D, x, dy, B, C, dt_rows, a_rows, ends, *, chunk,
         ],
         scratch_shapes=[pltpu.VMEM((r * p, n), f32),
                         pltpu.VMEM((chunk, r), f32)],
-        compiler_params=_GRID_ORDER, interpret=interpret,
+        compiler_params=GRID_ORDER, interpret=interpret,
     )(D, x, dy, B, C, dt_rows, a_rows, ends)
 
 
-def _positions_last(v):
+def positions_last(v):
     """[B, T, heads or groups, width] as [B, heads width, T]: the kernels'
     tiles hold a position a lane."""
     b, t = v.shape[:2]
@@ -528,9 +515,9 @@ def _positions_first(v, shape):
 
 def _scan_forward(x, dt, A, B, C, D, chunk, ends_dtype):
     dt_rows, a_rows = _rows_of_decay(dt, A, B.shape[2], chunk)
-    return _on_this_platform(
+    return on_this_platform(
         functools.partial(_forward_call, chunk=chunk, ends_dtype=ends_dtype),
-        D.astype(jnp.float32), *map(_positions_last, (x, B, C)), dt_rows,
+        D.astype(jnp.float32), *map(positions_last, (x, B, C)), dt_rows,
         a_rows)
 
 
@@ -551,9 +538,9 @@ def _scan_bwd(chunk, saved, dy):
     b, _, h, p = x.shape
     (dt_rows, a_rows), through_decay = jax.vjp(
         lambda dt, A: _rows_of_decay(dt, A, B.shape[2], chunk), dt, A)
-    dx, dB, dC, ddt_rows, da_rows, dd = _on_this_platform(
+    dx, dB, dC, ddt_rows, da_rows, dd = on_this_platform(
         functools.partial(_backward_call, chunk=chunk),
-        D.astype(jnp.float32), *map(_positions_last, (
+        D.astype(jnp.float32), *map(positions_last, (
             x, dy.astype(x.dtype), B, C)), dt_rows, a_rows, ends)
     ddt, dA = through_decay((ddt_rows, da_rows))
     dD = dd.reshape(b, h, -1).sum((0, 2)).astype(D.dtype)

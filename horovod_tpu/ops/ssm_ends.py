@@ -22,9 +22,9 @@ Two writings of each, as ``ops/ssd.py`` has of the scan.
 :func:`conv_silu_plain` and :func:`gated_norm_plain` are plain ``jax.numpy``
 that autodiff takes the gradient of: what the kernels are held to, reached
 by tests only. :func:`causal_conv_silu` and :func:`gated_group_norm` are
-what the mixer calls, on every backend (``ssd._on_this_platform``: Mosaic
-where the program is lowered for a TPU, the same kernels in interpret mode
-elsewhere).
+what the mixer calls, on every backend (``kernel_call.on_this_platform``:
+Mosaic where the program is lowered for a TPU, the same kernels in interpret
+mode elsewhere).
 
 *Positions along the lanes*, as the scan's kernels have them and for the
 same reason: XLA keeps the mixer's activations with a position minor, so
@@ -81,8 +81,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from horovod_tpu.ops.ssd import (_GRID_ORDER, _on_this_platform,
-                                 _positions_last)
+from horovod_tpu.ops.kernel_call import GRID_ORDER, on_this_platform
+from horovod_tpu.ops.ssd import positions_last
 
 LANES = 128
 # The conv works short, long tiles (no channel of it meets another):
@@ -372,7 +372,7 @@ def _conv_forward_call(x, w, b, *, at, dtype, tile, interpret):
         out_specs=narrow,
         out_shape=jax.ShapeDtypeStruct(
             (x.shape[0], w.shape[0], x.shape[2]), dtype),
-        compiler_params=_GRID_ORDER, interpret=interpret,
+        compiler_params=GRID_ORDER, interpret=interpret,
     )(x, x, w, b)
 
 
@@ -400,7 +400,7 @@ def _conv_backward_call(x, dy, w, b, *whole, at, place, tile, interpret):
                                          width), jnp.float32)],
         input_output_aliases={5: 0} if whole else {},
         scratch_shapes=[pltpu.VMEM((rows, width), jnp.float32)],
-        compiler_params=_GRID_ORDER, interpret=interpret,
+        compiler_params=GRID_ORDER, interpret=interpret,
     )(x, x, dy, w, b, *whole)
 
 
@@ -431,7 +431,7 @@ def _norm_forward_call(y, z, scale, *, groups, eps, at, dtype, tile,
         functools.partial(_norm_fwd_kernel, eps=eps, slab=slab), grid=grid,
         in_specs=[by_tile, of_z, by_channel], out_specs=by_tile,
         out_shape=jax.ShapeDtypeStruct(y.shape, dtype),
-        compiler_params=_GRID_ORDER, interpret=interpret,
+        compiler_params=GRID_ORDER, interpret=interpret,
     )(y, z, scale)
 
 
@@ -454,7 +454,7 @@ def _norm_backward_call(dout, y, z, scale, *, groups, eps, at, tile,
                    jax.ShapeDtypeStruct(y.shape, z.dtype),
                    jax.ShapeDtypeStruct((batch, channels, width),
                                         jnp.float32)],
-        compiler_params=_GRID_ORDER, interpret=interpret,
+        compiler_params=GRID_ORDER, interpret=interpret,
     )(dout, y, z, scale)
 
 
@@ -483,9 +483,9 @@ def _runs(widths, w, b):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
 def _conv(run, wide, w, b, at, dtype, widths):
     _count_call("conv", "fwd")
-    return tuple(_positions_last(_on_this_platform(
+    return tuple(positions_last(on_this_platform(
         functools.partial(_conv_forward_call, at=at + start, dtype=dtype,
-                          tile=CONV_TILE), _positions_last(wide), taps, bias))
+                          tile=CONV_TILE), positions_last(wide), taps, bias))
         for start, taps, bias in _runs(widths, w, b))
 
 
@@ -499,14 +499,14 @@ def _conv_bwd(at, dtype, widths, saved, dys):
     _count_call("conv", "bwd")
     whole, sums = (), []
     for (start, taps, bias), dy in zip(_runs(widths, w, b), dys):
-        dx, of_run = _on_this_platform(
+        dx, of_run = on_this_platform(
             functools.partial(_conv_backward_call, at=at + start,
                               place=(start, sum(widths)), tile=CONV_TILE),
-            _positions_last(wide), _positions_last(dy), taps, bias, *whole)
+            positions_last(wide), positions_last(dy), taps, bias, *whole)
         whole = (dx,)
         sums.append(of_run.sum((0, 3)))
     sums = jnp.concatenate(sums, axis=1)
-    return _positions_last(dx), jnp.zeros_like(wide), \
+    return positions_last(dx), jnp.zeros_like(wide), \
         sums[:-1].astype(w.dtype), sums[-1].astype(b.dtype)
 
 
@@ -537,10 +537,10 @@ def causal_conv_silu(x, w, b, dtype=None, at: int = 0, widths=None):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
 def _norm(y, run, wide, scale, groups, eps, at, dtype):
     _count_call("gate_norm", "fwd")
-    return _positions_last(_on_this_platform(
+    return positions_last(on_this_platform(
         functools.partial(_norm_forward_call, groups=groups, eps=eps, at=at,
                           dtype=dtype, tile=NORM_TILE),
-        _positions_last(y), _positions_last(wide), _columns(scale)))
+        positions_last(y), positions_last(wide), _columns(scale)))
 
 
 def _norm_fwd(y, run, wide, scale, groups, eps, at, dtype):
@@ -551,11 +551,11 @@ def _norm_fwd(y, run, wide, scale, groups, eps, at, dtype):
 def _norm_bwd(groups, eps, at, dtype, saved, dout):
     y, wide, scale = saved
     _count_call("gate_norm", "bwd")
-    dy, dz, pulls = _on_this_platform(
+    dy, dz, pulls = on_this_platform(
         functools.partial(_norm_backward_call, groups=groups, eps=eps,
                           at=at, tile=NORM_TILE),
-        *map(_positions_last, (dout, y, wide)), _columns(scale))
-    return _positions_last(dy), _positions_last(dz), \
+        *map(positions_last, (dout, y, wide)), _columns(scale))
+    return positions_last(dy), positions_last(dz), \
         jnp.zeros_like(wide), pulls.sum((0, 2)).astype(scale.dtype)
 
 

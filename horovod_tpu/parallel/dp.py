@@ -50,7 +50,7 @@ DP_AXES = ("data", "fsdp")
 # pieces that run inside neighbouring fusions (``%async_collective_fusion``
 # computations in the compiled text), and only an all-reduce of ONE operand
 # is taken. Each entry is necessary for that (the compile for a described
-# v5e:2x2 in tests/test_tpu_compile.py; the chip: PERF.md §6, PR 29):
+# v5e:2x2 in tests/test_tpu_compile_steps.py; the chip: PERF.md §6, PR 29):
 ASYNC_EXCHANGE_COMPILER_OPTIONS = {
     # forms start/done pairs of the all-reduces; without it no other entry
     # changes the program

@@ -242,20 +242,3 @@ def small_allreduce_latency(serving_mode: bool, ranks: int = 2,
             os.environ.pop("HOROVOD_SERVING_MODE", None)
         else:
             os.environ["HOROVOD_SERVING_MODE"] = prev
-
-
-def small_tensor_cliff_report(**kwargs) -> Dict[str, object]:
-    """Small-allreduce latency with serving mode off vs on,
-    plus the speedup — the regression number for the fusion-cycle cost
-    cliff satellite."""
-    off = small_allreduce_latency(False, **kwargs)
-    on = small_allreduce_latency(True, **kwargs)
-    # Mean is the headline: in fused mode the co-negotiation race means
-    # only a fraction of iterations actually fuse (the rest complete fast
-    # solo), so the p50 can land on the fast side while the mean carries
-    # the cliff iterations honestly.
-    mean = round(off["mean_ms"] / on["mean_ms"], 2) if on["mean_ms"] \
-        else None
-    p50 = round(off["p50_ms"] / on["p50_ms"], 2) if on["p50_ms"] else None
-    return {"fused_mode": off, "serving_mode": on,
-            "mean_speedup_x": mean, "p50_speedup_x": p50}

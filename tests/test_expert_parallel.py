@@ -233,9 +233,9 @@ def test_moe_topk_gradients_match_dense_reference():
     got = jax.jit(jax.grad(
         lambda x, *w: loss(lambda *a: moe_topk(*a, 4)[0], x, *w),
         argnums=(0, 1, 2, 3, 4)))(x, *weights)
-    want = jax.grad(
+    want = jax.jit(jax.grad(
         lambda x, *w: loss(lambda *a: _dense_gated(*a, 4), x, *w),
-        argnums=(0, 1, 2, 3, 4))(x, *weights)
+        argnums=(0, 1, 2, 3, 4)))(x, *weights)
     for g, w in zip(got, want):
         assert float(jnp.abs(w).sum()) > 0
         np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-3,
@@ -344,8 +344,8 @@ def test_the_shares_add_up_to_the_uncut_layer(shares):
         return jnp.sum(jnp.tanh(layer(x, w)) ** 2)
     got = jax.jit(jax.grad(lambda x, w: loss(
         lambda *a: cut_layer(*a)[0], x, w), argnums=(0, 1)))(x, w)
-    want = jax.grad(lambda x, w: loss(_uncut_layer, x, w),
-                    argnums=(0, 1))(x, w)
+    want = jax.jit(jax.grad(lambda x, w: loss(_uncut_layer, x, w),
+                            argnums=(0, 1)))(x, w)
     assert float(jnp.abs(got[1]["bias"]).sum()) == 0.0  # no gradient
     for g, v in zip(jax.tree_util.tree_leaves((got[0], {
             k: a for k, a in got[1].items() if k != "bias"})),
@@ -491,7 +491,8 @@ def _walked_share_against_dense(ep, sizes, first, count, tile, tiles):
         return jnp.sum(jnp.tanh(layer(x, w, experts)) ** 2)
     got = jax.jit(jax.grad(lambda x, w: loss(
         lambda *a: share(*a)[0], x, w), argnums=(0, 1)))(x, w)
-    want = jax.grad(lambda x, w: loss(dense, x, w), argnums=(0, 1))(x, w)
+    want = jax.jit(jax.grad(lambda x, w: loss(dense, x, w),
+                            argnums=(0, 1)))(x, w)
     for name, g, v in [("x", got[0], want[0])] + [
             (key, got[1][key], want[1][key])
             for key in ("router", "up", "down")]:
@@ -641,8 +642,8 @@ def test_a_full_load_is_exact_wherever_an_experts_pairs_end(small_tiles,
         return jnp.sum(jnp.tanh(layer(x, weights, experts)) ** 2)
     got = jax.jit(jax.grad(lambda x, w: loss(
         lambda *a: _told_topk(*a)[0], x, w), argnums=(0, 1)))(x, weights)
-    want = jax.grad(lambda x, w: loss(_told_dense_gated, x, w),
-                    argnums=(0, 1))(x, weights)
+    want = jax.jit(jax.grad(lambda x, w: loss(_told_dense_gated, x, w),
+                            argnums=(0, 1)))(x, weights)
     for name, g, v in zip(("x", "router", "gate", "up", "down"),
                           (got[0],) + tuple(got[1]),
                           (want[0],) + tuple(want[1])):
@@ -1104,8 +1105,8 @@ def test_the_shares_of_a_smallthinker_layer_add_up_to_the_uncut_layer(
         return jnp.sum(jnp.tanh(layer(r, x, w)) ** 2)
     got = jax.jit(jax.grad(lambda *a: loss(
         lambda *b: cut_layer(*b)[0], *a), argnums=(0, 1, 2)))(r, x, w)
-    want = jax.grad(lambda *a: loss(_uncut_smallthinker_layer, *a),
-                    argnums=(0, 1, 2))(r, x, w)
+    want = jax.jit(jax.grad(lambda *a: loss(_uncut_smallthinker_layer, *a),
+                            argnums=(0, 1, 2)))(r, x, w)
     for g, v in zip(jax.tree_util.tree_leaves(got),
                     jax.tree_util.tree_leaves(want)):
         assert float(jnp.abs(v).sum()) > 0

@@ -23,28 +23,30 @@ def test_mnist_convnet_shapes():
 
 def test_resnet18_forward():
     model = ResNet18(num_classes=10)
-    variables = model.init(jax.random.key(0), jnp.zeros((1, 64, 64, 3)),
-                           train=False)
-    out = model.apply(variables, jnp.zeros((2, 64, 64, 3)), train=False)
+    variables = jax.jit(lambda k, x: model.init(k, x, train=False))(
+        jax.random.key(0), jnp.zeros((1, 64, 64, 3)))
+    out = jax.jit(lambda v, x: model.apply(v, x, train=False))(
+        variables, jnp.zeros((2, 64, 64, 3)))
     assert out.shape == (2, 10)
 
 
 def test_resnet50_param_count():
     """ResNet-50 ImageNet has ~25.56M params (torchvision parity)."""
     model = ResNet50(num_classes=1000)
-    variables = model.init(jax.random.key(0), jnp.zeros((1, 32, 32, 3)),
-                           train=False)
+    variables = jax.eval_shape(  # the count is in the shapes
+        lambda k, x: model.init(k, x, train=False), jax.random.key(0),
+        jnp.zeros((1, 32, 32, 3)))
     n = _param_count(variables["params"])
     assert 25.4e6 < n < 25.7e6, f"param count {n}"
 
 
 def test_resnet50_train_mode_updates_batch_stats():
     model = ResNet50(num_classes=10, dtype=jnp.float32)
-    variables = model.init(jax.random.key(0), jnp.zeros((1, 32, 32, 3)),
-                           train=False)
+    variables = jax.jit(lambda k, x: model.init(k, x, train=False))(
+        jax.random.key(0), jnp.zeros((1, 32, 32, 3)))
     x = jnp.asarray(np.random.RandomState(0).rand(2, 32, 32, 3), jnp.float32)
-    out, new_state = model.apply(variables, x, train=True,
-                                 mutable=["batch_stats"])
+    out, new_state = jax.jit(lambda v, x: model.apply(
+        v, x, train=True, mutable=["batch_stats"]))(variables, x)
     assert out.shape == (2, 10)
     # batch stats must actually move
     old = jax.tree_util.tree_leaves(variables["batch_stats"])
@@ -58,10 +60,10 @@ def test_bert_base_param_count_and_forward():
     from horovod_tpu.models import BertBase
     model = BertBase(max_len=64, dtype=jnp.float32)
     tokens = jnp.asarray(np.random.RandomState(0).randint(0, 30522, (2, 16)))
-    variables = model.init(jax.random.key(0), tokens)
+    variables = jax.jit(model.init)(jax.random.key(0), tokens)
     n = _param_count(variables["params"])
     assert 105e6 < n < 115e6, f"param count {n}"
-    logits = model.apply(variables, tokens)
+    logits = jax.jit(model.apply)(variables, tokens)
     assert logits.shape == (2, 16, 30522)
     assert logits.dtype == jnp.float32
 
@@ -77,8 +79,8 @@ def test_bert_flash_attention_variant():
     tokens = jnp.asarray(rs.randint(0, 97, (2, 16)))
     model = BertEncoder(vocab=97, layers=2, hidden=32, heads=4, mlp_dim=64,
                         max_len=16, dtype=jnp.float32, use_flash=True)
-    variables = model.init(jax.random.key(0), tokens)
-    logits = model.apply(variables, tokens)
+    variables = jax.jit(model.init)(jax.random.key(0), tokens)
+    logits = jax.jit(model.apply)(variables, tokens)
     assert logits.shape == (2, 16, 97)
     assert np.isfinite(np.asarray(logits)).all()
 
@@ -89,7 +91,7 @@ def test_bert_flash_attention_variant():
         return optax.softmax_cross_entropy_with_integer_labels(
             lg, labels).mean()
 
-    grads = jax.grad(loss_fn)(variables["params"])
+    grads = jax.jit(jax.grad(loss_fn))(variables["params"])
     flat = jax.tree_util.tree_leaves(grads)
     assert all(np.isfinite(np.asarray(g)).all() for g in flat)
     assert sum(float(jnp.abs(g).sum()) for g in flat) > 0

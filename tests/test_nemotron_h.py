@@ -383,10 +383,10 @@ def test_two_key_heads_equal_explicitly_repeated_heads(seq):
                          jnp.repeat(v, 4, axis=2), causal=True)
     np.testing.assert_array_equal(np.asarray(grouped(q, k, v)),
                                   np.asarray(repeated(q, k, v)))
-    got = jax.grad(lambda *a: jnp.sum(jnp.tanh(grouped(*a))),
-                   argnums=(1, 2))(q, k, v)
-    want = jax.grad(lambda *a: jnp.sum(jnp.tanh(repeated(*a))),
-                    argnums=(1, 2))(q, k, v)
+    got = jax.jit(jax.grad(lambda *a: jnp.sum(jnp.tanh(grouped(*a))),
+                           argnums=(1, 2)))(q, k, v)
+    want = jax.jit(jax.grad(lambda *a: jnp.sum(jnp.tanh(repeated(*a))),
+                            argnums=(1, 2)))(q, k, v)
     for g, w in zip(got, want):
         assert g.shape == (1, seq, 2, 16)
         np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-5,

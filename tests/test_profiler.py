@@ -238,7 +238,8 @@ def _tiny_resnet(**kw):
 def test_bf16_policy_keeps_bn_statistics_fp32():
     model = _tiny_resnet(dtype=jnp.bfloat16, param_dtype=jnp.float32)
     x = jnp.ones((2, 32, 32, 3), jnp.bfloat16)
-    variables = model.init(jax.random.key(0), x, train=True)
+    variables = jax.jit(lambda k, x: model.init(k, x, train=True))(
+        jax.random.key(0), x)
 
     def dtypes(tree):
         return {leaf.dtype for leaf in jax.tree_util.tree_leaves(tree)}
@@ -248,8 +249,8 @@ def test_bf16_policy_keeps_bn_statistics_fp32():
 
     # one train-mode apply: the UPDATED running stats must still be fp32
     # and finite (the stat reduction ran in fp32, not bf16)
-    logits, mutated = model.apply(variables, x, train=True,
-                                  mutable=["batch_stats"])
+    logits, mutated = jax.jit(lambda v, x: model.apply(
+        v, x, train=True, mutable=["batch_stats"]))(variables, x)
     assert dtypes(mutated["batch_stats"]) == {jnp.dtype(jnp.float32)}
     assert all(bool(jnp.all(jnp.isfinite(leaf)))
                for leaf in jax.tree_util.tree_leaves(mutated["batch_stats"]))

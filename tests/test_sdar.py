@@ -7,6 +7,7 @@ policy; the noise and the loss by hand; the eight shares of one layer against
 the uncut layer; one ``dp.make_train_step`` on four virtual devices; the
 published geometry."""
 
+import functools
 import importlib.util
 import json
 import os
@@ -57,9 +58,9 @@ def relative_l2(got, want):
     return float(np.linalg.norm(got - want) / np.linalg.norm(want))
 
 
-def make(dtype, batch, seq, seed=0, **kw):
-    """(model, float32 parameters, the batch with its noise)."""
-    model = SdarMoeDecoder(dtype=dtype, **{**SIZES, **kw})
+@functools.lru_cache(maxsize=None)
+def _made(dtype, batch, seq, seed, kw):
+    model = SdarMoeDecoder(dtype=dtype, **{**SIZES, **dict(kw)})
     x0 = jax.random.randint(jax.random.key(seed + 100), (batch, seq), 0,
                             MASK_ID, jnp.int32)
     data = {"x0": x0, **sdar_noise(jax.random.key(seed + 200), x0,
@@ -69,22 +70,48 @@ def make(dtype, batch, seq, seed=0, **kw):
     return model, params, data
 
 
-def program(model, params, data):
+def make(dtype, batch, seq, seed=0, **kw):
+    """(model, float32 parameters, the batch with its noise). Made once a
+    module for the same arguments: tests share the arrays, and change none
+    in place."""
+    model, params, data = _made(dtype, batch, seq, seed,
+                                tuple(sorted(kw.items())))
+    return model, params, dict(data)
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _program(model, params, data):
     def loss_fn(p):
         logits, stats = model.apply({"params": p}, data["xt"], data["x0"])
         return sdar_loss(logits, data, stats)
-    (loss, aux), grads = jax.jit(
-        jax.value_and_grad(loss_fn, has_aux=True))(params)
+    return jax.value_and_grad(loss_fn, has_aux=True)(params)
+
+
+def program(model, params, data):
+    """(loss, aux, gradients) of the model's own loss: compiled once a
+    model (a flax module hashes by its fields) and batch shape."""
+    (loss, aux), grads = _program(model, params, data)
     return loss, aux, grads
 
 
-def reference(config_module, params, data, **kw):
+@functools.lru_cache(maxsize=None)
+def _reference(reference_forward, batch, seq, kw):
+    _, params, data = make(jnp.float32, batch, seq)
+
     def loss_fn(p):
-        return config_module.reference_forward(p, data,
-                                               **{**REFERENCE, **kw})
+        return reference_forward(p, data, **{**REFERENCE, **dict(kw)})
     (loss, chosen), grads = jax.jit(
         jax.value_and_grad(loss_fn, has_aux=True))(params)
     return loss, chosen, grads
+
+
+def reference(config_module, batch, seq, **kw):
+    """(loss, chosen experts, gradients) of the configuration's float32
+    reference on ``make(jnp.float32, batch, seq)``'s parameters and batch
+    (the parameters are float32 whatever a model's ``dtype``, and do not
+    depend on its recomputation policy): run once a module for a size."""
+    return _reference(config_module.reference_forward, batch, seq,
+                      tuple(sorted(kw.items())))
 
 
 # -- (a) float32 against float32 -------------------------------------------------
@@ -104,7 +131,7 @@ def test_float32_program_matches_the_reference(config_module, batch, seq,
     policy changes nothing."""
     model, params, data = make(jnp.float32, batch, seq, remat=remat)
     loss, aux, grads = program(model, params, data)
-    want, chosen, want_grads = reference(config_module, params, data)
+    want, chosen, want_grads = reference(config_module, batch, seq)
     assert float(loss) == pytest.approx(float(want), rel=1e-5)
     errors = jax.tree_util.tree_map(relative_l2, grads, want_grads)
     assert max(jax.tree_util.tree_leaves(errors)) < 1e-4, errors
@@ -121,7 +148,7 @@ def test_another_block_length_or_a_shifted_stream_is_another_model(
     that is handed the streams the other way round, is another function by
     far more than rounding."""
     model, params, data = make(jnp.float32, 1, 256)
-    want = reference(config_module, params, data)[2]
+    want = reference(config_module, 1, 256)[2]
 
     def worst_leaf(model, data):
         return max(jax.tree_util.tree_leaves(jax.tree_util.tree_map(
@@ -328,7 +355,7 @@ def test_bf16_policy_stays_near_the_reference(config_module):
     experts) to 25%; parameters and their gradients stay float32."""
     model, params, data = make(jnp.bfloat16, 2, 256)
     loss, _, grads = program(model, params, data)
-    want, _, want_grads = reference(config_module, params, data)
+    want, _, want_grads = reference(config_module, 2, 256)
     assert float(loss) == pytest.approx(float(want), rel=2.0 ** -10)
     errors = jax.tree_util.tree_map(relative_l2, grads, want_grads)
     for path, error in jax.tree_util.tree_flatten_with_path(errors)[0]:
@@ -359,9 +386,8 @@ def test_the_lowered_control_is_not_the_reference(config_module):
     """The control (every product's inputs at 3 mantissa bits, the router's
     at 7) differs from the reference on every leaf by more than the bf16
     program does on the leaves off the routers' path."""
-    _, params, data = make(jnp.float32, 1, 256)
-    want, _, want_grads = reference(config_module, params, data)
-    low, _, low_grads = reference(config_module, params, data, lowered=True)
+    want, _, want_grads = reference(config_module, 1, 256)
+    low, _, low_grads = reference(config_module, 1, 256, lowered=True)
     errors = jax.tree_util.tree_map(relative_l2, low_grads, want_grads)
     for name in ("Embed_0", "LmHead"):
         assert min(jax.tree_util.tree_leaves(errors[name])) > 0.02, errors

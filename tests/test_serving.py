@@ -382,15 +382,17 @@ def test_serving_mode_small_completes_ahead_of_bulk(monkeypatch):
     assert all(ts <= tb for ts, tb in times), times
 
 
-def test_small_tensor_cliff_microbench_runs():
-    """The small-tensor regression microbench: counters
-    prove the express lane engaged (on) and fusion engaged (off)."""
-    from horovod_tpu.serve.loadgen import small_tensor_cliff_report
-    rep = small_tensor_cliff_report(iters=6, big_elems=1 << 20)
-    assert rep["serving_mode"]["low_latency_responses"] == 6
-    assert rep["fused_mode"]["low_latency_responses"] == 0
-    assert rep["serving_mode"]["p50_ms"] is not None
-    assert rep["mean_speedup_x"] is not None
+@pytest.mark.parametrize("serving_mode,answered", [(True, 6), (False, 0)],
+                         ids=["serving", "fused"])
+def test_express_lane_answers_every_small_allreduce_in_serving_mode_only(
+        serving_mode, answered):
+    """The small-tensor microbench, a mode a run: the counters say the
+    express lane answered all six small allreduces in serving mode and none
+    in fused mode."""
+    from horovod_tpu.serve.loadgen import small_allreduce_latency
+    run = small_allreduce_latency(serving_mode, iters=6, big_elems=1 << 20)
+    assert run["low_latency_responses"] == answered
+    assert run["p50_ms"] is not None
 
 
 # ---------------------------------------------------------------------------

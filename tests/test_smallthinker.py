@@ -5,6 +5,7 @@ the plain float32 reference that ``benchmark/configs/smallthinker-21b-a3b.py``
 keeps, in float32 and under the bf16 policy; the two per-layer flags; one
 ``dp.make_train_step`` on four virtual devices; the published geometry."""
 
+import functools
 import importlib.util
 import json
 import os
@@ -52,10 +53,9 @@ def relative_l2(got, want):
     return float(np.linalg.norm(got - want) / np.linalg.norm(want))
 
 
-def make(dtype, batch, seq, seed=0, router_scale=1.0, **kw):
-    """(model, float32 parameters, batch). ``router_scale`` widens the
-    routers' logits to the spread they have at the published width."""
-    model = SmallThinkerDecoder(dtype=dtype, **{**SIZES, **kw})
+@functools.lru_cache(maxsize=None)
+def _made(dtype, batch, seq, seed, router_scale, kw):
+    model = SmallThinkerDecoder(dtype=dtype, **{**SIZES, **dict(kw)})
     tokens = jax.random.randint(jax.random.key(seed + 100), (batch, seq), 0,
                                 SIZES["vocab"], jnp.int32)
     params = jax.jit(model.init)(jax.random.key(seed), tokens)["params"]
@@ -66,22 +66,50 @@ def make(dtype, batch, seq, seed=0, router_scale=1.0, **kw):
                            "labels": jnp.roll(tokens, -1, axis=1)}
 
 
-def program(model, params, batch):
+def make(dtype, batch, seq, seed=0, router_scale=1.0, **kw):
+    """(model, float32 parameters, batch). ``router_scale`` widens the
+    routers' logits to the spread they have at the published width. Made
+    once a module for the same arguments: tests share the arrays, and
+    change none in place."""
+    return _made(dtype, batch, seq, seed, router_scale,
+                 tuple(sorted(kw.items())))
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _program(model, params, batch):
     def loss_fn(p):
         logits, stats = model.apply({"params": p}, batch["tokens"])
         return smallthinker_loss(logits, batch["labels"], stats)
-    (loss, aux), grads = jax.jit(
-        jax.value_and_grad(loss_fn, has_aux=True))(params)
+    return jax.value_and_grad(loss_fn, has_aux=True)(params)
+
+
+def program(model, params, batch):
+    """(loss, aux, gradients) of the model's own loss: compiled once a
+    model (a flax module hashes by its fields) and batch shape."""
+    (loss, aux), grads = _program(model, params, batch)
     return loss, aux, grads
 
 
-def reference(config_module, params, batch, **kw):
+@functools.lru_cache(maxsize=None)
+def _reference(reference_forward, batch, seq, router_scale, kw):
+    _, params, data = make(jnp.float32, batch, seq,
+                           router_scale=router_scale)
+
     def loss_fn(p):
-        return config_module.reference_forward(p, batch,
-                                               **{**REFERENCE, **kw})
+        return reference_forward(p, data, **{**REFERENCE, **dict(kw)})
     (loss, chosen), grads = jax.jit(
         jax.value_and_grad(loss_fn, has_aux=True))(params)
     return loss, chosen, grads
+
+
+def reference(config_module, batch, seq, router_scale=1.0, **kw):
+    """(loss, chosen experts, gradients) of the configuration's float32
+    reference on ``make(jnp.float32, batch, seq, router_scale=...)``'s
+    parameters and batch (the parameters are float32 whatever a model's
+    ``dtype``, and do not depend on its recomputation policy): run once a
+    module for a size."""
+    return _reference(config_module.reference_forward, batch, seq,
+                      router_scale, tuple(sorted(kw.items())))
 
 
 # -- (a) float32 against float32 -------------------------------------------------
@@ -100,7 +128,7 @@ def test_float32_program_matches_the_reference(config_module, batch, seq,
     on layers 1-3; a recomputation policy changes nothing."""
     model, params, data = make(jnp.float32, batch, seq, remat=remat)
     loss, aux, grads = program(model, params, data)
-    want, chosen, want_grads = reference(config_module, params, data)
+    want, chosen, want_grads = reference(config_module, batch, seq)
     assert float(loss) == pytest.approx(float(want), rel=1e-5)
     errors = jax.tree_util.tree_map(relative_l2, grads, want_grads)
     assert max(jax.tree_util.tree_leaves(errors)) < 1e-4, errors
@@ -115,7 +143,7 @@ def test_logits_match_the_reference_layer_by_layer_flags(config_module):
     layouts differ from the reference's in one flag of one layer is another
     function, by far more than rounding."""
     _, params, data = make(jnp.float32, 1, 256)
-    want = reference(config_module, params, data)[2]
+    want = reference(config_module, 1, 256)[2]
 
     def worst_leaf(**kw):
         model = SmallThinkerDecoder(dtype=jnp.float32, **{**SIZES, **kw})
@@ -163,9 +191,9 @@ def test_a_router_after_attention_is_another_model(config_module):
     check's limit."""
     model, params, data = make(jnp.float32, 2, 256, router_scale=8.0)
     _, _, grads = program(model, params, data)
-    want, chosen, want_grads = reference(config_module, params, data)
+    want, chosen, want_grads = reference(config_module, 2, 256, 8.0)
     _, late_chosen, late_grads = reference(
-        config_module, params, data, router_reads="after_attention")
+        config_module, 2, 256, 8.0, router_reads="after_attention")
     moved = np.mean([(np.sort(a, -1) != np.sort(b, -1)).any(-1).mean()
                      for a, b in zip(chosen, late_chosen)])
     assert moved > 0.25
@@ -189,7 +217,7 @@ def test_bf16_policy_stays_near_the_reference(config_module):
     parameter's gradient is float32 and the weights sum to one."""
     model, params, data = make(jnp.bfloat16, 2, 256, router_scale=8.0)
     loss, _, grads = program(model, params, data)
-    want, _, want_grads = reference(config_module, params, data)
+    want, _, want_grads = reference(config_module, 2, 256, 8.0)
     assert float(loss) == pytest.approx(float(want), rel=2.0 ** -10)
     errors = jax.tree_util.tree_map(relative_l2, grads, want_grads)
     flat = jax.tree_util.tree_flatten_with_path(errors)[0]

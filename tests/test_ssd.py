@@ -130,7 +130,7 @@ def test_kernels_match_the_chunked_reference(chunk):
 
 def test_kernels_hold_no_gradient_through_the_returned_states():
     args = inputs(8)
-    grads = jax.grad(lambda a: jnp.sum(kernels(8, **a)[1]))(args)
+    grads = jax.jit(jax.grad(lambda a: jnp.sum(kernels(8, **a)[1])))(args)
     assert all(not np.asarray(g).any() for g in grads.values())
 
 
@@ -140,13 +140,13 @@ def test_long_steps_never_overflow(scan):
     masked, non-positive difference, so nothing overflows and the result is
     the recurrence's (each state all but forgotten by the next position)."""
     args = inputs(3, dt_scale=400.0)
-    y, ends = scan(16, **args)
+    y, ends = jax.jit(lambda a: scan(16, **a))(args)
     want_y, _ = recurrence(**args)
     assert np.isfinite(np.asarray(y)).all()
     assert np.isfinite(np.asarray(ends)).all()
     np.testing.assert_allclose(np.asarray(y), np.asarray(want_y),
                                rtol=2e-4, atol=2e-5)
-    grads = jax.grad(lambda a: jnp.sum(scan(16, **a)[0]))(args)
+    grads = jax.jit(jax.grad(lambda a: jnp.sum(scan(16, **a)[0])))(args)
     assert all(np.isfinite(np.asarray(g)).all() for g in grads.values())
 
 
@@ -218,8 +218,9 @@ def test_kernels_work_any_heads_a_slab(per_group, width):
     def loss(scan, a):
         y, ends = scan(8, **a)
         return jnp.sum(jnp.sin(y)), (y, ends)
-    (got, want) = (jax.grad(functools.partial(loss, scan), has_aux=True)(
-        args) for scan in (kernels, chunked))
+    (got, want) = (jax.jit(jax.grad(functools.partial(loss, scan),
+                                    has_aux=True))(args)
+                   for scan in (kernels, chunked))
     for name in INPUTS:
         assert relative_l2(got[0][name], want[0][name]) < 2e-5, name
     assert relative_l2(got[1][0], want[1][0]) < 1e-6
@@ -264,7 +265,7 @@ def test_bf16_gradients_hold_the_policy(scan):
     def gradient(run, a):
         return jax.grad(lambda a: jnp.sum(
             run(128, **a)[0].astype(jnp.float32) * weight))(a)
-    want = gradient(chunked, exact)
+    want = jax.jit(functools.partial(gradient, chunked))(exact)
     got = jax.jit(functools.partial(gradient, scan))(rounded)
     for name in INPUTS:
         assert got[name].dtype == rounded[name].dtype

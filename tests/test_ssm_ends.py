@@ -81,8 +81,9 @@ def passes():
                 writings = [
                     lambda y, z, s, fn=fn: fn(y, z, s, groups, 1e-5, dtype)
                     for fn in (se.gated_group_norm, se.gated_norm_plain)]
-            done[key] = [(fn(*args), jax.grad(
-                _weighted(fn, v["cotangent"]), argnums=(0, 1, 2))(*args))
+            # the result and its gradients from one compiled program each
+            done[key] = [jax.jit(lambda *a, fn=fn: (fn(*a), jax.grad(
+                _weighted(fn, v["cotangent"]), argnums=(0, 1, 2))(*a)))(*args)
                 for fn in writings]
         return done[key]
     return both
@@ -144,7 +145,8 @@ def test_the_first_positions_see_zeros_and_no_sequence_sees_another(k):
     np.testing.assert_allclose(np.asarray(y[:, k - 1:]),
                                np.asarray(jax.nn.silu(x[:, :length - k + 1])),
                                rtol=1e-5)
-    dx = jax.grad(lambda x: jnp.sum(se.causal_conv_silu(x, w, b) * 1e3))(x)
+    dx = jax.jit(jax.grad(
+        lambda x: jnp.sum(se.causal_conv_silu(x, w, b) * 1e3)))(x)
     np.testing.assert_array_equal(np.asarray(dx[:, length - k + 1:]), 0.0)
     assert float(jnp.abs(dx[:, :length - k + 1]).min()) > 0.0
 
@@ -158,21 +160,23 @@ def test_channels_are_read_where_they_lie_in_a_wider_array():
     channels, groups = SHAPES[shape][2], SHAPES[shape][4]
     run = slice(at, at + channels)
 
-    got = jax.value_and_grad(_weighted(lambda x: se.causal_conv_silu(
-        x, v["w"], v["b"], at=at), v["cotangent"]))(v["x"])
-    want = jax.value_and_grad(_weighted(lambda x: se.conv_silu_plain(
-        x[..., run], v["w"], v["b"], dtype), v["cotangent"]))(v["x"])
+    got = jax.jit(jax.value_and_grad(_weighted(
+        lambda x: se.causal_conv_silu(x, v["w"], v["b"], at=at),
+        v["cotangent"])))(v["x"])
+    want = jax.jit(jax.value_and_grad(_weighted(
+        lambda x: se.conv_silu_plain(x[..., run], v["w"], v["b"], dtype),
+        v["cotangent"])))(v["x"])
     assert _distance(got[0], want[0]) < 2e-5
     assert _distance(got[1], want[1]) < 2e-5
     assert not np.asarray(got[1][..., :at]).any()
     assert not np.asarray(got[1][..., at + channels:]).any()
 
-    got = jax.grad(_weighted(lambda y, z: se.gated_group_norm(
+    got = jax.jit(jax.grad(_weighted(lambda y, z: se.gated_group_norm(
         y, z, v["scale"], groups, at=at), v["cotangent"]),
-        argnums=(0, 1))(v["y"], v["x"])
-    want = jax.grad(_weighted(lambda y, z: se.gated_norm_plain(
+        argnums=(0, 1)))(v["y"], v["x"])
+    want = jax.jit(jax.grad(_weighted(lambda y, z: se.gated_norm_plain(
         y, z[..., run], v["scale"], groups, 1e-5, dtype), v["cotangent"]),
-        argnums=(0, 1))(v["y"], v["x"])
+        argnums=(0, 1)))(v["y"], v["x"])
     for g, w_ in zip(got, want):
         assert g.shape == w_.shape and _distance(g, w_) < 2e-5
     assert not np.asarray(got[1][..., :at]).any()
@@ -198,8 +202,10 @@ def test_runs_of_the_conv_are_arrays_of_their_own_and_one_gradient(dtype):
     args = (v["x"], v["w"], v["b"])
     np.testing.assert_array_equal(np.asarray(in_runs(*args), np.float32),
                                   np.asarray(whole(*args), np.float32))
-    got = jax.grad(_weighted(in_runs, cotangent), argnums=(0, 1, 2))(*args)
-    want = jax.grad(_weighted(whole, cotangent), argnums=(0, 1, 2))(*args)
+    got = jax.jit(jax.grad(_weighted(in_runs, cotangent),
+                           argnums=(0, 1, 2)))(*args)
+    want = jax.jit(jax.grad(_weighted(whole, cotangent),
+                            argnums=(0, 1, 2)))(*args)
     for g, w_ in zip(got, want):
         np.testing.assert_array_equal(np.asarray(g, np.float32),
                                       np.asarray(w_, np.float32))
