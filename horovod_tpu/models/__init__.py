@@ -4,6 +4,12 @@ from horovod_tpu.models.gpt import (  # noqa: F401
     GptMedium,
     GptSmall,
 )
+from horovod_tpu.models.lfm2 import (  # noqa: F401
+    Lfm2_8B_A1B,
+    Lfm2MoeDecoder,
+    Lfm2Tiny,
+    lfm2_loss,
+)
 from horovod_tpu.models.nemotron_h import (  # noqa: F401
     Nemotron3Nano30B,
     NemotronHDecoder,

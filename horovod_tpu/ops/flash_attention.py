@@ -492,11 +492,14 @@ def _vmem_params(*blocks):
     """Room in VMEM for a call whose ``blocks`` ((shape, dtype) each, held
     twice by the pipeline) outgrow the compiler's default scoped limit of
     16 MiB: a kernel keeps one (batch, head)'s whole k and v (dk/dv: q and
-    do) resident, 4 MiB each at 16 384 positions of 128. None up to 8192
-    positions of 128, the shapes that compile under the default: their calls
-    are what they were."""
-    held = 2 * sum(math.prod(shape) * jnp.dtype(dtype).itemsize
-                   for shape, dtype in blocks)
+    do) resident, 4 MiB each at 16 384 positions of 128. A block lies in
+    VMEM with its minor dimension in whole registers of 128 lanes, so a head
+    of 64 takes the room of one of 128 there (16 384 positions of 64 asked
+    for 16.75 MiB under the default). None up to 8192 positions of 128 or
+    of 64, the shapes that compile under the default: their calls are what
+    they were."""
+    held = 2 * sum(math.prod(shape[:-1]) * -(-shape[-1] // 128) * 128
+                   * jnp.dtype(dtype).itemsize for shape, dtype in blocks)
     if held <= _DEFAULT_VMEM_BLOCKS:
         return None
     return pltpu.CompilerParams(

@@ -14,9 +14,12 @@ Two distinct mechanisms, matching where the work actually happens:
 - :func:`ssm_scope` — the parts of a Mamba-2 mixer (:data:`SSM_SCOPES`:
   in-projection, causal conv, the chunked scan of ``ops/ssd.py``, gated
   group norm, out-projection), written by ``models/nemotron_h.py``.
+- :func:`shortconv_scope` — the parts of a gated short-convolution operator
+  (:data:`SHORTCONV_SCOPES`: in-projection, the two gates and the taps of
+  ``ops/short_conv.py``, out-projection), written by ``models/lfm2.py``.
 - :func:`attn_scope` — the kind of an attention call (:data:`ATTN_SCOPES`:
   full causal or window, written by ``models/smallthinker.py``, whose
-  layers mix the two; the two streams of a block-diffusion pass, by
+  layers mix the two, and ``models/lfm2.py``'s attention layers; the two streams of a block-diffusion pass, by
   ``models/sdar.py``).
 - :func:`diffusion_scope` — the two ends of a block-diffusion objective
   (:data:`DIFFUSION_SCOPES`: the noising of a batch and the weighted loss
@@ -64,6 +67,11 @@ MOE_SCOPES = ("moe_router", "moe_dispatch", "moe_experts", "moe_combine",
 # own for the same reason.
 SSM_SCOPES = ("ssm_in_proj", "ssm_conv", "ssm_scan", "ssm_gate_norm",
               "ssm_out_proj")
+# The parts of one gated short-convolution operator (``models/lfm2.py``):
+# ``shortconv_mix`` is the two gates and the taps (``ops/short_conv.py``) and
+# nothing else, the other two the projections on either side of it.
+SHORTCONV_SCOPES = ("shortconv_in_proj", "shortconv_mix",
+                    "shortconv_out_proj")
 # The kind of an attention call in a model that mixes them
 # (``models/smallthinker.py``): rotary, the key heads' repeat and the
 # kernels of a full-causal or of a window layer; ``attn_blockdiff`` is the
@@ -99,6 +107,15 @@ def ssm_scope(name: str):
     if name not in SSM_SCOPES:
         raise ValueError(f"unknown state-space mixer scope {name!r}; one of "
                          f"{SSM_SCOPES}")
+    return collective_scope(name)
+
+
+def shortconv_scope(name: str):
+    """Name the enclosed traced ops as one part of a gated short-convolution
+    operator."""
+    if name not in SHORTCONV_SCOPES:
+        raise ValueError(f"unknown short-convolution scope {name!r}; one of "
+                         f"{SHORTCONV_SCOPES}")
     return collective_scope(name)
 
 

@@ -139,10 +139,14 @@ def test_an_unknown_phase_is_an_error():
     (annotate.moe_scope, annotate.MOE_SCOPES, "moe_", "moe_shared",
      "moe_everything"),
     (annotate.ssm_scope, annotate.SSM_SCOPES, "ssm_", "ssm_scan",
-     "ssm_everything")], ids=["moe", "ssm"])
+     "ssm_everything"),
+    (annotate.shortconv_scope, annotate.SHORTCONV_SCOPES, "shortconv_",
+     "shortconv_mix", "shortconv_everything")],
+    ids=["moe", "ssm", "shortconv"])
 def test_layer_scopes_take_their_names_and_refuse_others(
         scope, names, prefix, known, unknown):
-    """The expert layer's and the state-space mixer's parts: a name of the
+    """The expert layer's, the state-space mixer's and the short-convolution
+    operator's parts: a name of the
     list is written into the traced operations' ``op_name``; any other name
     is an error. Neither prefix is a phase's or a collective's."""
     assert known in names
