@@ -75,7 +75,8 @@ def main():
     tiles = [ep.share_tiles(layer, model.experts_held,
                             model.experts_per_token,
                             args.batch_per_replica * args.seq_len,
-                            record=True)
+                            record=True,
+                            widths=(model.hidden, model.expert_dim))
              for layer in load]
     rows = {kind: get_registry().counter(
         "hvd_moe_share_rows_total", kind=kind).value
@@ -87,7 +88,8 @@ def main():
               f"{load[0].astype(int).tolist()}; live tiles of those built, "
               f"by expert layer: {tiles}; rows of the held experts' pairs "
               f"{rows['held']:.0f}, rows the grouped matmuls computed for "
-              f"them (every slot of the live tiles) {rows['computed']:.0f}, "
+              f"them (widths that are no whole 128s: every slot of the live "
+              f"tiles) {rows['computed']:.0f}, "
               f"rows the way back to the tokens fetched to place them, at "
               f"most {rows['fetched']:.0f}")
     assert last < first, (first, last)

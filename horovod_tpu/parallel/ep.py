@@ -37,9 +37,11 @@ operation was:
 - ``moe_dispatch``: the ``k T`` (token, slot) pairs are sorted by expert
   (a stable argsort), and the tokens' rows are gathered into that order;
 - ``moe_experts``: one grouped matmul per projection over the sorted rows
-  (a full load: :func:`~horovod_tpu.ops.grouped_matmul.grouped_matmul`, the
-  repo's own Pallas kernels over row blocks of 128; a share's walk: a
-  batched ``dot_general`` over slots, an expert a batch entry);
+  (:func:`~horovod_tpu.ops.grouped_matmul.grouped_matmul`, the repo's own
+  Pallas kernels over the row blocks of 128 that hold a pair, for a full
+  load and for a share's walk whose expert matrices are whole 128s and at
+  least 1024 wide; a batched ``dot_general`` over slots, an expert a batch
+  entry, for every other walk: :func:`share_product`);
 - ``moe_combine``: the rows are gathered back into token order and summed
   with their router weights, in float32.
 
@@ -76,17 +78,48 @@ call is built with; how many were live and what the padding cost
 (``hvd_moe_grouped_rows_total{kind="held"|"computed"}``) is data, read back
 outside the step: :func:`grouped_blocks` over the load.
 
-**Why a full load takes the kernel and a share keeps slots.** A share's
-tile is slack by design (8 slots of 640 rows for 384 pairs an expert: XLA's
-batched product runs them at 79-95% of peak, sums the weights' gradient in
-its own epilogue and needs no table; the kernel was within 1.8% there and
-400 lines longer: ``PERF.md`` §6, PR 34), and its rows go back to their
-tokens by additions, in a kernel of their own (below, "A share"), where a
-full load's go back by gathers. A full load has no slack to give
-slots: 64 experts x 1.5 headroom is +50% rows through every gather and
-every product, where starting each expert on a block of 128 costs 64 half
-blocks, +6% (``PERF.md`` §6, PR 36). Which side a call is on is the static
-shape ``share_tile_rows(k T, count, E) < k T``, nothing else.
+**Slots are a share's layout; row blocks are what is computed, where the
+experts are wide enough** (PR 47; before it: "why a full load takes the
+kernel and a share keeps slots"). A share's tile gives every held expert a
+slot of 1.5 x a balanced router's pairs, so that one tile holds any step a
+bias rule or an auxiliary loss keeps near balance: a third to a half of a
+tile's rows hold no pair (computed / held 1.5 in ``lfm2-t16384``, 1.49 in
+``smallthinker-t16384``, 2.0 in ``sdar-t8192-bd4``, 1.65 in
+``nemotron3n-t8192``). The slack stays where it costs little, in the layout
+(a gather's indices, the way back's runs), and can leave the products: a
+slot is whole row blocks of 128, block ``b`` of a tile is held expert ``b //
+(S / 128)``'s, and the blocks of slot ``e`` that hold a pair of tile ``i``
+are its first ``ceil(clip(count_e - i S, 0, S) / 128)``. That is a full
+load's contract with one thing more, that the live blocks are not a prefix
+of the tile, so the kernels take the list of live blocks by grid step (each
+slot's prefix after the other, a cumulative sum over the slots) beside the
+block-to-expert table, and both loads run **one kernel body** (a full
+load's list names the blocks in order). :func:`share_product` decides which
+walks take it, from the expert matrices' static widths and nothing else:
+whole 128s and at least 1024 wide both ways (LFM2's 2048 x 1792; computed /
+held 1.5 -> 1.04). What the chip said (``PERF.md`` §6, PR 47; PR 34 before
+it). Alone, a tile's experts forward and backward are faster by blocks at
+every width in whole 128s (12.2 -> 11.3 ms in ``lfm2-t16384``, 6.1 -> 5.3
+in ``sdar-t8192-bd4``, 5.5 -> 5.4 in ``smallthinker-t16384``) and slower at
+Nemotron-H's 2688 x 1856 (2.4 -> 2.9, as PR 34 found), and by less than
+the rows say: XLA's batched product runs at about 90% of the chip's peak on
+every row it is given (``moe_experts_mfu`` counts three passes where the
+walk, which recomputes, runs four), the kernels at 80-86% at LFM2's widths.
+In the step the rest of the walk decides: XLA fuses the element-wise passes
+between the products (the gate, the router weights on the output's
+gradient, a row's sum, the sum of a matrix's gradient over tiles) into the
+batched products and cannot fuse them into a kernel call, 0.3-0.6 GB a
+layer that cross HBM on their own. At 1792 wide the rows skipped pay for
+that (``lfm2-t16384`` +1.35%); at 768 they do not (``smallthinker-t16384``
+-0.6%, ``sdar-t8192-bd4`` +0.25% with 0.5% more memory and a longer
+set-up), so those walks, like Nemotron-H's, keep XLA's batched product over
+whole slots. A full load has no slack to give slots: 64 experts x 1.5
+headroom is +50% rows through every gather and every product, where
+starting each expert on a block of 128 costs 64 half blocks, +6%
+(``PERF.md`` §6, PR 36). Walk or one tile is the static shape
+``share_tile_rows(k T, count, E) < k T``; which product a walk takes is
+counted at trace time, ``hvd_moe_share_product_total{path="blocks"
+|"slots"}``.
 
 **A share** (``held = (first, count)``): the layer holds ``count`` of the
 router's ``E`` experts, ``first .. first + count - 1``, as one chip of an
@@ -106,8 +139,9 @@ tile ``i`` is ``count`` slots of ``S`` rows, and slot ``e`` holds pairs
 expert's first pair plus its place, arithmetic on the tile's indices from
 the held counts alone, :func:`_share_tiles_of`). Every row block of a tile
 is thus one expert's, at a place known when the program is built, and the
-grouped matmul over a tile is XLA's own batched product ``[count, S, k] x
-[count, k, n]``, an expert a batch entry, whose time follows its rows
+grouped matmul over a tile is :func:`share_product`'s: the kernels over the
+tile's live row blocks, or XLA's own batched product ``[count, S, k] x
+[count, k, n]``, an expert a batch entry; either's time follows its rows
 (the compiler's ragged dot is paced by the (group, 512-row tile) pairs it
 visits: 2.0 ms a call for 0.2 ms of arithmetic at Nemotron-H's
 widths, where the batched product takes 0.3; ``PERF.md`` §6, PRs 31 and
@@ -135,11 +169,28 @@ one slot that fall in one block of 256 tokens are a contiguous run: the
 kernel builds a token block's result in VMEM from those runs, fetched in
 chunks of 32 rows, and writes it once, the first live tile without reading
 what was there (1.08 ms for the same tile, 0.66 into a fresh result; the
-bytes ask for 0.4-0.6). A row past its expert's last pair gathers some
-token in bounds, is computed like any other (a product writes every row:
-nothing is left unwritten, nothing needs zeroing), carries router weight 0
-and goes back to no token: it adds nothing to the output, and its rows of
-every gradient are zero because the gradient that reaches them is.
+bytes ask for 0.4-0.6). **A row that holds no pair** gathers some token in
+bounds, carries router weight 0 and goes back to no token. In a live block
+(and everywhere under the batched product, which writes every row) it is
+computed like any other: it adds nothing to the output, and its rows of
+every gradient are zero because the gradient that reaches them is (zero
+times a finite row). In a block no live step names, nothing is fetched,
+computed or written, forward or backward: its rows of each product's result
+hold whatever the buffer held, NaN for all anyone knows, and three guards
+keep that from anything. (1) *The way back stops at the count*: a row that
+is no pair carries token ``T``, so
+:func:`~horovod_tpu.ops.rows_to_tokens.add_rows_at_tokens` puts it in no
+run, forward (the weighted rows) and backward (the rows' gradient): no ``0 *
+NaN``, the row is never read as a number. The router weights' gradient, a
+row's sum scattered by sorted position, drops the same rows by their
+position ``k T``. (2) *The same list skips them in the next product*: what
+a dead block holds after ``silu(gate) * up`` is the third product's operand
+in blocks that product does not visit either, and the matrices' gradients
+(``_gmm_dw_kernel``) sum over live blocks only; an expert with no live
+block gets zeros by a select on the list. (3) *A live block's tail* is
+guard (1)'s weight 0 and today's padding: computed, finite, zero gradient.
+``tests/test_expert_parallel.py`` fills every unwritten row with NaN, both
+directions, and finds the output and every gradient finite and equal.
 
 The walk is a ``jax.custom_vjp`` over two loops whose trip count is read
 from the data, one forward and one backward, so each grouped matmul is in
@@ -152,13 +203,16 @@ gathers the tile's rows of the output's gradient, adds the rows' gradients
 into a float32 ``[T, d]`` at their tokens (``moe_dispatch``: the forward's
 way back again, every weight one), and sums the expert weights' gradients
 over the live tiles in their own dtype (one live tile: exact; float32 sums
-were a gigabyte of the step's temporaries). The one scatter left in the
+were a gigabyte of the step's temporaries; inside a tile the kernel sums an
+expert's live blocks in a float32 VMEM tile, as the batched product sums a
+slot in float32). The one scatter left in the
 walk is of a scalar a pair (the router weights' gradient by sorted
 position). At trace time
 ``hvd_moe_share_tiles_total{kind="built"}`` counts the tiles a layer call
 can come to and ``hvd_moe_share_tile_rows`` holds a tile's rows; which
 tiles were live, how many rows the held experts were sent and how many the
-live tiles computed for them and the way back fetched to place them
+products computed for them (the live row blocks, or whole tiles under the
+batched product) and the way back fetched to place them
 (``hvd_moe_share_rows_total{kind="held"|"computed"|"fetched"}``) is data,
 read back outside the step: :func:`share_tiles` over the load a model's
 state carries. A share whose rule gives a tile of all ``k T`` pairs (its slots
@@ -296,6 +350,9 @@ def _held_first(experts: jax.Array, first: int, count: int) -> jax.Array:
 
 SHARE_TILE_HEADROOM = 1.5  # a slot, over the pairs a balanced router sends
 SHARE_BLOCK_ROWS = 128  # a slot is whole row blocks of the matrix unit
+# the narrowest expert matrix whose walk takes the kernels over live blocks
+# (measured on the chip: 1792 wins, 768 loses; between them not measured)
+SHARE_BLOCKS_MIN_WIDTH = 1024
 
 
 def share_slot_rows(k_t: int, n_experts: int) -> int:
@@ -314,6 +371,27 @@ def share_tile_rows(k_t: int, count: int, n_experts: int) -> int:
     return min(k_t, count * share_slot_rows(k_t, n_experts))
 
 
+def _widths_of(expert_weights) -> Tuple[int, ...]:
+    """Both widths of every expert matrix ``[count, k, n]``."""
+    return tuple(n for w in expert_weights for n in w.shape[1:])
+
+
+def share_product(widths: Sequence[int]) -> str:
+    """What multiplies a tile's rows in a share's walk, decided here and
+    nowhere else, by the expert matrices' widths (static shapes):
+    ``"blocks"``, :func:`~horovod_tpu.ops.grouped_matmul.grouped_matmul`
+    over the row blocks that hold a pair, where every width is whole
+    ``SHARE_BLOCK_ROWS`` and at least ``SHARE_BLOCKS_MIN_WIDTH``
+    (LFM2's 2048 x 1792); else ``"slots"``, XLA's batched product over every
+    row of every slot (SmallThinker's 2560 x 768 and SDAR's 2048 x 768, too
+    narrow for the rows skipped to pay for the element-wise passes the
+    batched product fuses and a kernel call does not; Nemotron-H's 2688 x
+    1856, no whole 128s: ``PERF.md`` §6, PRs 34 and 47)."""
+    return "blocks" if all(
+        n % SHARE_BLOCK_ROWS == 0 and n >= SHARE_BLOCKS_MIN_WIDTH
+        for n in widths) else "slots"
+
+
 def _share_tiles_counter(kind: str):
     from horovod_tpu.metrics.registry import get_registry
     return get_registry().counter(
@@ -323,50 +401,63 @@ def _share_tiles_counter(kind: str):
         kind=kind)
 
 
-def _count_built_tiles(tiles: int, rows: int):
+def _count_built_tiles(tiles: int, rows: int, product: str):
     """Monitoring, at trace time as ``hvd_ssd_chunks_total`` is."""
     from horovod_tpu.metrics.registry import get_registry
     _share_tiles_counter("built").inc(tiles)
     get_registry().gauge(
         "hvd_moe_share_tile_rows",
         "rows of one tile of the share's walk traced last").set(rows)
+    get_registry().counter(
+        "hvd_moe_share_product_total",
+        "walks of a share built (at trace time) with the grouped-matmul "
+        "kernels over a tile's live row blocks, or with the batched product "
+        "over its slots: share_product of the expert matrices' widths",
+        path=product).inc()
 
 
 _SHARE_ROWS = (
     "hvd_moe_share_rows_total",
     "rows of a share's walk in the steps read back: the held experts' "
-    "pairs, the rows of the live tiles that the grouped matmuls computed "
-    "for them, and the rows of the chunks the way back to the tokens "
-    "fetched to place them (at most: every run taken to straddle a chunk)")
+    "pairs, the rows that the grouped matmuls computed for them (the live "
+    "row blocks, or under the batched product the live tiles whole), and "
+    "the rows of the chunks the way back to the tokens fetched to place "
+    "them (at most: every run taken to straddle a chunk)")
 
 
 def share_tiles(load, held: Tuple[int, int], k: int, tokens: int,
-                record: bool = False) -> Tuple[int, int]:
+                record: bool = False, widths: Sequence[int] = ()
+                ) -> Tuple[int, int]:
     """(live, built): of the ``built`` tiles a share's walk can come to
     (one held expert sent every one of the ``tokens``), the ``live`` ones a
     step that routed this ``load`` worked in: as many as the fullest held
     expert's pairs fill slots. Host side, outside the step: ``load`` is one
     expert layer's pairs per expert over all E (``MoeStats.expert_tokens``,
     or the ``load`` a model's ``router_state`` carries to the next step) of
-    a top-``k`` router over ``tokens`` tokens. ``record`` adds ``live`` to
+    a top-``k`` router over ``tokens`` tokens; ``widths`` are the expert
+    matrices' widths, which say as in the layer what multiplies a tile's
+    rows (:func:`share_product`). ``record`` adds ``live`` to
     ``hvd_moe_share_tiles_total{kind="live"}``, and to
     ``hvd_moe_share_rows_total`` the held experts' pairs (``kind="held"``),
-    the rows the live tiles computed for them (``kind="computed"``: whole
-    tiles, every slot of them) and the rows the way back fetches to place
-    them, a direction (``kind="fetched"``, the walk only: a slot's rows in
-    a tile come in whole chunks of ``chunk_rows_of(slot)``, and a chunk is
-    fetched once for each token block it holds rows of, so at most one more
-    chunk for each of the ``tokens / block_tokens_of(tokens)`` blocks the
-    rows can lie in; fetched / held is what the way back reads for a row it
-    places)."""
+    the rows the products computed for them (``kind="computed"``: each held
+    expert's pairs in whole row blocks of ``SHARE_BLOCK_ROWS``, or under
+    the batched product whole tiles, every slot of them) and the rows the
+    way back fetches to place them, a direction (``kind="fetched"``, the
+    walk only: a slot's rows in a tile come in whole chunks of
+    ``chunk_rows_of(slot)``, and a chunk is fetched once for each token
+    block it holds rows of, so at most one more chunk for each of the
+    ``tokens / block_tokens_of(tokens)`` blocks the rows can lie in;
+    fetched / held is what the way back reads for a row it places)."""
     first, count = held
     rows = share_tile_rows(k * tokens, count, len(load))
     held_rows = [int(n) for n in load[first:first + count]]
     counted = {"held": sum(held_rows)}
+    in_blocks = SHARE_BLOCK_ROWS * grouped_blocks(load, k, tokens, held)[0]
     if rows < k * tokens:
         slot = share_slot_rows(k * tokens, len(load))
         live, built = -(-max(held_rows) // slot), -(-tokens // slot)
-        counted["computed"] = live * rows
+        counted["computed"] = in_blocks \
+            if share_product(widths) == "blocks" else live * rows
         chunk, blocks = chunk_rows_of(slot), tokens // block_tokens_of(tokens)
         in_tiles = [min(slot, n - i * slot) for n in held_rows
                     for i in range(-(-n // slot))]
@@ -374,8 +465,7 @@ def share_tiles(load, held: Tuple[int, int], k: int, tokens: int,
             -(-n // chunk) + min(n, blocks) - 1 for n in in_tiles)
     else:  # the one-tile program below the walk: whole row blocks
         live, built = -(-sum(held_rows) // rows), 1
-        counted["computed"] = SHARE_BLOCK_ROWS * grouped_blocks(
-            load, k, tokens, held)[0]
+        counted["computed"] = in_blocks
     if record:
         from horovod_tpu.metrics.registry import get_registry
         _share_tiles_counter("live").inc(live)
@@ -389,7 +479,15 @@ def _rows_of(x: jax.Array, index: jax.Array) -> jax.Array:
     return x.at[index].get(mode="promise_in_bounds")
 
 
-def _share_tiles_of(tile, x, order, weights, sizes, expert):
+def _run_of(steps: int, ends: jax.Array) -> jax.Array:
+    """int32 [steps]: which of the consecutive runs that end before
+    ``ends`` [runs] (a cumulative sum) each of ``steps`` positions lies in;
+    the last run for a position past them all."""
+    return jnp.minimum(ends.shape[0] - 1, jnp.sum(
+        jnp.arange(steps)[:, None] >= ends, axis=1, dtype=jnp.int32))
+
+
+def _share_tiles_of(tile, x, order, weights, sizes, expert, expert_weights):
     """What both directions of a share's walk read: (the number of tiles
     the fullest held expert's pairs reach into, the function of a tile's
     index that gives (its tokens, the token a row goes back to (``T`` for a
@@ -397,12 +495,15 @@ def _share_tiles_of(tile, x, order, weights, sizes, expert):
     such a row), its rows' router weights, its tokens' rows, the experts as
     a function of (rows, *expert_weights))). A tile is one slot of
     ``tile / count`` rows a held expert: slot ``e`` of tile ``i`` holds
-    expert ``e``'s pairs ``[i S, (i + 1) S)``, so the grouped matmul over a
-    tile is one batched product ``[count, S, k] x [count, k, n]``, and the
-    tokens of a slot's pairs ascend (the sort is stable). A row past its
-    expert's last pair gathers some token in bounds and carries weight
-    0."""
+    expert ``e``'s pairs ``[i S, (i + 1) S)``, and the tokens of a slot's
+    pairs ascend (the sort is stable). The grouped matmul over a tile is
+    :func:`share_product`'s: the kernels over the row blocks that hold a
+    pair, or one batched product ``[count, S, k] x [count, k, n]`` over
+    every row. A row past its expert's last pair gathers some token in
+    bounds and carries weight 0; if its block holds no pair its rows of
+    every product are never written."""
     k, count = weights.shape[-1], sizes.shape[0]
+    by_blocks = share_product(_widths_of(expert_weights)) == "blocks"
     slot = tile // count
     pairs_in_all = order.shape[0]
     by_pair = weights.reshape(-1)
@@ -413,10 +514,27 @@ def _share_tiles_of(tile, x, order, weights, sizes, expert):
     within = jnp.tile(jnp.arange(slot), count)  # a row's place in its slot
     held_pairs, first_pair = by_row(sizes), by_row(jnp.cumsum(sizes) - sizes)
 
-    def dot(a, w):
+    def over_slots(a, w):
         out = jnp.einsum("esk,ekn->esn", a.reshape(count, slot, -1), w,
                          preferred_element_type=jnp.float32)
         return out.astype(a.dtype).reshape(tile, -1)
+
+    def over_live_blocks(i):
+        """The tile as row blocks of ``SHARE_BLOCK_ROWS``, slot ``e``'s all
+        held expert ``e``'s: the product over those that hold a pair of
+        tile ``i``, each slot's first ``ceil(pairs of it in the tile / a
+        block's rows)``, named slot after slot (a cumulative sum over the
+        slots, no sort)."""
+        rows, in_slot = SHARE_BLOCK_ROWS, slot // SHARE_BLOCK_ROWS
+        live = lax.div(jnp.clip(sizes - i * slot, 0, slot) + (rows - 1), rows)
+        ends = jnp.cumsum(live)
+        step = jnp.arange(count * in_slot, dtype=jnp.int32)
+        slot_of_step = _run_of(count * in_slot, ends)
+        block_of_step = jnp.minimum(  # past the live steps: never read
+            count * in_slot - 1, slot_of_step * in_slot + step
+            - _rows_of(ends - live, slot_of_step))
+        return lambda a, w: grouped_matmul(
+            a, w, lax.div(step, in_slot), block_of_step, ends[-1:])
 
     def at(i):
         nth = i * slot + within  # which of its expert's pairs a row holds
@@ -426,6 +544,7 @@ def _share_tiles_of(tile, x, order, weights, sizes, expert):
         tokens = lax.div(pairs, k)
         with moe_scope("moe_dispatch"):
             rows = _rows_of(x, tokens)
+        dot = over_live_blocks(i) if by_blocks else over_slots
 
         def experts(rows, *expert_weights):
             with moe_scope("moe_experts"):
@@ -444,7 +563,8 @@ def _walk(x, order, inverse, weights, sizes, expert_weights, expert, tile):
     experts' part of the weighted sum. Two loops with a trip count read
     from the data, one forward and one backward, so each grouped matmul is
     in the program once per direction, whatever the number of tiles."""
-    live, at = _share_tiles_of(tile, x, order, weights, sizes, expert)
+    live, at = _share_tiles_of(tile, x, order, weights, sizes, expert,
+                               expert_weights)
 
     def one_tile(i, out):
         _, back, _, weight, rows, experts = at(i)
@@ -470,7 +590,8 @@ def _walk_bwd(expert, tile, saved, g):
     weights' in the weights' dtype: exact with one live tile (float32 sums
     were a gigabyte of the step's temporaries at Nemotron-H's widths)."""
     x, order, inverse, weights, sizes, expert_weights = saved
-    live, at = _share_tiles_of(tile, x, order, weights, sizes, expert)
+    live, at = _share_tiles_of(tile, x, order, weights, sizes, expert,
+                               expert_weights)
 
     def one_tile(i, carry):
         d_x, d_by_pair, d_experts = carry
@@ -554,6 +675,8 @@ def grouped_blocks(load, k: int, tokens: int,
 class _Blocks(NamedTuple):
     """Where the pairs lie when each held expert's start on a row block."""
     group_of_block: jax.Array  # int32 [blocks]: the held expert of a block
+    block_of_step: jax.Array   # int32 [blocks]: the blocks in order (those
+    #                            that hold a pair are a prefix)
     live: jax.Array            # int32 [1]: the blocks that hold a pair
     pair_of_row: jax.Array     # int32 [rows]: the (token, slot) pair of a
     #                            row; of a row of padding, some pair
@@ -578,8 +701,7 @@ def _blocks_of(sizes, keys, order, inverse, is_share: bool) -> _Blocks:
     block_ends, pair_ends = jnp.cumsum(blocks), jnp.cumsum(sizes)
     # rows of padding before an expert's first pair
     padding = (block_ends - blocks) * block - (pair_ends - sizes)
-    group_of_block = jnp.minimum(count - 1, jnp.sum(
-        jnp.arange(built)[:, None] >= block_ends, axis=1, dtype=jnp.int32))
+    group_of_block = _run_of(built, block_ends)
 
     def by_row(of_expert):
         """[count] -> [rows]: every row of a block has its expert's value."""
@@ -590,7 +712,8 @@ def _blocks_of(sizes, keys, order, inverse, is_share: bool) -> _Blocks:
     # expert's last sorted position
     position = jnp.arange(built * block) - by_row(padding)
     return _Blocks(
-        group_of_block, block_ends[-1:], real=position < by_row(pair_ends),
+        group_of_block, jnp.arange(built, dtype=jnp.int32), block_ends[-1:],
+        real=position < by_row(pair_ends),
         pair_of_row=_rows_of(order, jnp.minimum(position, pairs - 1)),
         row_of_pair=inverse + jnp.sum(jnp.where(
             keys[:, None] == jnp.arange(count), padding, 0), axis=1),
@@ -695,9 +818,11 @@ def moe_dropless(x: jax.Array, route, expert: Callable,
     over the live blocks, the pairs' rows gathered back (module text, "A
     full load"). A share of fewer experts is :func:`_walk`: a tile is one
     slot of :func:`share_slot_rows` rows a held expert, the product over it
-    one batched ``dot_general``, nothing is done for a tile past the fullest
-    held expert's last pair, and a live tile's weighted rows are added to
-    their tokens in float32 by
+    :func:`share_product`'s (the same kernels over the slots' live row
+    blocks where the expert matrices are whole 128s and wide enough, else
+    one batched ``dot_general`` over the slots), nothing is done for a tile
+    past the fullest held expert's last pair, and a live tile's weighted
+    rows are added to their tokens in float32 by
     :func:`~horovod_tpu.ops.rows_to_tokens.add_rows_at_tokens`, token block
     by token block in VMEM and not by a scatter (module text, "A share").
     """
@@ -725,7 +850,8 @@ def moe_dropless(x: jax.Array, route, expert: Callable,
     sizes = stats.expert_tokens[first:first + count]
     tile = share_tile_rows(k * t, count, n_experts)
     if tile < k * t:
-        _count_built_tiles(-(-t // (tile // count)), tile)  # T in slots
+        _count_built_tiles(-(-t // (tile // count)), tile,  # T in slots
+                           share_product(_widths_of(expert_weights)))
         out = _walk(x, order, inverse, weights, sizes, tuple(expert_weights),
                     expert, tile)
         return out.astype(x.dtype), stats
@@ -735,7 +861,8 @@ def moe_dropless(x: jax.Array, route, expert: Callable,
     _grouped_counter("blocks", "built").inc(blocks.group_of_block.shape[0])
     with moe_scope("moe_experts"):
         def dot(a, w):
-            return grouped_matmul(a, w, blocks.group_of_block, blocks.live)
+            return grouped_matmul(a, w, blocks.group_of_block,
+                                  blocks.block_of_step, blocks.live)
         rows = expert(dot, rows, *expert_weights)
     with moe_scope("moe_combine"):
         rows = _rows_from_blocks(rows, blocks).reshape(t, k, d)

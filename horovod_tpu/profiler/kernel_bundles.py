@@ -20,7 +20,9 @@ name (a Pallas call jitted as ``_conv_backward_call`` is
 that kernel's bundles alone and does not abort in its VMEM report, as it
 does after the first program of an unfiltered dump. The kernels of
 :data:`KERNELS` (the mixer's two ends, ``ops/ssm_ends.py``) the tool compiles
-itself, at ``nemotron3n-t8192``'s shapes for a described v5e:
+itself, at ``nemotron3n-t8192``'s shapes for a described v5e, and those of
+:data:`GROUPED_KERNELS` (``ops/grouped_matmul.py``'s two under a share's
+walk) at a tile of ``lfm2-t16384``:
 
     JAX_PLATFORMS=cpu python -m horovod_tpu.profiler.kernel_bundles DIR \\
         --kernel conv_bwd
@@ -68,20 +70,44 @@ KERNELS = {
 }
 
 
+# kernel -> (the jitted call of ``ops/grouped_matmul.py``, the shapes of its
+# two arrays, its static values) at a tile of lfm2-t16384's walk: eight
+# slots of 3072 rows, 192 row blocks of 128 named by grid step, experts of
+# 2048 x 1792
+_TILE, _EXPERTS = 8 * 3072, (8, 2048, 1792)
+GROUPED_KERNELS = {
+    "gmm": ("_gmm_call", [(_TILE, 2048), _EXPERTS], dict(transposed=False)),
+    "gmm_t": ("_gmm_call", [(_TILE, 1792), _EXPERTS], dict(transposed=True)),
+    "gmm_dw": ("_gmm_dw_call", [(_TILE, 2048), (_TILE, 1792)],
+               dict(groups=8, dtype="bfloat16")),
+}
+
+
 def compile_kernel(name: str, directory) -> None:
-    """Compile one of :data:`KERNELS` alone for a described v5e with its
-    bundles dumped under ``directory``. Loads the TPU compiler: once a
-    process, before anything else has."""
-    call, shapes = KERNELS[name]
+    """Compile one of :data:`KERNELS` or :data:`GROUPED_KERNELS` alone for
+    a described v5e with its bundles dumped under ``directory``. Loads the
+    TPU compiler: once a process, before anything else has."""
+    grouped = GROUPED_KERNELS.get(name)
+    call, shapes = grouped[:2] if grouped else KERNELS[name]
     os.environ["LIBTPU_INIT_ARGS"] = dump_flags(directory, f"{call}.1")
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     import jax
     import jax.numpy as jnp
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
-    from horovod_tpu.ops import ssm_ends
+    from horovod_tpu.ops import grouped_matmul, ssm_ends
     chip = SingleDeviceSharding(topologies.get_topology_desc(
         platform="tpu", topology_name="v5e:2x2").devices[0])
+    if grouped:
+        blocks = _TILE // 128
+        tables = [jax.ShapeDtypeStruct((n,), jnp.int32, sharding=chip)
+                  for n in (blocks, blocks, 1)]
+        arrays = [jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=chip)
+                  for shape in shapes]
+        jax.jit(lambda *a: getattr(grouped_matmul, call)(
+            *a, block_rows=128, interpret=False, **grouped[2])).lower(
+                *tables, *arrays).compile()
+        return
     options = dict(interpret=False)
     if name.startswith("conv"):
         options.update(at=4096, tile=ssm_ends.CONV_TILE)
@@ -149,7 +175,8 @@ def main(argv=None) -> int:
                     help="print the LIBTPU_INIT_ARGS that dump to DIRECTORY")
     ap.add_argument("--only", default=None,
                     help="with --flags: dump this instruction alone")
-    ap.add_argument("--kernel", choices=sorted(KERNELS), default=None,
+    ap.add_argument("--kernel", default=None,
+                    choices=sorted(KERNELS) + sorted(GROUPED_KERNELS),
                     help="compile this kernel into DIRECTORY first")
     ap.add_argument("--top", type=int, default=3, help="programs to show")
     args = ap.parse_args(argv)

@@ -414,10 +414,29 @@ def test_a_share_rejects_weights_that_do_not_lead_with_its_count():
 @pytest.fixture
 def small_tiles(monkeypatch):
     """Row blocks of 8, so that a tiny layer walks tiles of four slots of
-    40 rows in threes: blocks of 128 would make 384 pairs one tile."""
+    40 rows in threes: blocks of 128 would make 384 pairs one tile. And the
+    tests' expert matrices (16 x 24) wide enough for the kernels over live
+    blocks, as 1024 is at blocks of 128."""
     from horovod_tpu.parallel import ep
     monkeypatch.setattr(ep, "SHARE_BLOCK_ROWS", 8)
+    monkeypatch.setattr(ep, "SHARE_BLOCKS_MIN_WIDTH", 16)
     return ep
+
+
+@pytest.fixture(params=["blocks", "slots"])
+def small_tiles_each_way(request, small_tiles, monkeypatch):
+    """``small_tiles`` with the walk's product taken each way. The tests'
+    expert matrices (16 x 24) are whole row blocks of 8, so the rule
+    (``ep.share_product``) takes the grouped-matmul kernels over the live
+    blocks; ``slots`` steers it to the batched product, as widths that are
+    not whole blocks do (Nemotron-H's 2688 x 1856 at blocks of 128)."""
+    assert small_tiles.share_product((D, F, F, D)) == "blocks"
+    assert small_tiles.share_product((D, F + 4)) == "slots"  # no whole 8s
+    assert small_tiles.share_product((D, 8)) == "slots"  # too narrow
+    if request.param == "slots":
+        monkeypatch.setattr(small_tiles, "share_product",
+                            lambda widths: "slots")
+    return small_tiles
 
 
 FIRST, COUNT = 4, 4
@@ -527,14 +546,198 @@ def test_a_walked_share_is_exact_wherever_the_held_pairs_end(small_tiles,
 
 @pytest.mark.parametrize("pairs,live", [(96, 3), (41, 2), (40, 1)],
                          ids=["three-tiles", "a-tile-and-a-row", "a-tile"])
-def test_a_walked_share_of_one_expert_with_more_than_a_tile(small_tiles,
-                                                            pairs, live):
+def test_a_walked_share_of_one_expert_with_more_than_a_tile(
+        small_tiles_each_way, pairs, live):
     """A share of one expert walks tiles of its one slot of 40 rows: it is
     sent three tiles full, a tile and a row, a tile; every pair is
-    computed."""
+    computed, by the kernels over the slot's live blocks and by the batched
+    product over the slot."""
     sizes = [0, 0, pairs, 0]
-    _walked_share_against_dense(small_tiles, sizes, FIRST + 2, 1, SLOT,
-                                (live, 3))
+    _walked_share_against_dense(small_tiles_each_way, sizes, FIRST + 2, 1,
+                                SLOT, (live, 3))
+
+
+def _told_gated_share(x, weights, experts):
+    """The share ``(FIRST, COUNT)`` of a gated layer (three matrices an
+    expert) with the choice given, the weights the chosen experts' softmax
+    probabilities."""
+    w_router, *expert_weights = weights
+
+    def route(x):
+        logits = x @ w_router
+        probs = jax.nn.softmax(logits, axis=-1)
+        return jnp.take_along_axis(probs, experts, axis=-1), experts, \
+            probs, logits
+    return moe_dropless(x, route, swiglu_expert,
+                        [w[FIRST:FIRST + COUNT] for w in expert_weights],
+                        held=(FIRST, COUNT))[0]
+
+
+def _told_dense_gated_share(x, weights, experts):
+    w_router, w_gate, w_up, w_down = (
+        w if i == 0 else w[FIRST:FIRST + COUNT]
+        for i, w in enumerate(weights))
+    picked = (experts[:, :, None] == jnp.arange(E)).any(axis=1)
+    gate = jnp.where(picked, jax.nn.softmax(x @ w_router, axis=-1), 0.0)
+    hidden = jax.nn.silu(jnp.einsum("td,edf->tef", x, w_gate)) * \
+        jnp.einsum("td,edf->tef", x, w_up)
+    return jnp.einsum("te,tef,efd->td", gate[:, FIRST:FIRST + COUNT],
+                      hidden, w_down)
+
+
+def _unwritten_is_nan(grouped_matmul):
+    """``ops/grouped_matmul.grouped_matmul`` with NaN in every row of its
+    result, and of the gradient towards the rows, that lies in a block no
+    live step names: the kernels write nothing there, and on the chip the
+    buffer may hold anything."""
+    def dead(out, group_of_block, block_of_step, live):
+        blocks = group_of_block.shape[0]
+        named = jnp.zeros(blocks, bool).at[block_of_step].max(
+            jnp.arange(blocks) < live[0])
+        return jnp.where(jnp.repeat(named, out.shape[0] // blocks)[:, None],
+                         out, jnp.nan)
+
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+    def product(a, w, group_of_block, block_of_step, live, transposed=False):
+        return dead(grouped_matmul(a, w, group_of_block, block_of_step, live,
+                                   transposed),
+                    group_of_block, block_of_step, live)
+
+    def forward(a, w, *tables_and_transposed):
+        return product(a, w, *tables_and_transposed), \
+            (a, w) + tables_and_transposed[:3]
+
+    def backward(transposed, saved, d_out):
+        a, w, *tables = saved
+        d_a, d_w = jax.vjp(lambda a, w: grouped_matmul(
+            a, w, *tables, transposed), a, w)[1](d_out)
+        return dead(d_a, *tables), d_w, None, None, None
+    product.defvjp(forward, backward)
+    return product
+
+
+BLOCK_PRODUCT_CASES = {
+    # name: (pairs of held experts FIRST .. FIRST + 3, live tiles, NaN)
+    "a-balanced-load": ([24, 24, 24, 24], 1, False),
+    "one-expert-sent-more-than-a-slot": ([24, 47, 20, 24], 2, False),
+    "an-expert-sent-nothing": ([24, 0, 30, 7], 1, False),
+    "counts-that-end-inside-on-and-a-row-past-a-block":
+        ([19, 16, 5, 33], 1, False),
+    "unwritten-rows-hold-nan": ([24, 0, 47, 13], 2, True),
+}
+
+
+@pytest.mark.parametrize("case", list(BLOCK_PRODUCT_CASES))
+def test_the_walks_block_product_is_its_batched_product_and_the_reference(
+        small_tiles, monkeypatch, case):
+    """A gated share (three matrices an expert, as LFM2's, SmallThinker's
+    and SDAR's) walked with the kernels over each slot's live row blocks,
+    walked with the batched product over whole slots, and the dense
+    reference: the output and the gradients of tokens, router and all three
+    matrices agree, under a balanced load (three of a slot's five blocks
+    live), one held expert sent more than a slot (a second tile in which
+    one slot alone has a live block), a held expert sent nothing (a slot
+    with no live block: its matrices' gradients exactly zero), counts that
+    end inside a block, on its edge and a row past it, and with NaN in
+    every row the kernels leave unwritten, forward and backward: nothing a
+    dead block holds reaches the output or any gradient."""
+    ep = small_tiles
+    sizes, live, unwritten_is_nan = BLOCK_PRODUCT_CASES[case]
+    assert -(-max(sizes) // SLOT) == live
+    weights = _gated_weights(sum(sizes))
+    x = jnp.asarray(np.random.RandomState(50).randn(T, D), jnp.float32)
+    experts = _choices(sizes)
+
+    def out_and_grads(layer):
+        def loss(x, weights):
+            out = layer(x, weights, experts)
+            return jnp.sum(jnp.tanh(out) ** 2), out
+        (_, out), grads = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1), has_aux=True))(x, weights)
+        return (out, grads[0]) + tuple(grads[1])
+    assert ep.share_product(ep._widths_of(weights[1:])) == "blocks"
+    if unwritten_is_nan:
+        monkeypatch.setattr(ep, "grouped_matmul",
+                            _unwritten_is_nan(ep.grouped_matmul))
+    by_blocks = out_and_grads(_told_gated_share)
+    monkeypatch.setattr(ep, "share_product", lambda widths: "slots")
+    by_slots = out_and_grads(_told_gated_share)
+    dense = out_and_grads(_told_dense_gated_share)
+    for name, blocks, slots, want in zip(
+            ("out", "x", "router", "gate", "up", "down"), by_blocks,
+            by_slots, dense):
+        assert np.isfinite(np.asarray(blocks)).all(), name
+        assert float(jnp.abs(want).sum()) > 0, name
+        np.testing.assert_allclose(np.asarray(blocks), np.asarray(slots),
+                                   rtol=1e-5, atol=1e-6, err_msg=name)
+        np.testing.assert_allclose(np.asarray(blocks), np.asarray(want),
+                                   rtol=2e-3, atol=2e-5, err_msg=name)
+    for name, g in zip(("gate", "up", "down"), by_blocks[3:]):
+        unused = np.abs(np.asarray(g)).reshape(E, -1).sum(axis=1) == 0
+        np.testing.assert_array_equal(
+            unused[FIRST:FIRST + COUNT], np.asarray(sizes) == 0,
+            err_msg=name)
+
+
+def test_a_balanced_load_at_lfm2s_shapes_computes_its_rows_in_blocks():
+    """LFM2's layer as ``lfm2-t16384`` holds it (16 384 tokens, top-4 of 32
+    experts of 2048 x 1792, 8 held; traced, not run): a slot is 3072 rows
+    and the walk is built with the kernels (``share_product``, counted by
+    ``hvd_moe_share_product_total{path="blocks"}``); Nemotron-H's 2688 x
+    1856 is built with the batched product (``path="slots"``), as experts
+    under 1024 wide are. From a
+    balanced load ``share_tiles`` counts the held pairs in whole blocks of
+    128: computed / held under 1.1 where whole slots were 1.5."""
+    from horovod_tpu.metrics.registry import get_registry
+    from horovod_tpu.parallel import ep
+
+    def counted(*names):
+        return [get_registry().counter(name, **labels).value for name, labels
+                in zip(names[::2], names[1::2])]
+    product = "hvd_moe_share_product_total"
+    rows = "hvd_moe_share_rows_total"
+
+    def traced(tokens, d, f, n_experts, k, shapes, expert):
+        route = functools.partial(route_sigmoid_topk, k=k,
+                                  bias=jnp.zeros(n_experts))
+        jax.eval_shape(
+            lambda x, router, *held: moe_dropless(
+                x, functools.partial(route, w_router=router), expert, held,
+                held=(0, 8)),
+            jax.ShapeDtypeStruct((tokens, d), jnp.bfloat16),
+            jax.ShapeDtypeStruct((d, n_experts), jnp.float32),
+            *(jax.ShapeDtypeStruct((8,) + shape, jnp.bfloat16)
+              for shape in shapes))
+    before = counted(product, dict(path="blocks"), product,
+                     dict(path="slots"))
+    traced(16384, 2048, 1792, 32, 4,
+           [(2048, 1792), (2048, 1792), (1792, 2048)], swiglu_expert)
+    assert counted(product, dict(path="blocks"), product,
+                   dict(path="slots")) == [before[0] + 1, before[1]]
+    traced(8192, 2688, 1856, 128, 6, [(2688, 1856), (1856, 2688)],
+           relu2_expert)
+    assert counted(product, dict(path="blocks"), product,
+                   dict(path="slots")) == [before[0] + 1, before[1] + 1]
+    # whole 128s, but under 1024 wide: SmallThinker's and SDAR's experts
+    assert ep.share_product((2560, 768, 768, 2560)) == "slots"
+    assert ep.share_product((2048, 768)) == "slots"
+    assert ep.share_product((1024, 1024)) == "blocks"
+    assert ep.share_slot_rows(4 * 16384, 32) == 3072
+    # the step-0 load of ``lfm2-t16384``'s first sparse layer on the chip
+    # (``PERF.md`` §6, PR 44): held experts are sent 1802-2365 pairs
+    load = np.full(32, 2048)
+    load[:8] = [1802, 2365, 2048, 1983, 2126, 2200, 1900, 2047]
+    def recorded(widths):
+        kinds = (rows, dict(kind="held"), rows, dict(kind="computed"))
+        before = counted(*kinds)
+        assert ep.share_tiles(load, (0, 8), 4, 16384, record=True,
+                              widths=widths) == (1, 6)
+        return [now - was for now, was in zip(counted(*kinds), before)]
+    held, computed = recorded((2048, 1792))
+    assert held == load[:8].sum() and computed % 128 == 0
+    assert 1.0 <= computed / held < 1.1
+    # the same load under the batched product: every slot of the one tile
+    assert recorded((2688, 1856)) == [held, 8 * 3072]
 
 
 @pytest.mark.parametrize("shares", [2, 4, 8])
@@ -565,7 +768,8 @@ def test_a_full_load_is_one_tile_and_traces_to_the_kernels_alone():
     (the three, towards the rows, towards the matrices; each traced for the
     TPU and for interpret mode, ``lax.platform_dependent``'s two branches)
     and no ``ragged_dot_general``; it is to the letter the program PR 36
-    wrote (sha256 of its text)."""
+    wrote with PR 47's one operand more a kernel call, the blocks by grid
+    step, here an ``iota`` (sha256 of its text)."""
     import hashlib
     from horovod_tpu.parallel import ep
     assert ep.share_tile_rows(8 * 8192, 64, 64) == 8 * 8192
@@ -582,9 +786,9 @@ def test_a_full_load_is_one_tile_and_traces_to_the_kernels_alone():
     assert backward.count("name=_gmm_call") == 2 * 6
     assert backward.count("name=_gmm_dw_call") == 2 * 3
     assert hashlib.sha256(forward.encode()).hexdigest() == \
-        "f203b1e570856a8e797ddb4eb759c89e628d1fc10c5f24c3fa69da911f36ba0c"
+        "76a335968c66e24fac1fdbeb10897d36a1d59db75a5461ef96209d57d80327db"
     assert hashlib.sha256(backward.encode()).hexdigest() == \
-        "1658fd8c9f6516024bf19d2dd18b4b04a30e2e6a706dcea9e8067b7b719b83ca"
+        "dc552f5fa5275fab110cf054ff799b46d9f7d0c0256fa52b3dd6620142931431"
 
 
 # -- a full load: every expert's pairs from a row block on ----------------------
@@ -717,13 +921,20 @@ def _walk_by_scatter_add(ep, x, order, weights, sizes, expert_weights,
     scatter-add, in float32 too since the rows are gathered from ``x`` in
     float32 and only then take the experts' dtype."""
     dtype, x = x.dtype, x.astype(jnp.float32)
-    at = ep._share_tiles_of(tile, x, order, weights, sizes, expert)[1]
+    at = ep._share_tiles_of(tile, x, order, weights, sizes, expert,
+                            expert_weights)[1]
     out = jnp.zeros(x.shape, jnp.float32)
     for i in range(-(-x.shape[0] // (tile // sizes.shape[0]))):
-        tokens, _, _, weight, rows, experts = at(i)
-        rows = experts(rows.astype(dtype), *expert_weights)
+        tokens, back, _, weight, rows, experts = at(i)
+        # a row that is no pair goes nowhere and nothing returns through
+        # it: under the kernels over live blocks its rows of every product
+        # may never have been written (interpret mode leaves them NaN)
+        is_pair = (back < x.shape[0])[:, None]
+        rows = experts(jnp.where(is_pair, rows, 0.0).astype(dtype),
+                       *expert_weights)
         out = out + jnp.zeros(x.shape, jnp.float32).at[tokens].add(
-            weight[:, None] * rows.astype(jnp.float32))
+            jnp.where(is_pair, weight[:, None] * rows.astype(jnp.float32),
+                      0.0))
     return out
 
 
@@ -784,21 +995,31 @@ def test_the_walks_way_back_is_the_scatter_adds(small_tiles, case):
                               held, relu2_expert, tile, sum(sizes) > 0)
 
 
-@pytest.mark.parametrize("cell", ["smallthinker", "nemotron"])
+@pytest.mark.parametrize("cell", ["smallthinker", "nemotron", "lfm2"])
 def test_the_way_back_at_both_cells_shapes_scaled_down(cell):
-    """Eight of the router's experts held, top-6, row blocks of 128 as
-    built: SmallThinker's layer (64 experts, ReGLU, the softmax of the
-    chosen logits) at a hidden size of two 128s and Nemotron-H's (128
-    experts, relu^2, sigmoid scores) at three; the output and the tokens'
+    """Eight of the router's experts held, row blocks of 128 as built:
+    SmallThinker's layer (top-6 of 64 experts, ReGLU, the softmax of the
+    chosen logits) at a hidden size of two 128s and Nemotron-H's (top-6 of
+    128, relu^2, sigmoid scores) at three, both with the batched product
+    over slots as in their cells (``ep.share_product``: experts too narrow,
+    or no whole 128s); and, since PR 47, LFM2's (top-4 of 32, SwiGLU,
+    sigmoid scores) with experts 1024 x 1024, the narrowest that take the
+    kernels over live blocks, nothing patched. The output and the tokens'
     gradient of the walk against the scatter-add's (in float32: two
     programs in bf16 differ by where the compiler rounds, not by the way
     back; ``tests/test_rows_to_tokens.py`` returns bf16 rows)."""
     from horovod_tpu.parallel import ep
     rng = np.random.RandomState(11)
+    k = 6
     if cell == "smallthinker":
         tokens, d, f, n_experts = 512, 256, 64, 64
         expert, shapes = reglu_expert, [(d, f), (d, f), (f, d)]
         route = functools.partial(route_topk_softmax, k=6)
+    elif cell == "lfm2":
+        tokens, d, f, n_experts, k = 512, 1024, 1024, 32, 4
+        expert, shapes = swiglu_expert, [(d, f), (d, f), (f, d)]
+        route = functools.partial(route_sigmoid_topk, k=4,
+                                  bias=jnp.zeros(n_experts))
     else:
         tokens, d, f, n_experts = 1024, 384, 48, 128
         expert, shapes = relu2_expert, [(d, f), (f, d)]
@@ -811,7 +1032,9 @@ def test_the_way_back_at_both_cells_shapes_scaled_down(cell):
     weights, experts, _, _ = route(x, router)
     order, inverse, weights, sizes, tile = _walk_operands(
         ep, x, experts, weights, 0, 8, n_experts)
-    assert tile == 8 * 128 < 6 * tokens and int(sizes.max()) <= 128
+    assert tile == 8 * 128 < k * tokens and int(sizes.max()) <= 128
+    assert ep.share_product(ep._widths_of(held)) == \
+        ("blocks" if cell == "lfm2" else "slots")
 
     _walk_against_scatter_add(ep, x, order, inverse, weights, sizes, held,
                               expert, tile, True)
@@ -830,33 +1053,46 @@ def _primitives(jaxpr, seen=None):
     return seen
 
 
-def test_a_walk_has_each_grouped_matmul_once_a_direction(small_tiles):
+def test_a_walk_has_each_grouped_matmul_once_a_direction(
+        small_tiles_each_way):
     """Three tiles and still two grouped matmuls forward and six in the
     backward walk (the two again, and their four transposes): the walk is a
     loop, not an unrolling, and has no fallback of its own. Its product is
-    one batched ``dot_general`` over the tile's slots, an expert a batch
-    entry; no ``ragged_dot`` is left in it. **No row goes back to its token
-    by a scatter**: the way back is ``ops/rows_to_tokens``'s kernel, once
-    forward (the weighted rows) and once backward (the rows' gradient), each
-    lowered for the TPU and in interpret mode elsewhere (a ``cond`` on the
-    platform, traced both ways), and the one scatter left is the backward
-    walk's, of a scalar a pair."""
+    ``ep.share_product``'s and no other: the grouped-matmul kernels over
+    the tile's live row blocks (``_gmm_call`` for the two and towards the
+    rows, ``_gmm_dw_call`` towards the matrices, and no ``dot_general`` with
+    an expert a batch entry), or one batched ``dot_general`` over the
+    tile's slots (and no grouped-matmul kernel); no ``ragged_dot`` is left
+    in it. **No row goes back to its token by a scatter**: the way back is
+    ``ops/rows_to_tokens``'s kernel, once forward (the weighted rows) and
+    once backward (the rows' gradient). Every kernel call is lowered for the
+    TPU and in interpret mode elsewhere (a ``cond`` on the platform, traced
+    both ways), and the one scatter left is the backward walk's, of a
+    scalar a pair."""
+    by_blocks = small_tiles_each_way.share_product((D, F)) == "blocks"
     batched = "([0], [0]))"  # dot_generals with an expert a batch entry
     w = _share_weights()
     x = jnp.zeros((T, D), jnp.float32)
     forward = jax.make_jaxpr(lambda x, w: _share(x, w, 4, 4))(x, w)
-    assert str(forward).count(batched) == 2
     both = jax.make_jaxpr(jax.grad(
         lambda x, w: _share(x, w, 4, 4)[0].sum(), argnums=(0, 1)))(x, w)
-    assert str(both).count(batched) == 2 + 6
-    assert "ragged_dot_general" not in str(forward) + str(both)
-    for jaxpr, loops, ways_back in ((forward, 1, 1), (both, 2, 2)):
+    for jaxpr, products, towards_matrices in ((forward, 2, 0), (both, 6, 2)):
+        text = str(jaxpr)
+        assert text.count(batched) == (
+            0 if by_blocks else products + towards_matrices)
+        assert text.count("name=_gmm_call") == \
+            (2 * products if by_blocks else 0)
+        assert text.count("name=_gmm_dw_call") == \
+            (2 * towards_matrices if by_blocks else 0)
+        assert "ragged_dot_general" not in text
+    for jaxpr, loops, ways_back, kernels in ((forward, 1, 1, 2),
+                                             (both, 2, 2, 8)):
         seen = _primitives(jaxpr.jaxpr)
+        calls = ways_back + (kernels if by_blocks else 0)
         assert len(seen["while"]) == loops
-        assert len(seen["cond"]) == ways_back  # the platform's, no other
+        assert len(seen["cond"]) == calls  # the platform's, no other
         assert sorted(eqn.params["interpret"] for eqn in
-                      seen["pallas_call"]) == \
-            [False] * ways_back + [True] * ways_back
+                      seen["pallas_call"]) == [False] * calls + [True] * calls
         assert "scatter-add" not in seen and "scatter_add" not in seen
         scattered = [eqn.outvars[0].aval.shape
                      for eqn in seen.get("scatter", [])]
@@ -905,9 +1141,12 @@ def test_share_tile_rule_and_live_tiles_by_hand():
     ([96, 81, 5, 80], 8 * (7 + 7 + 4 + 7 + 7 + 1 + 3 + 7 + 7)),
     ([1, 0, 0, 2], 8 * (1 + 2))],
     ids=["a-tile", "no-pair", "three-tiles", "three-pairs"])
-def test_the_way_back_counts_the_rows_it_fetches(small_tiles, sizes,
-                                                 fetched):
-    """``hvd_moe_share_rows_total{kind="fetched"}``, from the load alone: a
+def test_the_way_back_counts_the_rows_it_fetches(small_tiles_each_way,
+                                                 sizes, fetched):
+    """``hvd_moe_share_rows_total{kind="computed"}``: the held pairs in
+    whole row blocks of 8 under the kernels, every slot of the live tiles
+    under the batched product (``widths`` says which, as in the layer).
+    ``{kind="fetched"}``, from the load alone: a
     slot's ``n`` rows in a live tile are ``ceil(n / 8)`` chunks of 8 rows,
     and a chunk is fetched again for each of the 3 token blocks of 32 it
     holds rows of, ``min(n, 3) - 1`` more at most. The jobs
@@ -922,10 +1161,13 @@ def test_the_way_back_counts_the_rows_it_fetches(small_tiles, sizes,
     load = np.zeros(E)
     load[FIRST:FIRST + COUNT] = sizes
     before = {kind: rows(kind) for kind in ("held", "computed", "fetched")}
+    small_tiles = small_tiles_each_way
     live, _ = small_tiles.share_tiles(load, (FIRST, COUNT), K_SHARE, T,
-                                      record=True)
+                                      record=True, widths=(D, F))
     assert rows("held") == before["held"] + sum(sizes)
-    assert rows("computed") == before["computed"] + live * TILE
+    assert rows("computed") == before["computed"] + (
+        sum(8 * -(-n // 8) for n in sizes)
+        if small_tiles.share_product((D, F)) == "blocks" else live * TILE)
     assert rows("fetched") == before["fetched"] + fetched
     assert rt.chunk_rows_of(SLOT) == 8 and rt.block_tokens_of(T) == 32
     jobs = 0
@@ -959,12 +1201,13 @@ def test_the_walk_counts_its_tiles_in_the_registry(small_tiles):
     tiles = small_tiles.share_tiles(stats.expert_tokens, (4, 4), K_SHARE,
                                     T, record=True)
     assert tiles[1] == 3 and counter("live").value == live + tiles[0]
-    # the held experts' pairs, and every slot of the tiles they made live:
-    # their ratio is what the slots cost the grouped matmuls
+    # the held experts' pairs, and the row blocks of 8 that hold them:
+    # their ratio is what a slot's last block costs the grouped matmuls
     sizes = np.asarray(stats.expert_tokens)[4:8]
     assert rows("held").value == held_rows + sizes.sum() > held_rows
     assert tiles[0] == -(-sizes.max() // SLOT)
-    assert rows("computed").value == computed + tiles[0] * TILE
+    assert rows("computed").value == computed + sum(
+        8 * -(-n // 8) for n in sizes)
     # a full load builds no walk: it counts its row blocks instead
     def grouped(name, kind):
         return get_registry().counter(f"hvd_moe_grouped_{name}_total",

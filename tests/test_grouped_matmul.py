@@ -41,13 +41,18 @@ def _operands(sizes, built_blocks, k, n, dtype, transposed=False, seed=0):
         real[:, None], group_of_row
 
 
-def _product(table, live, real, transposed=False):
+def _product(table, live, real, transposed=False, steps=None):
     """The rows of no group zeroed on the way in and out: the kernel
-    leaves rows past the live blocks unwritten (``ep.moe_dropless`` gathers
-    only the rows that hold a pair, and zeroes the others' gradient)."""
+    leaves rows outside the live blocks unwritten (``ep.moe_dropless``
+    gathers only the rows that hold a pair, and zeroes the others'
+    gradient). ``steps``: the blocks by grid step, in order without it (a
+    full load's: the live blocks are a prefix)."""
+    if steps is None:
+        steps = jnp.arange(table.shape[0], dtype=jnp.int32)
+
     def product(a, w):
         a = jnp.where(real, a, jnp.zeros((), a.dtype))
-        out = grouped_matmul(a, w, table, live, transposed)
+        out = grouped_matmul(a, w, table, steps, live, transposed)
         return jnp.where(real, out, jnp.zeros((), out.dtype))
     return product
 
@@ -163,7 +168,77 @@ def test_rows_past_the_live_blocks_are_not_computed():
     a, w, table, live, real, group_of_row = _operands(
         [8, 8], 4, 16, 24, jnp.float32)
     a = a.at[16:].set(1.0)  # rows of blocks that are built and not live
-    out = np.asarray(grouped_matmul(a, w, table, live))
+    out = np.asarray(grouped_matmul(a, w, table, jnp.arange(4), live))
     want = np.einsum("rk,rkn->rn", np.asarray(a), np.asarray(w)[group_of_row])
     np.testing.assert_allclose(out[:16], want[:16], rtol=1e-5, atol=1e-5)
     assert not np.allclose(out[16:], want[16:], rtol=1e-2)
+
+
+# -- live blocks that are no prefix: a slot of whole blocks a group ------------
+
+def _slots(sizes, blocks_a_slot):
+    """Group ``g``'s rows from block ``g * blocks_a_slot`` on, as a tile of
+    ``ep._walk`` lays them: (block-to-group table, the live blocks slot
+    after slot then any block at all, their count [1], [rows] bool: a row
+    of a group, [rows] the group of each row)."""
+    groups = len(sizes)
+    table = np.repeat(np.arange(groups), blocks_a_slot).astype(np.int32)
+    live = [g * blocks_a_slot + b for g, size in enumerate(sizes)
+            for b in range(-(-size // R))]
+    steps = np.asarray(live + [0] * (len(table) - len(live)), np.int32)
+    real = np.zeros(len(table) * R, bool)
+    for g, size in enumerate(sizes):
+        assert size <= blocks_a_slot * R
+        real[g * blocks_a_slot * R:g * blocks_a_slot * R + size] = True
+    return jnp.asarray(table), jnp.asarray(steps), \
+        jnp.asarray([len(live)], jnp.int32), real, np.repeat(table, R)
+
+
+SLOT_CASES = {
+    "every-slot-two-thirds-full": [16, 13, 16, 11],
+    "a-slot-with-no-live-block": [24, 0, 9, 3],
+    "the-first-and-last-slots-empty": [0, 17, 8, 0],
+    "every-slot-full": [24, 24, 24, 24],
+    "no-live-block": [0, 0, 0, 0],
+    "one-row": [0, 0, 1, 0],
+}
+
+
+@pytest.mark.parametrize("transposed", [False, True], ids=["w", "w-T"])
+@pytest.mark.parametrize("case", list(SLOT_CASES))
+def test_live_blocks_named_by_a_list_match_an_einsum_a_group(case,
+                                                             transposed):
+    """The same kernels under a share's layout: four slots of three blocks,
+    the blocks that hold a row named by a compacted list (each slot's own
+    prefix). Output and both gradients are the einsum's; a block the list
+    does not name is not computed; a group with none gets zeros."""
+    sizes = SLOT_CASES[case]
+    table, steps, live, real, group_of_row = _slots(sizes, 3)
+    rng = np.random.RandomState(2)
+    k, n = (40, 24) if transposed else (24, 40)
+    a = jnp.asarray(np.where(real[:, None], rng.randn(len(real), k), 0.0),
+                    jnp.float32)
+    w = jnp.asarray(rng.randn(len(sizes), 24, 40) * 0.2, jnp.float32)
+    real = real[:, None]
+    got = _value_and_grads(jax.jit(_product(table, live, real, transposed,
+                                            steps)), a, w)
+    want = _value_and_grads(_reference(real, group_of_row, transposed), a, w)
+    for name, g, v in zip(("out", "d_rows", "d_matrices"), got, want):
+        assert g.shape == v.shape and g.dtype == v.dtype, name
+        assert np.isfinite(np.asarray(g)).all(), name
+        np.testing.assert_allclose(np.asarray(g), np.asarray(v), rtol=1e-5,
+                                   atol=1e-5, err_msg=name)
+    for g, size in enumerate(sizes):
+        assert (np.abs(np.asarray(got[2])[g]).sum() > 0) == (size > 0), g
+    # a block outside the list holds what the buffer held, not the product
+    ones = jnp.ones_like(a)
+    out = np.asarray(grouped_matmul(ones, w, table, steps, live, transposed))
+    full = np.asarray(_reference(np.ones_like(real), group_of_row,
+                                 transposed)(ones, w))
+    block_live = np.zeros(len(table), bool)
+    block_live[np.asarray(steps)[:int(live[0])]] = True
+    row_live = np.repeat(block_live, R)
+    np.testing.assert_allclose(out[row_live], full[row_live], rtol=1e-5,
+                               atol=1e-5)
+    if (~row_live).any():
+        assert not np.allclose(out[~row_live], full[~row_live], rtol=1e-2)

@@ -7,8 +7,8 @@ import re
 import pytest
 
 from tpu_compile_cases import (  # noqa: F401
-    _benchmark_on_path, _compiled_cell, _kernel_calls, _row_scatters,
-    no_persistent_cache, topo)
+    _benchmark_on_path, _compiled_cell, _kernel_calls, _products_by_blocks,
+    _row_scatters, no_persistent_cache, topo)
 
 SEQ, HIDDEN = 16384, 2048
 
@@ -53,9 +53,15 @@ def test_lfm2_cell_holds_the_causal_kernels_on_grouped_heads_of_64(
     are kept by name, so the forward kernel does not run twice (``"blocks"``
     would hold 2). q at 32 heads of 64, k and v at their own 8: a group of
     4, nothing repeated in HBM. Every call under ``attn_full`` of
-    ``Lfm2Block_1``; the share walks its pairs by XLA's batched product over
-    eight slots of 3072 rows (1.5 x 4 x 16 384 / 32), the largest any share
-    has run, and goes back to its tokens through ``_add_rows_kernel``; no
+    ``Lfm2Block_1``; the share walks tiles of eight slots of 3072 rows (1.5 x
+    4 x 16 384 / 32), the largest any share has run, and its experts (2048 x
+    1792: whole 128s, the kernels' side of ``ep.share_product``) multiply
+    the tile's live row blocks by ``ops/grouped_matmul.py``'s kernels, all
+    under ``moe_experts``: a layer's three products forward, and in the
+    backward walk the three again, their three transposes towards the rows
+    (``_gmm_kernel`` x 9) and the three towards the matrices
+    (``_gmm_dw_kernel`` x 3); no ``dot_general`` over ``[8, 3072, .]`` is
+    left. The rows go back to their tokens through ``_add_rows_kernel``; no
     ``ragged-dot``, no scatter of rows, no collective on one chip."""
     from horovod_tpu.parallel import ep
     job, _, compiled = lfm2_cell
@@ -63,6 +69,7 @@ def test_lfm2_cell_holds_the_causal_kernels_on_grouped_heads_of_64(
     calls, op_names = _kernel_calls(text)
     assert calls == {"_fwd_kernel": 1, "_bwd_dq_kernel": 1,
                      "_bwd_dkv_kernel": 1, "_add_rows_kernel": 2 * 4,
+                     "_gmm_kernel": 9 * 4, "_gmm_dw_kernel": 3 * 4,
                      "_mix_fwd_kernel": 2 * 4, "_mix_bwd_kernel": 4}
     assert job.flash_call == (1, SEQ, 32, 64, True) and job.flash_layers == 1
     way_back = op_names.pop("_add_rows_kernel")
@@ -71,6 +78,7 @@ def test_lfm2_cell_holds_the_causal_kernels_on_grouped_heads_of_64(
     assert sum("moe_dispatch" in name and "transpose(jvp(" in name
                for name in way_back) == 4
     assert not _row_scatters(text)
+    _products_by_blocks(op_names, "Lfm2SparseMoe_0", layers=4, matrices=3)
     # the middle of each of the four conv layers: its forward kernel twice
     # (the pass and the block's recomputation), its backward once
     middles = op_names.pop("_mix_fwd_kernel"), op_names.pop("_mix_bwd_kernel")
@@ -91,8 +99,8 @@ def test_lfm2_cell_holds_the_causal_kernels_on_grouped_heads_of_64(
     assert "ragged-dot" not in text
     slot = ep.share_slot_rows(4 * SEQ, 32)
     assert slot == 3072 and ep.share_tile_rows(4 * SEQ, 8, 32) == 8 * slot
-    assert re.search(rf"= f32\[8,{slot},1792\]\S* convolution\([^\n]*"
-                     r"moe_experts\)*/esk,ekn->esn/dot_general", text)
+    assert "esk,ekn->esn" not in text
+    assert ep.share_product((HIDDEN, 1792)) == "blocks"
     from horovod_tpu.profiler.annotate import SHORTCONV_SCOPES
     for scope in (*SHORTCONV_SCOPES, "moe_router", "moe_dispatch",
                   "moe_experts", "moe_combine", "attn_full"):

@@ -83,6 +83,9 @@ def test_nemotron_cell_holds_its_kernels_and_scopes(nemotron_cell):
         r"= f32(\[8,\d+,\d+\])\S* convolution\([^\n]*"
         r"moe_experts\)*/esk,ekn->esn/dot_general", text)
     assert len(products) == 8 * expert_layers
+    # 2688 x 1856 are no whole 128s: the slots' side of the shape rule,
+    # and no grouped-matmul kernel in the step (``calls`` above)
+    assert ep.share_product((2688, 1856)) == "slots"
     assert sorted(set(products)) == sorted(
         f"[8,{a},{b}]" for a, b in [(slot, 1856), (slot, 2688),
                                     (1856, 2688), (2688, 1856)])

@@ -44,7 +44,9 @@ def test_sdar_cell_holds_the_block_mask_kernels_and_no_score_array(
     No array of the step has [2L, 2L] or [L, L] elements a head: the mask
     and the scores exist in VMEM tiles alone (a noised block on itself is
     ``[.., 2048, 4, 8, 4, 4]``). The share walks by XLA's batched product
-    over sixteen slots of 1536 rows; one chip exchanges nothing."""
+    over sixteen slots of 1536 rows (experts of 2048 x 768 are under the
+    width at which a walk takes the kernels over live blocks,
+    ``ep.share_product``); one chip exchanges nothing."""
     from horovod_tpu.parallel import ep
     job, _, compiled = sdar_cell
     text = compiled.as_text()
@@ -71,6 +73,7 @@ def test_sdar_cell_holds_the_block_mask_kernels_and_no_score_array(
     assert slot == 1536 and ep.share_tile_rows(8 * 16384, 16, 128) == 16 * slot
     assert re.search(rf"= f32\[16,{slot},768\]\S* convolution\([^\n]*"
                      r"moe_experts\)*/esk,ekn->esn/dot_general", text)
+    assert ep.share_product((2048, 768)) == "slots"
     for scope in ("moe_router", "moe_dispatch", "moe_experts",
                   "moe_combine", "attn_blockdiff", "diffusion_loss"):
         assert scope in text, scope

@@ -41,7 +41,9 @@ def test_smallthinker_cell_holds_causal_and_window_kernels_side_by_side(
     routers under ``moe_router`` before their layer's attention; the share
     walks its pairs by XLA's batched product over eight slots of 2304 rows
     (1.5 x 6 x 16 384 / 64), no ``ragged-dot`` and no grouped-matmul
-    kernel; one chip exchanges nothing."""
+    kernel: experts of 2560 x 768 are under the width at which a walk takes
+    the kernels over live blocks (``ep.share_product``; PR 47 measured them
+    0.6% slower here); one chip exchanges nothing."""
     from horovod_tpu.parallel import ep
     job, _, compiled = smallthinker_cell
     text = compiled.as_text()
@@ -72,6 +74,7 @@ def test_smallthinker_cell_holds_causal_and_window_kernels_side_by_side(
     assert slot == 2304 and ep.share_tile_rows(6 * 16384, 8, 64) == 8 * slot
     assert re.search(rf"= f32\[8,{slot},768\]\S* convolution\([^\n]*"
                      r"moe_experts\)*/esk,ekn->esn/dot_general", text)
+    assert ep.share_product((2560, 768)) == "slots"
     for scope in ("moe_router", "moe_dispatch", "moe_experts",
                   "moe_combine", "attn_full", "attn_window"):
         assert scope in text, scope

@@ -129,6 +129,26 @@ def _row_scatters(text):
         r"= \w+(\[[\d,]*\])\S* scatter\([^\n]*moe_", text) if "," in shape]
 
 
+def _products_by_blocks(op_names, module, layers, matrices):
+    """Takes the grouped-matmul kernels out of ``op_names`` and holds them
+    to a walk whose product is the kernels over live row blocks
+    (``ep.share_product``): in each of ``layers`` expert layers, every call
+    under ``module`` and ``moe_experts`` inside the walk's loop; the
+    ``matrices`` products forward, and in the backward walk the products
+    again, their transposes towards the rows and, ``_gmm_dw_kernel``, the
+    transposes towards the matrices."""
+    products, towards = op_names.pop("_gmm_kernel"), \
+        op_names.pop("_gmm_dw_kernel")
+    for name in products + towards:
+        assert module in name and re.search(
+            r"while/body/[\w(]*moe_experts", name), name
+    backward = ["transpose(jvp(" in name for name in products]
+    assert backward.count(False) == matrices * layers
+    assert backward.count(True) == 2 * matrices * layers
+    assert len(towards) == matrices * layers
+    assert all("transpose(jvp(" in name for name in towards)
+
+
 def _compiled_cell(topo, workload):
     """(job, traffic, compiled): a cell as ``benchmark/compile_check.py``
     compiles it: the configuration's own job at its real size through
