@@ -4,6 +4,12 @@ from horovod_tpu.models.gpt import (  # noqa: F401
     GptMedium,
     GptSmall,
 )
+from horovod_tpu.models.joyai_flash import (  # noqa: F401
+    JoyaiFlashDecoder,
+    JoyaiFlashTiny,
+    JoyaiLlmFlash,
+    joyai_flash_loss,
+)
 from horovod_tpu.models.lfm2 import (  # noqa: F401
     Lfm2_8B_A1B,
     Lfm2MoeDecoder,

@@ -100,7 +100,7 @@ LFM2_8B_A1B_LAYER_TYPES = tuple(
     for i in range(24))
 
 
-def _dense(features: int, dtype, name: str) -> nn.Dense:
+def linear(features: int, dtype, name: str) -> nn.Dense:
     return nn.Dense(features, use_bias=False, dtype=dtype, kernel_init=INIT,
                     name=name)
 
@@ -121,11 +121,11 @@ class Lfm2ShortConv(nn.Module):
         d = x.shape[-1]
         w = self.param("conv", _taps_init, (self.taps, d), jnp.float32)
         with shortconv_scope("shortconv_in_proj"):
-            bcu = _dense(3 * d, self.dtype, "in_proj")(x)
+            bcu = linear(3 * d, self.dtype, "in_proj")(x)
         with shortconv_scope("shortconv_mix"):
             y = gated_short_conv(bcu, w, self.dtype)
         with shortconv_scope("shortconv_out_proj"):
-            return _dense(d, self.dtype, "out_proj")(y)
+            return linear(d, self.dtype, "out_proj")(y)
 
 
 class Lfm2Attention(nn.Module):
@@ -141,7 +141,7 @@ class Lfm2Attention(nn.Module):
         b, t, hidden = x.shape
 
         def heads_of(name, count):
-            return _dense(count * self.head_dim, self.dtype, name)(x) \
+            return linear(count * self.head_dim, self.dtype, name)(x) \
                 .reshape(b, t, count, self.head_dim)
         q, k, v = (heads_of("q_proj", self.heads),
                    heads_of("k_proj", self.kv_heads),
@@ -153,7 +153,7 @@ class Lfm2Attention(nn.Module):
         with attn_scope("attn_full"):
             o = attention(rotary(q, self.rope_theta),
                           rotary(k, self.rope_theta), v, causal=True)
-        return _dense(hidden, self.dtype, "out_proj")(
+        return linear(hidden, self.dtype, "out_proj")(
             o.reshape(b, t, self.heads * self.head_dim))
 
 
@@ -164,9 +164,9 @@ class Lfm2Mlp(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        gate = _dense(self.width, self.dtype, "w1")(x)
-        up = _dense(self.width, self.dtype, "w3")(x)
-        return _dense(x.shape[-1], self.dtype, "w2")(jax.nn.silu(gate) * up)
+        gate = linear(self.width, self.dtype, "w1")(x)
+        up = linear(self.width, self.dtype, "w3")(x)
+        return linear(x.shape[-1], self.dtype, "w2")(jax.nn.silu(gate) * up)
 
 
 class Lfm2Router(nn.Module):

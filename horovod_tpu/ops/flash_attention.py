@@ -5,7 +5,7 @@ softmax(QK^T)V blockwise in VMEM with online log-sum-exp accumulation, so
 the [T, T] score matrix never exists in HBM — the kernel streams K/V blocks
 through the MXU and keeps the fp32 accumulators on chip.
 
-Five design points make this the building block the rest of the framework
+Six design points make this the building block the rest of the framework
 composes with:
 
 - **log-sum-exp residual**: ``return_lse=True`` also returns the per-row
@@ -44,6 +44,15 @@ composes with:
   compared with a column of key positions, and the loops' bounds are the
   causal ones moved by a block (``"lt"``), so a tile no pair of which is
   visible is never loaded.
+- **queries and keys of one width, values of another**: ``q``/``k``
+  ``[.., d]`` against ``v`` ``[.., dv]`` (multi-head latent attention trains
+  with 192 and 128: a head's key is its own 128 beside one rotary 64 that
+  all heads share). Nothing in the bodies assumes one width: the scores
+  contract over ``d``, the output and ``dv`` accumulate ``dv`` wide, ``dq``
+  and ``dk`` ``d`` wide, ``delta`` sums over ``dv``. Such a call runs the
+  same bodies under ``_fwd_latent_kernel``, ``_bwd_dq_latent_kernel`` and
+  ``_bwd_dkv_latent_kernel``, so a reader that costs ``_fwd_kernel`` by one
+  head width never meets it; with equal widths the program is what it was.
 - **custom VJP**: backward is two Pallas kernels (dq gridded over q tiles,
   dk/dv gridded over k tiles) recomputing probabilities from the saved lse,
   the standard flash backward. The lse output is differentiable too
@@ -407,10 +416,12 @@ def _bwd_dkv_body(qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 # The kernel functions proper are thin: a call's name in the compiled text
 # and in a device trace is that of the ``*_kernel`` function it was traced
 # through (the Mosaic bytecode carries the frames), and a windowed call has a
-# name of its own, as has one under a block mask. A trace then tells window
-# and block-diffusion layers from causal ones, and a reader that costs
-# ``_fwd_kernel`` at the causal pair count never meets their calls. The
-# bodies above are shared; ``window`` and ``block_mask`` are static in them.
+# name of its own, as has one under a block mask and one whose values are
+# not as wide as its queries and keys (``latent``). A trace then tells
+# window, block-diffusion and latent layers from causal ones, and a reader
+# that costs ``_fwd_kernel`` at the causal pair count and one head width
+# never meets their calls. The bodies above are shared; ``window`` and
+# ``block_mask`` are static in them, and the widths are the blocks' own.
 def _fwd_kernel(*refs, **static):
     _fwd_body(*refs, **static)
 
@@ -444,6 +455,18 @@ def _bwd_dq_blockdiff_kernel(*refs, **static):
 
 
 def _bwd_dkv_blockdiff_kernel(*refs, **static):
+    _bwd_dkv_body(*refs, **static)
+
+
+def _fwd_latent_kernel(*refs, **static):
+    _fwd_body(*refs, **static)
+
+
+def _bwd_dq_latent_kernel(*refs, **static):
+    _bwd_dq_body(*refs, **static)
+
+
+def _bwd_dkv_latent_kernel(*refs, **static):
     _bwd_dkv_body(*refs, **static)
 
 
@@ -514,15 +537,18 @@ def _flash(q, k, v, q_off, k_off, causal, sm_scale, block_q, block_k,
     return o, lse
 
 
-def _kernel(plain, windowed, blockdiff, window, block_mask, **static):
+def _kernel(plain, windowed, blockdiff, latent, window, block_mask, widths,
+            **static):
     """The kernel function of a call: ``plain`` as it always was, with a
-    window ``windowed``, under a block mask ``blockdiff``: the same body
-    under a name of its own."""
+    window ``windowed``, under a block mask ``blockdiff``, and ``latent``
+    where ``widths`` (of q/k, of v) differ under the plain causal or full
+    mask: the same body under a name of its own."""
     if block_mask is not None:
         return functools.partial(blockdiff, block_mask=block_mask, **static)
-    if window is None:
-        return functools.partial(plain, **static)
-    return functools.partial(windowed, window=window, **static)
+    if window is not None:
+        return functools.partial(windowed, window=window, **static)
+    return functools.partial(latent if widths[0] != widths[1] else plain,
+                             **static)
 
 
 # The three calls proper, each under ``jax.jit`` and reached through
@@ -543,8 +569,9 @@ def _fwd_call(q_off, k_off, qb, kb, vb, *, causal, sm_scale, block_q,
     group = bh // kb.shape[0]  # k and v at their own heads: see _head_spec
     return pl.pallas_call(
         _kernel(_fwd_kernel, _fwd_window_kernel, _fwd_blockdiff_kernel,
-                window, block_mask, block_q=block_q, block_k=block_k,
-                causal=causal, sm_scale=sm_scale, kv_len=tk),
+                _fwd_latent_kernel, window, block_mask, (d, dv),
+                block_q=block_q, block_k=block_k, causal=causal,
+                sm_scale=sm_scale, kv_len=tk),
         grid=(bh, tq // block_q),
         in_specs=[
             scalar_spec(), scalar_spec(),
@@ -577,9 +604,9 @@ def _bwd_dq_call(q_off, k_off, qb, kb, vb, dob, lse, corr, *, causal,
     row = _head_spec(1, tq, tiled=False)  # lse, corr
     return pl.pallas_call(
         _kernel(_bwd_dq_kernel, _bwd_dq_window_kernel,
-                _bwd_dq_blockdiff_kernel, window, block_mask,
-                block_q=block_q, block_k=block_k, causal=causal,
-                sm_scale=sm_scale, kv_len=tk),
+                _bwd_dq_blockdiff_kernel, _bwd_dq_latent_kernel, window,
+                block_mask, (d, dv), block_q=block_q, block_k=block_k,
+                causal=causal, sm_scale=sm_scale, kv_len=tk),
         grid=(bh, tq // block_q),
         in_specs=[
             scalar_spec(), scalar_spec(),
@@ -611,9 +638,9 @@ def _bwd_dkv_call(q_off, k_off, qb, kb, vb, dob, lse, corr, *, causal,
     row = _head_spec(1, tq, tiled=False)
     return pl.pallas_call(
         _kernel(_bwd_dkv_kernel, _bwd_dkv_window_kernel,
-                _bwd_dkv_blockdiff_kernel, window, block_mask,
-                block_q=block_q, block_k=block_k, causal=causal,
-                sm_scale=sm_scale, q_len=tq),
+                _bwd_dkv_blockdiff_kernel, _bwd_dkv_latent_kernel, window,
+                block_mask, (d, dv), block_q=block_q, block_k=block_k,
+                causal=causal, sm_scale=sm_scale, q_len=tq),
         grid=(bh, tk // block_k),
         in_specs=[
             scalar_spec(), scalar_spec(),
@@ -793,6 +820,7 @@ def _blocks_block_plan(num_q: int, num_k: int, block_q: int, block_k: int,
 
 WINDOW_KIND = "window_"  # a window call's blocks, in the counter below
 BLOCKDIFF_KIND = "blockdiff_"  # and a call's under a block mask
+LATENT_KIND = "latent_"  # and a call's whose values are narrower than its keys
 
 
 def _noised_key_tiles(seq: int, block_q: int, block_k: int) -> int:
@@ -826,7 +854,9 @@ def _count_block_visits(plan: dict, batch_heads: int, prefix: str = ""):
     ``window_skipped_behind``): the share of its grid it never loads is then
     read apart from the causal calls'; so does a call under a block mask
     (``blockdiff_interior``, ``blockdiff_diagonal``, ``blockdiff_skipped``,
-    and ``blockdiff_noised_keys`` from :func:`blockdiff_attention`)."""
+    and ``blockdiff_noised_keys`` from :func:`blockdiff_attention`) and one
+    of unequal widths (``latent_interior``, ``latent_diagonal``,
+    ``latent_skipped``)."""
     from horovod_tpu.metrics.registry import get_registry
     for kind, visits in plan.items():
         if prefix and not kind.startswith(prefix):
@@ -846,6 +876,17 @@ def _count_call(group: int):
         "hvd_flash_calls_total",
         "flash-attention calls traced, by query heads to a key head",
         kv_group=str(group)).inc()
+
+
+def _count_latent_call(qk_dim: int, v_dim: int):
+    """Beside :func:`_count_call`: one count a traced call whose values are
+    not as wide as its queries and keys, by the two widths."""
+    from horovod_tpu.metrics.registry import get_registry
+    get_registry().counter(
+        "hvd_latent_calls_total",
+        "flash-attention calls traced with q/k of one width and v of "
+        "another (latent attention)",
+        qk_dim=str(qk_dim), v_dim=str(v_dim)).inc()
 
 
 def _static_offset(offset) -> Optional[int]:
@@ -870,7 +911,11 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
     q: [B, Tq, H, D]; k/v: [B, Tk, Hkv, D(v)], ``H`` a multiple of ``Hkv``
     (grouped-query attention: query head ``j`` on key head ``j // (H /
-    Hkv)``). k and v are handed to the kernels at their own heads: the index
+    Hkv)``). ``Dv`` need not be ``D`` (latent attention: 192 and 128): the
+    output and ``dv`` are ``Dv`` wide, the default scale is ``D ** -0.5``,
+    and the kernels run under ``_fwd_latent_kernel``, ... with their blocks
+    counted under ``latent_*`` kinds and ``hvd_latent_calls_total``. k and v
+    are handed to the kernels at their own heads: the index
     maps send a group's query heads to one key head, which a kernel then
     fetches once a group, and nothing repeats them in HBM; dk and dv are
     written a query head and summed over each group in float32
@@ -913,6 +958,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     block_mask = _checked_block_mask(
         block_mask, causal, window, q_offset is None and k_offset is None)
     _count_call(_kv_group(q, k, v))
+    latent = d != v.shape[-1] and window is None and block_mask is None
+    if latent:
+        _count_latent_call(d, v.shape[-1])
     block_q = _pick_block(tq, block_q)
     block_k = _pick_block(k.shape[1], block_k)
     if block_mask is not None and (block_q % block_mask[0]
@@ -930,7 +978,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             block_plan(tq, k.shape[1], block_q, block_k, causal, *offsets,
                        window=window, block_mask=block_mask), b * h,
             BLOCKDIFF_KIND if block_mask is not None else
-            WINDOW_KIND if window is not None else "")
+            WINDOW_KIND if window is not None else
+            LATENT_KIND if latent else "")
     o, lse = _flash(q, k, v, q_off, k_off, causal, scale, block_q, block_k,
                     interpret, window, block_mask)
     return (o, lse) if return_lse else o

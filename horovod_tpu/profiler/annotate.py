@@ -17,10 +17,20 @@ Two distinct mechanisms, matching where the work actually happens:
 - :func:`shortconv_scope` — the parts of a gated short-convolution operator
   (:data:`SHORTCONV_SCOPES`: in-projection, the two gates and the taps of
   ``ops/short_conv.py``, out-projection), written by ``models/lfm2.py``.
+- :func:`mla_scope` — the parts of a multi-head latent attention operator
+  around its attention call (:data:`MLA_SCOPES`: the query's and the
+  key-value's low-rank projections with their inner norms, rotary with
+  whatever builds the kernels' key, the out-projection), written by
+  ``models/joyai_flash.py``.
+- :func:`mtp_scope` — the parts of a multi-token-prediction module
+  (:data:`MTP_SCOPES`: the merge of the next token's embedding with the
+  stack's output, the module's block, its head and loss), written by
+  ``models/joyai_flash.py``.
 - :func:`attn_scope` — the kind of an attention call (:data:`ATTN_SCOPES`:
   full causal or window, written by ``models/smallthinker.py``, whose
   layers mix the two, and ``models/lfm2.py``'s attention layers; the two streams of a block-diffusion pass, by
-  ``models/sdar.py``).
+  ``models/sdar.py``; a latent call, q/k wider than v, by
+  ``models/joyai_flash.py``).
 - :func:`diffusion_scope` — the two ends of a block-diffusion objective
   (:data:`DIFFUSION_SCOPES`: the noising of a batch and the weighted loss
   over its masked positions), written by ``models/sdar.py``.
@@ -78,7 +88,23 @@ SHORTCONV_SCOPES = ("shortconv_in_proj", "shortconv_mix",
 # noised and the clean stream of a block-diffusion pass
 # (``models/sdar.py``: per-stream rotary, the repeat, the kernels under the
 # block mask, a noised block on itself, the merge).
-ATTN_SCOPES = ("attn_full", "attn_window", "attn_blockdiff")
+# ``attn_latent`` is the one call of a latent attention operator
+# (``models/joyai_flash.py``): q and k of 192, v of 128, nothing else under
+# it (rotary and the key's build are ``mla_rope``).
+ATTN_SCOPES = ("attn_full", "attn_window", "attn_blockdiff", "attn_latent")
+# The parts of one multi-head latent attention operator around that call
+# (``models/joyai_flash.py``): ``mla_q_proj`` is the query's down-projection,
+# its norm and its up-projection; ``mla_kv_proj`` the same for the latent of
+# keys and values; ``mla_rope`` the rotation of the queries' rotary part and
+# of the one rotary key, the splits, and whatever builds the key the kernels
+# take; ``mla_out_proj`` the out-projection.
+MLA_SCOPES = ("mla_q_proj", "mla_kv_proj", "mla_rope", "mla_out_proj")
+# The parts of one multi-token-prediction module (``models/joyai_flash.py``):
+# ``mtp_merge`` is the two norms, the embedding's second gather and the
+# projection of the two halves; ``mtp_block`` the module's own layer (whose
+# operations also carry their ``mla_*`` / ``moe_*`` scopes); ``mtp_head`` the
+# module's norm, its logits over the shared head and its cross-entropy.
+MTP_SCOPES = ("mtp_merge", "mtp_block", "mtp_head")
 # The two ends of a block-diffusion objective (``models/sdar.py``).
 DIFFUSION_SCOPES = ("diffusion_noise", "diffusion_loss")
 # Host spans the step wrapper (``metrics.timed_step``) writes.
@@ -124,6 +150,24 @@ def attn_scope(name: str):
     if name not in ATTN_SCOPES:
         raise ValueError(f"unknown attention scope {name!r}; one of "
                          f"{ATTN_SCOPES}")
+    return collective_scope(name)
+
+
+def mla_scope(name: str):
+    """Name the enclosed traced ops as one part of a latent attention
+    operator."""
+    if name not in MLA_SCOPES:
+        raise ValueError(f"unknown latent-attention scope {name!r}; one of "
+                         f"{MLA_SCOPES}")
+    return collective_scope(name)
+
+
+def mtp_scope(name: str):
+    """Name the enclosed traced ops as one part of a multi-token-prediction
+    module."""
+    if name not in MTP_SCOPES:
+        raise ValueError(f"unknown multi-token-prediction scope {name!r}; "
+                         f"one of {MTP_SCOPES}")
     return collective_scope(name)
 
 

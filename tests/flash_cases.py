@@ -106,3 +106,26 @@ def block_edge(t, group, edge):
     b = np.arange(t) // group
     return jnp.asarray(b[None, :] <= b[:, None] if edge == "le"
                        else b[None, :] < b[:, None])
+
+
+def kernel_functions(fn, *args) -> list:
+    """The kernel function of every ``pallas_call`` that tracing ``fn(*args)``
+    holds, by name, however deep in the jaxpr (``custom_vjp``, ``jit``): what
+    the compiled text of a chip names a call by (in interpret mode there is
+    no such text)."""
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found.append(eqn.params["jaxpr"].debug_info.func_src_info
+                             .split(" ")[0])
+                continue
+            for param in eqn.params.values():
+                for sub in (param if isinstance(param, (list, tuple))
+                            else [param]):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        walk(sub)
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found

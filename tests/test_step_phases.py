@@ -141,12 +141,17 @@ def test_an_unknown_phase_is_an_error():
     (annotate.ssm_scope, annotate.SSM_SCOPES, "ssm_", "ssm_scan",
      "ssm_everything"),
     (annotate.shortconv_scope, annotate.SHORTCONV_SCOPES, "shortconv_",
-     "shortconv_mix", "shortconv_everything")],
-    ids=["moe", "ssm", "shortconv"])
+     "shortconv_mix", "shortconv_everything"),
+    (annotate.mla_scope, annotate.MLA_SCOPES, "mla_", "mla_rope",
+     "mla_everything"),
+    (annotate.mtp_scope, annotate.MTP_SCOPES, "mtp_", "mtp_merge",
+     "mtp_everything")],
+    ids=["moe", "ssm", "shortconv", "mla", "mtp"])
 def test_layer_scopes_take_their_names_and_refuse_others(
         scope, names, prefix, known, unknown):
-    """The expert layer's, the state-space mixer's and the short-convolution
-    operator's parts: a name of the
+    """The expert layer's, the state-space mixer's, the short-convolution
+    operator's, the latent attention operator's and the
+    multi-token-prediction module's parts: a name of the
     list is written into the traced operations' ``op_name``; any other name
     is an error. Neither prefix is a phase's or a collective's."""
     assert known in names

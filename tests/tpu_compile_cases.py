@@ -83,19 +83,24 @@ KERNELS = {
 
 
 def _kernel_text(topo, kernel, seq, head_dim, causal, window=None,
-                 block_mask=None, heads=HEADS, kv_heads=None):
+                 block_mask=None, heads=HEADS, kv_heads=None, v_dim=None):
+    """``v_dim``: the values' (and the output's) width where it is not the
+    queries' and keys' ``head_dim`` (latent attention)."""
     one_chip = SingleDeviceSharding(topo.devices[0])
 
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    x = arg((1, seq, heads, head_dim), jnp.bfloat16)
-    kv = arg((1, seq, kv_heads or heads, head_dim), jnp.bfloat16)
+    v_dim = v_dim or head_dim
+    q = arg((1, seq, heads, head_dim), jnp.bfloat16)
+    o = arg((1, seq, heads, v_dim), jnp.bfloat16)
+    k = arg((1, seq, kv_heads or heads, head_dim), jnp.bfloat16)
+    v = arg((1, seq, kv_heads or heads, v_dim), jnp.bfloat16)
     lse = arg((heads, 1, seq), jnp.float32)
     off = arg((1,), jnp.float32)
     fn = functools.partial(KERNELS[kernel], causal, head_dim ** -0.5,
                            window=window, block_mask=block_mask)
-    return jax.jit(fn).lower(x, kv, kv, x, lse, x, off).compile().as_text()
+    return jax.jit(fn).lower(q, k, v, o, lse, o, off).compile().as_text()
 
 
 def _benchmark_on_path():
@@ -156,6 +161,12 @@ def _compiled_cell(topo, workload):
     _benchmark_on_path()
     from harness import spec as spec_lib
     from horovod_tpu.parallel import dp, mesh as mesh_lib
+    # A helper traced for an earlier cell of this process is cached with
+    # that trace's frames, and a kernel's bytecode then carries the earlier
+    # cell's kernel names beside its own (``HloIndex.kernel_name`` picks the
+    # rarest; two bodies alike tie): which cells share a worker is the
+    # scheduler's choice, so every cell starts from empty caches.
+    jax.clear_caches()
     spec = spec_lib.load()
     cell = spec_lib.workload(spec, workload)
     traffic = spec_lib.traffic(cell["traffic"])
