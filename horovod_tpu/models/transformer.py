@@ -23,9 +23,10 @@ class FlashSelfAttention(nn.Module):
     ``nn.MultiHeadDotProductAttention``. At/above the crossover
     (HOROVOD_FLASH_MIN_SEQ, default 1024) the Pallas flash kernel runs and
     the [T, T] score matrix never touches HBM; below it the router uses
-    plain XLA dot attention (an earlier chip run, no longer on file, had
-    flash 16% slower at seq 128; not measured on today's code). Bidirectional (BERT) by default; set
-    ``causal`` for decoder use."""
+    plain XLA dot attention, under a causal mask in row blocks that stop at
+    the diagonal (measured at 512: ``PERF.md`` §6, PR 49; where the two
+    paths cross has not been measured). Bidirectional (BERT) by default;
+    set ``causal`` for decoder use."""
 
     heads: int
     dtype: Any = jnp.bfloat16

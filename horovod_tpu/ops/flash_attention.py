@@ -98,12 +98,12 @@ from jax.experimental.pallas import tpu as pltpu
 from horovod_tpu.ops.kernel_call import (NEG_INF, NN, NT, TN, dot,
                                          on_this_platform, scalar_spec)
 
-# Short-sequence crossover for the auto-router (:func:`attention`). An
-# earlier chip run, no longer on file, had plain XLA dot attention ahead of
-# the Pallas kernel at seq 128 on BERT-Base (the score tiles are too small
-# to fill the grid) and flash ahead from ~2k through 8k; not measured on
-# today's code. Sequences shorter than this route to XLA; override with
-# HOROVOD_FLASH_MIN_SEQ.
+# Crossover of the auto-router (:func:`attention`): fewer keys than this go
+# to :func:`xla_attention`; override with HOROVOD_FLASH_MIN_SEQ. Where the
+# two paths cross is NOT measured on today's code: no cell runs one length
+# on both. Measured on each side: at 512 the XLA path in row blocks reads
+# 55.1% `mfu_device` (47.3 as one dense product), the kernels at 2048 read
+# 53.1 on the same model and tokens a step (``PERF.md`` §6, PR 49).
 DEFAULT_FLASH_MIN_SEQ = 1024
 
 
@@ -1013,50 +1013,160 @@ def _checked_window(window, causal: bool) -> Optional[int]:
     return int(window)
 
 
+# Rows of queries a causal :func:`xla_attention` call takes at a time, each
+# against the keys its mask lets it see. Chosen on the chip at `gpt2s-t512`'s
+# shape ([32, 512, 12, 64]) from 64 / 128 / 256: a step of 121.9 / 116.1 /
+# 118.1 ms against 135.2 as one block (``PERF.md`` §6, PR 49). A multiple of
+# 128 keeps the slices of k and v on lane boundaries where the compiler
+# holds the positions in lanes; at 64 the copies around them cost 4.6 ms a
+# step more than at 128.
+XLA_CAUSAL_BLOCK = 128
+
+
+def _xla_blocks(tq: int, tk: int, block: int, causal: bool,
+                window: Optional[int], block_mask: Optional[tuple]):
+    """``(r0, r1, k0, k1)`` of every row block of :func:`xla_attention`:
+    rows ``[r0, r1)`` against keys ``[k0, k1)``, the least range that holds
+    every key the mask shows one of those rows. Static arithmetic on the
+    mask the call states; without a causal mask one block, all of it."""
+    if not causal:
+        return ((0, tq, 0, tk),)
+    blocks = []
+    for r0 in range(0, tq, block):
+        r1 = min(r0 + block, tq)
+        k0, k1 = 0, r1
+        if window is not None:
+            k0 = max(0, r0 - window + 1)
+        if block_mask is not None:
+            group, edge = block_mask
+            k1 = min(tk, -(-r1 // group) * group) if edge == "le" \
+                else (r1 - 1) // group * group
+        blocks.append((r0, r1, k0, k1))
+    return tuple(blocks)
+
+
+def xla_score_plan(tq: int, tk: int, block: int, causal: bool,
+                   window: Optional[int] = None,
+                   block_mask: Optional[tuple] = None) -> dict:
+    """Scores of one (batch, head) of an :func:`xla_attention` call in row
+    blocks of ``block``: ``computed``, the entries of the products it makes,
+    and ``visible``, those its mask keeps. Pure arithmetic on static values,
+    like :func:`block_plan`; ``computed / visible`` is what the path pays
+    for the mask's dead scores (2.0 for one block of a long causal call)."""
+    computed = sum((r1 - r0) * (k1 - k0) for r0, r1, k0, k1 in
+                   _xla_blocks(tq, tk, block, causal, window, block_mask))
+    if not causal:
+        return {"computed": computed, "visible": tq * tk}
+    if block_mask is not None:
+        group, edge = block_mask
+        seen = [(i // group + (edge == "le")) * group for i in range(tq)]
+    else:
+        seen = [i + 1 if window is None else min(i + 1, window)
+                for i in range(tq)]
+    return {"computed": computed, "visible": sum(min(n, tk) for n in seen)}
+
+
+def _count_xla_scores(plan: dict, batch_heads: int):
+    """Monitoring, at trace time like :func:`_count_block_visits`: the
+    scores of the :func:`xla_attention` call just traced, by kind."""
+    from horovod_tpu.metrics.registry import get_registry
+    for kind, scores in plan.items():
+        get_registry().counter(
+            "hvd_xla_attention_scores_total",
+            "scores of the XLA attention calls traced: computed by the "
+            "products, visible under the mask",
+            kind=kind).inc(scores * batch_heads)
+
+
 def xla_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                   causal: bool = False,
                   sm_scale: Optional[float] = None,
                   window: Optional[int] = None,
                   block_mask: Optional[Tuple[int, str]] = None) -> jax.Array:
-    """Plain XLA dot attention — the short-sequence winner.
+    """Plain XLA dot attention, the path :func:`attention` takes below the
+    router's threshold.
 
     Same [B, T, H, D] layout and numerics contract as
     :func:`flash_attention` (matmuls in the input dtype, fp32 softmax), so
-    the router can swap between them freely. At short T the [T, T] score
-    matrix is small enough that XLA's fused softmax beats the Pallas
-    kernel's grid setup cost. ``window`` and ``block_mask`` as
-    :func:`flash_attention`'s.
+    the router can swap between them freely. ``window`` and ``block_mask``
+    as :func:`flash_attention`'s.
+
+    Under a causal mask the queries are taken in row blocks of
+    ``XLA_CAUSAL_BLOCK``, each against the keys it can see and no further
+    (:func:`_xla_blocks`), and the outputs are joined along ``T``: the
+    scores above the diagonal, half of ``[T, T]``, are neither computed nor
+    kept for the backward pass. Inside a block's range the mask is applied
+    by positions, so the same terms enter every row's softmax as in one
+    dense product (the masked ones were ``exp(-inf) = 0``). A call of at
+    most one block, and every call without a causal mask, is that one dense
+    product. :func:`xla_score_plan` counts what is computed, and
+    ``hvd_xla_attention_scores_total{kind=computed|visible}`` records it at
+    trace time.
+
+    Measured on the chip (``PERF.md`` §6, PR 49; `gpt2s-t512`, 32 x 512
+    tokens through twelve layers of 12 heads of 64): attention 70.5 ms of a
+    135.2 ms step as one dense product, 49.3 of 116.1 in blocks of 128
+    (52.7 of 118.1 at 256, 51.4 of 121.9 at 64). Where this path and the
+    kernels cross has not been measured (``DEFAULT_FLASH_MIN_SEQ``).
     """
-    d = q.shape[-1]
+    b, tq, h, d = q.shape
+    tk = k.shape[1]
     scale = sm_scale if sm_scale is not None else d ** -0.5
     window = _checked_window(window, causal)
     block_mask = _checked_block_mask(block_mask, causal, window)
+    if causal and tq != tk:
+        raise ValueError(
+            "xla_attention supports causal only for self-attention "
+            f"(Tq == Tk), got {tq} vs {tk}; use flash_attention with "
+            "q_offset/k_offset for sharded causal blocks")
+    _count_xla_scores(xla_score_plan(tq, tk, XLA_CAUSAL_BLOCK, causal,
+                                     window, block_mask), b * h)
+    blocks = _xla_blocks(tq, tk, XLA_CAUSAL_BLOCK, causal, window,
+                         block_mask)
+    if len(blocks) == 1:  # the one dense product, in the caller's own trace
+        return _xla_block(q, k, v, scale, causal, window, block_mask, 0, 0)
+    return _xla_rows(q, k, v, scale, window, block_mask, blocks)
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6))
+def _xla_rows(q, k, v, scale: float, window, block_mask, blocks: tuple):
+    """A causal call's row blocks, joined along ``T``. Under ``jax.jit`` so
+    that the layers of a model trace, differentiate and lower the blocks
+    once a signature and not once a layer (four blocks a layer doubled
+    `gpt2s-t512`'s trace and lowering without it, ``PERF.md`` §6, PR 49)."""
+    return jnp.concatenate(
+        [_xla_block(q[:, r0:r1], k[:, k0:k1], v[:, k0:k1], scale, True,
+                    window, block_mask, r0, k0)
+         for r0, r1, k0, k1 in blocks], axis=1)
+
+
+def _xla_block(q, k, v, scale: float, causal: bool, window, block_mask,
+               r0: int, k0: int) -> jax.Array:
+    """The rows ``q`` (positions from ``r0``) against the keys ``k``, ``v``
+    (positions from ``k0``): scores, softmax and ``p v`` of
+    :func:`xla_attention`, the mask by global positions."""
+    if not k.shape[1]:  # rows of block 0 under "lt" see no key: output 0
+        return jnp.zeros(q.shape[:-1] + v.shape[-1:], q.dtype)
     # Matmuls stay in the input dtype (bf16 rides the fast MXU path, same
     # as the flash kernel) with fp32 accumulation; only the softmax runs
-    # in fp32. Upcasting the operands would cost ~4x MXU throughput and 2x
-    # HBM traffic on the [B, H, T, T] scores — the short-seq regime this
-    # path exists to win.
+    # in fp32.
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                    preferred_element_type=jnp.float32) * scale
     if causal:
-        tq, tk = q.shape[1], k.shape[1]
-        if tq != tk:
-            raise ValueError(
-                "xla_attention supports causal only for self-attention "
-                f"(Tq == Tk), got {tq} vs {tk}; use flash_attention with "
-                "q_offset/k_offset for sharded causal blocks")
+        q_pos = r0 + jnp.arange(q.shape[1])[:, None]
+        k_pos = k0 + jnp.arange(k.shape[1])[None, :]
         if block_mask is not None:  # the edge, by blocks of G positions
             group, edge = block_mask
-            blocks = jnp.arange(tq) // group
-            mask = blocks[None, :] <= blocks[:, None] if edge == "le" \
-                else blocks[None, :] < blocks[:, None]
+            mask = k_pos // group <= q_pos // group if edge == "le" \
+                else k_pos // group < q_pos // group
         else:
-            mask = jnp.tril(jnp.ones((tq, tk), bool))
+            mask = k_pos <= q_pos
         if window is not None:  # the window's far edge: a second diagonal
-            mask &= ~jnp.tril(jnp.ones((tq, tk), bool), -window)
+            mask &= q_pos - k_pos < window
         s = jnp.where(mask[None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
-    if block_mask is not None:  # block 0 under "lt" sees no key: output 0
+    if block_mask is not None and block_mask[1] == "lt" \
+            and r0 < block_mask[0]:  # block 0 sees no key: output 0
         p = jnp.where(jnp.any(mask, axis=-1)[None, None, :, None], p, 0.0)
     return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v,
                       preferred_element_type=jnp.float32).astype(q.dtype)
@@ -1099,11 +1209,9 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array,
     """Length-routed attention: XLA dot attention below the crossover,
     the Pallas flash kernel at/above it.
 
-    A kernel built for long context has nothing to amortize on tiny score
-    tiles (an earlier chip run, no longer on file, had ``use_flash=True``
-    costing 16% at seq 128; not measured on today's code). This router
-    keeps the long-context path without making short-sequence models pay
-    for it. Routing keys on the KV length
+    The threshold is ``DEFAULT_FLASH_MIN_SEQ`` (what has been measured on
+    each side of it is said there; where the two paths cross has not been).
+    Routing keys on the KV length
     (the side that grows the score matrix). Semantics-bearing flash-only
     features (``return_lse``, ``q_offset``/``k_offset``) force the flash
     path regardless of length — the XLA path cannot honor them, and

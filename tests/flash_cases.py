@@ -108,24 +108,23 @@ def block_edge(t, group, edge):
                        else b[None, :] < b[:, None])
 
 
+def equations(jaxpr):
+    """Every equation of a jaxpr, those of its nested jaxprs too, however
+    deep (``custom_vjp``, ``jit``, a kernel's body)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for param in eqn.params.values():
+            for sub in (param if isinstance(param, (list, tuple))
+                        else [param]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from equations(sub)
+
+
 def kernel_functions(fn, *args) -> list:
     """The kernel function of every ``pallas_call`` that tracing ``fn(*args)``
-    holds, by name, however deep in the jaxpr (``custom_vjp``, ``jit``): what
-    the compiled text of a chip names a call by (in interpret mode there is
-    no such text)."""
-    found = []
-
-    def walk(jaxpr):
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "pallas_call":
-                found.append(eqn.params["jaxpr"].debug_info.func_src_info
-                             .split(" ")[0])
-                continue
-            for param in eqn.params.values():
-                for sub in (param if isinstance(param, (list, tuple))
-                            else [param]):
-                    sub = getattr(sub, "jaxpr", sub)
-                    if hasattr(sub, "eqns"):
-                        walk(sub)
-    walk(jax.make_jaxpr(fn)(*args).jaxpr)
-    return found
+    holds, by name: what the compiled text of a chip names a call by (in
+    interpret mode there is no such text)."""
+    return [eqn.params["jaxpr"].debug_info.func_src_info.split(" ")[0]
+            for eqn in equations(jax.make_jaxpr(fn)(*args).jaxpr)
+            if eqn.primitive.name == "pallas_call"]

@@ -92,8 +92,7 @@ def test_xla_attention_matches_dense_reference():
 
 def test_bert_short_seq_uses_router(monkeypatch):
     """BertBase(use_flash=True) at seq 128 must not invoke the Pallas
-    kernel (an earlier chip run, no longer on file, had flash 16% slower
-    there)."""
+    kernel: 128 is under the router's threshold."""
     from horovod_tpu.models.transformer import BertEncoder
     from horovod_tpu.ops import flash_attention as fa
 
