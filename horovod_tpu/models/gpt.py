@@ -17,6 +17,7 @@ import flax.linen as nn
 import jax.numpy as jnp
 
 from horovod_tpu.models.transformer import EncoderBlock
+from horovod_tpu.profiler.annotate import head_scope
 
 
 class GptDecoder(nn.Module):
@@ -42,8 +43,8 @@ class GptDecoder(nn.Module):
                              self.dtype, use_flash=self.use_flash,
                              causal=True)(x, deterministic=deterministic)
         x = nn.LayerNorm(dtype=self.dtype)(x)
-        logits = embed.attend(x)
-        return logits.astype(jnp.float32)
+        with head_scope("head_logits"):
+            return embed.attend(x).astype(jnp.float32)
 
 
 def GptSmall(**kw) -> GptDecoder:

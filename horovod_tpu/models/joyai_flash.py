@@ -108,8 +108,8 @@ from horovod_tpu.models.olmoe import INIT, rotary
 from horovod_tpu.models.smallthinker import REMAT_POLICIES
 from horovod_tpu.ops.flash_attention import attention
 from horovod_tpu.parallel import ep
-from horovod_tpu.profiler.annotate import (attn_scope, mla_scope, moe_scope,
-                                           mtp_scope)
+from horovod_tpu.profiler.annotate import (attn_scope, head_scope, mla_scope,
+                                           moe_scope, mtp_scope)
 
 
 def _pairs_as_halves(features: int, width: int, rope: int, dtype, name: str
@@ -336,7 +336,8 @@ class JoyaiFlashDecoder(nn.Module):
             x = block(attn, dense if i < self.first_k_dense else sparse,
                       self.eps, self.dtype, name=f"JoyaiBlock_{i}")(x)
         g = nn.RMSNorm(epsilon=self.eps, dtype=self.dtype, name="norm")(x)
-        logits = head(g)
+        with head_scope("head_logits"):
+            logits = head(g)
         if not self.mtp_layers:
             return logits, None
         with mtp_scope("mtp_merge"):
@@ -387,7 +388,8 @@ def joyai_flash_loss(model: JoyaiFlashDecoder, params, router_state, tokens):
     (logits, mtp_logits), new_state = model.apply(
         {"params": params, ROUTER_STATE: router_state}, tokens,
         mutable=[ROUTER_STATE])
-    loss = next_token = _masked_mean_ce(logits, tokens, 1)
+    with head_scope("head_loss"):
+        loss = next_token = _masked_mean_ce(logits, tokens, 1)
     aux = {"next_token_loss": next_token}
     if mtp_logits is not None:
         with mtp_scope("mtp_head"):

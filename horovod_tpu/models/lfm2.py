@@ -90,7 +90,8 @@ from horovod_tpu.models.smallthinker import REMAT_POLICIES
 from horovod_tpu.ops.flash_attention import attention
 from horovod_tpu.ops.short_conv import gated_short_conv
 from horovod_tpu.parallel import ep
-from horovod_tpu.profiler.annotate import attn_scope, shortconv_scope
+from horovod_tpu.profiler.annotate import (attn_part_scope, attn_scope,
+                                           head_scope, shortconv_scope)
 
 OPERATORS = ("conv", "full_attention")
 ROUTER_STATE = "router_state"
@@ -143,18 +144,22 @@ class Lfm2Attention(nn.Module):
         def heads_of(name, count):
             return linear(count * self.head_dim, self.dtype, name)(x) \
                 .reshape(b, t, count, self.head_dim)
-        q, k, v = (heads_of("q_proj", self.heads),
-                   heads_of("k_proj", self.kv_heads),
-                   heads_of("v_proj", self.kv_heads))
+        with attn_part_scope("attn_qkv_proj"):
+            q, k, v = (heads_of("q_proj", self.heads),
+                       heads_of("k_proj", self.kv_heads),
+                       heads_of("v_proj", self.kv_heads))
         # over each head's own head_dim values, one weight vector for all
         norm = functools.partial(nn.RMSNorm, epsilon=self.eps,
                                  dtype=self.dtype)
-        q, k = norm(name="q_layernorm")(q), norm(name="k_layernorm")(k)
+        with attn_part_scope("attn_qk_norm"):
+            q, k = norm(name="q_layernorm")(q), norm(name="k_layernorm")(k)
         with attn_scope("attn_full"):
-            o = attention(rotary(q, self.rope_theta),
-                          rotary(k, self.rope_theta), v, causal=True)
-        return linear(hidden, self.dtype, "out_proj")(
-            o.reshape(b, t, self.heads * self.head_dim))
+            with attn_part_scope("attn_rope"):
+                q, k = rotary(q, self.rope_theta), rotary(k, self.rope_theta)
+            o = attention(q, k, v, causal=True)
+        with attn_part_scope("attn_out_proj"):
+            return linear(hidden, self.dtype, "out_proj")(
+                o.reshape(b, t, self.heads * self.head_dim))
 
 
 class Lfm2Mlp(nn.Module):
@@ -317,9 +322,10 @@ class Lfm2MoeDecoder(nn.Module):
         x = nn.RMSNorm(epsilon=self.eps, dtype=self.dtype,
                        name="embedding_norm")(x)
         # bf16 inputs, float32 out of the accumulators: no bf16 logits
-        return jnp.einsum("btd,vd->btv", x,
-                          embed.embedding.astype(self.dtype),
-                          preferred_element_type=jnp.float32)
+        with head_scope("head_logits"):
+            return jnp.einsum("btd,vd->btv", x,
+                              embed.embedding.astype(self.dtype),
+                              preferred_element_type=jnp.float32)
 
 
 def Lfm2_8B_A1B(**kw) -> Lfm2MoeDecoder:
@@ -347,8 +353,9 @@ def lfm2_loss(model: Lfm2MoeDecoder, params, router_state, tokens, labels):
     logits, new_state = model.apply(
         {"params": params, ROUTER_STATE: router_state}, tokens,
         mutable=[ROUTER_STATE])
-    loss = optax.softmax_cross_entropy_with_integer_labels(
-        logits, labels).mean()
+    with head_scope("head_loss"):
+        loss = optax.softmax_cross_entropy_with_integer_labels(
+            logits, labels).mean()
     new_state = new_state.get(ROUTER_STATE, {})  # none without a sparse layer
     # Lfm2Block_<i>, in layer order (a tree's keys come sorted as text)
     blocks = sorted(new_state, key=lambda name: int(name.rsplit("_", 1)[1]))

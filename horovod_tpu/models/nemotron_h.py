@@ -82,7 +82,8 @@ from horovod_tpu.ops.flash_attention import attention
 from horovod_tpu.ops.ssd import ssd_scan
 from horovod_tpu.ops.ssm_ends import causal_conv_silu, gated_group_norm
 from horovod_tpu.parallel import ep
-from horovod_tpu.profiler.annotate import moe_scope, ssm_scope
+from horovod_tpu.profiler.annotate import (attn_part_scope, head_scope,
+                                           moe_scope, ssm_scope)
 
 INIT = nn.initializers.normal(stddev=0.02)  # transformers' initializer_range
 KINDS = "ME*"
@@ -212,11 +213,14 @@ class NemotronHAttention(nn.Module):
         def heads_of(name, count):
             return _dense(count * self.head_dim, self.dtype, name)(x) \
                 .reshape(b, t, count, self.head_dim)
-        o = attention(heads_of("q_proj", self.heads),
-                      heads_of("k_proj", self.kv_heads),
-                      heads_of("v_proj", self.kv_heads), causal=True)
-        return _dense(hidden, self.dtype, "o_proj")(
-            o.reshape(b, t, self.heads * self.head_dim))
+        with attn_part_scope("attn_qkv_proj"):
+            q, k, v = (heads_of("q_proj", self.heads),
+                       heads_of("k_proj", self.kv_heads),
+                       heads_of("v_proj", self.kv_heads))
+        o = attention(q, k, v, causal=True)
+        with attn_part_scope("attn_out_proj"):
+            return _dense(hidden, self.dtype, "o_proj")(
+                o.reshape(b, t, self.heads * self.head_dim))
 
 
 class NemotronHTopkRouter(nn.Module):
@@ -374,11 +378,12 @@ class NemotronHDecoder(nn.Module):
                       name=f"NemotronHBlock_{i}")(x)
         x = nn.RMSNorm(epsilon=self.eps, dtype=self.dtype, name="norm_f")(x)
         # bf16 inputs, float32 out of the accumulators: no bf16 logits
-        return nn.Dense(
-            self.vocab, use_bias=False, dtype=self.dtype, kernel_init=INIT,
-            dot_general=functools.partial(
-                jax.lax.dot_general, preferred_element_type=jnp.float32),
-            name="LmHead")(x)
+        with head_scope("head_logits"):
+            return nn.Dense(
+                self.vocab, use_bias=False, dtype=self.dtype,
+                kernel_init=INIT, dot_general=functools.partial(
+                    jax.lax.dot_general, preferred_element_type=jnp.float32),
+                name="LmHead")(x)
 
 
 NEMOTRON_3_NANO_PATTERN = \
@@ -409,8 +414,9 @@ def nemotron_h_loss(model: NemotronHDecoder, params, router_state, tokens,
     logits, new_state = model.apply(
         {"params": params, ROUTER_STATE: router_state}, tokens,
         mutable=[ROUTER_STATE])
-    loss = optax.softmax_cross_entropy_with_integer_labels(
-        logits, labels).mean()
+    with head_scope("head_loss"):
+        loss = optax.softmax_cross_entropy_with_integer_labels(
+            logits, labels).mean()
     new_state = new_state.get(ROUTER_STATE, {})  # none without an E layer
     # NemotronHBlock_<i>, in layer order (a tree's keys come sorted as text)
     blocks = sorted(new_state, key=lambda name: int(name.rsplit("_", 1)[1]))

@@ -35,6 +35,7 @@ import optax
 
 from horovod_tpu.ops.flash_attention import attention
 from horovod_tpu.parallel import ep
+from horovod_tpu.profiler.annotate import attn_part_scope, head_scope
 
 INIT = nn.initializers.normal(stddev=0.02)  # transformers' initializer_range
 
@@ -68,14 +69,19 @@ class OlmoeAttention(nn.Module):
         norm = functools.partial(nn.RMSNorm, epsilon=self.eps,
                                  dtype=self.dtype)
 
-        def split(y):
+        def heads_of(name, normed=True):
+            with attn_part_scope("attn_qkv_proj"):
+                y = proj(name=f"{name}_proj")(x)
+            if normed:
+                with attn_part_scope("attn_qk_norm"):
+                    y = norm(name=f"{name}_norm")(y)
             return y.reshape(b, t, self.heads, d // self.heads)
-        q = split(norm(name="q_norm")(proj(name="q_proj")(x)))
-        k = split(norm(name="k_norm")(proj(name="k_proj")(x)))
-        v = split(proj(name="v_proj")(x))
-        o = attention(rotary(q, self.rope_theta), rotary(k, self.rope_theta),
-                      v, causal=True)
-        return proj(name="o_proj")(o.reshape(b, t, d))
+        q, k, v = heads_of("q"), heads_of("k"), heads_of("v", normed=False)
+        with attn_part_scope("attn_rope"):
+            q, k = rotary(q, self.rope_theta), rotary(k, self.rope_theta)
+        o = attention(q, k, v, causal=True)
+        with attn_part_scope("attn_out_proj"):
+            return proj(name="o_proj")(o.reshape(b, t, d))
 
 
 class OlmoeSparseMoe(nn.Module):
@@ -149,11 +155,12 @@ class OlmoeDecoder(nn.Module):
             stats.append(layer_stats)
         x = nn.RMSNorm(epsilon=self.eps, dtype=self.dtype, name="norm")(x)
         # bf16 inputs, float32 out of the accumulators: no bf16 logits
-        logits = nn.Dense(
-            self.vocab, use_bias=False, dtype=self.dtype, kernel_init=INIT,
-            dot_general=functools.partial(
-                jax.lax.dot_general, preferred_element_type=jnp.float32),
-            name="LmHead")(x)
+        with head_scope("head_logits"):
+            logits = nn.Dense(
+                self.vocab, use_bias=False, dtype=self.dtype,
+                kernel_init=INIT, dot_general=functools.partial(
+                    jax.lax.dot_general, preferred_element_type=jnp.float32),
+                name="LmHead")(x)
         return logits, jax.tree_util.tree_map(
             lambda *leaves: jnp.stack(leaves), *stats)
 
@@ -172,12 +179,13 @@ def olmoe_loss(logits: jax.Array, labels: jax.Array, stats: ep.MoeStats,
     arXiv:2409.02060). Returns (loss, aux) as ``dp.make_train_step`` takes
     them: ``expert_tokens`` (int32 [layers, E], summed over chips) and the
     two auxiliary losses (averaged)."""
-    ce = optax.softmax_cross_entropy_with_integer_labels(
-        logits, labels).mean()
-    balance = ep.load_balancing_loss(stats.expert_tokens,
-                                     stats.router_prob_mean,
-                                     experts_per_token)
-    z = stats.router_z_loss.mean()
-    loss = ce + load_balancing_coef * balance + router_z_coef * z
+    with head_scope("head_loss"):
+        ce = optax.softmax_cross_entropy_with_integer_labels(
+            logits, labels).mean()
+        balance = ep.load_balancing_loss(stats.expert_tokens,
+                                         stats.router_prob_mean,
+                                         experts_per_token)
+        z = stats.router_z_loss.mean()
+        loss = ce + load_balancing_coef * balance + router_z_coef * z
     return loss, {"expert_tokens": stats.expert_tokens,
                   "load_balancing_loss": balance, "router_z_loss": z}

@@ -68,7 +68,8 @@ from horovod_tpu.models.olmoe import INIT, rotary
 from horovod_tpu.models.smallthinker import REMAT_POLICIES
 from horovod_tpu.ops.flash_attention import blockdiff_attention
 from horovod_tpu.parallel import ep
-from horovod_tpu.profiler.annotate import attn_scope, diffusion_scope
+from horovod_tpu.profiler.annotate import (attn_part_scope, attn_scope,
+                                           diffusion_scope, head_scope)
 
 
 def _dense(features: int, dtype, name: str) -> nn.Dense:
@@ -94,21 +95,25 @@ class SdarAttention(nn.Module):
         def heads_of(name, count):
             return _dense(count * self.head_dim, self.dtype, name)(x) \
                 .reshape(b, rows, count, self.head_dim)
-        q, k, v = (heads_of("q_proj", self.heads),
-                   heads_of("k_proj", self.kv_heads),
-                   heads_of("v_proj", self.kv_heads))
+        with attn_part_scope("attn_qkv_proj"):
+            q, k, v = (heads_of("q_proj", self.heads),
+                       heads_of("k_proj", self.kv_heads),
+                       heads_of("v_proj", self.kv_heads))
         # over each head's own head_dim values, one weight vector for all
         norm = functools.partial(nn.RMSNorm, epsilon=self.eps,
                                  dtype=self.dtype)
-        q, k = norm(name="q_norm")(q), norm(name="k_norm")(k)
+        with attn_part_scope("attn_qk_norm"):
+            q, k = norm(name="q_norm")(q), norm(name="k_norm")(k)
         with attn_scope("attn_blockdiff"):
             def in_sequence(y):  # positions 0..L-1 in either stream
                 streams = y.reshape(b * 2, rows // 2, *y.shape[2:])
                 return rotary(streams, self.rope_theta).reshape(y.shape)
-            o = blockdiff_attention(in_sequence(q), in_sequence(k), v,
-                                    self.block_length)
-        return _dense(hidden, self.dtype, "o_proj")(
-            o.reshape(b, rows, self.heads * self.head_dim))
+            with attn_part_scope("attn_rope"):
+                q, k = in_sequence(q), in_sequence(k)
+            o = blockdiff_attention(q, k, v, self.block_length)
+        with attn_part_scope("attn_out_proj"):
+            return _dense(hidden, self.dtype, "o_proj")(
+                o.reshape(b, rows, self.heads * self.head_dim))
 
 
 class SdarSparseMoe(nn.Module):
@@ -224,11 +229,12 @@ class SdarMoeDecoder(nn.Module):
         x = nn.RMSNorm(epsilon=self.eps, dtype=self.dtype, name="norm")(
             x[:, :seq])
         # bf16 inputs, float32 out of the accumulators: no bf16 logits
-        logits = nn.Dense(
-            self.vocab, use_bias=False, dtype=self.dtype, kernel_init=INIT,
-            dot_general=functools.partial(
-                jax.lax.dot_general, preferred_element_type=jnp.float32),
-            name="LmHead")(x)
+        with head_scope("head_logits"):
+            logits = nn.Dense(
+                self.vocab, use_bias=False, dtype=self.dtype,
+                kernel_init=INIT, dot_general=functools.partial(
+                    jax.lax.dot_general, preferred_element_type=jnp.float32),
+                name="LmHead")(x)
         return logits, stats
 
 

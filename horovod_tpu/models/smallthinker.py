@@ -67,7 +67,8 @@ import optax
 from horovod_tpu.models.olmoe import INIT, rotary
 from horovod_tpu.ops.flash_attention import FLASH_RESIDUALS, attention
 from horovod_tpu.parallel import ep
-from horovod_tpu.profiler.annotate import attn_scope
+from horovod_tpu.profiler.annotate import (attn_part_scope, attn_scope,
+                                           head_scope)
 
 REMAT_POLICIES = {
     "blocks": None,  # nothing saved inside a block
@@ -103,17 +104,21 @@ class SmallThinkerAttention(nn.Module):
         def heads_of(name, count):
             return _dense(count * self.head_dim, self.dtype, name)(x) \
                 .reshape(b, t, count, self.head_dim)
-        q, k, v = (heads_of("q_proj", self.heads),
-                   heads_of("k_proj", self.kv_heads),
-                   heads_of("v_proj", self.kv_heads))
+        with attn_part_scope("attn_qkv_proj"):
+            q, k, v = (heads_of("q_proj", self.heads),
+                       heads_of("k_proj", self.kv_heads),
+                       heads_of("v_proj", self.kv_heads))
         with attn_scope("attn_full" if self.window is None
                         else "attn_window"):
             if self.rope_theta is not None:
-                q, k = rotary(q, self.rope_theta), rotary(k, self.rope_theta)
+                with attn_part_scope("attn_rope"):
+                    q = rotary(q, self.rope_theta)
+                    k = rotary(k, self.rope_theta)
             o = attention(q, k, v, causal=True, window=self.window)
-        return _dense(hidden, self.dtype, "o_proj",
-                      _out_init(self.residual_out_std))(
-            o.reshape(b, t, self.heads * self.head_dim))
+        with attn_part_scope("attn_out_proj"):
+            return _dense(hidden, self.dtype, "o_proj",
+                          _out_init(self.residual_out_std))(
+                o.reshape(b, t, self.heads * self.head_dim))
 
 
 class SmallThinkerRouter(nn.Module):
@@ -241,11 +246,12 @@ class SmallThinkerDecoder(nn.Module):
             stats.append(layer_stats)
         x = nn.RMSNorm(epsilon=self.eps, dtype=self.dtype, name="norm")(x)
         # bf16 inputs, float32 out of the accumulators: no bf16 logits
-        logits = nn.Dense(
-            self.vocab, use_bias=False, dtype=self.dtype, kernel_init=INIT,
-            dot_general=functools.partial(
-                jax.lax.dot_general, preferred_element_type=jnp.float32),
-            name="LmHead")(x)
+        with head_scope("head_logits"):
+            logits = nn.Dense(
+                self.vocab, use_bias=False, dtype=self.dtype,
+                kernel_init=INIT, dot_general=functools.partial(
+                    jax.lax.dot_general, preferred_element_type=jnp.float32),
+                name="LmHead")(x)
         return logits, jax.tree_util.tree_map(
             lambda *leaves: jnp.stack(leaves), *stats)
 
@@ -272,6 +278,7 @@ def smallthinker_loss(logits: jax.Array, labels: jax.Array,
     """Mean next-token cross-entropy and no auxiliary term (the published
     config names none). Returns (loss, aux) as ``dp.make_train_step`` takes
     them; ``aux["expert_tokens"]`` is the step's load, int32 [layers, E]."""
-    loss = optax.softmax_cross_entropy_with_integer_labels(
-        logits, labels).mean()
+    with head_scope("head_loss"):
+        loss = optax.softmax_cross_entropy_with_integer_labels(
+            logits, labels).mean()
     return loss, {"expert_tokens": stats.expert_tokens}

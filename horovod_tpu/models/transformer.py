@@ -15,6 +15,7 @@ import flax.linen as nn
 import jax.numpy as jnp
 
 from horovod_tpu.ops.flash_attention import attention
+from horovod_tpu.profiler.annotate import attn_part_scope
 
 
 class FlashSelfAttention(nn.Module):
@@ -40,12 +41,14 @@ class FlashSelfAttention(nn.Module):
                              f"heads ({self.heads})")
         head_dim = d // self.heads
         proj = dict(features=(self.heads, head_dim), dtype=self.dtype)
-        q = nn.DenseGeneral(name="query", **proj)(x)
-        k = nn.DenseGeneral(name="key", **proj)(x)
-        v = nn.DenseGeneral(name="value", **proj)(x)
+        with attn_part_scope("attn_qkv_proj"):
+            q = nn.DenseGeneral(name="query", **proj)(x)
+            k = nn.DenseGeneral(name="key", **proj)(x)
+            v = nn.DenseGeneral(name="value", **proj)(x)
         o = attention(q, k, v, causal=self.causal)
-        return nn.DenseGeneral(features=d, axis=(-2, -1), dtype=self.dtype,
-                               name="out")(o)
+        with attn_part_scope("attn_out_proj"):
+            return nn.DenseGeneral(features=d, axis=(-2, -1),
+                                   dtype=self.dtype, name="out")(o)
 
 
 class EncoderBlock(nn.Module):

@@ -97,6 +97,13 @@ from jax.experimental.pallas import tpu as pltpu
 
 from horovod_tpu.ops.kernel_call import (NEG_INF, NN, NT, TN, dot,
                                          on_this_platform, scalar_spec)
+from horovod_tpu.profiler.annotate import attn_part_scope
+
+# What this file does around a kernel's call, and not in it, is named in the
+# device trace (``profiler/annotate.ATTN_PART_SCOPES``); the ``pallas_call``s
+# themselves carry no part and no ``name=``: readers know a kernel by its
+# function.
+_kernel_io = functools.partial(attn_part_scope, "attn_kernel_io")
 
 # Crossover of the auto-router (:func:`attention`): fewer keys than this go
 # to :func:`xla_attention`; override with HOROVOD_FLASH_MIN_SEQ. Where the
@@ -669,15 +676,17 @@ def _bwd_dkv_call(q_off, k_off, qb, kb, vb, dob, lse, corr, *, causal,
 def _flash_fwd(q, k, v, q_off, k_off, causal, sm_scale, block_q, block_k,
                interpret, window=None, block_mask=None):
     b, tq, h, _ = q.shape
+    with _kernel_io():
+        qb, kb, vb = _bh_first(q), _bh_first(k), _bh_first(v)
     o, lse = on_this_platform(
         functools.partial(_fwd_call, causal=causal, sm_scale=sm_scale,
                           block_q=block_q, block_k=block_k, window=window,
                           block_mask=block_mask),
-        q_off, k_off, _bh_first(q), _bh_first(k), _bh_first(v),
-        interpret=interpret)
-    o_out = checkpoint_name(_bh_last(o, b), FLASH_RESIDUALS[0])
-    lse = checkpoint_name(lse, FLASH_RESIDUALS[1])
-    lse_out = lse.reshape(b, h, tq)
+        q_off, k_off, qb, kb, vb, interpret=interpret)
+    with _kernel_io():
+        o_out = checkpoint_name(_bh_last(o, b), FLASH_RESIDUALS[0])
+        lse = checkpoint_name(lse, FLASH_RESIDUALS[1])
+        lse_out = lse.reshape(b, h, tq)
     return o_out, lse_out, (q, k, v, o_out, lse, q_off, k_off)
 
 
@@ -695,26 +704,28 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, interpret, window,
     do, dlse = cots
     b, tq, h, _ = q.shape
     group = h // k.shape[2]
-    dob = _bh_first(do.astype(q.dtype))
-    ob = _bh_first(o)
-    # delta_i = sum_j do_ij o_ij;  ds = p * (dp + dlse - delta) * scale
-    delta = jnp.sum(dob.astype(jnp.float32) * ob.astype(jnp.float32),
-                    axis=-1)  # [BH, Tq]
-    # dlse arrives [B, H, Tq], which is (B*H, Tq)-contiguous already
-    corr = (dlse.reshape(b * h, tq).astype(jnp.float32) - delta
-            if dlse is not None else -delta)
-    corr = corr.reshape(b * h, 1, tq)  # full-row blocks, like lse
+    with _kernel_io():
+        dob = _bh_first(do.astype(q.dtype))
+        ob = _bh_first(o)
+        # delta_i = sum_j do_ij o_ij;  ds = p * (dp + dlse - delta) * scale
+        delta = jnp.sum(dob.astype(jnp.float32) * ob.astype(jnp.float32),
+                        axis=-1)  # [BH, Tq]
+        # dlse arrives [B, H, Tq], which is (B*H, Tq)-contiguous already
+        corr = (dlse.reshape(b * h, tq).astype(jnp.float32) - delta
+                if dlse is not None else -delta)
+        corr = corr.reshape(b * h, 1, tq)  # full-row blocks, like lse
+        args = (q_off, k_off, _bh_first(q), _bh_first(k), _bh_first(v), dob,
+                lse, corr)
     static = dict(causal=causal, sm_scale=sm_scale, block_q=block_q,
                   block_k=block_k, window=window, block_mask=block_mask)
-    args = (q_off, k_off, _bh_first(q), _bh_first(k), _bh_first(v), dob, lse,
-            corr)
     dq = on_this_platform(functools.partial(_bwd_dq_call, **static), *args,
                           interpret=interpret)
     dk, dvv = on_this_platform(functools.partial(_bwd_dkv_call, **static),
                                *args, interpret=interpret)
-    return (_bh_last(dq, b), _bh_last(_sum_groups(dk, group), b),
-            _bh_last(_sum_groups(dvv, group), b),
-            jnp.zeros_like(q_off), jnp.zeros_like(k_off))
+    with _kernel_io():
+        return (_bh_last(dq, b), _bh_last(_sum_groups(dk, group), b),
+                _bh_last(_sum_groups(dvv, group), b),
+                jnp.zeros_like(q_off), jnp.zeros_like(k_off))
 
 
 _flash.defvjp(_flash_fwd_vjp, _flash_bwd)
@@ -1238,9 +1249,10 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array,
     if k.shape[1] < threshold:
         # flash_kwargs here can only hold tuning knobs (block sizes /
         # interpret), which have no meaning for the XLA formulation.
-        return xla_attention(q, *_repeat_kv(q, k, v), causal=causal,
-                             sm_scale=sm_scale, window=window,
-                             block_mask=block_mask)
+        with _kernel_io():
+            k, v = _repeat_kv(q, k, v)
+        return xla_attention(q, k, v, causal=causal, sm_scale=sm_scale,
+                             window=window, block_mask=block_mask)
     return flash_attention(q, k, v, causal=causal, sm_scale=sm_scale,
                            window=window, block_mask=block_mask,
                            **flash_kwargs)
@@ -1346,25 +1358,32 @@ def blockdiff_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     threshold = min_flash_seq if min_flash_seq is not None else \
         flash_min_seq()
     if seq < threshold:
-        kr, vr = _repeat_kv(q, k, v)
+        with _kernel_io():
+            kr, vr = _repeat_kv(q, k, v)
         s = jnp.einsum("bqhd,bkhd->bhqk", q, kr,
                        preferred_element_type=jnp.float32) * scale
         s = jnp.where(blockdiff_mask(seq, group)[None, None], s, NEG_INF)
         return jnp.einsum("bhqk,bkhd->bqhd",
                           jax.nn.softmax(s, axis=-1).astype(v.dtype), vr,
                           preferred_element_type=jnp.float32).astype(q.dtype)
-    k_clean, v_clean = k[:, seq:], v[:, seq:]  # their own heads, as held
     flash = functools.partial(flash_attention, causal=True, sm_scale=scale,
                               **flash_kwargs)
-    clean = flash(q[:, seq:], k_clean, v_clean, block_mask=(group, "le"))
-    past, past_lse = flash(q[:, :seq], k_clean, v_clean, return_lse=True,
+    with _kernel_io():
+        k_clean, v_clean = k[:, seq:], v[:, seq:]  # their own heads, as held
+        q_clean = q[:, seq:]
+    clean = flash(q_clean, k_clean, v_clean, block_mask=(group, "le"))
+    with _kernel_io():
+        q_noised = q[:, :seq]
+    past, past_lse = flash(q_noised, k_clean, v_clean, return_lse=True,
                            block_mask=(group, "lt"))
-    own, own_lse = block_diagonal_attention(q[:, :seq], k[:, :seq],
-                                            v[:, :seq], group, scale)
-    noised, _ = merge_attention(past, past_lse, own, own_lse)
+    with attn_part_scope("attn_self_block"):
+        own, own_lse = block_diagonal_attention(q[:, :seq], k[:, :seq],
+                                                v[:, :seq], group, scale)
     _count_block_visits(
         {"noised_keys": _noised_key_tiles(
             seq, _pick_block(seq, flash_kwargs.get("block_q", 512)),
             _pick_block(seq, flash_kwargs.get("block_k", 512)))},
         b * h, BLOCKDIFF_KIND)
-    return jnp.concatenate([noised, clean], axis=1)
+    with attn_part_scope("attn_merge"):
+        noised, _ = merge_attention(past, past_lse, own, own_lse)
+        return jnp.concatenate([noised, clean], axis=1)

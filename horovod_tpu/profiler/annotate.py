@@ -7,33 +7,16 @@ Two distinct mechanisms, matching where the work actually happens:
   ``parallel/dp.py`` and ``parallel/zero.py`` write them, so every device
   operation of a step says whether it is forward/backward, gradient
   exchange, optimizer update, parameter gather or output sync.
-- :func:`moe_scope` — the parts of an expert layer (:data:`MOE_SCOPES`:
-  router, dispatch, experts, combine, written by ``parallel/ep.py``'s
-  dropless layer; ``moe_shared``, the shared expert every token visits, by
-  the model that has one).
-- :func:`ssm_scope` — the parts of a Mamba-2 mixer (:data:`SSM_SCOPES`:
-  in-projection, causal conv, the chunked scan of ``ops/ssd.py``, gated
-  group norm, out-projection), written by ``models/nemotron_h.py``.
-- :func:`shortconv_scope` — the parts of a gated short-convolution operator
-  (:data:`SHORTCONV_SCOPES`: in-projection, the two gates and the taps of
-  ``ops/short_conv.py``, out-projection), written by ``models/lfm2.py``.
-- :func:`mla_scope` — the parts of a multi-head latent attention operator
-  around its attention call (:data:`MLA_SCOPES`: the query's and the
-  key-value's low-rank projections with their inner norms, rotary with
-  whatever builds the kernels' key, the out-projection), written by
-  ``models/joyai_flash.py``.
-- :func:`mtp_scope` — the parts of a multi-token-prediction module
-  (:data:`MTP_SCOPES`: the merge of the next token's embedding with the
-  stack's output, the module's block, its head and loss), written by
-  ``models/joyai_flash.py``.
-- :func:`attn_scope` — the kind of an attention call (:data:`ATTN_SCOPES`:
-  full causal or window, written by ``models/smallthinker.py``, whose
-  layers mix the two, and ``models/lfm2.py``'s attention layers; the two streams of a block-diffusion pass, by
-  ``models/sdar.py``; a latent call, q/k wider than v, by
-  ``models/joyai_flash.py``).
-- :func:`diffusion_scope` — the two ends of a block-diffusion objective
-  (:data:`DIFFUSION_SCOPES`: the noising of a batch and the weighted loss
-  over its masked positions), written by ``models/sdar.py``.
+- :func:`family_scope` — the parts of a layer, an operator or a head, one
+  family of names each (:data:`FAMILIES`: the table says which families
+  there are; the comment above each tuple of names says what a name covers
+  and which file writes it). ``moe_scope``, ``ssm_scope``,
+  ``shortconv_scope``, ``attn_scope``, ``attn_part_scope``, ``mla_scope``,
+  ``mtp_scope``, ``diffusion_scope`` and ``head_scope`` are that one
+  function with its family bound. A new model writes the names that are
+  here (every attention operator's parts are :data:`ATTN_PART_SCOPES`,
+  every head's :data:`HEAD_SCOPES`); a family of its own is for an operator
+  no other model has.
 - :func:`collective_scope` — ``jax.named_scope`` for code that runs INSIDE a
   jitted program (the in-jit collectives of ``parallel/collectives.py``).
   The scope becomes HLO op-name metadata, so the device trace of a
@@ -54,6 +37,7 @@ infrastructure, never a hard dependency).
 from __future__ import annotations
 
 import contextlib
+import functools
 
 
 @contextlib.contextmanager
@@ -92,6 +76,28 @@ SHORTCONV_SCOPES = ("shortconv_in_proj", "shortconv_mix",
 # (``models/joyai_flash.py``): q and k of 192, v of 128, nothing else under
 # it (rotary and the key's build are ``mla_rope``).
 ATTN_SCOPES = ("attn_full", "attn_window", "attn_blockdiff", "attn_latent")
+# The parts of one attention operator that are not its kernels (below the
+# router's threshold: not ``xla_attention``'s scores, softmax and ``p v``),
+# the same names in every model. ``attn_qkv_proj`` and ``attn_out_proj``: the
+# projections with the reshape to and from heads; ``attn_qk_norm``: the
+# per-head norms of q and k; ``attn_rope``: rotary (the models write these
+# four). ``attn_kernel_io``: what ``ops/flash_attention.py`` does around a
+# kernel call (the operands' way to ``[B * heads, T, D]`` and back, casts,
+# the backward's ``delta``, the sum of dk and dv over a group, the repeat of
+# the key heads on the XLA path, the slices of a block-diffusion pass's
+# clean keys); ``attn_self_block`` and ``attn_merge``: a noised block on
+# itself, and its merge with the kernels' result and the streams' join. A
+# part never encloses another part; it may sit inside or outside a kind
+# (:data:`ATTN_SCOPES`), and a kernel's call is under no part.
+ATTN_PART_SCOPES = ("attn_qkv_proj", "attn_qk_norm", "attn_rope",
+                    "attn_kernel_io", "attn_self_block", "attn_merge",
+                    "attn_out_proj")
+# A model's head: ``head_logits`` is the final norm's output times the (tied
+# or untied) head up to the float32 logits, written by the model;
+# ``head_loss`` the cross-entropy and whatever else the model's own loss
+# function adds over the logits (a multi-token-prediction module's head and
+# loss stay ``mtp_head``, a diffusion objective's loss ``diffusion_loss``).
+HEAD_SCOPES = ("head_logits", "head_loss")
 # The parts of one multi-head latent attention operator around that call
 # (``models/joyai_flash.py``): ``mla_q_proj`` is the query's down-projection,
 # its norm and its up-projection; ``mla_kv_proj`` the same for the latent of
@@ -120,63 +126,38 @@ def step_phase(name: str):
     return collective_scope(PHASE_PREFIX + name)
 
 
-def moe_scope(name: str):
-    """Name the enclosed traced ops as one part of an expert layer."""
-    if name not in MOE_SCOPES:
-        raise ValueError(f"unknown expert-layer scope {name!r}; one of "
-                         f"{MOE_SCOPES}")
+# family -> (what an error message calls it, its names)
+FAMILIES = {
+    "moe": ("expert-layer", MOE_SCOPES),
+    "ssm": ("state-space mixer", SSM_SCOPES),
+    "shortconv": ("short-convolution", SHORTCONV_SCOPES),
+    "attn": ("attention", ATTN_SCOPES),
+    "attn_part": ("attention-part", ATTN_PART_SCOPES),
+    "mla": ("latent-attention", MLA_SCOPES),
+    "mtp": ("multi-token-prediction", MTP_SCOPES),
+    "diffusion": ("diffusion", DIFFUSION_SCOPES),
+    "head": ("head", HEAD_SCOPES),
+}
+
+
+def family_scope(family: str, name: str):
+    """Name the enclosed traced ops as the part ``name`` of ``family``
+    (:data:`FAMILIES`); a name the family does not list is an error."""
+    what, names = FAMILIES[family]
+    if name not in names:
+        raise ValueError(f"unknown {what} scope {name!r}; one of {names}")
     return collective_scope(name)
 
 
-def ssm_scope(name: str):
-    """Name the enclosed traced ops as one part of a state-space mixer."""
-    if name not in SSM_SCOPES:
-        raise ValueError(f"unknown state-space mixer scope {name!r}; one of "
-                         f"{SSM_SCOPES}")
-    return collective_scope(name)
-
-
-def shortconv_scope(name: str):
-    """Name the enclosed traced ops as one part of a gated short-convolution
-    operator."""
-    if name not in SHORTCONV_SCOPES:
-        raise ValueError(f"unknown short-convolution scope {name!r}; one of "
-                         f"{SHORTCONV_SCOPES}")
-    return collective_scope(name)
-
-
-def attn_scope(name: str):
-    """Name the enclosed traced ops as an attention call of one kind."""
-    if name not in ATTN_SCOPES:
-        raise ValueError(f"unknown attention scope {name!r}; one of "
-                         f"{ATTN_SCOPES}")
-    return collective_scope(name)
-
-
-def mla_scope(name: str):
-    """Name the enclosed traced ops as one part of a latent attention
-    operator."""
-    if name not in MLA_SCOPES:
-        raise ValueError(f"unknown latent-attention scope {name!r}; one of "
-                         f"{MLA_SCOPES}")
-    return collective_scope(name)
-
-
-def mtp_scope(name: str):
-    """Name the enclosed traced ops as one part of a multi-token-prediction
-    module."""
-    if name not in MTP_SCOPES:
-        raise ValueError(f"unknown multi-token-prediction scope {name!r}; "
-                         f"one of {MTP_SCOPES}")
-    return collective_scope(name)
-
-
-def diffusion_scope(name: str):
-    """Name the enclosed traced ops as one end of a diffusion objective."""
-    if name not in DIFFUSION_SCOPES:
-        raise ValueError(f"unknown diffusion scope {name!r}; one of "
-                         f"{DIFFUSION_SCOPES}")
-    return collective_scope(name)
+moe_scope = functools.partial(family_scope, "moe")
+ssm_scope = functools.partial(family_scope, "ssm")
+shortconv_scope = functools.partial(family_scope, "shortconv")
+attn_scope = functools.partial(family_scope, "attn")
+attn_part_scope = functools.partial(family_scope, "attn_part")
+mla_scope = functools.partial(family_scope, "mla")
+mtp_scope = functools.partial(family_scope, "mtp")
+diffusion_scope = functools.partial(family_scope, "diffusion")
+head_scope = functools.partial(family_scope, "head")
 
 
 def collective_scope(name: str):

@@ -8,8 +8,8 @@ import re
 import pytest
 
 from tpu_compile_cases import (  # noqa: F401
-    KERNELS, _compiled_cell, _kernel_calls, _kernel_text, _row_scatters,
-    no_persistent_cache, topo)
+    KERNELS, _compiled_cell, _kernel_calls, _kernel_text, _parts_hold,
+    _row_scatters, no_persistent_cache, topo)
 
 SEQ, HEADS, QK, V = 8192, 32, 192, 128
 LATENT_KERNELS = {"forward": "_fwd_latent_kernel",
@@ -115,3 +115,16 @@ def test_latent_kernel_compiles_for_v5e_under_its_own_name(topo, kernel):
     text = _kernel_text(topo, kernel, SEQ, QK, True, heads=HEADS, v_dim=V)
     calls, _ = _kernel_calls(text)
     assert calls == {LATENT_KERNELS[kernel]: 1}
+
+
+def test_joyai_cell_names_what_surrounds_its_kernels_and_its_head(joyai_cell):
+    """The operator keeps its ``mla_*`` names; of the shared ones the step
+    holds what ``flash_attention`` writes inside ``attn_latent`` and the
+    main head's two (the module's stays ``mtp_head``). Each kernel's call
+    under ``attn_latent`` and no part."""
+    text = joyai_cell[2].as_text()
+    _parts_hold(text, ("attn_kernel_io", "head_logits", "head_loss"),
+                "attn_latent")
+    for scope in ("mla_q_proj", "mla_kv_proj", "mla_rope", "mla_out_proj",
+                  "mtp_head"):
+        assert scope in text, scope

@@ -7,8 +7,8 @@ import re
 import pytest
 
 from tpu_compile_cases import (  # noqa: F401
-    _benchmark_on_path, _compiled_cell, _kernel_calls, _products_by_blocks,
-    _row_scatters, no_persistent_cache, topo)
+    _benchmark_on_path, _compiled_cell, _kernel_calls, _parts_hold,
+    _products_by_blocks, _row_scatters, no_persistent_cache, topo)
 
 SEQ, HIDDEN = 16384, 2048
 
@@ -174,3 +174,13 @@ def test_the_middles_kernels_compile_alone_at_the_cells_shapes(topo,
     assert not [ins.shape for ins in unfused
                 if ins.opcode in ("copy", "transpose", "pad")
                 and str(SEQ) in ins.shape]
+
+
+def test_lfm2_cell_names_its_attention_parts_and_its_head(lfm2_cell):
+    """The one attention layer: projections, per-head norms, rotary, what
+    surrounds the three calls; the tied head and the loss. Each kernel's
+    call under ``attn_full`` and no part."""
+    _parts_hold(lfm2_cell[2].as_text(),
+                ("attn_qkv_proj", "attn_qk_norm", "attn_rope",
+                 "attn_kernel_io", "attn_out_proj", "head_logits",
+                 "head_loss"), "attn_full")

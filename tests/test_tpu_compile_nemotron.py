@@ -7,8 +7,8 @@ import re
 import pytest
 
 from tpu_compile_cases import (  # noqa: F401
-    _benchmark_on_path, _compiled_cell, _kernel_calls, _row_scatters,
-    no_persistent_cache, topo)
+    _benchmark_on_path, _compiled_cell, _kernel_calls, _parts_hold,
+    _row_scatters, no_persistent_cache, topo)
 
 
 @pytest.fixture(scope="module")
@@ -186,3 +186,12 @@ def test_nemotron_cell_moves_the_two_ends_once_a_pass(nemotron_cell):
     copies = [found for found in _activation_copies(text)
               if "ssm_" in hlo.instructions[found[0]].op_name]
     assert not copies, copies
+
+
+def test_nemotron_cell_names_its_attention_parts_and_its_head(nemotron_cell):
+    """Attention without positions or norms: the projections, what
+    surrounds the kernels' calls, the head and the loss. The model writes
+    no kind; a kernel's call carries no part."""
+    _parts_hold(nemotron_cell[2].as_text(),
+                ("attn_qkv_proj", "attn_kernel_io", "attn_out_proj",
+                 "head_logits", "head_loss"))

@@ -7,7 +7,8 @@ import re
 import pytest
 
 from tpu_compile_cases import (  # noqa: F401
-    _compiled_cell, _kernel_calls, _row_scatters, no_persistent_cache, topo)
+    _compiled_cell, _kernel_calls, _parts_hold, _row_scatters,
+    no_persistent_cache, topo)
 
 
 @pytest.fixture(scope="module")
@@ -79,3 +80,15 @@ def test_sdar_cell_holds_the_block_mask_kernels_and_no_score_array(
         assert scope in text, scope
     opcodes = re.findall(r"[\s)]([a-z\-]+)\(", text)
     assert "all-reduce" not in opcodes
+
+
+def test_sdar_cell_names_its_attention_parts_and_its_head(sdar_cell):
+    """Every shared part an attention operator can have, once each: the
+    model's four, what ``flash_attention`` does around the six calls a
+    layer, the noised block on itself and the merge; the head's logits (the
+    loss stays ``diffusion_loss``). Each kernel's call under
+    ``attn_blockdiff`` and no part."""
+    _parts_hold(sdar_cell[2].as_text(),
+                ("attn_qkv_proj", "attn_qk_norm", "attn_rope",
+                 "attn_kernel_io", "attn_self_block", "attn_merge",
+                 "attn_out_proj", "head_logits"), "attn_blockdiff")

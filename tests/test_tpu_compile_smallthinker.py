@@ -7,7 +7,8 @@ import re
 import pytest
 
 from tpu_compile_cases import (  # noqa: F401
-    _compiled_cell, _kernel_calls, _row_scatters, no_persistent_cache, topo)
+    _compiled_cell, _kernel_calls, _parts_hold, _row_scatters,
+    no_persistent_cache, topo)
 
 
 @pytest.fixture(scope="module")
@@ -80,3 +81,14 @@ def test_smallthinker_cell_holds_causal_and_window_kernels_side_by_side(
         assert scope in text, scope
     opcodes = re.findall(r"[\s)]([a-z\-]+)\(", text)
     assert "all-reduce" not in opcodes
+
+
+def test_smallthinker_cell_names_its_attention_parts_and_its_head(
+        smallthinker_cell):
+    """The projections, rotary (the window layers'), what surrounds the
+    kernels' calls, the head and the loss; no per-head norm in this model.
+    Each kernel's call under its kind and no part."""
+    _parts_hold(smallthinker_cell[2].as_text(),
+                ("attn_qkv_proj", "attn_rope", "attn_kernel_io",
+                 "attn_out_proj", "head_logits", "head_loss"),
+                "attn_full|attn_window")

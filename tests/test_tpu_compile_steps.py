@@ -14,7 +14,7 @@ import pytest
 from jax.sharding import (NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
-from tpu_compile_cases import (_kernel_calls,  # noqa: F401
+from tpu_compile_cases import (_kernel_calls, _parts_hold,  # noqa: F401
                                no_persistent_cache, topo)
 
 
@@ -93,6 +93,18 @@ def test_one_chip_step_has_nothing_to_exchange(gpt2_width_step_text):
     # nor anything of the asynchronous exchange (PR 29): no option got there
     assert "async_collective_fusion" not in text(1)
     assert "async_collective_name" not in text(1)
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_gpt2_width_step_names_its_attention_parts_and_its_head(
+        gpt2_width_step_text, chips):
+    """``FlashSelfAttention`` has neither norms nor positions of its own: the
+    projections, what surrounds the kernels' calls, and the tied head's
+    logits (the loss is the caller's, under no name). The model writes no
+    kind; a kernel's call carries no part."""
+    _parts_hold(gpt2_width_step_text[0](chips),
+                ("attn_qkv_proj", "attn_kernel_io", "attn_out_proj",
+                 "head_logits"))
 
 
 def test_four_chip_exchange_is_all_reduces_and_no_packing(

@@ -127,6 +127,32 @@ def _kernel_calls(text):
     return kernels.inventory(hlo), op_names
 
 
+def _parts_hold(text, parts, kind=None):
+    """Holds a compiled step's ``op_name``s to the rules of the attention
+    operator's parts and the head's (``profiler/annotate.ATTN_PART_SCOPES``,
+    ``HEAD_SCOPES``): each of ``parts``, and no other, is named forward and
+    in the transposed pass; no ``op_name`` holds two parts; a flash kernel's
+    call is under no part, and under ``kind`` (a pattern over
+    ``ATTN_SCOPES``) where the model writes one."""
+    from horovod_tpu.profiler import annotate
+    part = re.compile(r"\b(%s)\b" % "|".join(
+        annotate.ATTN_PART_SCOPES + annotate.HEAD_SCOPES))
+    op_names = set(re.findall(r'op_name="([^"]*)"', text))
+    found = {False: set(), True: set()}
+    for name in op_names:
+        named = part.findall(name)
+        assert len(named) <= 1, name
+        if named:
+            found["transpose(" in name].add(named[0])
+    assert found[False] == found[True] == set(parts)
+    flash = [name for kernel, names in _kernel_calls(text)[1].items()
+             if re.match(r"_(fwd|bwd_dq|bwd_dkv)_", kernel) for name in names]
+    assert flash
+    for name in flash:
+        assert not part.search(name), name
+        assert kind is None or re.search(kind, name), name
+
+
 def _row_scatters(text):
     """The shapes of the scatters of rows under an expert layer's scopes:
     a share's walk has none (its one scatter is of a scalar a pair)."""
