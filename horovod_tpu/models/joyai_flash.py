@@ -27,7 +27,7 @@ those columns with the pairs' first members before their second
 (:func:`_pairs_as_halves`: a reordering of the MATRIX's columns, so no
 activation is shuffled; a reshape of ``[T, 32, 64]`` to pairs cost the TPU
 whole copies in layouts of two lanes, ``PERF.md`` §6), and the rotation is the
-rotate-half of ``models/olmoe.rotary``, as the published code permutes before
+rotate-half of ``ops/rotary.rotary``, as the published code permutes before
 its own: the same order for q and k, so every score is the one of the
 interleaved order. A head's key is ``[k_nope | k_r]``; scores ``q . k /
 sqrt(nope + rope)``, causal softmax, ``o = p v``, ``o W_o``. The key is built
@@ -104,9 +104,10 @@ import optax
 
 from horovod_tpu.models.lfm2 import (ROUTER_STATE, Lfm2Experts, Lfm2Mlp,
                                      Lfm2Router, linear)
-from horovod_tpu.models.olmoe import INIT, rotary
+from horovod_tpu.models.olmoe import INIT
 from horovod_tpu.models.smallthinker import REMAT_POLICIES
 from horovod_tpu.ops.flash_attention import attention
+from horovod_tpu.ops.rotary import rotary
 from horovod_tpu.parallel import ep
 from horovod_tpu.profiler.annotate import (attn_scope, head_scope, mla_scope,
                                            moe_scope, mtp_scope)

@@ -24,7 +24,8 @@ with the residual in the activations' dtype.
   ``k``, ``v`` in ``kv_heads`` heads of ``head_dim``, no bias; an RMSNorm
   over each head's own ``head_dim`` values of q and of k (``q_layernorm``,
   ``k_layernorm``: one weight vector for all heads), then rotary
-  (rotate-half, ``rope_theta``), causal ``softmax(q k^T / sqrt(head_dim)) v``
+  (rotate-half, ``rope_theta``, ``ops/rotary.rotary``), causal
+  ``softmax(q k^T / sqrt(head_dim)) v``
   with query head ``j`` on key head ``j // (heads / kv_heads)`` through the
   length-routed ``ops/flash_attention.attention`` under ``attn_full``, k and
   v at their own heads; ``out_proj``.
@@ -85,9 +86,10 @@ import jax
 import jax.numpy as jnp
 import optax
 
-from horovod_tpu.models.olmoe import INIT, rotary
+from horovod_tpu.models.olmoe import INIT
 from horovod_tpu.models.smallthinker import REMAT_POLICIES
 from horovod_tpu.ops.flash_attention import attention
+from horovod_tpu.ops.rotary import rotary
 from horovod_tpu.ops.short_conv import gated_short_conv
 from horovod_tpu.parallel import ep
 from horovod_tpu.profiler.annotate import (attn_part_scope, attn_scope,
@@ -155,7 +157,7 @@ class Lfm2Attention(nn.Module):
             q, k = norm(name="q_layernorm")(q), norm(name="k_layernorm")(k)
         with attn_scope("attn_full"):
             with attn_part_scope("attn_rope"):
-                q, k = rotary(q, self.rope_theta), rotary(k, self.rope_theta)
+                q, k = rotary((q, k), self.rope_theta)
             o = attention(q, k, v, causal=True)
         with attn_part_scope("attn_out_proj"):
             return linear(hidden, self.dtype, "out_proj")(

@@ -7,8 +7,11 @@ OLMoE-1B-7B (arXiv:2409.02060; ``allenai/OLMoE-1B-7B-0125-Instruct``
 
 Attention: ``q = RMSNorm(W_q x)``, ``k = RMSNorm(W_k x)`` (the norm runs
 over the whole projection, before the split into heads), ``v = W_v x``,
-rotary positions (rotate-half) on q and k, the length-routed attention op
-the dense models share (``ops/flash_attention.attention``), ``W_o``. MoE: the
+rotary positions (rotate-half) on q and k through the one rotary operator
+the five rotary models share (``ops/rotary.rotary``: q and k of a call in one
+pass, the backward its own, the rotation by the negated angles), the
+length-routed attention op the dense models share
+(``ops/flash_attention.attention``), ``W_o``. MoE: the
 dropless top-k layer of ``parallel/ep.moe_topk`` with gated (SwiGLU) experts
 of three matrices. A final RMSNorm and an untied head; no bias anywhere.
 Modules keep flax's own names (``OlmoeBlock_0/OlmoeAttention_0``,
@@ -19,8 +22,9 @@ names them (``q_proj``, ``q_norm``, ``gate_proj``, ``router``).
 
 The dense models' dtype policy: float32 parameters, ``dtype`` (bf16)
 activations and matmul inputs with float32 accumulation; router logits and
-softmax, the norms' statistics, the rotary angles, the logits and the loss
-in float32.
+softmax, the norms' statistics, the rotary angles and rotation
+(``ops/rotary.py``, forward and backward), the logits and the loss in
+float32.
 """
 
 from __future__ import annotations
@@ -34,22 +38,11 @@ import jax.numpy as jnp
 import optax
 
 from horovod_tpu.ops.flash_attention import attention
+from horovod_tpu.ops.rotary import rotary
 from horovod_tpu.parallel import ep
 from horovod_tpu.profiler.annotate import attn_part_scope, head_scope
 
 INIT = nn.initializers.normal(stddev=0.02)  # transformers' initializer_range
-
-
-def rotary(x: jax.Array, theta: float) -> jax.Array:
-    """Rotate-half rotary embedding of [B, T, H, D] at positions 0..T-1,
-    angles and rotation in float32."""
-    t, d = x.shape[1], x.shape[-1]
-    inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    angles = jnp.arange(t, dtype=jnp.float32)[:, None] * inv_freq[None, :]
-    cos, sin = jnp.cos(angles)[:, None, :], jnp.sin(angles)[:, None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                           axis=-1).astype(x.dtype)
 
 
 class OlmoeAttention(nn.Module):
@@ -78,7 +71,7 @@ class OlmoeAttention(nn.Module):
             return y.reshape(b, t, self.heads, d // self.heads)
         q, k, v = heads_of("q"), heads_of("k"), heads_of("v", normed=False)
         with attn_part_scope("attn_rope"):
-            q, k = rotary(q, self.rope_theta), rotary(k, self.rope_theta)
+            q, k = rotary((q, k), self.rope_theta)
         o = attention(q, k, v, causal=True)
         with attn_part_scope("attn_out_proj"):
             return proj(name="o_proj")(o.reshape(b, t, d))

@@ -14,7 +14,9 @@ noised stream first), and every layer with input ``x`` is
     h   = RMSNorm(x)
     q, k, v = h W_q, h W_k, h W_v            heads / kv_heads heads of head_dim
     q, k = RMSNorm over each head's head_dim   (q_norm, k_norm: per head)
-    q, k = rotary(q, k) at the row's position IN ITS SEQUENCE (both: 0..L-1)
+    q, k = rotary(q, k) at the row's position IN ITS SEQUENCE (both: 0..L-1;
+              ops/rotary.rotary over [2B, L, ...], one call for both, the
+              backward its own)
     x1  = x + W_o Attn(q, k, v)   under the block-diffusion mask:
               xt on xt: b(k) == b(q);  xt on x0: b(k) < b(q);
               x0 on x0: b(k) <= b(q);  x0 on xt: never
@@ -64,9 +66,10 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from horovod_tpu.models.olmoe import INIT, rotary
+from horovod_tpu.models.olmoe import INIT
 from horovod_tpu.models.smallthinker import REMAT_POLICIES
 from horovod_tpu.ops.flash_attention import blockdiff_attention
+from horovod_tpu.ops.rotary import rotary
 from horovod_tpu.parallel import ep
 from horovod_tpu.profiler.annotate import (attn_part_scope, attn_scope,
                                            diffusion_scope, head_scope)
@@ -105,11 +108,11 @@ class SdarAttention(nn.Module):
         with attn_part_scope("attn_qk_norm"):
             q, k = norm(name="q_norm")(q), norm(name="k_norm")(k)
         with attn_scope("attn_blockdiff"):
-            def in_sequence(y):  # positions 0..L-1 in either stream
-                streams = y.reshape(b * 2, rows // 2, *y.shape[2:])
-                return rotary(streams, self.rope_theta).reshape(y.shape)
+            def streams(y):  # positions 0..L-1 in either stream
+                return y.reshape(b * 2, rows // 2, *y.shape[2:])
             with attn_part_scope("attn_rope"):
-                q, k = in_sequence(q), in_sequence(k)
+                q, k = (y.reshape(b, rows, *y.shape[2:]) for y in rotary(
+                    (streams(q), streams(k)), self.rope_theta))
             o = blockdiff_attention(q, k, v, self.block_length)
         with attn_part_scope("attn_out_proj"):
             return _dense(hidden, self.dtype, "o_proj")(

@@ -15,7 +15,7 @@ with no dense feed-forward and no shared expert. Attention: ``q`` in
 bias and no q/k norm, query head ``j`` on key head ``j // (heads /
 kv_heads)``, through the length-routed ``ops/flash_attention.attention``. Two
 per-layer flags make the layers differ: ``rope_layout[l] = 1`` gives q and k
-rotary positions (rotate-half, ``models/olmoe.rotary``), 0 gives the layer
+rotary positions (rotate-half, ``ops/rotary.rotary``), 0 gives the layer
 **no position signal**; ``sliding_window_layout[l] = 1`` narrows the causal
 mask to ``0 <= i - j < window``, 0 keeps it causal. The published layouts
 are ``0,1,1,1`` repeated: one full layer without positions, three window
@@ -64,8 +64,9 @@ import jax
 import jax.numpy as jnp
 import optax
 
-from horovod_tpu.models.olmoe import INIT, rotary
+from horovod_tpu.models.olmoe import INIT
 from horovod_tpu.ops.flash_attention import FLASH_RESIDUALS, attention
+from horovod_tpu.ops.rotary import rotary
 from horovod_tpu.parallel import ep
 from horovod_tpu.profiler.annotate import (attn_part_scope, attn_scope,
                                            head_scope)
@@ -112,8 +113,7 @@ class SmallThinkerAttention(nn.Module):
                         else "attn_window"):
             if self.rope_theta is not None:
                 with attn_part_scope("attn_rope"):
-                    q = rotary(q, self.rope_theta)
-                    k = rotary(k, self.rope_theta)
+                    q, k = rotary((q, k), self.rope_theta)
             o = attention(q, k, v, causal=True, window=self.window)
         with attn_part_scope("attn_out_proj"):
             return _dense(hidden, self.dtype, "o_proj",

@@ -81,14 +81,16 @@ ATTN_SCOPES = ("attn_full", "attn_window", "attn_blockdiff", "attn_latent")
 # the same names in every model. ``attn_qkv_proj`` and ``attn_out_proj``: the
 # projections with the reshape to and from heads; ``attn_qk_norm``: the
 # per-head norms of q and k; ``attn_rope``: rotary (the models write these
-# four). ``attn_kernel_io``: what ``ops/flash_attention.py`` does around a
+# four; the rotation is ``ops/rotary.rotary``, whose forward kernel's call
+# and whose own backward, traced where the call site was, both name it).
+# ``attn_kernel_io``: what ``ops/flash_attention.py`` does around a
 # kernel call (the operands' way to ``[B * heads, T, D]`` and back, casts,
 # the backward's ``delta``, the sum of dk and dv over a group, the repeat of
 # the key heads on the XLA path, the slices of a block-diffusion pass's
 # clean keys); ``attn_self_block`` and ``attn_merge``: a noised block on
 # itself, and its merge with the kernels' result and the streams' join. A
 # part never encloses another part; it may sit inside or outside a kind
-# (:data:`ATTN_SCOPES`), and a kernel's call is under no part.
+# (:data:`ATTN_SCOPES`), and a flash kernel's call is under no part.
 ATTN_PART_SCOPES = ("attn_qkv_proj", "attn_qk_norm", "attn_rope",
                     "attn_kernel_io", "attn_self_block", "attn_merge",
                     "attn_out_proj")

@@ -5,6 +5,7 @@ at the cells' shapes, a second or two a case. The steps are
 (``test_tpu_compile_sdar.py``, ``_smallthinker.py``, ``_nemotron.py``).
 """
 
+import math
 import re
 
 import jax
@@ -15,7 +16,8 @@ from jax.sharding import SingleDeviceSharding
 from horovod_tpu.ops import flash_attention as fa
 
 from tpu_compile_cases import (  # noqa: F401
-    KERNELS, _kernel_calls, _kernel_text, no_persistent_cache, topo)
+    KERNELS, _kernel_calls, _kernel_text, _unfused, no_persistent_cache,
+    topo)
 
 
 @pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
@@ -155,6 +157,41 @@ def test_rows_to_tokens_kernel_compiles_for_v5e(topo, tokens, d, tile,
     assert memory.alias_size_in_bytes == memory.output_size_in_bytes \
         == tokens * d * 4
     assert memory.temp_size_in_bytes < 1 << 20
+
+
+@pytest.mark.parametrize("batch,seq,heads,kv_heads", [
+    (2, 8192, 32, 4), (1, 16384, 28, 4), (2, 4096, 16, 16)],
+    ids=["sdar-t8192-bd4", "smallthinker-t16384", "olmoe-t4096"])
+def test_rotary_compiles_for_v5e(topo, batch, seq, heads, kv_heads):
+    """q and k of a call at the three cells with heads of 128 (``sdar``'s
+    two streams are its batch): the forward ONE kernel call for both arrays
+    and no float32 array of q's size beside it; the backward no kernel: the
+    same rotation in ``jax.numpy``, which never holds a float32 half of q
+    (the halves it swaps are bf16)."""
+    from horovod_tpu.ops.rotary import rotary
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def arg(count):
+        return jax.ShapeDtypeStruct((batch, seq, count, 128), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def float32_of(text, least):
+        """The float32 arrays of at least ``least`` elements that the
+        program writes out."""
+        return [shape for ins in _unfused(text)[1]
+                for shape in re.findall(r"f32\[([\d,]+)\]", ins.shape)
+                if math.prod(int(d) for d in shape.split(",")) >= least]
+    size = batch * seq * heads * 128
+    forward = jax.jit(lambda q, k: rotary((q, k), 1e6)).lower(
+        arg(heads), arg(kv_heads)).compile().as_text()
+    assert _kernel_calls(forward)[0] == {"_rotary_kernel": 1}
+    assert not float32_of(forward, size)
+    backward = jax.jit(lambda q, k, dq, dk: jax.vjp(
+        lambda q, k: rotary((q, k), 1e6), q, k)[1]((dq, dk))).lower(
+        arg(heads), arg(kv_heads), arg(heads), arg(kv_heads)) \
+        .compile().as_text()
+    assert not _kernel_calls(backward)[0]
+    assert not float32_of(backward, size // 2)
 
 
 ENDS = {  # channels of the array, of the run, where the run starts

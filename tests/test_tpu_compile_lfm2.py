@@ -7,8 +7,8 @@ import re
 import pytest
 
 from tpu_compile_cases import (  # noqa: F401
-    _benchmark_on_path, _compiled_cell, _kernel_calls, _parts_hold,
-    _products_by_blocks, _row_scatters, no_persistent_cache, topo)
+    _compiled_cell, _kernel_calls, _parts_hold, _products_by_blocks,
+    _row_scatters, _unfused, no_persistent_cache, topo)
 
 SEQ, HIDDEN = 16384, 2048
 
@@ -19,18 +19,6 @@ def lfm2_cell(topo):
     16 384 tokens, every block recomputed but for its attention's output,
     through ``dp.make_stateful_train_step``."""
     return _compiled_cell(topo, "lfm2-t16384")
-
-
-def _unfused(text):
-    """The instructions the step runs one by one: those of no fused
-    computation."""
-    _benchmark_on_path()
-    from harness import hlo_text
-    hlo = hlo_text.HloIndex(text)
-    fused = {body for ins in hlo.instructions.values()
-             if ins.opcode == "fusion" for body in ins.calls}
-    return hlo, [ins for ins in hlo.instructions.values()
-                 if ins.computation not in fused]
 
 
 def test_lfm2_cell_fits_one_v5e_at_full_size(lfm2_cell):

@@ -56,9 +56,15 @@ def test_sdar_cell_holds_the_block_mask_kernels_and_no_score_array(
     assert calls == {"_fwd_blockdiff_kernel": 2 * layers,
                      "_bwd_dq_blockdiff_kernel": 2 * layers,
                      "_bwd_dkv_blockdiff_kernel": 2 * layers,
-                     "_add_rows_kernel": 2 * layers}
+                     "_add_rows_kernel": 2 * layers,
+                     "_rotary_kernel": 2 * layers}
     assert job.flash_call is None
     op_names.pop("_add_rows_kernel")
+    # rotary (PR 51): q and k of both streams through one call a layer, in
+    # the block's forward and in its recomputation; the backward is XLA's
+    turned = op_names.pop("_rotary_kernel")
+    assert all("attn_blockdiff/attn_rope" in name for name in turned)
+    assert sum("rematted_computation" in name for name in turned) == layers
     for kernel, names in op_names.items():
         assert all("attn_blockdiff" in name for name in names), kernel
         backward = [("transpose(jvp(" in name) for name in names]

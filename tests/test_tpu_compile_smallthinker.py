@@ -52,7 +52,8 @@ def test_smallthinker_cell_holds_causal_and_window_kernels_side_by_side(
     assert calls == {
         "_fwd_kernel": 2, "_bwd_dq_kernel": 2, "_bwd_dkv_kernel": 2,
         "_fwd_window_kernel": 6, "_bwd_dq_window_kernel": 6,
-        "_bwd_dkv_window_kernel": 6, "_add_rows_kernel": 2 * 8}
+        "_bwd_dkv_window_kernel": 6, "_add_rows_kernel": 2 * 8,
+        "_rotary_kernel": 2 * 6}
     assert job.flash_layers == 2 and job.facts["window_layers"] == 6
     way_back = op_names.pop("_add_rows_kernel")
     assert sum("moe_combine" in name and "transpose(" not in name
@@ -60,6 +61,11 @@ def test_smallthinker_cell_holds_causal_and_window_kernels_side_by_side(
     assert sum("moe_dispatch" in name and "transpose(jvp(" in name
                for name in way_back) == 8
     assert not _row_scatters(text)
+    # rotary (PR 51): q and k of a window layer through one call, in the
+    # block's forward and in its recomputation; the backward is XLA's
+    turned = op_names.pop("_rotary_kernel")
+    assert all("attn_window/attn_rope" in name for name in turned)
+    assert sum("rematted_computation" in name for name in turned) == 6
     for kernel, names in op_names.items():
         scope = "attn_window" if "window" in kernel else "attn_full"
         assert all(scope in name for name in names), kernel

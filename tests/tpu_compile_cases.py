@@ -113,6 +113,18 @@ def _benchmark_on_path():
         sys.path.insert(0, benchmark)
 
 
+def _unfused(text):
+    """(the text's index, the instructions the program runs one by one:
+    those of no fused computation)."""
+    _benchmark_on_path()
+    from harness import hlo_text
+    hlo = hlo_text.HloIndex(text)
+    fused = {body for ins in hlo.instructions.values()
+             if ins.opcode == "fusion" for body in ins.calls}
+    return hlo, [ins for ins in hlo.instructions.values()
+                 if ins.computation not in fused]
+
+
 def _kernel_calls(text):
     """{kernel: count} of the step's ``tpu_custom_call``s as the benchmark
     names them (``harness.kernels.inventory``: a Pallas kernel by its
