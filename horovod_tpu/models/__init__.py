@@ -40,6 +40,12 @@ from horovod_tpu.models.smallthinker import (  # noqa: F401
     SmallThinkerTiny,
     smallthinker_loss,
 )
+from horovod_tpu.models.trinity import (  # noqa: F401
+    TrinityDecoder,
+    TrinityMini,
+    TrinityTiny,
+    trinity_loss,
+)
 from horovod_tpu.models.transformer import (  # noqa: F401
     BertBase,
     BertEncoder,

@@ -164,16 +164,23 @@ class Lfm2Attention(nn.Module):
                 o.reshape(b, t, self.heads * self.head_dim))
 
 
+SWIGLU_NAMES = ("w1", "w3", "w2")  # gate, up, down as this checkpoint has them
+
+
 class Lfm2Mlp(nn.Module):
-    """``w2(silu(w1 x) * w3 x)``: the dense feed-forward."""
+    """``w2(silu(w1 x) * w3 x)``: the dense feed-forward. ``names`` are the
+    gate's, the up- and the down-projection's: another checkpoint's model
+    (``models/trinity.py``) names its own."""
     width: int
     dtype: Any = jnp.bfloat16
+    names: Tuple[str, str, str] = SWIGLU_NAMES
 
     @nn.compact
     def __call__(self, x):
-        gate = linear(self.width, self.dtype, "w1")(x)
-        up = linear(self.width, self.dtype, "w3")(x)
-        return linear(x.shape[-1], self.dtype, "w2")(jax.nn.silu(gate) * up)
+        w1, w3, w2 = self.names
+        gate = linear(self.width, self.dtype, w1)(x)
+        up = linear(self.width, self.dtype, w3)(x)
+        return linear(x.shape[-1], self.dtype, w2)(jax.nn.silu(gate) * up)
 
 
 class Lfm2Router(nn.Module):
@@ -206,17 +213,34 @@ class Lfm2Router(nn.Module):
 
 class Lfm2Experts(nn.Module):
     """The routed SwiGLU experts held here, stacked: ``w1``, ``w3``
-    [held, d, f], ``w2`` [held, f, d]."""
+    [held, d, f], ``w2`` [held, f, d], under ``names``."""
     held: int
     width: int
+    names: Tuple[str, str, str] = SWIGLU_NAMES
 
     @nn.compact
     def __call__(self, hidden: int):
         w1, w3 = (self.param(name, INIT, (self.held, hidden, self.width),
-                             jnp.float32) for name in ("w1", "w3"))
-        return w1, w3, self.param("w2", INIT,
+                             jnp.float32) for name in self.names[:2])
+        return w1, w3, self.param(self.names[2], INIT,
                                   (self.held, self.width, hidden),
                                   jnp.float32)
+
+
+def routed_swiglu(x, router: Lfm2Router, experts: Lfm2Experts, held, dtype):
+    """``sum_k w_k expert_k(x)`` over the experts held here as [tokens, d],
+    through ``parallel/ep.moe_dropless``; the step's load is left where
+    ``router`` says. For a module's compact ``__call__``, which makes and
+    names the two."""
+    d = x.shape[-1]
+    route, load = router(d)
+    weights = experts(d)
+    out, stats = ep.moe_dropless(
+        x.reshape(-1, d).astype(dtype), route, ep.swiglu_expert,
+        tuple(w.astype(dtype) for w in weights), held=held)
+    if load is not None:
+        load.value = stats.expert_tokens.astype(jnp.float32)
+    return out
 
 
 class Lfm2SparseMoe(nn.Module):
@@ -230,19 +254,13 @@ class Lfm2SparseMoe(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        d = x.shape[-1]
         held = self.experts_held[1] if self.experts_held else self.experts
-        route, load = Lfm2Router(
-            self.experts, self.experts_per_token, self.routed_scale,
-            self.bias_update_rate, name="gate")(d)
-        weights = Lfm2Experts(held, self.expert_dim, name="experts")(d)
-        out, stats = ep.moe_dropless(
-            x.reshape(-1, d).astype(self.dtype), route, ep.swiglu_expert,
-            tuple(w.astype(self.dtype) for w in weights),
-            held=self.experts_held)
-        if load is not None:
-            load.value = stats.expert_tokens.astype(jnp.float32)
-        return out.reshape(x.shape)
+        return routed_swiglu(
+            x, Lfm2Router(self.experts, self.experts_per_token,
+                          self.routed_scale, self.bias_update_rate,
+                          name="gate"),
+            Lfm2Experts(held, self.expert_dim, name="experts"),
+            self.experts_held, self.dtype).reshape(x.shape)
 
 
 class Lfm2Block(nn.Module):
@@ -347,11 +365,13 @@ def Lfm2Tiny(**kw) -> Lfm2MoeDecoder:
     return Lfm2MoeDecoder(**{**sizes, **kw})
 
 
-def lfm2_loss(model: Lfm2MoeDecoder, params, router_state, tokens, labels):
+def lfm2_loss(model, params, router_state, tokens, labels,
+              router=("Lfm2SparseMoe_0", "gate")):
     """Mean next-token cross-entropy, no auxiliary term: balance is the
     bias rule's. Returns ``(loss, (new router_state, aux))`` as
     ``dp.make_stateful_train_step`` takes them; ``aux["expert_tokens"]`` is
-    this step's load, float32 [sparse layers, experts]."""
+    this step's load, float32 [sparse layers, experts]. ``router`` is where
+    a block of ``model`` keeps its :class:`Lfm2Router`."""
     logits, new_state = model.apply(
         {"params": params, ROUTER_STATE: router_state}, tokens,
         mutable=[ROUTER_STATE])
@@ -359,8 +379,8 @@ def lfm2_loss(model: Lfm2MoeDecoder, params, router_state, tokens, labels):
         loss = optax.softmax_cross_entropy_with_integer_labels(
             logits, labels).mean()
     new_state = new_state.get(ROUTER_STATE, {})  # none without a sparse layer
-    # Lfm2Block_<i>, in layer order (a tree's keys come sorted as text)
+    # <Block>_<i>, in layer order (a tree's keys come sorted as text)
     blocks = sorted(new_state, key=lambda name: int(name.rsplit("_", 1)[1]))
-    loads = [new_state[b]["Lfm2SparseMoe_0"]["gate"]["load"] for b in blocks]
+    loads = [new_state[b][router[0]][router[1]]["load"] for b in blocks]
     return loss, (new_state, {"expert_tokens": jnp.stack(loads)
                               if loads else jnp.zeros((0, model.experts))})

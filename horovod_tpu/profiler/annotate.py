@@ -12,8 +12,9 @@ Two distinct mechanisms, matching where the work actually happens:
   there are; the comment above each tuple of names says what a name covers
   and which file writes it). ``moe_scope``, ``ssm_scope``,
   ``shortconv_scope``, ``attn_scope``, ``attn_part_scope``, ``mla_scope``,
-  ``mtp_scope``, ``diffusion_scope`` and ``head_scope`` are that one
-  function with its family bound. A new model writes the names that are
+  ``mtp_scope``, ``diffusion_scope``, ``head_scope``, ``outgate_scope`` and
+  ``postnorm_scope`` are that one function with its family bound, one for
+  each of the table's eleven families. A new model writes the names that are
   here (every attention operator's parts are :data:`ATTN_PART_SCOPES`,
   every head's :data:`HEAD_SCOPES`); a family of its own is for an operator
   no other model has.
@@ -115,6 +116,16 @@ MLA_SCOPES = ("mla_q_proj", "mla_kv_proj", "mla_rope", "mla_out_proj")
 MTP_SCOPES = ("mtp_merge", "mtp_block", "mtp_head")
 # The two ends of a block-diffusion objective (``models/sdar.py``).
 DIFFUSION_SCOPES = ("diffusion_noise", "diffusion_loss")
+# A gate on an attention operator's output (``models/trinity.py``):
+# ``outgate_proj`` is the gate's own projection of the layer's normed input,
+# as wide as the queries; ``outgate_mul`` its sigmoid and the product with
+# the kernels' output, between the call and ``attn_out_proj``. No attention
+# part: ``benchmark/harness/attn_parts.py``'s groups are the shared names'.
+OUTGATE_SCOPES = ("outgate_proj", "outgate_mul")
+# The norm of a branch's OUTPUT and its addition to the residual stream
+# (``models/trinity.py``: four norms a layer): ``postnorm_attn`` after the
+# attention operator, ``postnorm_ff`` after the feed-forward.
+POSTNORM_SCOPES = ("postnorm_attn", "postnorm_ff")
 # Host spans the step wrapper (``metrics.timed_step``) writes.
 STEP_SPAN = "hvd.step"
 STEP_DISPATCH_SPAN = "hvd.step.dispatch"
@@ -139,6 +150,8 @@ FAMILIES = {
     "mtp": ("multi-token-prediction", MTP_SCOPES),
     "diffusion": ("diffusion", DIFFUSION_SCOPES),
     "head": ("head", HEAD_SCOPES),
+    "outgate": ("output-gate", OUTGATE_SCOPES),
+    "postnorm": ("post-norm", POSTNORM_SCOPES),
 }
 
 
@@ -160,6 +173,8 @@ mla_scope = functools.partial(family_scope, "mla")
 mtp_scope = functools.partial(family_scope, "mtp")
 diffusion_scope = functools.partial(family_scope, "diffusion")
 head_scope = functools.partial(family_scope, "head")
+outgate_scope = functools.partial(family_scope, "outgate")
+postnorm_scope = functools.partial(family_scope, "postnorm")
 
 
 def collective_scope(name: str):

@@ -22,9 +22,10 @@ import pytest
 
 from horovod_tpu.models import (GptDecoder, JoyaiFlashTiny, Lfm2Tiny,
                                 NemotronHTiny, OlmoeDecoder, SdarTiny,
-                                SmallThinkerTiny, joyai_flash_loss, lfm2_loss,
-                                nemotron_h_loss, olmoe_loss, sdar_loss,
-                                sdar_noise, smallthinker_loss)
+                                SmallThinkerTiny, TrinityTiny,
+                                joyai_flash_loss, lfm2_loss, nemotron_h_loss,
+                                olmoe_loss, sdar_loss, sdar_noise,
+                                smallthinker_loss, trinity_loss)
 from horovod_tpu.ops import flash_attention as fa
 from horovod_tpu.profiler import annotate
 
@@ -123,6 +124,13 @@ MODELS = {
     "joyai_flash": (
         lambda: _stateful(JoyaiFlashTiny(num_layers=1), joyai_flash_loss),
         ("head_logits", "head_loss")),
+    # the gate and the post-norms are families of their own: no part
+    "trinity": (
+        lambda: _stateful(TrinityTiny(layer_types=("sliding_attention",
+                                                   "full_attention")),
+                          _next_token(trinity_loss)),
+        (*PROJECTIONS, "attn_qk_norm", "attn_rope", "attn_kernel_io",
+         "head_logits", "head_loss")),
 }
 
 

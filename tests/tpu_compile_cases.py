@@ -172,14 +172,19 @@ def _row_scatters(text):
         r"= \w+(\[[\d,]*\])\S* scatter\([^\n]*moe_", text) if "," in shape]
 
 
-def _products_by_blocks(op_names, module, layers, matrices):
+def _products_by_blocks(op_names, module, layers, matrices,
+                        recomputed=False):
     """Takes the grouped-matmul kernels out of ``op_names`` and holds them
     to a walk whose product is the kernels over live row blocks
     (``ep.share_product``): in each of ``layers`` expert layers, every call
     under ``module`` and ``moe_experts`` inside the walk's loop; the
     ``matrices`` products forward, and in the backward walk the products
     again, their transposes towards the rows and, ``_gmm_dw_kernel``, the
-    transposes towards the matrices."""
+    transposes towards the matrices. ``recomputed``: the block's
+    recomputation runs the forward walk once more, because something after
+    the experts keeps their output alive in backward (a norm of the
+    feed-forward's output, ``models/trinity.py``); where nothing does, the
+    compiler drops the recomputed walk."""
     products, towards = op_names.pop("_gmm_kernel"), \
         op_names.pop("_gmm_dw_kernel")
     for name in products + towards:
@@ -187,9 +192,36 @@ def _products_by_blocks(op_names, module, layers, matrices):
             r"while/body/[\w(]*moe_experts", name), name
     backward = ["transpose(jvp(" in name for name in products]
     assert backward.count(False) == matrices * layers
-    assert backward.count(True) == 2 * matrices * layers
+    assert backward.count(True) == (3 if recomputed else 2) * matrices \
+        * layers
+    assert sum("rematted_computation" in name for name in products) == \
+        (matrices * layers if recomputed else 0)
     assert len(towards) == matrices * layers
     assert all("transpose(jvp(" in name for name in towards)
+
+
+def _scopes_hold(text, scopes, layers):
+    """Holds a compiled step's ``op_name``s to a family of scopes of its own
+    (``profiler/annotate.FAMILIES``): each of ``scopes`` is named in every
+    one of the ``layers`` blocks forward, in the block's recomputation and
+    in the transposed pass, and no ``op_name`` holds two of them or one of
+    them beside an attention part."""
+    from horovod_tpu.profiler import annotate
+    mine = re.compile(r"\b(%s)\b" % "|".join(scopes))
+    part = re.compile(r"\b(%s)\b" % "|".join(annotate.ATTN_PART_SCOPES))
+    found = {}
+    for name in set(re.findall(r'op_name="([^"]*)"', text)):
+        named = mine.findall(name)
+        assert len(set(named)) <= 1 and not (named and part.search(name)), \
+            name
+        if named:
+            way = "recomputed" if "rematted_computation" in name else \
+                "backward" if "transpose(" in name else "forward"
+            block = re.search(r"Block_(\d+)", name).group(1)
+            found.setdefault((named[0], way), set()).add(block)
+    assert found == {(scope, way): {str(i) for i in range(layers)}
+                     for scope in scopes
+                     for way in ("forward", "recomputed", "backward")}
 
 
 def _compiled_cell(topo, workload):
