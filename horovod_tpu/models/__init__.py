@@ -1,4 +1,9 @@
 from horovod_tpu.models.mnist import MnistConvNet  # noqa: F401
+# the operator the losses below call
+from horovod_tpu.ops.head_loss import (  # noqa: F401
+    cross_entropy,
+    head_cross_entropy,
+)
 from horovod_tpu.models.gpt import (  # noqa: F401
     GptDecoder,
     GptMedium,

@@ -100,13 +100,13 @@ from typing import Any, Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-import optax
 
 from horovod_tpu.models.lfm2 import (ROUTER_STATE, Lfm2Experts, Lfm2Mlp,
                                      Lfm2Router, linear)
 from horovod_tpu.models.olmoe import INIT
 from horovod_tpu.models.smallthinker import REMAT_POLICIES
 from horovod_tpu.ops.flash_attention import attention
+from horovod_tpu.ops.head_loss import head_cross_entropy
 from horovod_tpu.ops.rotary import rotary
 from horovod_tpu.parallel import ep
 from horovod_tpu.profiler.annotate import (attn_scope, head_scope, mla_scope,
@@ -271,7 +271,9 @@ class JoyaiFlashDecoder(nn.Module):
     """Causal LM: embedding -> ``num_layers`` blocks -> RMSNorm -> head, and
     behind them the multi-token-prediction module on the same embedding and
     head. Returns float32 ``(logits, mtp_logits)`` [B, T, vocab] each
-    (``mtp_logits`` None with ``mtp_layers`` 0); apply with
+    (``mtp_logits`` None with ``mtp_layers`` 0), or with ``head=False`` the
+    two normed hidden states [B, T, hidden] they are the products of
+    (:func:`joyai_flash_loss` runs the head itself); apply with
     ``mutable=["router_state"]`` to train the expert biases."""
 
     num_layers: int = 40
@@ -300,7 +302,7 @@ class JoyaiFlashDecoder(nn.Module):
     dtype: Any = jnp.bfloat16
 
     @nn.compact
-    def __call__(self, tokens):
+    def __call__(self, tokens, head: bool = True):
         if self.mtp_layers not in (0, 1):
             raise ValueError(
                 f"mtp_layers {self.mtp_layers}: the published configuration "
@@ -326,7 +328,7 @@ class JoyaiFlashDecoder(nn.Module):
         embed = nn.Embed(self.vocab, self.hidden, dtype=jnp.float32,
                          embedding_init=INIT, name="embed_tokens")
         # bf16 inputs, float32 out of the accumulators: no bf16 logits
-        head = nn.Dense(
+        head_of = nn.Dense(
             self.vocab, use_bias=False, dtype=self.dtype, kernel_init=INIT,
             dot_general=functools.partial(
                 jax.lax.dot_general, preferred_element_type=jnp.float32),
@@ -337,19 +339,21 @@ class JoyaiFlashDecoder(nn.Module):
             x = block(attn, dense if i < self.first_k_dense else sparse,
                       self.eps, self.dtype, name=f"JoyaiBlock_{i}")(x)
         g = nn.RMSNorm(epsilon=self.eps, dtype=self.dtype, name="norm")(x)
+        z = None
+        if self.mtp_layers:
+            with mtp_scope("mtp_merge"):
+                next_embedding = embed(jnp.roll(tokens, -1, axis=1)) \
+                    .astype(self.dtype)
+            z = JoyaiMtp(functools.partial(block, attn, sparse, self.eps,
+                                           self.dtype),
+                         self.eps, self.dtype, name="JoyaiMtp_0")(
+                             g, next_embedding)
+        if not head:
+            return g, z
         with head_scope("head_logits"):
-            logits = head(g)
-        if not self.mtp_layers:
-            return logits, None
-        with mtp_scope("mtp_merge"):
-            next_embedding = embed(jnp.roll(tokens, -1, axis=1)) \
-                .astype(self.dtype)
-        z = JoyaiMtp(functools.partial(block, attn, sparse, self.eps,
-                                       self.dtype),
-                     self.eps, self.dtype, name="JoyaiMtp_0")(
-                         g, next_embedding)
+            logits = head_of(g)
         with mtp_scope("mtp_head"):
-            return logits, head(z)
+            return logits, None if z is None else head_of(z)
 
 
 # The decoder's defaults ARE the published geometry (50.19 B parameters with
@@ -367,15 +371,7 @@ def JoyaiFlashTiny(**kw) -> JoyaiFlashDecoder:
     return JoyaiFlashDecoder(**{**sizes, **kw})
 
 
-def _masked_mean_ce(logits, tokens, ahead: int):
-    """Mean cross-entropy of ``logits_i`` against ``t_{i+ahead}`` over the
-    ``T - ahead`` positions that have such a token, all T rows computed
-    alike and the rest masked (no slice of the logits)."""
-    t = tokens.shape[1]
-    ce = optax.softmax_cross_entropy_with_integer_labels(
-        logits, jnp.roll(tokens, -ahead, axis=1))
-    return jnp.where(jnp.arange(t) < t - ahead, ce, 0.0).sum() \
-        / (tokens.shape[0] * (t - ahead))
+MTP_HEAD = (functools.partial(mtp_scope, "mtp_head"),) * 2
 
 
 def joyai_flash_loss(model: JoyaiFlashDecoder, params, router_state, tokens):
@@ -385,16 +381,31 @@ def joyai_flash_loss(model: JoyaiFlashDecoder, params, router_state, tokens):
     as ``dp.make_stateful_train_step`` takes them; ``aux["expert_tokens"]``
     is this step's load, float32 [sparse layers, experts], the stack's in
     layer order and the module's last; ``aux["next_token_loss"]`` and
-    ``aux["mtp_loss"]`` the two means."""
-    (logits, mtp_logits), new_state = model.apply(
-        {"params": params, ROUTER_STATE: router_state}, tokens,
+    ``aux["mtp_loss"]`` the two means.
+
+    Door A of ``ops/head_loss.py``, twice over the one shared ``lm_head``:
+    the loss holds the model and its parameters, so it stops the decoder
+    before its head and hands each hidden state and the kernel to
+    ``head_cross_entropy`` (the module's under ``mtp_head``). A head's mean
+    is of ``logits_i`` against ``t_{i+ahead}`` over the ``T - ahead``
+    positions that have such a token: all T rows are computed alike and the
+    rest weigh 0 (no slice of a hidden state)."""
+    (hidden, mtp_hidden), new_state = model.apply(
+        {"params": params, ROUTER_STATE: router_state}, tokens, head=False,
         mutable=[ROUTER_STATE])
-    with head_scope("head_loss"):
-        loss = next_token = _masked_mean_ce(logits, tokens, 1)
+    b, t = tokens.shape
+
+    def mean_ce(hidden, ahead: int, *scopes):
+        has_label = jnp.broadcast_to(jnp.arange(t) < t - ahead, (b, t))
+        return head_cross_entropy(
+            hidden.reshape(b * t, -1), params["lm_head"]["kernel"],
+            jnp.roll(tokens, -ahead, axis=1).reshape(b * t),
+            has_label.reshape(b * t).astype(jnp.float32), *scopes) \
+            / (b * (t - ahead))
+    loss = next_token = mean_ce(hidden, 1)
     aux = {"next_token_loss": next_token}
-    if mtp_logits is not None:
-        with mtp_scope("mtp_head"):
-            aux["mtp_loss"] = _masked_mean_ce(mtp_logits, tokens, 2)
+    if mtp_hidden is not None:
+        aux["mtp_loss"] = mean_ce(mtp_hidden, 2, MTP_HEAD)
         loss = loss + model.mtp_lambda * aux["mtp_loss"]
     new_state = new_state.get(ROUTER_STATE, {})  # none without a sparse layer
     aux["expert_tokens"] = jnp.stack(_expert_loads(new_state)) \

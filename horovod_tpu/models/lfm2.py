@@ -84,11 +84,11 @@ from typing import Any, Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-import optax
 
 from horovod_tpu.models.olmoe import INIT
 from horovod_tpu.models.smallthinker import REMAT_POLICIES
 from horovod_tpu.ops.flash_attention import attention
+from horovod_tpu.ops.head_loss import head_cross_entropy
 from horovod_tpu.ops.rotary import rotary
 from horovod_tpu.ops.short_conv import gated_short_conv
 from horovod_tpu.parallel import ep
@@ -282,7 +282,9 @@ class Lfm2Block(nn.Module):
 class Lfm2MoeDecoder(nn.Module):
     """Causal LM: embedding -> one block a layer of ``layer_types`` ->
     RMSNorm -> the embedding's rows as the head. Returns float32 logits
-    [B, T, vocab]; apply with ``mutable=["router_state"]`` to train the
+    [B, T, vocab], or with ``head=False`` the normed hidden state [B, T,
+    hidden] they are the product of (a loss that runs the head itself,
+    :func:`lfm2_loss`); apply with ``mutable=["router_state"]`` to train the
     expert biases."""
 
     layer_types: Tuple[str, ...] = LFM2_8B_A1B_LAYER_TYPES
@@ -306,7 +308,7 @@ class Lfm2MoeDecoder(nn.Module):
     dtype: Any = jnp.bfloat16
 
     @nn.compact
-    def __call__(self, tokens):
+    def __call__(self, tokens, head: bool = True):
         unknown = set(self.layer_types) - set(OPERATORS)
         if unknown or not self.layer_types:
             raise ValueError(f"a layer's operator is one of {OPERATORS}; "
@@ -341,6 +343,8 @@ class Lfm2MoeDecoder(nn.Module):
                       self.eps, self.dtype, name=f"Lfm2Block_{i}")(x)
         x = nn.RMSNorm(epsilon=self.eps, dtype=self.dtype,
                        name="embedding_norm")(x)
+        if not head:
+            return x
         # bf16 inputs, float32 out of the accumulators: no bf16 logits
         with head_scope("head_logits"):
             return jnp.einsum("btd,vd->btv", x,
@@ -366,18 +370,26 @@ def Lfm2Tiny(**kw) -> Lfm2MoeDecoder:
 
 
 def lfm2_loss(model, params, router_state, tokens, labels,
-              router=("Lfm2SparseMoe_0", "gate")):
+              router=("Lfm2SparseMoe_0", "gate"),
+              head=("embed_tokens", "embedding")):
     """Mean next-token cross-entropy, no auxiliary term: balance is the
     bias rule's. Returns ``(loss, (new router_state, aux))`` as
     ``dp.make_stateful_train_step`` takes them; ``aux["expert_tokens"]`` is
     this step's load, float32 [sparse layers, experts]. ``router`` is where
-    a block of ``model`` keeps its :class:`Lfm2Router`."""
-    logits, new_state = model.apply(
-        {"params": params, ROUTER_STATE: router_state}, tokens,
+    a block of ``model`` keeps its :class:`Lfm2Router`, ``head`` where
+    ``params`` keep the head's matrix.
+
+    Door A of ``ops/head_loss.py``: the loss holds the model and its
+    parameters, so it stops the decoder before its head and hands the hidden
+    state and the matrix to ``head_cross_entropy``; no [B T, vocab] array
+    stands between the forward and the backward pass."""
+    hidden, new_state = model.apply(
+        {"params": params, ROUTER_STATE: router_state}, tokens, head=False,
         mutable=[ROUTER_STATE])
-    with head_scope("head_loss"):
-        loss = optax.softmax_cross_entropy_with_integer_labels(
-            logits, labels).mean()
+    rows = labels.size
+    loss = head_cross_entropy(
+        hidden.reshape(rows, -1), params[head[0]][head[1]],
+        labels.reshape(rows), jnp.ones(rows, jnp.float32)) / rows
     new_state = new_state.get(ROUTER_STATE, {})  # none without a sparse layer
     # <Block>_<i>, in layer order (a tree's keys come sorted as text)
     blocks = sorted(new_state, key=lambda name: int(name.rsplit("_", 1)[1]))

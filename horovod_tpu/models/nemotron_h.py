@@ -76,9 +76,9 @@ from typing import Any, Callable, Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-import optax
 
 from horovod_tpu.ops.flash_attention import attention
+from horovod_tpu.ops.head_loss import head_cross_entropy
 from horovod_tpu.ops.ssd import ssd_scan
 from horovod_tpu.ops.ssm_ends import causal_conv_silu, gated_group_norm
 from horovod_tpu.parallel import ep
@@ -320,7 +320,9 @@ class NemotronHBlock(nn.Module):
 
 class NemotronHDecoder(nn.Module):
     """Causal LM: embedding -> one block a character of ``pattern`` ->
-    RMSNorm -> untied head. Returns float32 logits [B, T, vocab]; apply with
+    RMSNorm -> untied head. Returns float32 logits [B, T, vocab], or with
+    ``head=False`` the normed hidden state [B, T, hidden] they are the
+    product of (:func:`nemotron_h_loss` runs the head itself); apply with
     ``mutable=["router_state"]`` to train the correction biases."""
 
     pattern: str = "MEMEM*EME"
@@ -348,7 +350,7 @@ class NemotronHDecoder(nn.Module):
     dtype: Any = jnp.bfloat16
 
     @nn.compact
-    def __call__(self, tokens):
+    def __call__(self, tokens, head: bool = True):
         unknown = set(self.pattern + self.remat) - set(KINDS)
         if unknown or not self.pattern:
             raise ValueError(
@@ -377,6 +379,8 @@ class NemotronHDecoder(nn.Module):
             x = block(mixers[kind], self.eps, self.dtype,
                       name=f"NemotronHBlock_{i}")(x)
         x = nn.RMSNorm(epsilon=self.eps, dtype=self.dtype, name="norm_f")(x)
+        if not head:
+            return x
         # bf16 inputs, float32 out of the accumulators: no bf16 logits
         with head_scope("head_logits"):
             return nn.Dense(
@@ -410,13 +414,19 @@ def nemotron_h_loss(model: NemotronHDecoder, params, router_state, tokens,
     """Mean next-token cross-entropy, no auxiliary term: balance is the
     bias rule's. Returns ``(loss, (new router_state, aux))`` as
     ``dp.make_stateful_train_step`` takes them; ``aux["expert_tokens"]`` is
-    this step's load, float32 [expert layers, experts]."""
-    logits, new_state = model.apply(
-        {"params": params, ROUTER_STATE: router_state}, tokens,
+    this step's load, float32 [expert layers, experts].
+
+    Door A of ``ops/head_loss.py``: the loss holds the model and its
+    parameters, so it stops the decoder before its head and hands the hidden
+    state and ``LmHead``'s kernel to ``head_cross_entropy``; no [B T, vocab]
+    array stands between the forward and the backward pass."""
+    hidden, new_state = model.apply(
+        {"params": params, ROUTER_STATE: router_state}, tokens, head=False,
         mutable=[ROUTER_STATE])
-    with head_scope("head_loss"):
-        loss = optax.softmax_cross_entropy_with_integer_labels(
-            logits, labels).mean()
+    rows = labels.size
+    loss = head_cross_entropy(
+        hidden.reshape(rows, -1), params["LmHead"]["kernel"],
+        labels.reshape(rows), jnp.ones(rows, jnp.float32)) / rows
     new_state = new_state.get(ROUTER_STATE, {})  # none without an E layer
     # NemotronHBlock_<i>, in layer order (a tree's keys come sorted as text)
     blocks = sorted(new_state, key=lambda name: int(name.rsplit("_", 1)[1]))

@@ -69,6 +69,7 @@ import jax.numpy as jnp
 from horovod_tpu.models.olmoe import INIT
 from horovod_tpu.models.smallthinker import REMAT_POLICIES
 from horovod_tpu.ops.flash_attention import blockdiff_attention
+from horovod_tpu.ops.head_loss import cross_entropy
 from horovod_tpu.ops.rotary import rotary
 from horovod_tpu.parallel import ep
 from horovod_tpu.profiler.annotate import (attn_part_scope, attn_scope,
@@ -288,16 +289,16 @@ def sdar_loss(logits: jax.Array, batch: dict, stats: ep.MoeStats):
     :func:`sdar_noise`'s ``masked`` and ``weight``. No auxiliary term (the
     published config names none). Returns (loss, aux) as
     ``dp.make_train_step`` takes them: ``expert_tokens`` is the step's load,
-    int32 [layers, E], ``masked_tokens`` the positions the loss is over."""
+    int32 [layers, E], ``masked_tokens`` the positions the loss is over.
+
+    Door B of ``ops/head_loss.py``: the loss is handed the logits (its
+    caller runs the model); the schedule's weight at the masked positions
+    and 0 elsewhere are the operator's row weights."""
     with diffusion_scope("diffusion_loss"):
-        # the label's logit through a mask, not a gather: its backward is a
-        # select in the softmax's own pass and no scatter into a second
-        # [B, L, vocab] array (1.2 GB of temporaries at 8192 x 18 992)
-        labels = batch["x0"][..., None] == jnp.arange(
-            logits.shape[-1], dtype=batch["x0"].dtype)
-        ce = jax.nn.logsumexp(logits, axis=-1) - jnp.sum(
-            jnp.where(labels, logits, 0.0), axis=-1)
-        loss = jnp.where(batch["masked"], ce * batch["weight"], 0.0).mean()
+        loss = cross_entropy(
+            logits, batch["x0"],
+            jnp.where(batch["masked"], batch["weight"], 0.0)) \
+            / batch["masked"].size
         return loss, {"expert_tokens": stats.expert_tokens,
                       "masked_tokens": jnp.sum(batch["masked"],
                                                dtype=jnp.int32)}

@@ -62,10 +62,10 @@ from typing import Any, Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-import optax
 
 from horovod_tpu.models.olmoe import INIT
 from horovod_tpu.ops.flash_attention import FLASH_RESIDUALS, attention
+from horovod_tpu.ops.head_loss import cross_entropy
 from horovod_tpu.ops.rotary import rotary
 from horovod_tpu.parallel import ep
 from horovod_tpu.profiler.annotate import (attn_part_scope, attn_scope,
@@ -277,8 +277,13 @@ def smallthinker_loss(logits: jax.Array, labels: jax.Array,
                       stats: ep.MoeStats):
     """Mean next-token cross-entropy and no auxiliary term (the published
     config names none). Returns (loss, aux) as ``dp.make_train_step`` takes
-    them; ``aux["expert_tokens"]`` is the step's load, int32 [layers, E]."""
+    them; ``aux["expert_tokens"]`` is the step's load, int32 [layers, E].
+
+    Door B of ``ops/head_loss.py``: the loss is handed the logits (its
+    caller runs the model), so the operator is the row function over the
+    whole array."""
     with head_scope("head_loss"):
-        loss = optax.softmax_cross_entropy_with_integer_labels(
-            logits, labels).mean()
+        loss = cross_entropy(logits, labels, jnp.ones(labels.shape,
+                                                      jnp.float32)) \
+            / labels.size
     return loss, {"expert_tokens": stats.expert_tokens}

@@ -185,8 +185,10 @@ class TrinityBlock(nn.Module):
 
 class TrinityDecoder(nn.Module):
     """Causal LM: scaled embedding -> one block a layer of ``layer_types``
-    -> RMSNorm -> untied head. Returns float32 logits [B, T, vocab]; apply
-    with ``mutable=["router_state"]`` to train the expert biases."""
+    -> RMSNorm -> untied head. Returns float32 logits [B, T, vocab], or with
+    ``head=False`` the normed hidden state [B, T, hidden] they are the
+    product of (:data:`trinity_loss` runs the head itself); apply with
+    ``mutable=["router_state"]`` to train the expert biases."""
 
     layer_types: Tuple[str, ...] = PERIOD * 8
     num_dense_layers: int = 2
@@ -210,7 +212,7 @@ class TrinityDecoder(nn.Module):
     dtype: Any = jnp.bfloat16
 
     @nn.compact
-    def __call__(self, tokens):
+    def __call__(self, tokens, head: bool = True):
         unknown = set(self.layer_types) - set(LAYER_TYPES)
         if unknown or not self.layer_types:
             raise ValueError(f"a layer's attention is one of {LAYER_TYPES}; "
@@ -246,6 +248,8 @@ class TrinityDecoder(nn.Module):
                       dense if i < self.num_dense_layers else sparse,
                       self.eps, self.dtype, name=f"TrinityBlock_{i}")(x)
         x = nn.RMSNorm(epsilon=self.eps, dtype=self.dtype, name="norm")(x)
+        if not head:
+            return x
         # bf16 inputs, float32 out of the accumulators: no bf16 logits
         with head_scope("head_logits"):
             return nn.Dense(
@@ -273,5 +277,7 @@ def TrinityTiny(**kw) -> TrinityDecoder:
 # Mean next-token cross-entropy, no auxiliary term (balance is the bias
 # rule's): ``(loss, (new router_state, {"expert_tokens": this step's load,
 # float32 [sparse layers, experts]}))`` as ``dp.make_stateful_train_step``
-# takes them.
-trinity_loss = functools.partial(lfm2_loss, router=("TrinityMoE_0", "router"))
+# takes them. ``lfm2_loss``'s door A of ``ops/head_loss.py`` on the untied
+# head's kernel [hidden, vocab].
+trinity_loss = functools.partial(lfm2_loss, router=("TrinityMoE_0", "router"),
+                                 head=("lm_head", "kernel"))

@@ -31,7 +31,8 @@ from horovod_tpu.profiler import annotate
 
 PARTS = annotate.ATTN_PART_SCOPES + annotate.HEAD_SCOPES
 PART = re.compile(r"\b(%s)\b" % "|".join(PARTS))
-NAME_STACK = re.compile(r'"([^"]*(?:attn_|head_)[^"]*)"')
+# a name stack, not a source file's path (``ops/head_loss.py`` is one)
+NAME_STACK = re.compile(r'"(?!/)([^"]*(?:attn_|head_)[^"]*)"')
 PROJECTIONS = ("attn_qkv_proj", "attn_out_proj")
 BATCH, SEQ = 2, 32
 

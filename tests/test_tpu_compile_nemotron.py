@@ -23,7 +23,9 @@ def test_nemotron_cell_fits_one_v5e_at_full_size(nemotron_cell):
     job, traffic, compiled = nemotron_cell
     memory = compiled.memory_analysis()
     total = memory.argument_size_in_bytes + memory.temp_size_in_bytes
-    assert 10.67e9 < total < 15.0e9, total
+    # full size: 8.0 GB of arguments, and the step's temporaries are 2.66 GB
+    # since the head walks its rows in chunks (``ops/head_loss.py``)
+    assert 10.5e9 < total < 15.0e9, total
     # 667 M parameters and AdamW's moments at 12 bytes
     assert memory.argument_size_in_bytes == pytest.approx(8.0e9, rel=2e-3)
     recorded = traffic["memory_analysis"]
